@@ -5,7 +5,7 @@
    (checkpoint + train-dispatch wall): the report Reliability section's
    exact formula, with the async leg charging only the ON-PATH cost
    (device->host snapshot + bounded-queue enqueue). Trials interleave
-   sync/async so the pair is same-window (the BENCH_r0x protocol), and
+   sync/async so the pair is same-window (bench.py's slope protocol), and
    the async leg drains its writer before the clock stops — nothing
    off-path is hidden outside the window.
 
@@ -193,10 +193,13 @@ def main(argv=None):
         "bench_version": BENCH_VERSION,
         "created": time.strftime("%Y-%m-%d %H:%M:%S"),
         "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "n_devices": len(jax.devices()),
         "cpu_fallback_caveat": (
             "emulated CPU devices: machinery + relative ratios, not chip "
             "performance"
+            if jax.devices()[0].platform == "cpu"
+            else None
         ),
         "protocol": (
             "same-window: sync/async legs interleaved per trial, per-leg "

@@ -4,7 +4,7 @@ config, written as MPMD_r01.json beside the other bench records.
 
 1. **Epoch pair** — the same training epochs dispatched through the
    lockstep SPMD program and the MPMD per-stage runtime, interleaved per
-   trial (the BENCH_r0x protocol), per-leg minima. Both runtimes train
+   trial (bench.py's slope protocol), per-leg minima. Both runtimes train
    the identical math (weights hash-equal — the in-suite lattice and
    ``make mpmd-smoke`` pin that bitwise), so the wall ratio is pure
    runtime cost.
@@ -242,12 +242,16 @@ def main(argv=None):
             "dp": 1, "pp": 4, "tp": 1, "schedule": "gpipe",
             "global_batch_size": 128, "mubatches": 4,
             "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
         },
         "cpu_fallback_caveat": (
             "emulated CPU devices: machinery + relative ratios, not chip "
             "performance; the dispatch-overhead share is the CPU-honest "
             "number (it measures the host-issue wall the MPMD refactor "
             "exists to remove)"
+            if jax.devices()[0].platform == "cpu"
+            else None
         ),
         "protocol": (
             "same-window: lockstep/mpmd epochs interleaved per trial, "

@@ -16,7 +16,7 @@ Two layouts, the dp2 flagship and the dp2 x pp2 composition:
    report's OOM-forecast row rests on.
 
 2. **Epoch pair** — the stages' training epochs interleaved per trial
-   (the BENCH_r0x protocol), per-leg minima. On CPU the ZeRO collectives
+   (bench.py's slope protocol), per-leg minima. On CPU the ZeRO collectives
    are op-issue-bound host work, so the walls show the stages' COST here,
    not their chip behavior — recorded with that caveat, the memory ladder
    is the headline.
@@ -183,6 +183,8 @@ def main(argv=None):
             "model": args.model, "optimizer": args.optimizer,
             "global_batch_size": 128, "mubatches": 4, "trials": args.trials,
             "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
         },
         "cpu_fallback_caveat": (
             "emulated CPU devices: the memory ladder is XLA's own "
@@ -190,6 +192,8 @@ def main(argv=None):
             "quantity); the walls are op-issue-bound host dispatch, not "
             "chip behavior — ZeRO-3's per-tick gathers COST wall time "
             "here, the stage is a memory trade"
+            if jax.devices()[0].platform == "cpu"
+            else None
         ),
         "protocol": (
             "same-window: the three stages' epochs interleaved per trial, "

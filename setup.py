@@ -6,8 +6,7 @@ setup(
     description="TPU-native distributed-training framework (DP x PP on a JAX mesh)",
     packages=find_packages(include=["shallowspeed_tpu", "shallowspeed_tpu.*"]),
     python_requires=">=3.10",
-    # 0.4.37 is the oldest runtime the compat layer supports
-    # (parallel/compat.py maps jax.shard_map/check_vma onto the
-    # jax.experimental spelling; multihost probes is_initialized)
-    install_requires=["jax>=0.4.37", "numpy"],
+    # written and tested against exactly one installation: jax/jaxlib 0.9.0
+    # (libtpu 0.0.34 on the chip); there are no version branches in the code
+    install_requires=["jax>=0.9.0", "numpy"],
 )

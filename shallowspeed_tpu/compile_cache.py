@@ -1,0 +1,37 @@
+"""Where JAX's persistent compilation cache lives — one policy, one place.
+
+Every entry point that compiles (``train.py``, ``python -m
+shallowspeed_tpu.serving``, ``bench.py``, ``chip_smoke.py``,
+``tests/conftest.py``) calls ``enable_compile_cache()`` before its first
+compile, so a second process on the same machine loads executables instead of
+rebuilding them. The directory is part of the cache key, so it has to be the
+same path every time: ``JAX_COMPILATION_CACHE_DIR`` when the caller set it
+(JAX reads that variable itself — nothing is set here), otherwise
+``.jax_cache`` beside the package, resolved from this file's own location and
+never from the working directory, a temporary name, a pid or the clock.
+
+This is JAX's own store and is unrelated to ``aot_cache.py`` /
+``--aot-cache`` (an opt-in, separately keyed executable store).
+"""
+
+import os
+from pathlib import Path
+
+import jax
+
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on and return the directory in effect.
+
+    Thresholds: cache every program, however small or quick. A training or
+    serving process compiles dozens of sub-second helper programs next to
+    the one big epoch program; at the default thresholds (1 s, and a
+    minimum entry size) they would be rebuilt by every process.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir

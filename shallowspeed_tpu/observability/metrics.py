@@ -18,8 +18,8 @@ Three recorders share one surface:
                       ``SCHEMA_VERSION`` and stamped both in the header
                       record and in every record's ``"v"`` field, so a
                       consumer can hard-fail on records it doesn't
-                      understand instead of misreading them (the BENCH_r0x
-                      lesson: unlabeled records cost more than no records).
+                      understand instead of misreading them (unlabeled
+                      records cost more than no records).
 
 Record shapes (all lines share ``v``/``ts``/``kind``/``name``):
 
@@ -735,8 +735,8 @@ def _shard_path(path):
     append target), the path unchanged otherwise — including when jax is
     absent or uninitialized (the sink must not force a jax dependency).
 
-    The probe checks the DISTRIBUTED runtime state first (multihost
-    compat helper) and only asks ``jax.process_count()`` — which
+    The probe checks the DISTRIBUTED runtime state first
+    (``multihost._distributed_is_initialized``) and only asks ``jax.process_count()`` — which
     initializes the backend as a side effect — once distributed is known
     to be up. Consequence: construct the sink AFTER
     ``jax.distributed.initialize()`` / ``parallel.multihost.initialize()``

@@ -86,7 +86,7 @@ human or a bench gate actually asks of a run:
 
 ``--baseline`` compares throughput against another run's JSONL or a
 bench-style JSON record (``{"value": ..., "unit": "samples/s"}``, or a
-tpu_capture artifact's ``headline_best_sps``). A regression beyond
+capture artifact's ``headline_best_sps``). A regression beyond
 ``--threshold`` (default 10%) exits **2** — the CI/bench gate contract;
 malformed inputs exit 1; a clean report exits 0.
 """
@@ -956,7 +956,8 @@ def baseline_throughput(path):
     reason)``. ``.jsonl`` is another metrics stream (same steady-state
     rules; multihost shard names/globs like ``run.jsonl.p*`` count too);
     ``.json`` accepts a bench record (``value`` + samples/s unit)
-    or a tpu_capture artifact (``headline_best_sps``)."""
+    or a capture artifact (``headline_best_sps``, the shape of
+    TPU_CAPTURE_r02_runF.json)."""
     p = Path(path)
     if p.suffix == ".jsonl" or ".jsonl." in p.name:
         base = build_report(read_jsonl(p), source=str(p))

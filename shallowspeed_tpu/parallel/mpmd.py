@@ -56,12 +56,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from shallowspeed_tpu import ops
 from shallowspeed_tpu.parallel import executor as E
-from shallowspeed_tpu.parallel.compat import shard_map
 from shallowspeed_tpu.parallel.lowering import (
     OP_BWD,
     OP_BWD_W,

@@ -35,19 +35,10 @@ import jax
 
 
 def _distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a fallback for jax 0.4.x,
-    where the predicate doesn't exist yet: the distributed client handle on
-    ``jax._src.distributed.global_state`` (not re-exported at
-    ``jax.distributed`` on those versions) is the same signal that function
-    reads."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src.distributed import global_state
-    except ImportError:  # pragma: no cover - neither API: assume fresh
-        return False
-    return getattr(global_state, "client", None) is not None
+    """Whether ``jax.distributed`` is up — its own predicate, behind one
+    name so callers that must not initialize a backend (the metrics sink's
+    shard probe) and the tests share a seam."""
+    return bool(jax.distributed.is_initialized())
 
 
 def _reset_half_initialized_state():

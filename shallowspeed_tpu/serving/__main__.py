@@ -267,6 +267,7 @@ def main(argv=None):
         return _fleet_main(args)
 
     from shallowspeed_tpu.api import TrainingSession
+    from shallowspeed_tpu.compile_cache import enable_compile_cache
     from shallowspeed_tpu.observability import JsonlMetrics
     from shallowspeed_tpu.serving.engine import ServingEngine
     from shallowspeed_tpu.serving.loadgen import (
@@ -276,6 +277,7 @@ def main(argv=None):
         run_open_loop,
     )
 
+    enable_compile_cache()
     metrics = JsonlMetrics(args.metrics_out) if args.metrics_out else None
     session = TrainingSession(
         dp=args.dp,

@@ -54,7 +54,6 @@ zero chaos flaps) are the machine-checked gate.
 import argparse
 import json
 import math
-import os
 import sys
 
 from shallowspeed_tpu.observability import slo
@@ -536,14 +535,14 @@ def main(argv=None):
         raise SystemExit("need --knee-from SWEEP_JSON or --knee-rps")
     if args.slo_ms is None:
         raise SystemExit("need --slo-ms (or a sweep record that carries it)")
-    if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-        caveats.append(
-            "CPU fallback: replica workers run the JAX CPU backend — "
-            "absolute rates/latencies are machine-specific; the "
-            "scoreboard's comparisons (static vs autoscaled vs oracle) "
-            "replay the identical seeded trace, which is what the "
-            "verdicts gate on"
-        )
+    caveats.append(
+        "CPU replicas: the fleet's workers always run the JAX CPU backend "
+        "(fleet.require_cpu_host) — absolute rates/latencies are "
+        "machine-specific and say nothing about an accelerator; the "
+        "scoreboard's comparisons (static vs autoscaled vs oracle) "
+        "replay the identical seeded trace, which is what the "
+        "verdicts gate on"
+    )
     if args.dispatch_floor_ms:
         caveats.append(
             f"dispatch_floor_ms={args.dispatch_floor_ms:g}: replica "

@@ -402,20 +402,22 @@ def test_epoch_kernel_matches_fused_via_api(data_dir):
     assert runs[False][1] == runs[True][1]
 
 
-def test_adam_epoch_kernel_checkpoint_resume_cross_layout(data_dir, tmp_path):
-    """Optimizer state PRODUCED BY the epoch kernel (adam's m/v mirrors +
-    the step counter advanced inside the kernel) must ride the checkpoint
+def test_momentum_epoch_kernel_checkpoint_resume_cross_layout(data_dir, tmp_path):
+    """Optimizer state PRODUCED BY the epoch kernel (momentum's velocity
+    mirror, advanced inside the kernel) must ride the checkpoint
     protocol like scan-produced state: resuming an interrupted kernel run
     reproduces the uninterrupted trajectory bit-for-bit, and the same
     checkpoint resumes onto a DP x PP mesh."""
-    kw = dict(optimizer="adam", lr=2e-4, fuse_mubatches=True, epoch_kernel=True)
+    kw = dict(
+        optimizer="momentum", lr=1e-3, fuse_mubatches=True, epoch_kernel=True
+    )
     ref = _session(data_dir, **kw)
     ref.train_epoch()
     ref.train_epoch()
 
     run = _session(data_dir, **kw)
     run.train_epoch()
-    ck = tmp_path / "adam_kernel.npz"
+    ck = tmp_path / "momentum_kernel.npz"
     run.save(ck)
     resumed = _session(data_dir, resume=ck, **kw)
     resumed.train_epoch()
@@ -423,7 +425,7 @@ def test_adam_epoch_kernel_checkpoint_resume_cross_layout(data_dir, tmp_path):
 
     # cross-layout: the kernel-trained state stacks onto a mesh session
     mesh = _session(
-        data_dir, optimizer="adam", lr=2e-4, dp=2, pp=2, schedule="gpipe",
+        data_dir, optimizer="momentum", lr=1e-3, dp=2, pp=2, schedule="gpipe",
         resume=ck,
     )
     mesh.train_epoch()
@@ -473,11 +475,13 @@ def test_run_kernel_api_validation(data_dir):
 
 
 def test_run_kernel_state_rides_checkpoint_protocol(data_dir, tmp_path):
-    """Optimizer state produced INSIDE the whole-run kernel (adam's m/v
-    mirrors + step counter advanced across a multi-epoch grid) must ride
+    """Optimizer state produced INSIDE the whole-run kernel (momentum's
+    velocity mirror advanced across a multi-epoch grid) must ride
     the checkpoint protocol: save after a 2-epoch one-op run, resume, and
     land bit-for-bit on the uninterrupted 4-epoch one-op run."""
-    kw = dict(optimizer="adam", lr=2e-4, fuse_mubatches=True, run_kernel=True)
+    kw = dict(
+        optimizer="momentum", lr=1e-3, fuse_mubatches=True, run_kernel=True
+    )
     ref = _session(data_dir, **kw)
     ref.train_run(4, with_eval=False)
 

@@ -17,9 +17,8 @@ import pytest
 
 from shallowspeed_tpu import model as Mo
 from shallowspeed_tpu.parallel import gradsync
-from shallowspeed_tpu.parallel.compat import shard_map
 from shallowspeed_tpu.parallel.executor import slot_shapes
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 SIZES = (48, 40, 36, 32, 28, 24, 18, 10)

@@ -1166,7 +1166,7 @@ def test_jsonl_multihost_shard_suffix_and_glob_read(tmp_path, monkeypatch):
 
     base = tmp_path / "multi.jsonl"
     # a live 2-process distributed runtime, as the probe sees it (the
-    # compat gate first — it keeps the probe from initializing the
+    # distributed-state gate first — it keeps the probe from initializing the
     # backend in single-process runs — then the public process surface)
     monkeypatch.setattr(multihost, "_distributed_is_initialized", lambda: True)
     monkeypatch.setattr(jax, "process_count", lambda: 2)

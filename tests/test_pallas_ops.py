@@ -274,10 +274,13 @@ class TestMegaKernel:
         spec = Mo.make_model_spec((20, 16, 12, 10), 1, 32)
         with pytest.raises(ValueError, match="fuse_mubatches"):
             trainer.make_train_epoch(spec, SGD(0.01), megakernel=True)
-        with pytest.raises(ValueError, match="SGD, momentum and adam"):
-            trainer.make_train_epoch(
-                spec, NotAnOptimizer(), fuse_mubatches=True, megakernel=True
-            )
+        for unsupported in (NotAnOptimizer(), Adam(2e-4)):
+            # adam: its traced-exponent bias correction (scalar b ** t)
+            # does not compile under Mosaic, so it has no kernel variant
+            with pytest.raises(ValueError, match="SGD and momentum"):
+                trainer.make_train_epoch(
+                    spec, unsupported, fuse_mubatches=True, megakernel=True
+                )
         spec2 = Mo.make_model_spec((20, 16, 12, 10), 2, 32)
         with pytest.raises(ValueError, match="single-stage"):
             trainer.make_train_epoch(
@@ -421,63 +424,19 @@ class TestMomentumKernels:
         # OOM VMEM at Mosaic compile time on chip
         sizes = (700, 700, 10)
         params = 700 * 700 + 700 + 700 * 10 + 10
-        for n in (1, 2):  # momentum, adam
+        for n in (1, 2):
             assert pallas_ops._kernel_bytes(8, sizes, state_mirrors=n) == (
                 pallas_ops._kernel_bytes(8, sizes) + n * 4 * 2 * params
             )
         # boundary: this config fits the SGD budget but NOT the momentum
-        # (or adam) budget — the validator must catch the difference
+        # budget — the validator must catch the difference
         assert pallas_ops.train_step_kernel_fits(128, sizes)
         assert not pallas_ops.train_step_kernel_fits(128, sizes, state_mirrors=1)
-        # the flagship class fits even adam's two mirrors
+        # the flagship class fits with room for a second mirror
         assert pallas_ops.train_step_kernel_fits(
             128, (784, 128, 10), state_mirrors=2
         )
 
-
-class TestAdamKernels:
-    """Adam/AdamW variants of the step and epoch kernels: BIT-identity
-    (params, both moment mirrors, the step counter, loss) with the fused
-    XLA path through optimizer.Adam — the bias-correction powers b**t use
-    the same traced-t expression as Adam.apply."""
-
-    def test_step_and_epoch_adam_bit_identical(self):
-        from shallowspeed_tpu.optimizer import Adam
-
-        sizes, B, M, nb = (20, 16, 12, 10), 32, 4, 3
-        rng = np.random.RandomState(7)
-        X = jnp.asarray(rng.rand(nb, M, B // M, sizes[0]).astype(np.float32))
-        Y = jnp.asarray(
-            np.eye(sizes[-1], dtype=np.float32)[
-                rng.randint(0, sizes[-1], (nb, M, B // M))
-            ]
-        )
-        spec = Mo.make_model_spec(sizes, 1, B)
-        opt = Adam(2e-4, weight_decay=1e-4)
-        out = {}
-        for name, kw in {
-            "xla": {},
-            "mega": {"megakernel": True},
-            "epoch": {"epoch_kernel": True},
-        }.items():
-            params = jax.tree.map(jnp.asarray, Mo.init_model(spec))
-            st = opt.init(params)
-            epoch = trainer.make_train_epoch(
-                spec, opt, fuse_mubatches=True, **kw
-            )
-            # two epochs so nonzero moments + a mid-range t feed epoch 2
-            params, st, _ = epoch(params, st, X, Y)
-            params, st, loss = epoch(params, st, X, Y)
-            out[name] = (jax.device_get(params), jax.device_get(st), float(loss))
-        for other in ("mega", "epoch"):
-            assert out["xla"][2] == out[other][2]
-            assert float(out["xla"][1]["t"]) == float(out[other][1]["t"]) == 2 * nb
-            for tree_idx in (0, 1):  # params, then {m, v, t} state
-                for a, b in zip(
-                    jax.tree.leaves(out["xla"][tree_idx]),
-                    jax.tree.leaves(out[other][tree_idx]),
-                ):
-                    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 class TestClipKernels:
     """Global-norm clipping INSIDE the mega/epoch kernels (round-4 verdict
@@ -511,9 +470,8 @@ class TestClipKernels:
         [
             SGD(0.01, weight_decay=1e-4),
             MomentumSGD(0.01, 0.9),
-            Adam(2e-4),
         ],
-        ids=["sgd", "momentum", "adam"],
+        ids=["sgd", "momentum"],
     )
     def test_clip_bit_identical_across_variants(self, opt):
         CLIP = 0.05  # far below the natural grad norm: binds every batch
@@ -561,9 +519,8 @@ class TestRunKernel:
         [
             (SGD(0.01, weight_decay=1e-4), None),
             (MomentumSGD(0.01, 0.9), 0.05),
-            (Adam(2e-4), None),
         ],
-        ids=["sgd", "momentum+clip", "adam"],
+        ids=["sgd", "momentum+clip"],
     )
     def test_run_kernel_bit_identical_to_epoch_loop(self, opt, clip):
         sizes, B, M, nb, E = (20, 16, 12, 10), 32, 4, 3, 4
@@ -652,3 +609,14 @@ class TestRunKernel:
         params = jax.tree.map(jnp.asarray, Mo.init_model(spec))
         with pytest.raises(ValueError, match="n_epochs >= 1"):
             run(params, (), X, Y, 0)
+
+
+def test_interpret_rule_is_cpu_only(monkeypatch):
+    """Interpreter on a host CPU, Mosaic on a TPU, an error under any other
+    backend name — never a silent interpreted run on unknown hardware."""
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert pallas_ops._interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        pallas_ops._interpret()

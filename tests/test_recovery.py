@@ -109,13 +109,6 @@ def test_retry_call_bounded_budget_and_exception_filter():
         retry.retry_call(lambda: None, attempts=0)
 
 
-def test_retry_cli_prints_schedule(capsys):
-    assert retry.main(["--attempts", "4", "--base", "2", "--jitter", "0"]) == 0
-    out = capsys.readouterr().out.splitlines()
-    assert [int(l) for l in out] == [2, 4, 8, 16]
-    assert retry.main(["--attempts", "2", "--jitter", "2.0"]) == 1  # bad args
-
-
 # ---------------------------------------------------------------------------
 # faults: the injection harness
 # ---------------------------------------------------------------------------

@@ -21,7 +21,8 @@ Examples:
     python train.py --dp 8               # 8-way data parallel
     python train.py --pp 4 --schedule gpipe
     python train.py --dp 2 --pp 4 --schedule pipedream
-On a single-chip host, multi-device layouts run on emulated CPU devices:
+JAX's default backend runs it (the TPU, on a host that has one). For tests,
+any layout also runs on emulated CPU devices:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python train.py --dp 2 --pp 4 --schedule gpipe
 """
@@ -34,7 +35,7 @@ import sys
 import time
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dp", type=int, default=1, help="data-parallel replicas")
     ap.add_argument("--pp", type=int, default=1, help="pipeline stages")
@@ -82,7 +83,7 @@ def main():
         "divide sgd's lr by ~1/(1-mu) (1e-3 reaches 99.65%% in 20 epochs; "
         "sgd's 6e-3 diverges late). adam's normalized step is ~lr per "
         "element — 2e-4 reaches 99.86%% after ONE epoch, but destabilizes on "
-        "long runs (see BASELINE.md); prefer sgd/momentum past a few epochs",
+        "long runs; prefer sgd/momentum past a few epochs",
     )
     ap.add_argument("--momentum", type=float, default=0.9)
     ap.add_argument(
@@ -316,14 +317,14 @@ def main():
     ap.add_argument(
         "--megakernel",
         action="store_true",
-        help="with --fuse-mubatches (SGD, momentum or adam): run each training batch as "
+        help="with --fuse-mubatches (SGD or momentum): run each training batch as "
         "ONE Pallas kernel — forward, head, backward and update in a single "
         "op (identical numerics; shortest possible serial op chain)",
     )
     ap.add_argument(
         "--epoch-kernel",
         action="store_true",
-        help="with --fuse-mubatches (SGD, momentum or adam): run each ENTIRE epoch as "
+        help="with --fuse-mubatches (SGD or momentum): run each ENTIRE epoch as "
         "one Pallas kernel — the batch axis is the kernel grid and the "
         "params stay VMEM-resident across the epoch (identical numerics; "
         "one device op per epoch instead of one per batch)",
@@ -331,7 +332,7 @@ def main():
     ap.add_argument(
         "--run-kernel",
         action="store_true",
-        help="with --fuse-mubatches (SGD, momentum or adam): run the whole "
+        help="with --fuse-mubatches (SGD or momentum): run the whole "
         "multi-epoch training run as ONE Pallas kernel when dispatched via "
         "--fused-run --no-eval (grid = epochs x batches, params VMEM-resident "
         "for the entire run; identical numerics). Per-epoch runs and the "
@@ -395,7 +396,7 @@ def main():
         "flag-operand Pallas kernel (same math; see docs/performance.md). "
         "Sequential path: use --megakernel or SHALLOWSPEED_PALLAS=1",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     # fail fast on incoherent fault-tolerance flag combinations — at
     # argparse time, before any backend or data is touched
@@ -507,8 +508,10 @@ def main():
 
     from shallowspeed_tpu.api import TrainingSession
     from shallowspeed_tpu.checkpoint import CheckpointError
+    from shallowspeed_tpu.compile_cache import enable_compile_cache
     from shallowspeed_tpu.observability import HealthError, JsonlMetrics, capture
 
+    enable_compile_cache()
     metrics = JsonlMetrics(args.metrics_out) if args.metrics_out else None
     try:
         run = TrainingSession(
@@ -603,6 +606,13 @@ def main():
         f"devices={jax.devices()} layout: DP={args.dp} x PP={args.pp} x "
         f"TP={args.tp} ({layout}) batches/epoch={run.batches_per_epoch}" + note
     )
+    placed = run.placement()
+    if placed is not None:
+        print(
+            f"mesh placement: {placed['layout']} device_ids="
+            f"{placed['device_ids']} param bytes per device="
+            f"{placed['param_bytes']}"
+        )
 
     def profiled(i):
         # trace one post-compile epoch when asked (observability.capture =
