@@ -46,6 +46,8 @@ from shallowspeed_tpu.checkpoint import (
 )
 from shallowspeed_tpu.data import Dataset, default_data_dir
 from shallowspeed_tpu.observability import NullMetrics, costmodel, program_audit
+from shallowspeed_tpu.observability import scopes
+from shallowspeed_tpu.observability import span as host_span
 from shallowspeed_tpu.observability.flight import FlightRecorder
 from shallowspeed_tpu.observability.health import HealthError, make_monitor
 from shallowspeed_tpu.observability.slo import (
@@ -762,6 +764,7 @@ class TrainingSession:
             self.flight.total_steps = self.global_step
         self._epoch_compiled = False  # compile-span already recorded?
         self._epoch_dispatched = False  # first train_epoch includes compile
+        self._registered_shape = None  # Y of the program scopes.py knows
         self._cost_recorded = False  # cost_model event already emitted?
         self._cost_xla_recorded = False  # ... with the XLA cross-check leg?
 
@@ -1477,6 +1480,33 @@ class TrainingSession:
             self._X[k0:k1], self._Y[k0:k1],
         )
 
+    def _run_epoch_program(self, args):
+        """Dispatch the epoch (or chunk) program on ``args``, keep the new
+        state, read the mean loss back: ``(outputs, loss)``. The two halves
+        are always spans on the profiler's clock (``epoch/dispatch``,
+        ``epoch/readback``; ``jax.profiler.TraceAnnotation``, so a capture
+        shows them beside the device's operations) and also records of the
+        metrics stream where a recorder is attached. The first dispatch of
+        each batch-axis length (a chunk and a whole epoch are two programs
+        under one name) registers the program with ``observability.scopes``
+        (the jitted callable and the arguments' shapes, no array), so that a
+        trace reader can ask for the op index of the program that ran last;
+        the MPMD runtime's epoch is a host loop over stage programs, not one
+        program, and is not registered."""
+        if args[-1].shape != self._registered_shape and self.runtime != "mpmd":
+            scopes.register_program(self._epoch_fn, args)
+            self._registered_shape = args[-1].shape
+        metrics = self._metrics if self._metrics.enabled else None
+        with host_span("epoch/dispatch", metrics):
+            out = self._epoch_fn(*args)
+        if self._sequential:
+            self._params, self._opt_state = out[0], out[1]
+        else:
+            self._stacked, self._opt_state = out[0], out[1]
+        with host_span("epoch/readback", metrics):
+            loss = float(out[2])  # forces device completion
+        return out, loss
+
     def train_steps(self, n):
         """Train up to ``n`` optimizer steps of the CURRENT epoch (clipped at
         the epoch boundary) — the preemption-safe unit: the epoch-scan
@@ -1532,12 +1562,7 @@ class TrainingSession:
         self._ensure_chunk_audited(k0, k1)
         t0 = time.perf_counter()
         with self._metrics.span("train_steps"):
-            out = self._epoch_fn(*self._sliced_epoch_args(k0, k1))
-            if self._sequential:
-                self._params, self._opt_state, mean_loss = out[0], out[1], out[2]
-            else:
-                self._stacked, self._opt_state, mean_loss = out[0], out[1], out[2]
-            loss = float(mean_loss)  # forces device completion
+            out, loss = self._run_epoch_program(self._sliced_epoch_args(k0, k1))
         wall = time.perf_counter() - t0
         aux = (
             out[3]
@@ -1842,12 +1867,7 @@ class TrainingSession:
         epoch_index = self.epoch
         t0 = time.perf_counter()
         with self._metrics.span("train_epoch"):
-            out = self._epoch_fn(*self._epoch_args())
-            if self._sequential:
-                self._params, self._opt_state, mean_loss = out[0], out[1], out[2]
-            else:
-                self._stacked, self._opt_state, mean_loss = out[0], out[1], out[2]
-            loss = float(mean_loss)  # forces device completion
+            out, loss = self._run_epoch_program(self._epoch_args())
         aux = (
             out[3]
             if (self._epoch_aux or self._step_aux or self._digests)

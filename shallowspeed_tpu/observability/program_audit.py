@@ -997,3 +997,450 @@ def format_bytes(n):
         if abs(n) >= div:
             return f"{n / div:,.2f} {unit}"
     return f"{n:,.0f} B"
+
+
+# ---------------------------------------------------------------------------
+# The op index: every instruction of a compiled program, by name, with the
+# scope the program gave it (observability/scopes.py) and a class
+#
+# A device trace names its events after HLO instructions (``fusion.194``,
+# ``copy.148``). The index says what each of them IS: rules, in this order,
+#   1. a fusion that holds a convolution/dot takes that member's scope (a
+#      matmul with a relu epilogue is the matmul); otherwise
+#   2. the instruction's own ``op_name`` has a known scope (a fusion carries
+#      its root's ``op_name``) -> that scope's class;
+#   3. data movement the compiler inserted carries no scope (``copy.148 =
+#      f32[4,8192,2048]{1,2,0} copy(get-tuple-element(param))``): its operand
+#      chain is followed back, across fusion, branch and loop boundaries, to
+#      an entry parameter (named after the argument's pytree path) or to a
+#      loop-carried value, which takes the class of the scoped instruction
+#      that WRITES it in the loop body (the stash's ``.at[slot].set`` names
+#      the stash); where the chain ends nowhere, a consumer's scope decides;
+#   4. scalar integer/predicate results with no class -> ``control``;
+# anything else is ``unattributed``. ``while``/``conditional``/``call`` are
+# containers, and so is every COMPUTATION name: the v5e trace emits an event
+# per executed branch computation (``region_4.7``), spanning the events of
+# its instructions. The async halves (``slice-start``/``-done``,
+# ``copy-start``/``-done``, ``async-*``) are NOT: they are the DMA's own time
+# and have no events inside them.
+# ---------------------------------------------------------------------------
+
+_CONTAINER_OPCODES = ("while", "conditional", "call")
+# opcodes that hand a value on unchanged (or re-laid-out): the chain of
+# rule 3 walks through them
+_PASS_THROUGH = (
+    "copy", "copy-start", "copy-done", "slice-start", "slice-done", "bitcast",
+    "reshape", "transpose", "convert", "optimization-barrier",
+    "async-start", "async-update", "async-done",
+)
+# scope-less instructions that cut or join buffers: the chain walks through
+# them too, trying each buffer they read (``_data_operands``)
+_CUTS = ("fusion", "dynamic-slice", "slice", "pad", "concatenate", "custom-call")
+_JOINS = ("fusion", "concatenate", "custom-call")
+# opcodes rule 3 applies to when they carry no class of their own
+_MOVES = _PASS_THROUGH + ("dynamic-update-slice", "broadcast") + _CUTS
+_MATMUL_OPCODES = ("convolution", "dot")
+# scopes that name a buffer, writes before reads: among a fusion's members
+# they decide what a neighbouring copy moves (see _Origins._scoped)
+_BUFFER_SCOPES = ("stash", "mail", "acc", "update", "batch", "unstash")
+
+_INSTR_RE = re.compile(r"^\s+(?P<root>ROOT )?%?(?P<name>[^\s=]+) = (?P<rest>.*)$")
+_COMP_RE = re.compile(r"^(?P<entry>ENTRY )?%?(?P<name>[^\s(]+) \(.*\) -> .*\{\s*$")
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_CALLED_RE = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)"
+)
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+# entry parameter (pytree path of the argument) -> class
+_ARGUMENT_CLASSES = (
+    (("stacked", "params", "opt_state"), "update"),
+    (("X", "Y", "x", "y", "xb", "yb"), "batch"),
+    (("flags",), "control"),
+)
+
+
+def _balanced(text, start):
+    """Index just past the parenthesis group opening at ``text[start]``."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(text)
+
+
+def _parse_instruction(line):
+    m = _INSTR_RE.match(line)
+    if not m:
+        return None
+    rest = m.group("rest")
+    end = _balanced(rest, 0) if rest.startswith("(") else rest.find(" ")
+    type_str, tail = rest[:end], rest[end:].lstrip()
+    paren = tail.find("(")
+    if paren < 0:
+        return None
+    close = _balanced(tail, paren)
+    args, attrs = tail[paren + 1 : close - 1], tail[close:]
+    op_name = _OP_NAME_RE.search(attrs)
+    called = {}
+    for key, comp in _CALLED_RE.findall(attrs):
+        called[key] = comp
+    branches = _BRANCHES_RE.search(attrs)
+    if branches:
+        called["branches"] = _OPERAND_RE.findall(branches.group(1)) or [
+            b.strip() for b in branches.group(1).split(",")
+        ]
+    elif "true_computation" in called:
+        # operand 0 is the predicate: true is the first argument's branch
+        called["branches"] = [called["true_computation"], called["false_computation"]]
+    index = re.search(r"\bindex=(\d+)", attrs)
+    kind = re.search(r"\bkind=(k\w+)", attrs)
+    opcode = tail[:paren]
+    return {
+        "name": m.group("name"),
+        "root": bool(m.group("root")),
+        "opcode": opcode,
+        "type": type_str,
+        "operands": _OPERAND_RE.findall(args),
+        "number": int(args) if opcode == "parameter" and args.isdigit() else None,
+        "index": int(index.group(1)) if index else None,
+        "op_name": op_name.group(1).replace("\\'", "'") if op_name else "",
+        "kind": kind.group(1) if kind else "",
+        "called": called,
+    }
+
+
+def parse_hlo(hlo_text):
+    """``(instructions by name, computations)`` of an HLO dump, where a
+    computation is ``{"entry": bool, "instructions": [names], "root": name}``.
+    Names are unique within a module; each instruction knows its
+    ``computation``."""
+    instrs, comps, current = {}, {}, None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMP_RE.match(line)
+            if m:
+                current = m.group("name")
+                comps[current] = {
+                    "entry": bool(m.group("entry")), "instructions": [], "root": None,
+                }
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        ins = _parse_instruction(line)
+        if ins is None:
+            continue
+        ins["computation"] = current
+        instrs.setdefault(ins["name"], ins)
+        comps[current]["instructions"].append(ins["name"])
+        if ins["root"]:
+            comps[current]["root"] = ins["name"]
+    return instrs, comps
+
+
+def _data_operands(ins):
+    """The operands that are buffers the instruction moves: all of a fusion's
+    or a join's, the first of anything else (the rest are indices, pad
+    values, update slices)."""
+    return ins["operands"] if ins["opcode"] in _JOINS else ins["operands"][:1]
+
+
+def _is_control_type(type_str):
+    """Integer or predicate results of at most a tick table's size."""
+    shapes = _SHAPE_RE.findall(type_str)
+    return (
+        bool(shapes)
+        and all(dtype[0] in "su" or dtype == "pred" for dtype, _ in shapes)
+        and sum(_shape_bytes_each(type_str)) <= 1024
+    )
+
+
+def _argument_class(path):
+    head = re.split(r"[\[.']", path, maxsplit=1)[0]
+    for names, cls in _ARGUMENT_CLASSES:
+        if head in names:
+            return cls
+    return None
+
+
+class _Origins:
+    """Rule 3: where a moved value comes from and where it goes.
+
+    ``of(name)`` follows the operand chain back, across fusion, branch and
+    loop boundaries, to ``(class, what, firm)``: an entry parameter (``what``
+    its pytree path), a loop-carried value (``what`` is ``<while>#<k>``,
+    classed by the scoped instruction that writes it in the body) — both
+    ``firm`` — or merely the scoped instruction that produced the value.
+    ``sink(name)`` follows the users forward to the first scoped consumer,
+    or into the loop-carried value the result initialises."""
+
+    def __init__(self, instrs, comps, scope_of):
+        self.instrs, self.comps, self.scope_of = instrs, comps, scope_of
+        self.callers = {}  # computation -> (calling instruction, role, position)
+        self.users = {}  # name -> [(using instruction, operand position)]
+        self.params = {}  # computation -> {number: parameter instruction}
+        for ins in instrs.values():
+            called = ins["called"]
+            for key in ("calls", "to_apply", "body", "condition"):
+                if key in called:
+                    self.callers[called[key]] = (ins, key, None)
+            for b, comp in enumerate(called.get("branches", ())):
+                self.callers[comp] = (ins, "branch", b)
+            for position, operand in enumerate(ins["operands"]):
+                self.users.setdefault(operand, []).append((ins, position))
+            if ins["opcode"] == "parameter":
+                self.params.setdefault(ins["computation"], {})[ins["number"] or 0] = ins
+        self._memo = {}
+
+    def _scoped(self, ins):
+        """``(class, scope, False)`` of a scoped instruction as the producer
+        or consumer of a BUFFER: a fusion that holds the stash's
+        ``.at[slot].set`` beside the matmul that computes the value is, for
+        the copy next to it, the stash."""
+        found = [self.scope_of(ins["op_name"])]
+        if ins["opcode"] == "fusion":
+            members = self.comps.get(ins["called"].get("calls"), {})
+            found += [
+                self.scope_of(self.instrs[n]["op_name"])
+                for n in members.get("instructions", ())
+            ]
+        found = [(cls, scope) for scope, cls in found if cls]
+        if not found:
+            return None
+        cls, scope = min(
+            found,
+            key=lambda cs: _BUFFER_SCOPES.index(cs[1])
+            if cs[1] in _BUFFER_SCOPES
+            else len(_BUFFER_SCOPES),
+        )
+        return (cls, scope, False)
+
+    def _root(self, comp):
+        return self.comps.get(comp, {}).get("root")
+
+    # -- backwards ---------------------------------------------------------
+
+    def of(self, name, index=None, seen=frozenset()):
+        key = (name, index)
+        if key in self._memo:
+            return self._memo[key]
+        if key in seen or name not in self.instrs:
+            return None
+        found = self._walk(self.instrs[name], index, seen | {key})
+        if not seen:  # only a walk that started here saw every path
+            self._memo[key] = found
+        return found
+
+    def _walk(self, ins, index, seen):
+        op, operands = ins["opcode"], ins["operands"]
+        if op == "get-tuple-element":
+            return self.of(operands[0], ins["index"], seen)
+        if op == "tuple" and index is not None and index < len(operands):
+            return self.of(operands[index], None, seen)
+        if op == "parameter":
+            return self._parameter(ins, index, seen)
+        if op == "while" and index is not None:
+            return self._carried(ins, index, seen)
+        if op == "conditional" and index is not None:
+            for comp in ins["called"].get("branches", ()):
+                found = self.of(self._root(comp), index, seen)
+                if found:
+                    return found
+            return None
+        scoped = self._scoped(ins)
+        if scoped:
+            return scoped
+        if op == "fusion" and index is not None:
+            return self.of(self._root(ins["called"].get("calls")), index, seen)
+        if op in _PASS_THROUGH or op in _CUTS:
+            walks = (self.of(o, None, seen) for o in _data_operands(ins))
+            return next(filter(None, walks), None)
+        return None
+
+    def _parameter(self, ins, index, seen):
+        comp = ins["computation"]
+        if self.comps[comp]["entry"]:
+            cls = _argument_class(ins["op_name"])
+            return (cls, f"arg {ins['op_name']}", True) if cls else None
+        caller, role, position = self.callers.get(comp, (None, None, None))
+        if caller is None:
+            return None
+        if role in ("body", "condition"):
+            return self._carried(caller, index, seen) if index is not None else None
+        if role == "branch":  # operand 0 selects; operand 1 + b feeds branch b
+            if position + 1 >= len(caller["operands"]):
+                return None
+            return self.of(caller["operands"][position + 1], index, seen)
+        number = ins["number"] or 0
+        if number < len(caller["operands"]):
+            return self.of(caller["operands"][number], index, seen)
+        return None
+
+    def _carried(self, loop, k, seen):
+        """Element ``k`` of a loop's carried tuple: the class of what writes
+        it in the body; where the body hands it on unchanged, of what it was
+        initialised from."""
+        found = self.of(self._root(loop["called"].get("body")), k, seen)
+        if not found and loop["operands"]:
+            found = self.of(loop["operands"][0], k, seen)
+        if not found:
+            return None
+        what = found[1] if found[2] else f"{loop['name']}#{k} {found[1]}"
+        return (found[0], what, True)
+
+    # -- forwards ----------------------------------------------------------
+
+    def sink(self, name, seen=frozenset()):
+        if name in seen or len(seen) > 16:
+            return None
+        seen = seen | {name}
+        for user, position in self.users.get(name, ()):
+            op = user["opcode"]
+            found = self._scoped(user)
+            if found is None and op == "tuple":
+                found = self._sink_element(user, position, seen)
+            elif found is None and (
+                op in _PASS_THROUGH
+                or op in ("custom-call", "get-tuple-element", "fusion")
+            ):
+                found = self.sink(user["name"], seen)
+            if found:
+                return found
+        return None
+
+    def _sink_readers(self, holder, k, seen):
+        """The consumers of element ``k`` of the tuple ``holder`` yields."""
+        for user, _ in self.users.get(holder, ()):
+            if user["opcode"] == "get-tuple-element" and user["index"] == k:
+                found = self.sink(user["name"], seen)
+                if found:
+                    return found
+        return None
+
+    def _sink_element(self, tup, k, seen):
+        """Where element ``k`` of a tuple goes: out of a branch (to the
+        conditional's readers), round a loop (to the body's readers), or
+        into a loop or a branch as its argument."""
+        caller, role, _ = self.callers.get(tup["computation"], (None, None, None))
+        if tup["root"] and role == "branch":
+            return self._sink_readers(caller["name"], k, seen)
+        if tup["root"] and role == "body":
+            param = self.params.get(tup["computation"], {}).get(0)
+            return param and self._sink_readers(param["name"], k, seen)
+        for user, position in self.users.get(tup["name"], ()):
+            if user["opcode"] == "while":
+                return self._carried(user, k, frozenset())
+            if user["opcode"] == "conditional" and position >= 1:
+                branch = user["called"].get("branches", ())[position - 1 : position]
+                param = branch and self.params.get(branch[0], {}).get(0)
+                found = param and self._sink_readers(param["name"], k, seen)
+                if found:
+                    return found
+        return None
+
+    def moved(self, ins):
+        """Rule 3 for one scope-less instruction: ``(class, via)`` or
+        ``None``. Firm origins first, then a consumer, then a producer. Data
+        staged for or taken from a relay is the mailbox's traffic: ``relay``
+        is the collective-permute alone."""
+        found = next(filter(None, map(self.of, _data_operands(ins))), None)
+        if not (found and found[2]):
+            consumer = self.sink(ins["name"])
+            if consumer:
+                found = (consumer[0], f"to {consumer[1]}", consumer[2])
+            elif found:
+                found = (found[0], f"from {found[1]}", False)
+        if not found:
+            return None
+        return ("mailbox" if found[0] == "relay" else found[0], found[1])
+
+
+def _result_bytes(ins):
+    """Bytes an instruction yields. An async collective's ``-start`` tuple
+    pairs operands with results (``_shape_bytes``); a DMA's (``copy-start``,
+    ``slice-start``: destination, source and a context word, in either order)
+    moves the smaller of its two buffers."""
+    opcode = ins["opcode"]
+    if opcode in ("copy-start", "slice-start", "async-start"):
+        sizes = _shape_bytes_each(ins["type"])
+        if len(sizes) == 3:
+            return min(sizes[:2])
+    return _shape_bytes(ins["type"], async_start=opcode.endswith("-start"))
+
+
+def op_index(hlo_text):
+    """``{instruction name: entry}`` for every instruction of every
+    computation in a post-optimization HLO dump (``Compiled.as_text()``),
+    plus one ``container`` entry per computation name. An entry holds
+    ``opcode``, result ``bytes``, ``type`` (the result type, layouts cut),
+    ``scope``, ``cls``, ``computation``, ``container`` (``while`` /
+    ``conditional`` / ``call`` and computation names) and, where they say
+    something, ``kind`` (a fusion's), ``mixed`` (a fusion whose members span
+    more than one class) and ``via`` (rule 3: the argument or the
+    loop-carried value the instruction moves). The rules are in the section
+    comment above. Classes: those of ``observability/scopes.py`` plus
+    ``control``, ``container``, ``unattributed``."""
+    from shallowspeed_tpu.observability.scopes import scope_of
+
+    instrs, comps = parse_hlo(hlo_text)
+    origins = _Origins(instrs, comps, scope_of)
+    fused = {
+        ins["called"]["calls"]
+        for ins in instrs.values()
+        if ins["opcode"] == "fusion" and "calls" in ins["called"]
+    }
+
+    index = {}
+    for ins in instrs.values():
+        opcode = ins["opcode"]
+        scope, cls = scope_of(ins["op_name"])
+        entry = {
+            "opcode": opcode,
+            "bytes": _result_bytes(ins),
+            "type": re.sub(r"\{[^{}]*\}", "", ins["type"])[:120],
+            "scope": scope,
+            "computation": ins["computation"],
+            "container": opcode in _CONTAINER_OPCODES,
+        }
+        if entry["container"]:
+            entry["cls"] = "container"
+            index[ins["name"]] = entry
+            continue
+        if opcode == "fusion":
+            members = [
+                instrs[n]
+                for n in comps.get(ins["called"].get("calls"), {}).get("instructions", ())
+            ]
+            member_scopes = [scope_of(m["op_name"]) for m in members]
+            classes = {c for _, c in member_scopes if c}
+            if len(classes) > 1:
+                entry["mixed"] = sorted(classes)
+            for member, (m_scope, m_cls) in zip(members, member_scopes):
+                if member["opcode"] in _MATMUL_OPCODES and m_cls:
+                    scope, cls = m_scope, m_cls
+                    break
+            if ins["kind"]:
+                entry["kind"] = ins["kind"]
+        if cls is None and opcode in _MOVES and ins["computation"] not in fused:
+            found = origins.moved(ins)
+            if found:
+                cls, entry["via"] = found
+        if cls is None and _is_control_type(ins["type"]):
+            cls = "control"
+        entry["scope"], entry["cls"] = scope, cls or "unattributed"
+        index[ins["name"]] = entry
+    for name in comps:
+        index.setdefault(
+            name,
+            {
+                "opcode": "computation", "bytes": 0, "type": "", "scope": None,
+                "cls": "container", "computation": name, "container": True,
+            },
+        )
+    return index

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from shallowspeed_tpu.observability.scopes import scope, scoped
+
 # Opt-in Pallas kernel path for the fused linear+relu hot op (pallas_ops.py);
 # default is plain XLA, which already fuses well for this model class.
 _PALLAS = os.environ.get("SHALLOWSPEED_PALLAS", "0") == "1"
@@ -55,11 +57,13 @@ DEFAULT_PRECISION = lax.Precision.HIGHEST
 _NEG_MASK = -1e30
 
 
+@scoped("act")
 def relu(x):
     """max(x, 0). Reference: functional.py:4-5."""
     return jnp.maximum(x, 0.0)
 
 
+@scoped("act")
 def relu_grad(g, bitmask):
     """VJP of relu given the cached activation bitmask (out > 0).
 
@@ -74,6 +78,7 @@ _INV_SQRT2 = 0.7071067811865476
 _INV_SQRT_2PI = 0.3989422804014327
 
 
+@scoped("act")
 def gelu(x):
     """Exact (erf) GELU: x * Phi(x) — the transformer-block activation of
     the model zoo. gelu(0) == 0, so zero-padded rows and columns stay
@@ -82,6 +87,7 @@ def gelu(x):
     return 0.5 * x * (1.0 + lax.erf(x * _INV_SQRT2))
 
 
+@scoped("act")
 def gelu_grad_mult(z):
     """d gelu(z)/dz = Phi(z) + z * phi(z), from the pre-activation ``z``.
 
@@ -94,11 +100,13 @@ def gelu_grad_mult(z):
     return 0.5 * (1.0 + lax.erf(z * _INV_SQRT2)) + z * phi
 
 
+@scoped("act")
 def gelu_grad(g, z):
     """VJP of gelu given the cached pre-activation z."""
     return g * gelu_grad_mult(z)
 
 
+@scoped("linear/fwd")
 def linear(x, w, b, precision=DEFAULT_PRECISION):
     """y = x @ w.T + b with w: (out, in), b: (1, out) or (out,).
 
@@ -107,6 +115,7 @@ def linear(x, w, b, precision=DEFAULT_PRECISION):
     return jnp.matmul(x, w.T, precision=precision) + jnp.reshape(b, (1, -1))
 
 
+@scoped("linear/dgrad")
 def linear_grad_input(g, w, precision=DEFAULT_PRECISION):
     """The relay-critical half of linear's VJP: dx = g @ w.
 
@@ -118,6 +127,7 @@ def linear_grad_input(g, w, precision=DEFAULT_PRECISION):
     return jnp.matmul(g, w, precision=precision)
 
 
+@scoped("linear/wgrad")
 def linear_grad_weight(g, x, precision=DEFAULT_PRECISION):
     """The deferrable half of linear's VJP: (dw, db) = (g.T @ x, sum_rows(g)).
 
@@ -164,10 +174,13 @@ def linear_relu_fused(x, w, b, precision=DEFAULT_PRECISION):
     if _PALLAS:
         from shallowspeed_tpu import pallas_ops
 
-        y, mask = pallas_ops.linear_relu_fwd(x, w, b, precision=precision)
+        with scope("linear/fwd"):
+            y, mask = pallas_ops.linear_relu_fwd(x, w, b, precision=precision)
         return y, mask > 0
     y = linear(x, w, b, precision=precision)
-    return relu(y), y > 0
+    with scope("act"):
+        mask = y > 0
+    return relu(y), mask
 
 
 def linear_relu_grad_fused(g, bitmask, x, w, precision=DEFAULT_PRECISION):
@@ -175,9 +188,10 @@ def linear_relu_grad_fused(g, bitmask, x, w, precision=DEFAULT_PRECISION):
     if _PALLAS:
         from shallowspeed_tpu import pallas_ops
 
-        dx, dw, db = pallas_ops.linear_relu_bwd(
-            g, bitmask.astype(jnp.float32), x, w, precision=precision
-        )
+        with scope("linear/dgrad"):  # one kernel for dx, dw and db
+            dx, dw, db = pallas_ops.linear_relu_bwd(
+                g, bitmask.astype(jnp.float32), x, w, precision=precision
+            )
         return dx, dw, jnp.reshape(db, (-1,))
     return linear_grad(relu_grad(g, bitmask), x, w, precision=precision)
 
@@ -195,6 +209,7 @@ def _stability_max(z, group_rows):
     return jnp.broadcast_to(m, g.shape).reshape(z.shape)
 
 
+@scoped("softmax")
 def softmax(z, valid_mask=None, group_rows=None):
     """Row softmax with the reference's exact quirks (functional.py:24-27):
 
@@ -214,6 +229,7 @@ def softmax(z, valid_mask=None, group_rows=None):
     return z_exp / (z_exp.sum(axis=1, keepdims=True) + 1e-7)
 
 
+@scoped("softmax")
 def softmax_grad(g, z, valid_mask=None, group_rows=None):
     """VJP of softmax, recomputing the forward from the cached *input* z.
 
@@ -226,6 +242,7 @@ def softmax_grad(g, z, valid_mask=None, group_rows=None):
     return gz - out * gz.sum(axis=-1, keepdims=True)
 
 
+@scoped("loss")
 def mse_loss(p, t, batch_size):
     """sum((t - p)^2) / batch_size. Reference: functional.py:38-40.
 
@@ -236,12 +253,14 @@ def mse_loss(p, t, batch_size):
     return ((t - p) ** 2).sum() / batch_size
 
 
+@scoped("loss")
 def mse_loss_grad(p, t, batch_size):
     """dL/dp = -2 (t - p) / batch_size. Reference: functional.py:43-44."""
     return -2.0 * (t - p) / batch_size
 
 
 @partial(jax.jit, static_argnames=("batch_size", "group_rows"))
+@scoped("head_grad")
 def softmax_mse_head_grad(z, t, batch_size, valid_mask=None, group_rows=None):
     """Fused loss-head backward: d(MSE(softmax(z), t))/dz.
 

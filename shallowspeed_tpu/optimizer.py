@@ -25,6 +25,8 @@ import dataclasses
 
 import jax
 
+from shallowspeed_tpu.observability.scopes import scoped
+
 
 @dataclasses.dataclass(frozen=True)
 class SGD:
@@ -49,6 +51,7 @@ class SGD:
     def _decay(self, p):
         return p * _decay_factor(self.lr, self.weight_decay) if self.weight_decay else p
 
+    @scoped("update")
     def apply(self, params, grads, state=()):
         new = jax.tree.map(lambda p, g: self._decay(p) - self.lr * g, params, grads)
         return new, state
@@ -79,6 +82,7 @@ class MomentumSGD:
     def _decay(self, p):
         return p * _decay_factor(self.lr, self.weight_decay) if self.weight_decay else p
 
+    @scoped("update")
     def apply(self, params, grads, state):
         velocity = jax.tree.map(lambda v, g: self.momentum * v + g, state, grads)
         new = jax.tree.map(lambda p, v: self._decay(p) - self.lr * v, params, velocity)
@@ -113,6 +117,7 @@ class Adam:
     def state_layout(self):
         return {"m": "params", "v": "params", "t": "scalar"}
 
+    @scoped("update")
     def apply(self, params, grads, state):
         import jax.numpy as jnp
 
@@ -186,6 +191,7 @@ def tree_sq_sum(tree, cross_device_sum=None):
     return sq
 
 
+@scoped("update")
 def global_norm(tree, cross_device_sum=None):
     """Global L2 norm over every leaf of a pytree (see ``tree_sq_sum``)."""
     import jax.numpy as jnp
@@ -193,6 +199,7 @@ def global_norm(tree, cross_device_sum=None):
     return jnp.sqrt(tree_sq_sum(tree, cross_device_sum))
 
 
+@scoped("update")
 def clip_tree(grads, clip_norm, cross_device_sum=None):
     """Scale a gradient pytree by the global-norm clip factor. The local
     sum-of-squares is optionally reduced by ``cross_device_sum`` (a callable,

@@ -86,6 +86,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from shallowspeed_tpu import ops
 from shallowspeed_tpu.model import ModelSpec, init_model
+from shallowspeed_tpu.observability.scopes import scope, scoped
 from shallowspeed_tpu.parallel.lowering import (
     OP_BWD,
     OP_BWD_W,
@@ -906,6 +907,23 @@ def zero_block_state_from_logical(logical, opt, spec: ModelSpec, mesh: Mesh, ord
 # ---------------------------------------------------------------------------
 
 
+def _stash(buf, slot, val):
+    """Park ``val`` in a stash buffer's lowering-assigned ``slot``."""
+    with scope("stash"):
+        return buf.at[slot].set(val)
+
+
+def _unstash(buf, slot):
+    with scope("unstash"):
+        return buf[slot]
+
+
+def _microbatch(a, i):
+    """One microbatch's rows of the step's local batch."""
+    with scope("batch"):
+        return a[i]
+
+
 def _fit(a, width):
     """Slice or zero-pad the last dim to ``width`` (exact under the padding
     invariant: dropped columns are always zero)."""
@@ -917,6 +935,7 @@ def _fit(a, width):
     return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - cur)])
 
 
+@scoped("act")
 def _stage_fwd(
     Ws, bs, active, relu, dims, x, precision, kernel_backend="xla",
     act="relu", residual=None,
@@ -969,6 +988,7 @@ def _stage_fwd(
     return x, tuple(xs), tuple(masks)
 
 
+@scoped("act")
 def _stage_bwd(
     Ws, active, relu, dims, xs, masks, g, precision, kernel_backend="xla",
     act="relu", residual=None,
@@ -1006,6 +1026,7 @@ def _stage_bwd(
     return g, tuple(gWs), tuple(gbs)
 
 
+@scoped("act")
 def _stage_bwd_input(Ws, active, relu, dims, masks, g, precision,
                      act="relu", residual=None):
     """The relay-critical half of the split backward: the dgrad chain only.
@@ -1036,6 +1057,7 @@ def _stage_bwd_input(Ws, active, relu, dims, masks, g, precision,
     return g, tuple(g_effs)
 
 
+@scoped("act")
 def _stage_bwd_weight(active, dims, xs, g_effs, precision):
     """The deferred half of the split backward: per-slot wgrads from the
     stashed activations and the stashed effective output-grads. Slots are
@@ -1416,6 +1438,15 @@ def make_pipeline_step(
     # behind the clip/grad-norm/param-norm scalars must span them all
     pp_axes = "pp" if tp_n == 1 else ("pp", "tp")
     z1_axes = ("dp", "pp") if tp_n == 1 else ("dp", "pp", "tp")
+
+    def sum_pp(sq):
+        with scope("sync/pp"):
+            return lax.psum(sq, pp_axes)
+
+    def sum_z1(sq):
+        with scope("sync/dp"):
+            return lax.psum(sq, z1_axes)
+
     W_rel = relay_width(spec)  # ppermute payload / mailbox width (<= D_in)
     M = prog.num_micro_batches
     Kf, Kb = prog.n_fwd_slots, prog.n_bwd_slots
@@ -1558,7 +1589,10 @@ def make_pipeline_step(
                 jnp.int32,
             )
 
-    # tick tables as device constants, scanned over their leading (T) axis
+    # tick tables as program constants, scanned over their leading (T) axis.
+    # Host arrays: they compile to the same constants as device arrays
+    # would, and a closure of device arrays would pin them for as long as
+    # observability.scopes keeps the jitted callable
     tab_dict = dict(
         op=prog.op,
         mb=prog.mb,
@@ -1584,7 +1618,7 @@ def make_pipeline_step(
         # recompute programs route the input-stash write/read pair (the
         # forward stores its stage input; the recompute frees it)
         tab_dict.update(xw=prog.xin_write, xr=prog.xin_read)
-    tabs = jax.tree.map(jnp.asarray, tab_dict)
+    tabs = jax.tree.map(np.asarray, tab_dict)
     # ring shifts: with virtual chunks the device-(P-1) -> device-0 wrap IS a
     # stage boundary (chunk c on the last device feeds chunk c+1 on the
     # first); without chunks nothing ever sends on the wrap link and its zero
@@ -1616,8 +1650,9 @@ def make_pipeline_step(
                 return a[0]
             return lax.dynamic_index_in_dim(a, v, 0, keepdims=False)
 
-        x = x.reshape(M, mb_sz, D_in)  # local dp shard, padded to D_in
-        y = y.reshape(M, mb_sz, D_out) if y is not None else None
+        with scope("batch"):
+            x = x.reshape(M, mb_sz, D_in)  # local dp shard, padded to D_in
+            y = y.reshape(M, mb_sz, D_out) if y is not None else None
 
         carry = dict(
             fwd_mail=jnp.zeros((Kf + 1, mb_sz, W_rel), jnp.float32),
@@ -1684,6 +1719,7 @@ def make_pipeline_step(
         zero_fwd = jnp.zeros((mb_sz, W_rel), jnp.float32)
         zero_bwd = jnp.zeros((mb_sz, W_rel), jnp.float32)
 
+        @scoped("tick")
         def tick(carry, row):
             opv = row["op"][stage]
             mb_i = row["mb"][stage]  # M = trash
@@ -1719,7 +1755,8 @@ def make_pipeline_step(
                         seg = lax.dynamic_slice(
                             pshard, (s.off + v * s.k,), (s.k,)
                         )
-                    full = lax.all_gather(seg, "dp", axis=0, tiled=True)
+                    with scope("sync/dp"):
+                        full = lax.all_gather(seg, "dp", axis=0, tiled=True)
                     gathered.append(full[: s.sz].reshape(s.shape))
                 return gathered[:L], gathered[L:]
 
@@ -1734,9 +1771,10 @@ def make_pipeline_step(
                 gz = c["gz"]
                 for s, g in zip(zb_slots, list(gW_d) + list(gb_d)):
                     vec = jnp.pad(g.reshape(-1), (0, dp_n * s.k - s.sz))
-                    sh = lax.psum_scatter(
-                        vec, "dp", scatter_dimension=0, tiled=True
-                    )
+                    with scope("sync/dp"):
+                        sh = lax.psum_scatter(
+                            vec, "dp", scatter_dimension=0, tiled=True
+                        )
                     if V == 1:
                         gz = gz.at[s.off : s.off + s.k].add(sh)
                     else:
@@ -1746,6 +1784,45 @@ def make_pipeline_step(
                 c = dict(c)
                 c["gz"] = gz
                 return c
+
+            def accumulate(c, gW_d, gb_d):
+                """Add one microbatch's weight gradients to the step's
+                accumulators (the chunk's row, or the ZeRO-2/3 shard)."""
+                with scope("acc"):
+                    if shard_grads:
+                        return z3_scatter_grads(c, gW_d, gb_d)
+                    r = 0 if V == 1 else v
+                    c["gW"] = tuple(a.at[r].add(d) for a, d in zip(c["gW"], gW_d))
+                    c["gb"] = tuple(a.at[r].add(d) for a, d in zip(c["gb"], gb_d))
+                    return c
+
+            def stage_input(buf, slot, read="mail"):
+                """The stage's input: the microbatch's rows on the global
+                first stage, else what the relay left in the mailbox (or,
+                at a recompute tick, what the forward parked in the input
+                stash), padded up to D_in so both branches of the where
+                agree (exact: relayed activations are zero beyond their
+                true boundary width)."""
+                x_mb = _microbatch(x, mb_r)
+                with scope(read):
+                    return jnp.where(load_in, x_mb, _fit(buf[slot], D_in))
+
+            def incoming_grad(c, g0):
+                """The backward's input gradient: the head's own on the
+                global last stage, else the relayed one. The head grad is
+                D_out wide, relayed grads W_rel wide; fit both to the wider
+                so the where agrees (padding is exact zeros)."""
+                Wb = max(D_out, W_rel)
+                with scope("mail"):
+                    return jnp.where(
+                        is_head,
+                        _fit(g0, Wb),
+                        _fit(c["bwd_mail"][row["rb"][stage]], Wb),
+                    )
+
+            def payload_of(send, out):
+                with scope("mail"):
+                    return jnp.where(send == 1, _fit(out, W_rel), 0.0)
 
             def noop(c):
                 return c, zero_fwd, zero_bwd
@@ -1767,12 +1844,7 @@ def make_pipeline_step(
 
             def forward(c):
                 Ws, bs, active, relu, residual, head_mask = chunk_params()
-                # non-input stages receive a W_rel-wide relay; pad it up to
-                # D_in so both branches of the where agree (exact: relayed
-                # activations are zero beyond their true boundary width)
-                x_in = jnp.where(
-                    load_in, x[mb_r], _fit(c["fwd_mail"][row["rf"][stage]], D_in)
-                )
+                x_in = stage_input(c["fwd_mail"], row["rf"][stage])
                 out, xs_l, masks_l = run_stage_fwd(
                     Ws, bs, active, relu, residual, x_in
                 )
@@ -1785,24 +1857,24 @@ def make_pipeline_step(
                         # the global stage 0 reloads from HBM, its xw is
                         # the trash slot
                         xw = row["xw"][stage]
-                        c["xin"] = c["xin"].at[xw].set(x_in)
+                        c["xin"] = _stash(c["xin"], xw, x_in)
                     else:
                         sw = row["sw"][stage]  # lowering-assigned stash slot
                         c["xs"] = tuple(
-                            buf.at[sw].set(val)
+                            _stash(buf, sw, val)
                             for buf, val in zip(c["xs"], xs_l)
                         )
                         c["masks"] = tuple(
-                            buf.at[sw].set(val)
+                            _stash(buf, sw, val)
                             for buf, val in zip(c["masks"], masks_l)
                         )
-                        c["z"] = c["z"].at[sw].set(out)
-                    mb_loss = ops.mse_loss(p, y[mb_r], B_global)
-                    c["loss"] = c["loss"] + jnp.where(is_head, mb_loss, 0.0)
+                        c["z"] = _stash(c["z"], sw, out)
+                    mb_loss = ops.mse_loss(p, _microbatch(y, mb_r), B_global)
+                    with scope("loss"):
+                        c["loss"] = c["loss"] + jnp.where(is_head, mb_loss, 0.0)
                 else:
-                    c["preds"] = c["preds"].at[mb_i].set(jnp.where(is_head, p, 0.0))
-                payload = jnp.where(row["sf"][stage] == 1, _fit(out, W_rel), 0.0)
-                return c, payload, zero_bwd
+                    c["preds"] = _stash(c["preds"], mb_i, jnp.where(is_head, p, 0.0))
+                return c, payload_of(row["sf"][stage], out), zero_bwd
 
             def recompute(c):
                 # OP_RECOMPUTE: re-run the stage forward from the parked
@@ -1812,21 +1884,19 @@ def make_pipeline_step(
                 # stashed twin's forward tick stored. No loss accumulation
                 # (the forward tick already tallied it), no sends.
                 Ws, bs, active, relu, residual, head_mask = chunk_params()
-                x_in = jnp.where(
-                    load_in, x[mb_r], _fit(c["xin"][row["xr"][stage]], D_in)
-                )
+                x_in = stage_input(c["xin"], row["xr"][stage], read="unstash")
                 out, xs_l, masks_l = run_stage_fwd(
                     Ws, bs, active, relu, residual, x_in
                 )
                 c = dict(c)
                 sw = row["sw"][stage]
                 c["xs"] = tuple(
-                    buf.at[sw].set(val) for buf, val in zip(c["xs"], xs_l)
+                    _stash(buf, sw, val) for buf, val in zip(c["xs"], xs_l)
                 )
                 c["masks"] = tuple(
-                    buf.at[sw].set(val) for buf, val in zip(c["masks"], masks_l)
+                    _stash(buf, sw, val) for buf, val in zip(c["masks"], masks_l)
                 )
-                c["z"] = c["z"].at[sw].set(out)
+                c["z"] = _stash(c["z"], sw, out)
                 return c, zero_fwd, zero_bwd
 
             def backward(c):
@@ -1835,16 +1905,12 @@ def make_pipeline_step(
                 # stash slot in [0, Ks) (replay-asserted), so no clamp needed
                 sr = row["sr"][stage]
                 g0 = ops.softmax_mse_head_grad(
-                    c["z"][sr], y[mb_r], B_global, valid_mask=head_mask[None, :]
+                    _unstash(c["z"], sr), _microbatch(y, mb_r), B_global,
+                    valid_mask=head_mask[None, :],
                 )
-                # head grad is D_out wide, relayed grads W_rel wide; fit both
-                # to the wider so the where agrees (padding is exact zeros)
-                Wb = max(D_out, W_rel)
-                g_in = jnp.where(
-                    is_head, _fit(g0, Wb), _fit(c["bwd_mail"][row["rb"][stage]], Wb)
-                )
-                xs_r = tuple(buf[sr] for buf in c["xs"])
-                masks_r = tuple(buf[sr] for buf in c["masks"])
+                g_in = incoming_grad(c, g0)
+                xs_r = tuple(_unstash(buf, sr) for buf in c["xs"])
+                masks_r = tuple(_unstash(buf, sr) for buf in c["masks"])
                 if tp_n > 1:
                     dx, gW_d, gb_d = _stage_bwd_tp(
                         Ws, active, relu, dims, xs_r, masks_r, g_in,
@@ -1855,17 +1921,8 @@ def make_pipeline_step(
                         Ws, active, relu, dims, xs_r, masks_r, g_in,
                         precision, kernel_backend, act=act, residual=residual,
                     )
-                c = dict(c)
-                if shard_grads:
-                    c = z3_scatter_grads(c, gW_d, gb_d)
-                elif V == 1:
-                    c["gW"] = tuple(a.at[0].add(d) for a, d in zip(c["gW"], gW_d))
-                    c["gb"] = tuple(a.at[0].add(d) for a, d in zip(c["gb"], gb_d))
-                else:
-                    c["gW"] = tuple(a.at[v].add(d) for a, d in zip(c["gW"], gW_d))
-                    c["gb"] = tuple(a.at[v].add(d) for a, d in zip(c["gb"], gb_d))
-                payload = jnp.where(row["sb"][stage] == 1, _fit(dx, W_rel), 0.0)
-                return c, zero_fwd, payload
+                c = accumulate(dict(c), gW_d, gb_d)
+                return c, zero_fwd, payload_of(row["sb"][stage], dx)
 
             def backward_input(c):
                 # split B-input: the combined backward's dgrad chain at the
@@ -1875,13 +1932,11 @@ def make_pipeline_step(
                 Ws, bs, active, relu, residual, head_mask = chunk_params()
                 sp = row["sp"][stage]
                 g0 = ops.softmax_mse_head_grad(
-                    c["z"][sp], y[mb_r], B_global, valid_mask=head_mask[None, :]
+                    _unstash(c["z"], sp), _microbatch(y, mb_r), B_global,
+                    valid_mask=head_mask[None, :],
                 )
-                Wb = max(D_out, W_rel)
-                g_in = jnp.where(
-                    is_head, _fit(g0, Wb), _fit(c["bwd_mail"][row["rb"][stage]], Wb)
-                )
-                masks_r = tuple(buf[sp] for buf in c["masks"])
+                g_in = incoming_grad(c, g0)
+                masks_r = tuple(_unstash(buf, sp) for buf in c["masks"])
                 if tp_n > 1:
                     dx, g_effs = _stage_bwd_input_tp(
                         Ws, active, relu, dims, masks_r, g_in, precision,
@@ -1895,10 +1950,9 @@ def make_pipeline_step(
                 c = dict(c)
                 gw = row["gw"][stage]
                 c["gstash"] = tuple(
-                    buf.at[gw].set(val) for buf, val in zip(c["gstash"], g_effs)
+                    _stash(buf, gw, val) for buf, val in zip(c["gstash"], g_effs)
                 )
-                payload = jnp.where(row["sb"][stage] == 1, _fit(dx, W_rel), 0.0)
-                return c, zero_fwd, payload
+                return c, zero_fwd, payload_of(row["sb"][stage], dx)
 
             def backward_weight(c):
                 # split B-weight: wgrads from the two stashes, accumulated
@@ -1909,8 +1963,8 @@ def make_pipeline_step(
                 active, _, _, _ = chunk_flags()
                 sr = row["sr"][stage]
                 gr = row["gr"][stage]
-                xs_r = tuple(buf[sr] for buf in c["xs"])
-                geff_r = tuple(buf[gr] for buf in c["gstash"])
+                xs_r = tuple(_unstash(buf, sr) for buf in c["xs"])
+                geff_r = tuple(_unstash(buf, gr) for buf in c["gstash"])
                 if tp_n > 1:
                     gW_d, gb_d = _stage_bwd_weight_tp(
                         active, dims, xs_r, geff_r, precision, tp_idx, tp_n
@@ -1919,15 +1973,7 @@ def make_pipeline_step(
                     gW_d, gb_d = _stage_bwd_weight(
                         active, dims, xs_r, geff_r, precision
                     )
-                c = dict(c)
-                if shard_grads:
-                    c = z3_scatter_grads(c, gW_d, gb_d)
-                elif V == 1:
-                    c["gW"] = tuple(a.at[0].add(d) for a, d in zip(c["gW"], gW_d))
-                    c["gb"] = tuple(a.at[0].add(d) for a, d in zip(c["gb"], gb_d))
-                else:
-                    c["gW"] = tuple(a.at[v].add(d) for a, d in zip(c["gW"], gW_d))
-                    c["gb"] = tuple(a.at[v].add(d) for a, d in zip(c["gb"], gb_d))
+                c = accumulate(dict(c), gW_d, gb_d)
                 return c, zero_fwd, zero_bwd
 
             # branch order is the op-code encoding: OP_NOOP=0, OP_FWD=1,
@@ -1946,10 +1992,16 @@ def make_pipeline_step(
             carry, fwd_out, bwd_out = lax.switch(opv, branches, carry)
 
             # uniform collectives outside the switch: relay payloads
-            incoming_f = lax.ppermute(fwd_out, "pp", fwd_perm)
-            incoming_b = lax.ppermute(bwd_out, "pp", bwd_perm)
-            carry["fwd_mail"] = carry["fwd_mail"].at[row["inf"][stage]].set(incoming_f)
-            carry["bwd_mail"] = carry["bwd_mail"].at[row["inb"][stage]].set(incoming_b)
+            with scope("relay"):
+                incoming_f = lax.ppermute(fwd_out, "pp", fwd_perm)
+                incoming_b = lax.ppermute(bwd_out, "pp", bwd_perm)
+            with scope("mail"):
+                carry["fwd_mail"] = (
+                    carry["fwd_mail"].at[row["inf"][stage]].set(incoming_f)
+                )
+                carry["bwd_mail"] = (
+                    carry["bwd_mail"].at[row["inb"][stage]].set(incoming_b)
+                )
             return carry, None
 
         # tick_unroll amortizes the scan's per-tick loop overhead (each tick
@@ -1960,11 +2012,14 @@ def make_pipeline_step(
             preds = carry["preds"][:M].reshape(M * mb_sz, D_out)
             # only head-stage ticks ever wrote predictions (zeros elsewhere);
             # broadcast them over pp
-            return lax.psum(preds, "pp")
+            with scope("sync/pp"):
+                return lax.psum(preds, "pp")
 
         # loss was only accumulated on head-stage ticks (zero elsewhere)
-        loss = lax.psum(carry["loss"], "dp")
-        loss = lax.pmax(loss, "pp")  # replicate scalar across devices
+        with scope("sync/dp"):
+            loss = lax.psum(carry["loss"], "dp")
+        with scope("sync/pp"):
+            loss = lax.pmax(loss, "pp")  # replicate scalar across devices
 
         if zero >= 2:
             # ZeRO-2/3 tail: the dp-summed gradient lives as this rank's
@@ -1988,17 +2043,14 @@ def make_pipeline_step(
                 # reassembled shard is the anchor's column deal, bitwise
                 pieces = [[] for _ in zb_slots]
                 for si, a, b in sync_plan.buckets:
-                    pieces[si].append(
-                        (
-                            a,
-                            lax.psum_scatter(
-                                mats[si][:, a:b],
-                                "dp",
-                                scatter_dimension=0,
-                                tiled=False,
-                            ),
+                    with scope("sync/dp"):
+                        piece = lax.psum_scatter(
+                            mats[si][:, a:b],
+                            "dp",
+                            scatter_dimension=0,
+                            tiled=False,
                         )
-                    )
+                    pieces[si].append((a, piece))
                 gsh = jnp.concatenate(
                     [
                         p
@@ -2009,12 +2061,12 @@ def make_pipeline_step(
             if with_grad_norm:
                 # shards partition the dp-summed gradient across every
                 # sharded axis; per-slot padding is exactly zero
-                gnorm = jnp.sqrt(lax.psum(jnp.sum(gsh * gsh), z1_axes))
+                gnorm = jnp.sqrt(sum_z1(jnp.sum(gsh * gsh)))
             if clip_norm is not None:
                 from shallowspeed_tpu.optimizer import clip_tree
 
                 gsh = clip_tree(
-                    gsh, clip_norm, lambda sq: lax.psum(sq, z1_axes)
+                    gsh, clip_norm, sum_z1
                 )
             if zero == 3:
                 pch = pshard
@@ -2066,7 +2118,8 @@ def make_pipeline_step(
                     seg = new_ch[s.off : s.off + s.rows * s.k].reshape(
                         s.rows, 1, s.k
                     )
-                    mat = lax.all_gather(seg, "dp", axis=1, tiled=True)
+                    with scope("sync/dp"):
+                        mat = lax.all_gather(seg, "dp", axis=1, tiled=True)
                     full = mat.reshape(s.rows, dp_n * s.k)[:, : s.sz]
                     (outW if s.kind == "W" else outb).append(
                         full.reshape((s.rows,) + s.shape)
@@ -2081,9 +2134,7 @@ def make_pipeline_step(
                     # is exactly zero), so the shard norm IS the logical
                     # norm after the cross-axis psum
                     outs += (
-                        jnp.sqrt(
-                            lax.psum(jnp.sum(new_ch * new_ch), z1_axes)
-                        ),
+                        jnp.sqrt(sum_z1(jnp.sum(new_ch * new_ch))),
                     )
                 else:
                     from shallowspeed_tpu.optimizer import (
@@ -2092,7 +2143,7 @@ def make_pipeline_step(
 
                     outs += (
                         gnorm_of(
-                            new_stacked, lambda sq: lax.psum(sq, pp_axes)
+                            new_stacked, sum_pp
                         ),
                     )
             return outs
@@ -2111,9 +2162,10 @@ def make_pipeline_step(
             # the concatenated outputs ARE the anchor chunk, bitwise)
             gpad = jnp.pad(gvec, (0, pad))
             if sync_plan is None:
-                gsh = lax.psum_scatter(
-                    gpad, "dp", scatter_dimension=0, tiled=True
-                )
+                with scope("sync/dp"):
+                    gsh = lax.psum_scatter(
+                        gpad, "dp", scatter_dimension=0, tiled=True
+                    )
             else:
                 from shallowspeed_tpu.parallel import gradsync
 
@@ -2122,7 +2174,7 @@ def make_pipeline_step(
                 # chunks partition the dp-summed gradient across every
                 # sharded axis, so the pre-clip global norm is one
                 # cross-axis reduction
-                gnorm = jnp.sqrt(lax.psum(jnp.sum(gsh * gsh), z1_axes))
+                gnorm = jnp.sqrt(sum_z1(jnp.sum(gsh * gsh)))
             if with_digests:
                 # per-(chunk, slot) grad squared sums from this replica's
                 # flat chunk: static segment ids sliced at the chunk
@@ -2157,7 +2209,7 @@ def make_pipeline_step(
                 # chunks partition the full summed gradient across the
                 # sharded axes (dp, pp[, tp])
                 gsh = clip_tree(
-                    gsh, clip_norm, lambda sq: lax.psum(sq, z1_axes)
+                    gsh, clip_norm, sum_z1
                 )
             pvec = jnp.concatenate(
                 [w.reshape(-1) for w in stacked["W"]]
@@ -2182,7 +2234,8 @@ def make_pipeline_step(
                 opt_state.update(nscalars)
             else:
                 new_ch, _ = opt.apply(pch, gsh, ())
-            new_vec = lax.all_gather(new_ch, "dp", axis=0, tiled=True)[:flat]
+            with scope("sync/dp"):
+                new_vec = lax.all_gather(new_ch, "dp", axis=0, tiled=True)[:flat]
             outW, outb, off = [], [], 0
             for o, i in w_dims:  # this device's LOCAL slot shapes
                 n = V * o * i
@@ -2201,7 +2254,7 @@ def make_pipeline_step(
 
                 # post-update param norm: padded entries are exactly zero,
                 # so the pp-psum'd stacked norm IS the logical norm
-                outs += (gnorm_of(new_stacked, lambda sq: lax.psum(sq, pp_axes)),)
+                outs += (gnorm_of(new_stacked, sum_pp),)
             if with_digests:
                 outs += (_digest_grids(new_stacked, dgsq_w, dgsq_b),)
             return outs
@@ -2213,8 +2266,9 @@ def make_pipeline_step(
         # bucket's all-reduce with the rest of the tail. The clip-norm /
         # grad-norm consumers below always read the POST-SYNC tree.
         if sync_plan is None:
-            gW = lax.psum(carry["gW"], "dp")
-            gb = lax.psum(carry["gb"], "dp")
+            with scope("sync/dp"):
+                gW = lax.psum(carry["gW"], "dp")
+                gb = lax.psum(carry["gb"], "dp")
             grads = {"W": gW, "b": gb}  # (V, ...) leaves, mirroring the shards
         else:
             from shallowspeed_tpu.parallel import gradsync
@@ -2227,7 +2281,7 @@ def make_pipeline_step(
 
             # each pp device holds its stages' full (dp-summed) gradient;
             # padded entries are exactly zero so this IS the logical norm
-            gnorm = global_norm(grads, lambda sq: lax.psum(sq, pp_axes))
+            gnorm = global_norm(grads, sum_pp)
         if with_digests:
             # post-sync PRE-clip per-block grad squared sums (the clip
             # below reassigns ``grads``)
@@ -2238,7 +2292,7 @@ def make_pipeline_step(
 
             # each pp device holds its stages' full (dp-summed) gradient;
             # the global norm needs the cross-stage total
-            grads = clip_tree(grads, clip_norm, lambda sq: lax.psum(sq, pp_axes))
+            grads = clip_tree(grads, clip_norm, sum_pp)
         local = {"W": stacked["W"], "b": stacked["b"]}
         new_local, opt_state = opt.apply(local, grads, opt_state)
         outs = (new_local, opt_state, loss)
@@ -2247,7 +2301,7 @@ def make_pipeline_step(
         if with_step_stats:
             from shallowspeed_tpu.optimizer import global_norm as gnorm_of
 
-            outs += (gnorm_of(new_local, lambda sq: lax.psum(sq, pp_axes)),)
+            outs += (gnorm_of(new_local, sum_pp),)
         if with_digests:
             outs += (_digest_grids(new_local, dgsq_w, dgsq_b),)
         return outs
@@ -2344,7 +2398,9 @@ def make_pipeline_step(
         )
 
         def step_impl(stacked, flags, opt_state, x, y):
-            return smapped(stacked, flags, opt_state, _fit(x, D_in), _fit(y, D_out))
+            with scope("batch"):
+                x, y = _fit(x, D_in), _fit(y, D_out)
+            return smapped(stacked, flags, opt_state, x, y)
 
         if jit:
             return jax.jit(step_impl, donate_argnums=(0, 2))
@@ -2359,7 +2415,9 @@ def make_pipeline_step(
     )
 
     def eval_impl(stacked, flags, x):
-        return smapped(stacked, flags, _fit(x, D_in))
+        with scope("batch"):
+            x = _fit(x, D_in)
+        return smapped(stacked, flags, x)
 
     return jax.jit(eval_impl) if jit else eval_impl
 

@@ -70,6 +70,8 @@ import dataclasses
 import jax.numpy as jnp
 from jax import lax
 
+from shallowspeed_tpu.observability.scopes import scoped
+
 
 @dataclasses.dataclass(frozen=True)
 class BucketLeaf:
@@ -267,6 +269,7 @@ def plan_buckets(spec, dp, pp, bucket_bytes, zero1=False, zero=None, tp=1):
     return plan_dp_buckets(spec, pp, bucket_bytes, tp=tp)
 
 
+@scoped("sync/dp")
 def psum_bucketed(grads, plan, axis_name="dp"):
     """Per-bucket DP gradient sync: for each bucket, flatten its leaves
     into ONE contiguous vector, ``lax.psum`` it (one all-reduce op per
@@ -290,6 +293,7 @@ def psum_bucketed(grads, plan, axis_name="dp"):
     return {"W": tuple(out["W"]), "b": tuple(out["b"])}
 
 
+@scoped("sync/dp")
 def psum_scatter_bucketed(gvec_padded, plan, axis_name="dp"):
     """Per-bucket ZeRO-1 gradient sync: view the padded flat gradient as
     ``(dp, chunk)`` — row d is the contiguous chunk replica d updates —

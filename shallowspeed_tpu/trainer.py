@@ -29,6 +29,7 @@ from jax import lax
 
 from shallowspeed_tpu import ops
 from shallowspeed_tpu.model import ModelSpec, model_backward, model_forward
+from shallowspeed_tpu.observability.scopes import scope
 
 
 def _digest_aux(params, grads):
@@ -148,8 +149,9 @@ def _make_batch_step(
         gradient norm."""
         if fuse_mubatches:
             rows = xb.shape[1]
-            x = xb.reshape(-1, xb.shape[-1])
-            y = yb.reshape(-1, yb.shape[-1])
+            with scope("batch"):
+                x = xb.reshape(-1, xb.shape[-1])
+                y = yb.reshape(-1, yb.shape[-1])
             out, res = model_forward(
                 params, spec, x, precision=precision, head_group_rows=rows
             )
@@ -164,8 +166,11 @@ def _make_batch_step(
             x, y = mxy
             out, res = model_forward(params, spec, x, precision=precision)
             _, grads = model_backward(params, spec, res, y, precision=precision)
-            loss = loss + ops.mse_loss(out, y, spec.global_batch_size)
-            return (jax.tree.map(jnp.add, acc, grads), loss), None
+            with scope("loss"):
+                loss = loss + ops.mse_loss(out, y, spec.global_batch_size)
+            with scope("acc"):
+                acc = jax.tree.map(jnp.add, acc, grads)
+            return (acc, loss), None
 
         zeros = jax.tree.map(jnp.zeros_like, params)
         (grads, loss), _ = lax.scan(

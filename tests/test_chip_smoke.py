@@ -147,9 +147,10 @@ def test_result_line_and_unrun_leg_are_printed(smoke, tiny_data, tmp_path, monke
 
 
 def test_compile_cache_dir_policy(tmp_path, monkeypatch):
-    """Unset: <checkout>/.jax_cache whatever the working directory. Set:
-    JAX reads the variable itself and the helper leaves the option alone."""
+    """``<base>/<scope tag>``: base is <checkout>/.jax_cache whatever the
+    working directory, or JAX_COMPILATION_CACHE_DIR when that is set."""
     from shallowspeed_tpu.compile_cache import enable_compile_cache
+    from shallowspeed_tpu.observability.scopes import CACHE_TAG
 
     before = jax.config.jax_compilation_cache_dir
     try:
@@ -158,9 +159,9 @@ def test_compile_cache_dir_policy(tmp_path, monkeypatch):
             (tmp_path / name).mkdir()
             monkeypatch.chdir(tmp_path / name)
             jax.config.update("jax_compilation_cache_dir", None)
-            assert enable_compile_cache() == str(ROOT / ".jax_cache")
+            assert enable_compile_cache() == str(ROOT / ".jax_cache" / CACHE_TAG)
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "given"))
         jax.config.update("jax_compilation_cache_dir", "as-jax-read-it")
-        assert enable_compile_cache() == "as-jax-read-it"
+        assert enable_compile_cache() == str(tmp_path / "given" / CACHE_TAG)
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
