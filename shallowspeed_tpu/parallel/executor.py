@@ -19,11 +19,18 @@ optimizer step — is ONE jitted XLA computation:
 - stage-to-stage activation/grad relays are ``jax.lax.ppermute`` shifts over
   ``pp`` (the reference's blocking Send/Recv pairs, pipe.py:367-381);
 - microbatch activation stashes (reference Module._cache) are fixed-shape
-  ring buffers carried through the scan; mailbox slots come from the lowering;
+  ring buffers carried through the scan, one per residual, shaped
+  ``(slots + 1, width, mb)``: a slot is stored FEATURE-MAJOR, the
+  orientation a v5e's forward matmuls leave their outputs in, so that a
+  tick writes its one slot in place (``_stash`` parks ``val.T``,
+  ``_unstash`` returns ``buf[slot].T``; the reason and the compiled-text
+  figures are where the rings are allocated). Mailbox slots come from the
+  lowering and stay ``(mb, width)``;
 - split-backward programs (``backward_split`` schedules, 2BP arxiv
   2405.18047) add a FOURTH switch branch: OP_BWD cells run only the
   relay-critical dgrad chain (B-input, stashing the per-slot effective
-  output-grads into a grad-stash ring), and OP_BWD_W cells — packed by the
+  output-grads into a grad-stash ring, the one ring whose slots stay
+  ``(mb, width)``), and OP_BWD_W cells — packed by the
   lowering into former bubble ticks — finish the deferred wgrads from the
   activation + grad stashes, accumulating in the combined schedule's order
   so the fp sums (and the weight hash) are bit-identical;
@@ -908,14 +915,17 @@ def zero_block_state_from_logical(logical, opt, spec: ModelSpec, mesh: Mesh, ord
 
 
 def _stash(buf, slot, val):
-    """Park ``val`` in a stash buffer's lowering-assigned ``slot``."""
+    """Park ``val`` (mb, width) in a stash ring's lowering-assigned ``slot``.
+    Slots are stored feature-major, ``(width, mb)``: see the ring
+    allocations in ``make_pipeline_step``."""
     with scope("stash"):
-        return buf.at[slot].set(val)
+        return buf.at[slot].set(val.T)
 
 
 def _unstash(buf, slot):
+    """The ``(mb, width)`` value parked in ``slot``."""
     with scope("unstash"):
-        return buf[slot]
+        return buf[slot].T
 
 
 def _microbatch(a, i):
@@ -1667,16 +1677,29 @@ def make_pipeline_step(
             # and gelu derivative VALUES (f32) for the gelu family — same
             # slot discipline, family-appropriate dtype
             mask_dtype = jnp.bool_ if act == "relu" else jnp.float32
+            # Every ring the forward side writes (xs, masks, z, xin, and
+            # inference's preds) keeps its slots FEATURE-MAJOR, (width, mb):
+            # ``_stash`` parks ``val.T``, ``_unstash`` hands ``buf[slot].T``
+            # back, callers see (mb, width) and the transposes are exact. On
+            # a v5e the forward's matmuls (x @ W.T, W stored (out, in)) leave
+            # their outputs, and the masks made from them, batch-minor, and
+            # the one-slot write wants ring and value in one layout: with
+            # rings shaped (K+1, mb, width) XLA re-laid-out every WHOLE ring
+            # twice around it. The dp2 x pp2 pipedream step at mlp-deep's
+            # shapes, compiled for v5e:2x2, held 50 whole-ring copies in its
+            # forward branch (3.4 GB per tick, 8.20 GB of temporaries); this
+            # way it holds none (5.35 GB). tests/test_op_index.py keeps the
+            # compiled text to that; docs/observability.md has the recipe.
             carry.update(
                 xs=tuple(
-                    jnp.zeros((Ks + 1, mb_sz, w), jnp.float32)
+                    jnp.zeros((Ks + 1, w, mb_sz), jnp.float32)
                     for w in xs_widths
                 ),
                 masks=tuple(
-                    jnp.zeros((Ks + 1, mb_sz, w), mask_dtype)
+                    jnp.zeros((Ks + 1, w, mb_sz), mask_dtype)
                     for w in mask_widths
                 ),
-                z=jnp.zeros((Ks + 1, mb_sz, D_out), jnp.float32),
+                z=jnp.zeros((Ks + 1, D_out, mb_sz), jnp.float32),
                 loss=jnp.zeros((), jnp.float32),
             )
             if shard_grads:
@@ -1698,7 +1721,14 @@ def make_pipeline_step(
                 # assigned by the lowering, +1 trash — sized exactly like
                 # the activation stash, because it IS the same discipline;
                 # widths match the masks': the g_eff of a slot lives in
-                # the same representation as its relu mask)
+                # the same representation as its relu mask).
+                # The one ring that stays BATCH-major, (Kg+1, mb, width): the
+                # backward writes it, and turned it (a) breaks the bitwise
+                # split == unsplit contract on the CPU, whose wgrad matmul
+                # sums a turned g_eff in another order, and (b) at mlp-deep's
+                # shapes makes the v5e compiler copy 11.2 GB of rings per
+                # B-input tick where it copies 3.2 (step temporaries 10.5 ->
+                # 16.8 GB). So g_eff goes through ``_stash`` already turned.
                 carry.update(
                     gstash=tuple(
                         jnp.zeros((Kg + 1, mb_sz, w), jnp.float32)
@@ -1712,10 +1742,10 @@ def make_pipeline_step(
                 # one). Freed at the recompute tick — the short lifetime
                 # analysis/stash.py proves
                 carry.update(
-                    xin=jnp.zeros((Kx + 1, mb_sz, D_in), jnp.float32)
+                    xin=jnp.zeros((Kx + 1, D_in, mb_sz), jnp.float32)
                 )
         else:
-            carry.update(preds=jnp.zeros((M + 1, mb_sz, D_out), jnp.float32))
+            carry.update(preds=jnp.zeros((M + 1, D_out, mb_sz), jnp.float32))
         zero_fwd = jnp.zeros((mb_sz, W_rel), jnp.float32)
         zero_bwd = jnp.zeros((mb_sz, W_rel), jnp.float32)
 
@@ -1805,7 +1835,8 @@ def make_pipeline_step(
                 true boundary width)."""
                 x_mb = _microbatch(x, mb_r)
                 with scope(read):
-                    return jnp.where(load_in, x_mb, _fit(buf[slot], D_in))
+                    parked = _unstash(buf, slot) if read == "unstash" else buf[slot]
+                    return jnp.where(load_in, x_mb, _fit(parked, D_in))
 
             def incoming_grad(c, g0):
                 """The backward's input gradient: the head's own on the
@@ -1950,7 +1981,7 @@ def make_pipeline_step(
                 c = dict(c)
                 gw = row["gw"][stage]
                 c["gstash"] = tuple(
-                    _stash(buf, gw, val) for buf, val in zip(c["gstash"], g_effs)
+                    _stash(buf, gw, val.T) for buf, val in zip(c["gstash"], g_effs)
                 )
                 return c, zero_fwd, payload_of(row["sb"][stage], dx)
 
@@ -1964,7 +1995,7 @@ def make_pipeline_step(
                 sr = row["sr"][stage]
                 gr = row["gr"][stage]
                 xs_r = tuple(_unstash(buf, sr) for buf in c["xs"])
-                geff_r = tuple(_unstash(buf, gr) for buf in c["gstash"])
+                geff_r = tuple(_unstash(buf, gr).T for buf in c["gstash"])
                 if tp_n > 1:
                     gW_d, gb_d = _stage_bwd_weight_tp(
                         active, dims, xs_r, geff_r, precision, tp_idx, tp_n
@@ -2009,7 +2040,7 @@ def make_pipeline_step(
         carry, _ = lax.scan(tick, carry, tabs, unroll=tick_unroll)
 
         if not training:
-            preds = carry["preds"][:M].reshape(M * mb_sz, D_out)
+            preds = carry["preds"][:M].swapaxes(1, 2).reshape(M * mb_sz, D_out)
             # only head-stage ticks ever wrote predictions (zeros elsewhere);
             # broadcast them over pp
             with scope("sync/pp"):

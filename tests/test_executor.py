@@ -305,3 +305,19 @@ def test_relay_width_is_true_boundary_maximum():
     assert w < spec.stages[0].in_dim
     # degenerate single-stage model: no boundary to relay
     assert E.relay_width(Mo.make_model_spec((8, 4), 1, B)) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bool_])
+def test_stash_slots_are_feature_major_and_round_trip(dtype):
+    """A ring is ``(slots, width, mb)``; what ``_stash`` parks comes back
+    from ``_unstash`` bit for bit as the ``(mb, width)`` value it was, and
+    no other slot is touched."""
+    slots, mb, width = 3, 8, 5
+    val = jnp.asarray(np.random.RandomState(0).randn(mb, width) > 0.3, dtype)
+    if dtype == jnp.float32:
+        val = val * jnp.float32(np.pi)
+    ring = E._stash(jnp.zeros((slots, width, mb), dtype), 1, val)
+    assert ring.shape == (slots, width, mb)
+    np.testing.assert_array_equal(np.asarray(ring[1]), np.asarray(val).T)
+    np.testing.assert_array_equal(np.asarray(E._unstash(ring, 1)), np.asarray(val))
+    assert not np.asarray(ring[0]).any() and not np.asarray(ring[2]).any()

@@ -190,3 +190,131 @@ def test_named_scope_is_spelled_in_scopes_py_only():
         if "named_scope(" in p.read_text()
     ]
     assert users == ["observability/scopes.py"]
+
+
+# -- the tick program's stash rings, in the text compiled for the chip --------
+#
+# The CPU compiler assigns no layouts, so only a compile for a described
+# v5e:2x2 (no chip attached, nothing runs) can show whether a tick re-lays-out
+# whole stash rings around its one-slot write (executor.py, where the rings
+# are allocated; the recipe is in docs/observability.md).
+
+# dp2 x pp2 pipedream, M 4 -> whole-ring ``copy`` the BACKWARD branch holds
+# today: it hands every ring on in the layout it took it in, to read one slot.
+# A later PR lowers these pins; the forward branch and the loop body hold none.
+RING_CASES = {
+    "deep-default": dict(
+        sizes=(784,) + (256,) * 22 + (10,), batch=4096, precision="default",
+        backward_copies=25,
+    ),
+    "mnist-highest": dict(
+        sizes=(784, 128, 127, 126, 125, 124, 123, 10), batch=65536,
+        precision="highest", backward_copies=9,
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def v5e_mesh():
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever libtpu raises where it cannot describe one
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "pp"))
+
+
+def _compiled_tick_step(mesh, sizes, batch, precision, M=4):
+    """``Compiled.as_text()`` of the training step for ``mesh`` (described
+    devices: shapes in, no array), and the program's ring geometry."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from shallowspeed_tpu import model as Mo
+    from shallowspeed_tpu.api import PRECISIONS
+    from shallowspeed_tpu.optimizer import SGD
+    from shallowspeed_tpu.parallel import executor as E
+    from shallowspeed_tpu.parallel import lower_schedule, make_mesh
+    from shallowspeed_tpu.schedules import SCHEDULES
+
+    spec = Mo.make_model_spec(sizes, 2, batch)
+    prog = lower_schedule(SCHEDULES["pipedream"], M, 2)
+    mb = batch // 2 // M
+
+    def described(shape, dtype, pspec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, pspec))
+
+    stacked, flags = jax.tree.map(
+        lambda a: described(a.shape, a.dtype, a.sharding.spec),
+        E.init_stacked(spec, make_mesh(2, 2)),
+    )
+    x, y = (described((batch, w), np.float32, P("dp")) for w in (sizes[0], sizes[-1]))
+    step = E.make_pipeline_step(
+        mesh, spec, prog, mb, SGD(0.006), precision=PRECISIONS[precision]
+    )
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = step.lower(stacked, flags, (), x, y).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    return text, prog.n_stash_slots + 1, mb
+
+
+def _moves_memory_space_only(raw_type):
+    """A ``copy-start`` whose two buffers differ in nothing but ``S(n)``:
+    the compiler parking a small ring in fast memory, not a re-layout."""
+    layouts = [re.sub(r"S\(\d+\)", "", l) for l in re.findall(r"\{[^{}]*\}", raw_type)]
+    return len(layouts) >= 2 and layouts[0] == layouts[1]
+
+
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_forward_tick_copies_no_whole_stash_ring(v5e_mesh, case):
+    from shallowspeed_tpu.observability.program_audit import parse_hlo
+
+    cfg = RING_CASES[case]
+    text, slots, mb = _compiled_tick_step(
+        v5e_mesh, cfg["sizes"], cfg["batch"], cfg["precision"]
+    )
+    instrs, _ = parse_hlo(text)
+    index = op_index(text)
+    (tick,) = [
+        i for i in instrs.values()
+        if i["opcode"] == "conditional" and len(i["called"].get("branches", ())) == 3
+    ]
+    noop, forward, backward = tick["called"]["branches"]  # the op-code order
+
+    def ring_movers(computation):
+        """``{opcode: [names]}`` of the top-level ``copy`` / ``copy-start`` /
+        ``transpose`` of ``computation`` whose result is a whole stash ring,
+        in either orientation."""
+        found = {"copy": [], "copy-start": [], "transpose": []}
+        for name, e in index.items():
+            if (
+                e["computation"] != computation
+                or e["cls"] != "stash"
+                or e["opcode"] not in found
+            ):
+                continue
+            dims = re.search(r"\[([\d,]*)\]", e["type"]).group(1).split(",")
+            dims = [int(d) for d in dims if d]
+            if len(dims) == 3 and dims[0] == slots and mb in dims[1:]:
+                found[e["opcode"]].append(name)
+        return found
+
+    for computation in (forward, noop, tick["computation"]):
+        movers = ring_movers(computation)
+        assert not movers["copy"] and not movers["transpose"], (computation, movers)
+        relayouts = [
+            n for n in movers["copy-start"]
+            if not _moves_memory_space_only(instrs[n]["type"])
+        ]
+        assert not relayouts, (computation, relayouts)
+    # also what shows that the rings were found at all
+    assert len(ring_movers(backward)["copy"]) == cfg["backward_copies"]

@@ -354,3 +354,30 @@ def test_slope_timing_failures_dict_salvages_good_configs(monkeypatch):
     assert abs(slopes["good"] - 0.01) < 1e-12
     assert "good" not in failures
     assert "stuck" in failures and "stuck" not in slopes
+
+
+def test_tick_times_splits_a_tick_into_branch_wait_and_transfer():
+    """scripts/tick_times.py on a hand-made plane: one whole step of two
+    ticks (a ``while`` holding two ``conditional``), a step loop around it
+    that is not a tick loop, and the relays that follow each branch."""
+    scripts_dir = str(ROOT / "scripts")
+    sys.path.insert(0, scripts_dir)
+    try:
+        import tick_times
+    finally:
+        sys.path.remove(scripts_dir)
+    ms = 1e6
+    events = [
+        ["while.9", 0, 100 * ms, ""],  # the step loop: one conditional-free level up
+        ["while.1", 10 * ms, 40 * ms, ""],
+        ["conditional.1", 10 * ms, 5 * ms, ""],
+        ["collective-permute-start.1", 15 * ms, 7 * ms, ""],  # waits for the partner
+        ["collective-permute-start.2", 22 * ms, 0, ""],
+        ["collective-permute-done.1", 22 * ms, 2 * ms, ""],
+        ["conditional.1", 30 * ms, 12 * ms, ""],
+        ["collective-permute-done.1", 45 * ms, 3 * ms, ""],
+    ]
+    plane = {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": events}]}
+    rows = tick_times.tick_rows(plane, 2)
+    assert rows == [[(5.0, 7.0, 2.0, 20.0)], [(12.0, 0.0, 3.0, 20.0)]]
+    assert tick_times.tick_rows(plane, 10) == [[] for _ in range(10)]
