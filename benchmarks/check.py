@@ -18,23 +18,61 @@ float32 storage itself costs: an update far below the weight's last bit is
 rounded onto the weight's grid in both runs, and two correct runs that summed
 in another order can land one grid point apart. The configuration's file
 states both numbers and the reason for them.
+
+Every tensor of every layer is compared, whatever it is called: a layer is a
+dictionary of arrays, or of further dictionaries of them (a norm's scale, a
+router, a convolution's taps), and the three runs have to hold the same ones.
 """
 
 import numpy as np
 
 
 def layers(params):
-    """``session.params()`` (a list of stages, each a list of Linears) -> the
-    Linears in model order."""
+    """``session.params()`` (a list of stages, each a list of layers) -> the
+    layers in model order."""
     return [layer for stage in params for layer in stage]
+
+
+def prefix(arrays, steps, batch, mubatches):
+    """The first ``steps`` batches of each array of the training set, as the
+    reference takes them: ``(steps, mubatches, rows, ...)``, copied out of
+    the memory maps."""
+    return [
+        np.array(a[: steps * batch]).reshape(
+            steps, mubatches, batch // mubatches, *a.shape[1:]
+        )
+        for a in arrays
+    ]
+
+
+def tensors(layer, path=()):
+    """``{"key/path": array}`` of one layer's array leaves, keys sorted at
+    every level (so ``W`` comes before ``b``)."""
+    if not isinstance(layer, dict):
+        return {"/".join(path): layer}
+    found = {}
+    for key in sorted(layer):
+        found.update(tensors(layer[key], (*path, str(key))))
+    return found
 
 
 def compare(system, reference, start, tolerance, loss=None, ref_loss=None):
     """-> ``{"ok", "worst", "where", "loss_gap", ...}``; ``worst`` is the
     largest gap in units of what is allowed (over 1 fails)."""
+    if not len(system) == len(reference) == len(start):
+        raise ValueError(
+            f"{len(system)} layers against the reference's {len(reference)} "
+            f"from a start of {len(start)}"
+        )
     worst, where, worst_max, ratios = 0.0, None, 0.0, []
-    for index, (s, r, s0) in enumerate(zip(system, reference, start)):
-        for key in ("W", "b"):
+    for index, trees in enumerate(zip(system, reference, start)):
+        s, r, s0 = map(tensors, trees)
+        if not set(s) == set(r) == set(s0):
+            raise ValueError(
+                f"layer {index}: the system holds {sorted(s)}, the reference "
+                f"{sorted(r)}, the start {sorted(s0)}"
+            )
+        for key in s:
             sys_w = np.asarray(s[key], np.float64).reshape(-1)
             ref_w = np.asarray(r[key], np.float64).reshape(-1)
             moved_w = ref_w - np.asarray(s0[key], np.float64).reshape(-1)

@@ -1,4 +1,6 @@
-"""The one traffic generator: a training set drawn from ``--seed``.
+"""Generator ``gaussian_clusters``: a training set of float rows with one-hot
+targets, drawn from ``--seed``. The width of a row and the number of classes
+are the first and the last of the session's ``sizes``.
 
 The arithmetic is ``prepare_data._load_synthetic`` followed by
 ``prepare_data.prepare``'s centring (Gaussian class clusters, noise of
@@ -24,11 +26,13 @@ def _threads():
     return max(1, min(12, (os.cpu_count() or 2) - 1))
 
 
-def make_dataset(seed, rows, dim, classes, data_dir):
+def make_dataset(seed, rows, session, data, data_dir):
     """Write ``x_train.npy`` (rows, dim) and ``y_train.npy`` (rows, classes),
     float32, the pair ``data.Dataset`` loads without pandas, and return them
     as the memory maps they were drawn into: a set of several gigabytes is
-    written once, by the threads that draw it, and never copied."""
+    written once, by the threads that draw it, and never copied. ``data``
+    (the configuration's block) holds nothing this generator reads."""
+    dim, classes = session["sizes"][0], session["sizes"][-1]
     data_dir = Path(data_dir)
     data_dir.mkdir(parents=True, exist_ok=True)
     n_chunks = -(-rows // CHUNK_ROWS)

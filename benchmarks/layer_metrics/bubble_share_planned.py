@@ -9,7 +9,7 @@ from shallowspeed_tpu.parallel.lowering import program_stats
 
 
 def read(run):
-    kw = run["cell"]["mix"]["session"]
+    kw = run["cell"]["session"]
     if kw.get("pp", 1) < 2:
         return None
     prog = lower_schedule(

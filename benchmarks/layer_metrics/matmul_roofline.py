@@ -18,7 +18,7 @@ def is_matmul(ev):
 
 def bound(run, samples):
     """-> (seconds, which) for ``samples`` on the cell's chips."""
-    kw = run["cell"]["mix"]["session"]
+    kw = run["cell"]["session"]
     rows = kw["global_batch_size"] // kw.get("dp", 1) // kw["mubatches"]
     chips, peaks = run["cell"]["chips"], run["peaks"]
     by_flops = samples * run["flops_per_sample"] / (chips * peaks["flops_per_s"])

@@ -24,10 +24,11 @@ loaded from a compile cache another tree filled: the cache key ignores
 scopes). Otherwise::
 
     {"module": "jit_epoch_core", "chips": [{
-        "steps": optimizer steps in the part of the chip's window that the
-            trace names correctly (see ``_chip``); every per-step number
-            below is over these,
-        "mislabelled_ms": device time of the operations before that part,
+        "steps": optimizer steps in the chip's window, which begins where
+            the trace names the operations correctly
+            (``xtrace.reduce_device``); every per-step number below is over
+            these,
+        "mislabelled_ms": device time of the operations dropped before it,
         "classes": {cls: {"ms_per_step": .., "ops_per_step": ..}},
         "resolved": share of non-container time whose name is in the index,
         "coverage": share of it in a class other than unattributed/unresolved,
@@ -39,9 +40,9 @@ scopes). Otherwise::
             things moved, a carry leaf or an argument:
             [[via, class, ms_per_step, MB_per_step], ...],
         "idle": stage_idle_share / device_idle_share / comm_exposed_share
-            over the correctly named part with containers dropped by the
-            index and, beside each, over the whole window with containers
-            told by name as xtrace.leaves does (what the readers publish)}],
+            with containers dropped by the index and, beside each, with
+            containers told by name as xtrace.leaves does (what the readers
+            publish)}],
      "containers": events dropped, by family: [count, summed ms],
      "unattributed": the ten largest unattributed/unresolved/mixed
         instructions: [name, opcode, type, class, ms_per_step]}
@@ -86,15 +87,15 @@ def _inside(starts, ends, at):
     return i >= 0 and at < ends[i]
 
 
-def _shares(dev, leaf, module, lo):
+def _shares(dev, leaf, module):
     """The three idle shares of the existing readers over ``leaf`` events,
-    in the chip's window from ``lo`` on."""
-    hi = dev["window"][1]
+    in the chip's window."""
+    lo, hi = dev["window"]
     compute = xtrace.union(
         xtrace.spans([ev for ev in leaf if not xtrace.is_comm(ev[0])])
     )
     envelope = xtrace.union(
-        xtrace.spans([ev for ev in dev["modules"] if ev[0] == module and ev[1] >= lo])
+        xtrace.spans([ev for ev in dev["modules"] if ev[0] == module])
     )
     sync = xtrace.union(
         xtrace.spans([ev for ev in leaf if ev[0].lower().startswith(SYNC)])
@@ -116,21 +117,11 @@ def _chip(run, dev, module, index, containers, loose):
         (ev[1], ev[1] + ev[2]) for ev in dev["modules"] if ev[0] == module
     )
     starts, ends = [r[0] for r in runs], [r[1] for r in runs]
-    # The part of the window the trace names correctly begins with the first
-    # execution labelled as the main module. On a chip that runs a second
-    # program between epochs (chip 0: jit__multi_slice) the execution already
-    # under way when the trace starts carries that program's label and every
-    # one of its operations is named ``region.<n>``: nothing to join.
-    lo, hi = dev["window"]
-    named_lo = max(lo, starts[0]) if starts else hi
-    steps = xtrace.steps_in_window(run, dev) * (hi - named_lo) / (hi - lo)
+    steps = xtrace.steps_in_window(run, dev)
     classes, moved, leaf = {}, {}, []
-    known = total = k_output = mislabelled = 0.0
+    known = total = k_output = 0.0
     for ev in dev["ops"]:
         name, at, dur, kind = ev
-        if at < named_lo:
-            mislabelled += dur
-            continue
         ours = _inside(starts, ends, at)
         entry = index.get(name) if ours else None
         if entry is None:
@@ -168,12 +159,12 @@ def _chip(run, dev, module, index, containers, loose):
     moved_by_class = {}
     for (_, cls), (ns, _) in moved.items():
         moved_by_class[cls] = moved_by_class.get(cls, 0.0) + ns / 1e6 / steps
-    by_index = _shares(dev, leaf, module, named_lo)
-    by_name = _shares(dev, dev["leaf"], module, lo)
+    by_index = _shares(dev, leaf, module)
+    by_name = _shares(dev, dev["leaf"], module)
     return {
         "name": dev["name"],
         "steps": steps,
-        "mislabelled_ms": mislabelled / 1e6,
+        "mislabelled_ms": dev["mislabelled_ns"] / 1e6,
         "classes": {
             cls: {"ms_per_step": ns / 1e6 / steps, "ops_per_step": n / steps}
             for cls, (ns, n) in sorted(classes.items())

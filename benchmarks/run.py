@@ -4,17 +4,19 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A new process builds one ``TrainingSession`` through the public API from the
-cell's configuration and mix (``cells.py``), on data drawn from the seed
-(``datagen.py``), trains the first steps for the reference check, warms up the
-one epoch program the cell runs, and then calls ``train_epoch()`` back to back
-for ``--seconds``: one dispatch and one loss readback per epoch, ``train.py``'s
-own loop. It times that loop itself with the host clock (every call ends in a
-readback, which cannot return before the device is done), reads the peak
-memory from the devices, and only then runs the plain reference
-(``references/``) and compares (``check.py``). The last line of stdout is the
-record. With ``--trace 1`` a short stretch of the same loop is traced
-afterwards and the record carries the cell's per-layer metrics, each computed
-by its own reader under ``layer_metrics/``.
+cell's configuration and mix (``cells.py``), on data drawn from the seed by
+the generator the configuration names (``datasets/``), trains the first steps
+for the reference check, warms up the one epoch program the cell runs, and
+then calls ``train_epoch()`` back to back for ``--seconds``: one dispatch and
+one loss readback per epoch, ``train.py``'s own loop. It times that loop
+itself with the host clock (every call ends in a readback, which cannot return
+before the device is done), reads the peak memory from the devices, and only
+then runs the plain reference (``references/``) and compares (``check.py``).
+The last line of stdout is the record; its last key, ``compared``, and the
+last lines of stderr hold each number that was compared beside its limit.
+With ``--trace 1`` a short stretch of the same loop is traced afterwards and
+the record carries the cell's per-layer metrics, each computed by its own
+reader under ``layer_metrics/``.
 
 The three end-to-end metrics: ``samples_per_s`` is the samples of one epoch
 over the median time from one epoch's completed readback to the next;
@@ -50,7 +52,6 @@ sys.path.insert(0, str(ROOT))  # the system under test
 
 import cells  # noqa: E402
 import check  # noqa: E402
-import datagen  # noqa: E402
 import xtrace  # noqa: E402
 import yardstick  # noqa: E402
 
@@ -213,7 +214,6 @@ def main(argv=None):
     model = cells.load_module(HERE / "references" / f"{config['reference']}.py")
 
     import jax
-    import numpy as np
 
     from compile_clock import CompileClock
     from shallowspeed_tpu.api import TrainingSession
@@ -232,20 +232,15 @@ def main(argv=None):
 
     # data from the seed, written where data.Dataset reads it, removed again
     # as soon as the session holds it
-    kwargs = {**config["session"], **mix["session"]}
-    sizes, batch = kwargs["sizes"], kwargs["global_batch_size"]
-    mubatches, steps = kwargs["mubatches"], config["check"]["steps"]
+    kwargs = cell["session"]
+    batch, mubatches = kwargs["global_batch_size"], kwargs["mubatches"]
+    steps = config["check"]["steps"]
     work_dir = WORK_DIR / cell["name"]
     data_dir = work_dir / "data"
     t = time.perf_counter()
-    X, Y = datagen.make_dataset(
-        args.seed, mix["dataset_rows"], sizes[0], sizes[-1], data_dir
-    )
-    prefix = [
-        np.array(a[: steps * batch]).reshape(steps, mubatches, batch // mubatches, -1)
-        for a in (X, Y)
-    ]
-    del X, Y
+    arrays = cells.make_dataset(cell, args.seed, mix["dataset_rows"], data_dir)
+    prefix = check.prefix(arrays, steps, batch, mubatches)
+    del arrays
     marks["data_s"] = time.perf_counter() - t
 
     t = time.perf_counter()
@@ -344,11 +339,25 @@ def main(argv=None):
     gc.collect()
     ref_params, ref_losses = model.make_reference(config)(start, *prefix)
     report = check.compare(
-        after, check.layers([ref_params]), start, config["check"],
+        after, ref_params, start, config["check"],
         loss=prefix_loss, ref_loss=sum(ref_losses) / len(ref_losses),
     )
     verdicts["reference"] = report["ok"]
     say(f"reference check: {json.dumps(report)}")
+    # each number that was compared, beside its limit (the booleans among the
+    # verdicts are on the line after); a gap that is not finite reads 1e300
+
+    def beside(value, limit):
+        return {"value": value if math.isfinite(value) else 1e300, "limit": limit}
+
+    compared = {
+        "update_gap_over_allowed": beside(report["worst"], 1.0),
+        "compiles_in_window": beside(compiles, 0),
+    }
+    if "loss_gap" in report:
+        compared["loss_gap_over_ref_loss"] = beside(
+            report["loss_gap"] / abs(report["ref_loss"]), config["check"]["loss_rtol"]
+        )
 
     flops = model.train_flops_per_sample(config)
     if peaks is not None:
@@ -414,7 +423,13 @@ def main(argv=None):
                 "device_ops": xtrace.top_device_ops(devs),
                 "idle_gaps": xtrace.top_idle_gaps(traced["trace"], devs),
             }
+    record["compared"] = compared  # last in the record, and last on stderr
     faulthandler.cancel_dump_traceback_later()
+    for name, number in compared.items():
+        print(
+            f"bench: compared {name}: {number['value']!r} limit {number['limit']!r}",
+            file=sys.stderr, flush=True,
+        )
     print(json.dumps(record), flush=True)
     return 0
 

@@ -13,7 +13,7 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-RECORD_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RECORD_KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
 
 def run_cell(workload, chips, *extra):
@@ -34,8 +34,17 @@ def test_rehearsal_prints_the_contracts_record_and_no_device_metric(cell, trace)
     done = run_cell(cell["name"], cell["chips"], "--trace", trace, "--rehearse")
     assert done.returncode == 0, done.stderr[-2000:]
     record = json.loads(done.stdout.strip().splitlines()[-1])
-    assert set(record) == RECORD_KEYS
+    assert list(record) == RECORD_KEYS  # the numbers compared come last
     assert record["correct"] is True
+    # each number compared beside its limit, in the record and as the last
+    # lines of stderr
+    compared = record["compared"]
+    assert compared["update_gap_over_allowed"]["value"] <= 1.0
+    assert compared["update_gap_over_allowed"]["limit"] == 1.0
+    assert compared["compiles_in_window"] == {"value": 0, "limit": 0}
+    last = done.stderr.strip().splitlines()[-len(compared):]
+    assert [line.split()[2].rstrip(":") for line in last] == list(compared)
+    assert all(line.startswith("bench: compared ") for line in last)
     assert record["attempted"] > 0 and record["failed"] == 0
     assert record["metrics"] == {}
     assert set(record["device"]) == {"platform", "kind", "count"}

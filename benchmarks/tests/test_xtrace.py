@@ -13,6 +13,7 @@ import xtrace
 HERE = Path(__file__).resolve().parent
 ONE_CHIP = HERE / "recorded" / "v5e_1chip_mnist_b128.json.gz"
 FOUR_CHIPS = HERE / "recorded" / "v5e_4chip_dp2pp2.json.gz"
+MISLABELLED = HERE / "recorded" / "v5e_4chip_dp2pp2_scopes.json.gz"
 
 
 def reader(name):
@@ -73,6 +74,40 @@ def test_exposed_communication_is_what_no_compute_covers():
     assert xtrace.total(dev["compute"]) == 85
     assert dev["exposed_comm"] == [(50, 85)]
     assert xtrace.reduce_device({"name": "/device:TPU:1", "lines": []}) is None
+
+
+def test_the_window_opens_at_the_first_execution_labelled_as_the_main_module():
+    def chip(n, ops, modules):
+        return {"name": f"/device:TPU:{n}", "lines": [
+            {"name": "XLA Ops", "events": ops},
+            {"name": "XLA Modules", "events": modules},
+        ]}
+
+    trace = {"planes": [
+        # runs a second program between epochs; the trace began inside one
+        # of the epoch program's executions, which carries the other's label
+        chip(0, [ev("region.7", 0, 30), ev("region.8", 5, 25), ev("copy.1", 32, 38),
+                 ev("fusion.2", 60, 80), ev("while.3", 85, 100), ev("fusion.4", 90, 100)],
+             [ev("jit__multi_slice(1)", 0, 30), ev("jit__multi_slice(1)", 31, 39),
+              ev("jit_epoch_core(2)", 50, 100)]),
+        # runs nothing else; its execution is labelled 3 ns before its first
+        # operation starts, and that label stays
+        chip(1, [ev("fusion.2", 3, 40), ev("fusion.4", 50, 100)],
+             [ev("jit_epoch_core(2)", 0, 100)]),
+        # no execution of the main module: nothing the trace names
+        chip(2, [ev("region.1", 0, 100)], [ev("jit__multi_slice(1)", 0, 100)]),
+    ]}
+    chip0, chip1 = xtrace.reduce_trace(trace)
+    assert chip0["name"] == "/device:TPU:0" and chip1["name"] == "/device:TPU:1"
+    assert chip0["window"] == (50, 100)
+    assert [e[0] for e in chip0["ops"]] == ["fusion.2", "while.3", "fusion.4"]
+    assert [e[0] for e in chip0["modules"]] == ["jit_epoch_core(2)"]
+    assert chip0["mislabelled_ns"] == 30 + 20 + 6  # loops and bodies alike
+    assert xtrace.total(chip0["busy"]) == 30
+    assert chip1["window"] == (3, 100) and chip1["mislabelled_ns"] == 0
+    assert len(chip1["ops"]) == 2 and len(chip1["modules"]) == 1
+    assert xtrace.main_module([chip0, chip1]) == "jit_epoch_core(2)"
+    assert dict(xtrace.top_device_ops([chip0, chip1])) == {"fusion": (30 + 87) / 2e9}
 
 
 def test_names():
@@ -204,6 +239,100 @@ def test_four_chips_breakdown(four_chips):
     assert ops["all-reduce"] == pytest.approx(0.004780682)
     gaps = xtrace.top_idle_gaps(traced["trace"], traced["devices"])
     assert gaps[0][0] == "host: $array.py:631 _value"  # the loss readback
+
+
+# -- four chips, recorded, chip 0's head mislabelled ---------------------------
+
+
+@pytest.fixture(scope="module")
+def mislabelled():
+    """130 ms of the same cell around an epoch boundary (PR 24). On chip 0
+    the first 48 ms belong to an execution that was under way when the trace
+    began: the profiler labelled it ``jit__multi_slice`` and named every one
+    of its operations ``region.<n>``. -> the run reduced plane by plane with
+    nothing dropped (what the readers saw until PR 26), and as
+    ``reduce_trace`` reduces it."""
+    import yardstick
+
+    trace = xtrace.load_json(MISLABELLED)
+    cell = cells.load_cell("mlp-deep.dp2pp2-b65536")
+    model = cells.load_module(HERE.parent / "references" / "mlp_sgd.py")
+
+    def run_of(devices):
+        return {
+            "traced": {"trace": trace, "devices": devices, "epoch_s": [1.015155]},
+            "session": {"steps_per_epoch": 4, "batch": 65536},
+            "cell": cell,
+            "model": model,
+            "peaks": yardstick.peaks_for("TPU v5 lite"),
+            "flops_per_sample": model.train_flops_per_sample(cell["config"]),
+        }
+
+    whole = [xtrace.reduce_device(p) for p in xtrace.device_planes(trace)]
+    return run_of(whole), run_of(xtrace.reduce_trace(trace))
+
+
+def regions(dev):
+    return sum(xtrace.op_family(e[0]) == "region" for e in dev["ops"])
+
+
+def test_a_chips_head_before_its_first_main_execution_is_dropped(mislabelled):
+    whole, named = (run["traced"]["devices"] for run in mislabelled)
+    assert [regions(d) for d in whole] == [543, 0, 0, 0]
+    assert [regions(d) for d in named] == [0, 0, 0, 0]
+    main = xtrace.main_module(named)
+    assert main.startswith("jit_epoch_core(") and main == xtrace.main_module(whole)
+    first = min(e[1] for e in whole[0]["modules"] if e[0] == main)
+    assert first == 1_168_144_127.0
+    assert named[0]["window"] == (first, whole[0]["window"][1])
+    assert len(whole[0]["ops"]) == 1412 and len(named[0]["ops"]) == 866
+    assert all(e[1] >= first for e in named[0]["ops"] + named[0]["modules"])
+    # its device time, loops and their bodies both counted, as optable did
+    assert named[0]["mislabelled_ns"] == pytest.approx(138_366_946.0)
+    # chips 1 to 3 ran nothing but the epoch program: nothing to drop
+    assert named[1:] == whole[1:]
+
+
+def test_the_readers_count_over_what_is_left(mislabelled):
+    whole, named = mislabelled
+    chip0 = named["traced"]["devices"][0]
+    steps = xtrace.steps_in_window(named, chip0)
+    assert steps == pytest.approx((1.25e9 - 1_168_144_127.0) / 1e9 * 4 / 1.015155)
+    # region was the largest "operation" of the breakdown; it is none
+    assert xtrace.top_device_ops(whole["traced"]["devices"])[0][0] == "region"
+    ops = dict(xtrace.top_device_ops(named["traced"]["devices"]))
+    assert "region" not in ops
+    assert ops["collective-permute-start"] == pytest.approx(0.02567899975)
+    per_chip = [
+        len(d["ops"]) / xtrace.steps_in_window(named, d)
+        for d in named["traced"]["devices"]
+    ]
+    assert reader("device_ops_per_step")(named) == pytest.approx(sum(per_chip) / 4)
+    # chip 0's matmul fusions were divided into the steps of the whole
+    # window, their mislabelled half not among them: 206% of the roofline.
+    # (Around an epoch boundary the steps of 130 ms are fewer than the time
+    # says, so these shares all read high; a traced run holds whole epochs.)
+    roofline = cells.load_module(HERE.parent / "layer_metrics" / "matmul_roofline.py")
+
+    def share(run, dev):
+        matmul_s = sum(e[2] for e in dev["leaf"] if roofline.is_matmul(e)) / 1e9
+        samples = xtrace.steps_in_window(run, dev) * 65536
+        return roofline.bound(run, samples)[0] / matmul_s
+
+    assert share(whole, whole["traced"]["devices"][0]) == pytest.approx(2.0615448)
+    assert share(named, chip0) == pytest.approx(1.2980735)
+    assert share(named, named["traced"]["devices"][1]) == pytest.approx(1.4581088)
+    assert roofline.read(whole) == pytest.approx(150.83483)
+    assert roofline.read(named) == pytest.approx(131.74805)
+    # the idle gaps are those of a chip whose window is whole: chip 0's, cut,
+    # holds no epoch boundary and so none of the host's gaps
+    trace = named["traced"]["trace"]
+    assert dict(xtrace.top_idle_gaps(trace, [chip0])) == {
+        "in jit_epoch_core: between ops": pytest.approx(0.018817554)
+    }
+    gaps = xtrace.top_idle_gaps(trace, named["traced"]["devices"])
+    assert gaps == xtrace.top_idle_gaps(trace, named["traced"]["devices"][1:2])
+    assert gaps[0][0].startswith("host: ") and gaps[0][1] > 0.02
 
 
 def test_cut_clips_and_json_round_trips(tmp_path, one_chip):

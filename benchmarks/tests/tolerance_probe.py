@@ -25,25 +25,20 @@ sys.path[:0] = [str(HERE.parent), str(HERE)]
 
 import cells  # noqa: E402
 import check  # noqa: E402
-import datagen  # noqa: E402
 
 
 def main(workload, lower, seed=1, rehearse=False):
-    import numpy as np
-
     from shallowspeed_tpu import model as Mo
 
     cell = cells.load_cell(workload, rehearse=rehearse)
-    config, mix = cell["config"], cell["mix"]
-    kw = {**config["session"], **mix["session"]}
+    config, kw = cell["config"], cell["session"]
     batch, mub, steps = kw["global_batch_size"], kw["mubatches"], config["check"]["steps"]
-    sizes = kw["sizes"]
     data_dir = HERE.parent / "data" / "bench" / "tolerance_probe"
-    X, Y = datagen.make_dataset(seed, steps * batch, sizes[0], sizes[-1], data_dir)
-    prefix = [np.array(a).reshape(steps, mub, batch // mub, -1) for a in (X, Y)]
-    del X, Y
+    arrays = cells.make_dataset(cell, seed, steps * batch, data_dir)
+    prefix = check.prefix(arrays, steps, batch, mub)
+    del arrays
     shutil.rmtree(data_dir, ignore_errors=True)
-    start = check.layers(Mo.init_model(Mo.make_model_spec(sizes, 1, batch)))
+    start = check.layers(Mo.init_model(Mo.make_model_spec(kw["sizes"], 1, batch)))
     reference = cells.load_module(HERE / "references" / f"{config['reference']}.py")
     lowered = copy.deepcopy(config)
     lowered["session"]["precision"] = lower

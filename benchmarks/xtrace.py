@@ -183,25 +183,64 @@ def leaves(events):
 # -- one device, reduced ---------------------------------------------------
 
 
-def reduce_device(plane):
+def main_module(devices):
+    """The program the loop runs: the module name with the most device time
+    (``devices``: anything with the chips' ``modules`` events)."""
+    seconds = {}
+    for dev in devices:
+        for ev in dev["modules"]:
+            seconds[ev[0]] = seconds.get(ev[0], 0.0) + ev[2]
+    return max(seconds, key=seconds.get) if seconds else None
+
+
+def named_from(modules, main):
+    """Where the part of a chip's trace that names the operations correctly
+    begins: at the start of the first execution labelled as the main module
+    (``None``: the chip has no such execution). On a chip that runs a second
+    program between epochs (chip 0 of a mesh: ``jit__multi_slice``) the
+    execution already under way when the trace starts carries that program's
+    label and every one of its operations is named ``region.<n>``: no
+    operation of the program's, and nothing to count a step's work by."""
+    starts = [ev[1] for ev in modules if ev[0] == main]
+    return min(starts) if starts else None
+
+
+def reduce_device(plane, main=None):
     """Everything the readers need from one chip's plane, or ``None`` when no
     operation ran on it in the trace. The window is the span of the chip's own
     events: its clock and the host's differ by about a millisecond, and the
     chip is inside a program at both edges of a window cut out of a training
-    loop, so the span neither hides nor invents idle time."""
+    loop, so the span neither hides nor invents idle time. With ``main`` (the
+    trace's main module, as ``reduce_trace`` passes it) the window begins
+    where ``named_from`` says, the events before it are dropped, and
+    ``mislabelled_ns`` is the device time of the operations that went."""
     ops = line_events(plane, OPS_LINE)
     if not ops:
         return None
-    leaf = leaves(ops)
+    modules = line_events(plane, MODULES_LINE)
     lo = min(ev[1] for ev in ops)
     hi = max(ev[1] + ev[2] for ev in ops)
+    mislabelled = 0.0
+    if main is not None:
+        first = named_from(modules, main)
+        if first is None:
+            return None
+        # an execution may be labelled a little before its first operation
+        # starts: the window still opens no earlier than the chip's events
+        lo = max(lo, first)
+        mislabelled = sum(ev[2] for ev in ops if ev[1] < first)
+        ops = [ev for ev in ops if ev[1] >= first]
+        modules = [ev for ev in modules if ev[1] >= first]
+        if not ops:
+            return None
+    leaf = leaves(ops)
     busy = union(spans(leaf))
     comm = union(spans([ev for ev in leaf if is_comm(ev[0])]))
     compute = union(spans([ev for ev in leaf if not is_comm(ev[0])]))
-    modules = line_events(plane, MODULES_LINE)
     return {
         "name": plane["name"],
         "window": (lo, hi),
+        "mislabelled_ns": mislabelled,
         "ops": ops,
         "leaf": leaf,
         "busy": busy,
@@ -213,16 +252,14 @@ def reduce_device(plane):
 
 
 def reduce_trace(trace):
-    return [d for d in map(reduce_device, device_planes(trace)) if d is not None]
-
-
-def main_module(devices):
-    """The program the loop runs: the module name with the most device time."""
-    seconds = {}
-    for dev in devices:
-        for ev in dev["modules"]:
-            seconds[ev[0]] = seconds.get(ev[0], 0.0) + ev[2]
-    return max(seconds, key=seconds.get) if seconds else None
+    """The chips of the trace, each reduced over the part of its span that
+    the trace names correctly (``named_from``)."""
+    planes = device_planes(trace)
+    main = main_module(
+        [{"modules": line_events(plane, MODULES_LINE)} for plane in planes]
+    )
+    reduced = [reduce_device(plane, main) for plane in planes]
+    return [dev for dev in reduced if dev is not None]
 
 
 # -- the breakdown ---------------------------------------------------------
@@ -262,13 +299,18 @@ def _innermost_host_span(host_lines, at_ns):
 
 
 def top_idle_gaps(trace, devices, n=10):
-    """``[[what, seconds], ...]``: the idle time of the busiest chip's window,
-    summed by what covers it. A gap inside a program execution is the
-    device's own (``in <module>: between ops``); one between two executions is
-    the host's, named by the innermost host span at its middle."""
+    """``[[what, seconds], ...]``: the idle time of one chip's window, summed
+    by what covers it: the chip whose window is longest (a chip whose
+    mislabelled head was dropped may hold no epoch boundary, and so none of
+    the host's gaps), of several the first. A gap inside a program execution
+    is the device's own (``in <module>: between ops``); one between two
+    executions is the host's, named by the innermost host span at its
+    middle."""
     if not devices:
         return []
-    dev = min(devices, key=lambda d: int(d["name"].rsplit(":", 1)[1]))
+    dev = min(
+        devices, key=lambda d: (-window_s(d), int(d["name"].rsplit(":", 1)[1]))
+    )
     host_lines = _host_lines(trace)
     inside = union(spans(dev["modules"]))
     seconds = {}
