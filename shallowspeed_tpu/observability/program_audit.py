@@ -20,7 +20,7 @@ the compiled-program evidence:
   spec and the lowered tick tables (``lowering.program_comm_bytes``) —
   which collective kinds the layout requires/forbids, and the bytes each
   device moves per optimizer step per mesh axis (dp ring all-reduce of the
-  gradient, 2 ppermutes x relay width x ticks for the pipeline,
+  gradient, issued relays x relay width for the pipeline,
   reduce-scatter + all-gather under ZeRO-1), with a bandwidth-bound
   lower-bound step time against the interconnect peak and a comms- vs
   compute-bound verdict;
@@ -37,9 +37,11 @@ variadic op), version-dependently; loss psums, pmax replication and the
 norm reductions add more. Exact all-reduce counts are therefore compiler
 noise, but the KIND set is the layout's signature: a sequential program
 must contain no collectives at all, a pipeline (pp > 1) program must
-relay through collective-permutes (one per direction, so >= 2; at pp == 1
-the executor's permutes are device-local self-loops — allowed in the
-census, never demanded nor counted as interconnect traffic), dp > 1
+relay through collective-permutes (one per direction in which its tick
+table ever sends: two for a training program, one for an inference one; at
+pp == 1 no pair of devices ever sends and the executor emits none — the
+census allows one, never demands it nor counts it as interconnect
+traffic), dp > 1
 without ZeRO-1 must all-reduce and must NOT reduce-scatter/all-gather,
 and ZeRO-1 must reduce-scatter AND all-gather (even at dp=1 — the
 chunked update always lowers both).
@@ -505,14 +507,17 @@ def expected_comms(
     - ``axes``: per-mesh-axis expected traffic, bytes PER DEVICE PER
       OPTIMIZER STEP (one global batch):
 
-      * ``pp`` (pp > 1 only — at pp == 1 the executor's permutes are
-        device-local self-loops, not interconnect traffic): 2 ppermutes
-        (one per direction) every tick, payload
-        ``mubatch_size x relay_width`` f32 — wire bytes are
-        ``2 * ticks * payload`` from the ACTUAL tick tables
-        (``lowering.program_comm_bytes``), so masked no-op ticks are
-        counted (the SPMD program really ships their zero payloads), and
-        the useful (send-table) bytes ride alongside;
+      * ``pp`` (pp > 1 only — at pp == 1 no device pair ever sends):
+        one ppermute per direction, issued in the ticks in which some
+        device has a payload due in it, over the pairs that ever send;
+        payload ``mubatch_size x relay_width`` f32 — wire bytes are
+        ``issued relays x payload`` on the busiest device, from the
+        ACTUAL tick tables (``lowering.program_comm_bytes``): within an
+        issued relay the devices with nothing due ship zero payloads and
+        are counted, a tick with nothing due in a direction ships nothing;
+        the useful (send-table) bytes ride alongside, and
+        ``hlo_min_permute_ops`` is the number of directions that ever
+        send, which ``check_census`` demands of the compiled program;
       * ``dp`` (no zero1): the gradient psum as a ring all-reduce —
         ``2 * (dp-1)/dp x grad_bytes`` where ``grad_bytes`` is this
         device's PADDED stacked gradient (slot stacks x 4 bytes);
@@ -611,27 +616,23 @@ def expected_comms(
             }
             required.append("all_reduce")
         if pp > 1:
-            # only a real pipeline axis demands the relay permutes; at
-            # pp == 1 the executor still emits them, but as SELF-LOOPS —
-            # present in the census (allowed), zero interconnect traffic
-            # (an on-device copy must not inflate the bandwidth bound)
+            # only a real pipeline axis relays: at pp == 1 no device pair
+            # ever sends, and the executor emits no permute at all
             required.append("collective_permute")
             comm = program_comm_bytes(prog, spec, mubatch_size)
-            # the executor emits BOTH directions every tick, but an
-            # inference program never reads its backward mailbox, so XLA
-            # dead-code-eliminates that whole direction (observed on the
-            # compiled census: exactly one permute survives) — the wire
-            # model and the census rule both count one direction
-            wire = comm["wire_bytes_per_device"]
-            useful = comm["useful_bytes_per_device"]
-            if inference:
-                wire //= 2
+            # the relays follow the send tables: one permute per direction
+            # that ever sends (an inference program has no backward one),
+            # issued in that direction's due ticks over its sending pairs
             axes["pp"] = {
                 "kind": "collective_permute",
                 "ticks": comm["num_ticks"],
+                "hlo_min_permute_ops": sum(
+                    n > 0
+                    for n in (comm["relays_issued_fwd"], comm["relays_issued_bwd"])
+                ),
                 "payload_bytes": comm["relay_payload_bytes"],
-                "bytes_per_step_per_device": wire,
-                "useful_bytes_per_step_per_device": useful,
+                "bytes_per_step_per_device": comm["wire_bytes_per_device"],
+                "useful_bytes_per_step_per_device": comm["useful_bytes_per_device"],
             }
         if inference:
             # inference/serving program: a forward-only relay plus ONE
@@ -777,16 +778,18 @@ def check_census(census, expected, ops=None):
                 f"forbidden collective {kind!r} appears {n}x in the "
                 "compiled program"
             )
-    if "collective_permute" in expected.get("required", ()):
-        n = census.get("collective_permute", {}).get("count", 0)
-        # inference programs relay ONE direction (the backward mailbox is
-        # dead code and XLA eliminates its permute), so the both-directions
-        # rule applies to training programs only
-        if 0 < n < 2 and not expected.get("inference"):
-            mismatches.append(
-                "pipeline relay must permute in BOTH directions "
-                f"(>= 2 collective-permutes); compiled program has {n}"
-            )
+    pp_axis = (expected.get("axes") or {}).get("pp") or {}
+    want = pp_axis.get("hlo_min_permute_ops", 0)
+    n = census.get("collective_permute", {}).get("count", 0)
+    # one permute per direction in which the tick table ever sends: two for
+    # a training pipeline, one for an inference one. Zero is the required-
+    # kinds leg's to report.
+    if 0 < n < want:
+        mismatches.append(
+            f"pipeline relay must permute in every direction its tick table "
+            f"sends in (>= {want} collective-permutes); compiled program "
+            f"has {n}"
+        )
     tp_axis = (expected.get("axes") or {}).get("tp") or {}
     if expected.get("inference") and not tp_axis:
         # a forward-only program has exactly one lawful all-reduce — the

@@ -13,7 +13,7 @@ scope belongs to a class, the unit the benchmark's class table
     pointwise  act, softmax, loss, head_grad            (ops.py)
     stash      stash, unstash        (executor: activation/grad stashes)
     mailbox    mail                  (executor: relay mailboxes, payloads)
-    relay      relay                 (executor: the two ppermutes of a tick)
+    relay      relay                 (executor: the ppermutes a tick has due)
     grad_acc   acc                   (microbatch gradient accumulation)
     sync       sync/dp, sync/pp      (gradsync, executor step tail)
     update     update                (optimizer.py: apply, clip, norms)
@@ -65,7 +65,7 @@ SCOPES = tuple(_CLASS_OF)
 
 # Bump when a scope MOVES to other code without any name changing: the
 # compile cache cannot tell such a tree from its parent (see CACHE_TAG).
-_SALT = "1"
+_SALT = "2"  # PR 29: ``mail`` also names the mailboxes' allocation
 
 
 def cache_tag(names=SCOPES, salt=_SALT):

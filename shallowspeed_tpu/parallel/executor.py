@@ -17,7 +17,14 @@ optimizer step — is ONE jitted XLA computation:
   ``lax.switch``es between {noop, forward, backward} — pipeline bubbles are
   the noop branch (masked compute, like the blank cells of the pebble graph);
 - stage-to-stage activation/grad relays are ``jax.lax.ppermute`` shifts over
-  ``pp`` (the reference's blocking Send/Recv pairs, pipe.py:367-381);
+  ``pp`` (the reference's blocking Send/Recv pairs, pipe.py:367-381), and
+  they follow the send tables: a direction's ``ppermute`` and its mailbox
+  write are issued only in the ticks in which SOME stage has a payload due
+  in it, over the device pairs on which some tick sends. That predicate is
+  a column of the static tick table, the same scalar on every device:
+  uniform across devices is what SPMD asks of a collective, uniform across
+  ticks it does not. A tick with nothing due issues no collective and does
+  not make two stages wait for each other;
 - microbatch activation stashes (reference Module._cache) are fixed-shape
   ring buffers carried through the scan, one per residual, shaped
   ``(slots + 1, width, mb)``: a slot is stored FEATURE-MAJOR, the
@@ -25,7 +32,9 @@ optimizer step — is ONE jitted XLA computation:
   tick writes its one slot in place (``_stash`` parks ``val.T``,
   ``_unstash`` returns ``buf[slot].T``; the reason and the compiled-text
   figures are where the rings are allocated). Mailbox slots come from the
-  lowering and stay ``(mb, width)``;
+  lowering; the forward mailbox and its payload are feature-major too (the
+  forward writes the one and reads the other), the backward ones stay
+  ``(mb, width)``;
 - split-backward programs (``backward_split`` schedules, 2BP arxiv
   2405.18047) add a FOURTH switch branch: OP_BWD cells run only the
   relay-critical dgrad chain (B-input, stashing the per-slot effective
@@ -1628,13 +1637,19 @@ def make_pipeline_step(
         # recompute programs route the input-stash write/read pair (the
         # forward stores its stage input; the recompute frees it)
         tab_dict.update(xw=prog.xin_write, xr=prog.xin_read)
+    # relays follow the send tables, in space and in time. In space: a
+    # direction's perm holds the device pairs on which some tick sends (with
+    # virtual chunks the device-(P-1) -> device-0 wrap IS a stage boundary,
+    # chunk c on the last device feeding chunk c+1 on the first; without
+    # chunks nothing ever sends on the wrap link and the perm leaves it out),
+    # and a direction that never sends (inference's backward, a pp 1 mesh)
+    # has no perm and no ppermute. In time: ``rlf``/``rlb`` are (T,) columns,
+    # true in the ticks in which SOME device sends in that direction: one
+    # scalar per tick, the same on every device, so every member of the
+    # collective's group takes the same side of the conditional around it.
+    fwd_perm, bwd_perm = prog.relay_perms()
+    tab_dict.update(rlf=prog.relay_fwd, rlb=prog.relay_bwd)
     tabs = jax.tree.map(np.asarray, tab_dict)
-    # ring shifts: with virtual chunks the device-(P-1) -> device-0 wrap IS a
-    # stage boundary (chunk c on the last device feeds chunk c+1 on the
-    # first); without chunks nothing ever sends on the wrap link and its zero
-    # payload lands in the receiver's trash slot
-    fwd_perm = [(d, (d + 1) % P_) for d in range(P_)]
-    bwd_perm = [(d, (d - 1) % P_) for d in range(P_)]
 
     def per_device(stacked, flags, opt_state, x, y):
         # local views: stage axis is sharded to V rows per device on pp
@@ -1664,10 +1679,25 @@ def make_pipeline_step(
             x = x.reshape(M, mb_sz, D_in)  # local dp shard, padded to D_in
             y = y.reshape(M, mb_sz, D_out) if y is not None else None
 
-        carry = dict(
-            fwd_mail=jnp.zeros((Kf + 1, mb_sz, W_rel), jnp.float32),
-            bwd_mail=jnp.zeros((Kb + 1, mb_sz, W_rel), jnp.float32),
-        )
+        # The forward mailbox and the forward payload are FEATURE-MAJOR,
+        # (width, mb), like the stash rings below and for their reason: that
+        # is how a v5e's forward leaves its output and wants its input. The
+        # relay's conditional is laid out before the tick body that calls
+        # it, from its operands' logical shapes alone: with (mb, width) it
+        # kept the mailbox batch-major and the forward branch then turned
+        # the WHOLE mailbox to read one slot (text compiled for v5e:2x2 at
+        # mlp-deep's shapes: a 134 MB copy per forward tick). This way
+        # neither the payload (67 MB, twice a tick on the parent) nor the
+        # mailbox is re-laid-out anywhere; tests/test_op_index.py holds the
+        # compiled text to that. The backward's dgrad leaves its payload
+        # batch-major, so that direction stays (mb, width).
+        with scope("mail"):
+            # named here: only conditionals touch the mailboxes now, and the
+            # op index does not follow a carry leaf into one to class this
+            carry = dict(
+                fwd_mail=jnp.zeros((Kf + 1, W_rel, mb_sz), jnp.float32),
+                bwd_mail=jnp.zeros((Kb + 1, mb_sz, W_rel), jnp.float32),
+            )
         if training:
             # residual stashes (lowering-assigned slots, +1 trash), grad
             # accumulators, head-logit stash and the loss tally only exist in
@@ -1746,7 +1776,17 @@ def make_pipeline_step(
                 )
         else:
             carry.update(preds=jnp.zeros((M + 1, D_out, mb_sz), jnp.float32))
-        zero_fwd = jnp.zeros((mb_sz, W_rel), jnp.float32)
+        # The payload a branch does not produce. The forward one is made
+        # inside each branch and the backward one once, outside the loop: two
+        # hoisted zero buffers (they no longer have one shape to share) cost
+        # the epoch program 25 MB of temporaries over the parent's, and two
+        # made in the branches let the compiler hand the backward payload on
+        # batch-minor and turn it twice per backward tick; this way the
+        # program's temporaries are 42 MB UNDER the parent's (compiled for
+        # v5e:2x2 at mlp-deep's shapes: 4,192 MB for 4,234).
+        def zero_fwd():
+            return jnp.zeros((W_rel, mb_sz), jnp.float32)
+
         zero_bwd = jnp.zeros((mb_sz, W_rel), jnp.float32)
 
         @scoped("tick")
@@ -1835,7 +1875,7 @@ def make_pipeline_step(
                 true boundary width)."""
                 x_mb = _microbatch(x, mb_r)
                 with scope(read):
-                    parked = _unstash(buf, slot) if read == "unstash" else buf[slot]
+                    parked = _unstash(buf, slot) if read == "unstash" else buf[slot].T
                     return jnp.where(load_in, x_mb, _fit(parked, D_in))
 
             def incoming_grad(c, g0):
@@ -1856,7 +1896,7 @@ def make_pipeline_step(
                     return jnp.where(send == 1, _fit(out, W_rel), 0.0)
 
             def noop(c):
-                return c, zero_fwd, zero_bwd
+                return c, zero_fwd(), zero_bwd
 
             def run_stage_fwd(Ws, bs, active, relu, residual, x_in):
                 """The ONE stage-forward call both the forward tick and the
@@ -1905,7 +1945,7 @@ def make_pipeline_step(
                         c["loss"] = c["loss"] + jnp.where(is_head, mb_loss, 0.0)
                 else:
                     c["preds"] = _stash(c["preds"], mb_i, jnp.where(is_head, p, 0.0))
-                return c, payload_of(row["sf"][stage], out), zero_bwd
+                return c, payload_of(row["sf"][stage], out).T, zero_bwd
 
             def recompute(c):
                 # OP_RECOMPUTE: re-run the stage forward from the parked
@@ -1928,7 +1968,7 @@ def make_pipeline_step(
                     _stash(buf, sw, val) for buf, val in zip(c["masks"], masks_l)
                 )
                 c["z"] = _stash(c["z"], sw, out)
-                return c, zero_fwd, zero_bwd
+                return c, zero_fwd(), zero_bwd
 
             def backward(c):
                 Ws, bs, active, relu, residual, head_mask = chunk_params()
@@ -1953,7 +1993,7 @@ def make_pipeline_step(
                         precision, kernel_backend, act=act, residual=residual,
                     )
                 c = accumulate(dict(c), gW_d, gb_d)
-                return c, zero_fwd, payload_of(row["sb"][stage], dx)
+                return c, zero_fwd(), payload_of(row["sb"][stage], dx)
 
             def backward_input(c):
                 # split B-input: the combined backward's dgrad chain at the
@@ -1983,7 +2023,7 @@ def make_pipeline_step(
                 c["gstash"] = tuple(
                     _stash(buf, gw, val.T) for buf, val in zip(c["gstash"], g_effs)
                 )
-                return c, zero_fwd, payload_of(row["sb"][stage], dx)
+                return c, zero_fwd(), payload_of(row["sb"][stage], dx)
 
             def backward_weight(c):
                 # split B-weight: wgrads from the two stashes, accumulated
@@ -2005,7 +2045,7 @@ def make_pipeline_step(
                         active, dims, xs_r, geff_r, precision
                     )
                 c = accumulate(dict(c), gW_d, gb_d)
-                return c, zero_fwd, zero_bwd
+                return c, zero_fwd(), zero_bwd
 
             # branch order is the op-code encoding: OP_NOOP=0, OP_FWD=1,
             # OP_BWD=2 (B-input when split), OP_BWD_W=3, OP_RECOMPUTE=4
@@ -2022,21 +2062,37 @@ def make_pipeline_step(
                 branches.append(recompute)
             carry, fwd_out, bwd_out = lax.switch(opv, branches, carry)
 
-            # uniform collectives outside the switch: relay payloads
-            with scope("relay"):
-                incoming_f = lax.ppermute(fwd_out, "pp", fwd_perm)
-                incoming_b = lax.ppermute(bwd_out, "pp", bwd_perm)
-            with scope("mail"):
-                carry["fwd_mail"] = (
-                    carry["fwd_mail"].at[row["inf"][stage]].set(incoming_f)
-                )
-                carry["bwd_mail"] = (
-                    carry["bwd_mail"].at[row["inb"][stage]].set(incoming_b)
-                )
+            # collectives outside the switch, uniform ACROSS DEVICES and not
+            # across ticks: a direction's ppermute and its mailbox write run
+            # only in the ticks in which the tick table has a payload due in
+            # that direction (row["rlf"]/row["rlb"], read without [stage]).
+            # A skipped relay would have carried zeros into a trash slot no
+            # branch reads, and it would have held the stages to each other
+            # for a tick in which neither has anything for the other.
+            def relay(mail, payload, slot, perm):
+                with scope("relay"):
+                    incoming = lax.ppermute(payload, "pp", perm)
+                with scope("mail"):
+                    return mail.at[slot].set(incoming)
+
+            for mail, out, perm, due, slots in (
+                ("fwd_mail", fwd_out, fwd_perm, row["rlf"], row["inf"]),
+                ("bwd_mail", bwd_out, bwd_perm, row["rlb"], row["inb"]),
+            ):
+                if perm:
+                    carry[mail] = lax.cond(
+                        due,
+                        partial(relay, perm=perm),
+                        lambda mail, payload, slot: mail,
+                        carry[mail],
+                        out,
+                        slots[stage],
+                    )
             return carry, None
 
         # tick_unroll amortizes the scan's per-tick loop overhead (each tick
-        # body is one small stage compute + two ppermutes); numerics identical
+        # body is one small stage compute + the relays it has due); numerics
+        # identical
         carry, _ = lax.scan(tick, carry, tabs, unroll=tick_unroll)
 
         if not training:

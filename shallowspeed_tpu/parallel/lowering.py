@@ -118,6 +118,35 @@ class TickProgram:
     xin_write: np.ndarray = None  # (T, S) int32: xin slot a forward fills
     xin_read: np.ndarray = None  # (T, S) int32: xin slot a recompute frees
 
+    # Where a relay is due, read off the send tables (derived, so a program
+    # whose send tables are replaced cannot disagree with them). The executor
+    # issues a direction's ``ppermute`` only in the ticks of its column and
+    # only over the pairs of its perm: every device sees the same column and
+    # the same perm, which is all that SPMD asks of a collective.
+    @property
+    def relay_fwd(self):
+        """(T,) bool: some device emits a forward payload at tick t."""
+        return self.send_fwd.any(axis=1)
+
+    @property
+    def relay_bwd(self):
+        """(T,) bool: some device emits a backward payload at tick t."""
+        return self.send_bwd.any(axis=1)
+
+    def relay_perms(self):
+        """``(fwd_perm, bwd_perm)``: the ``(source, destination)`` device
+        pairs on which some tick sends. Forward payloads go d -> (d+1) % P,
+        backward ones d -> (d-1) % P; without virtual chunks nothing ever
+        sends on the wrap link (P-1 -> 0 forward, 0 -> P-1 backward), so a
+        V = 1 program drops it and an interleaved one keeps it. A direction
+        whose table never sends (inference's backward, a pp 1 mesh) has no
+        pair and gets no ``ppermute``."""
+        P = self.num_stages
+        return tuple(
+            [(int(d), int(d + step) % P) for d in np.flatnonzero(table.any(axis=0))]
+            for table, step in ((self.send_fwd, 1), (self.send_bwd, -1))
+        )
+
 
 class ScheduleLoweringError(ValueError):
     pass
@@ -230,6 +259,11 @@ def program_stats(prog, spec=None, mubatch_size=None, tp=1):
         "cells_recompute": int(np.sum(prog.op == OP_RECOMPUTE)),
         "sends_fwd": int(np.sum(prog.send_fwd)),
         "sends_bwd": int(np.sum(prog.send_bwd)),
+        # ticks in which the executor issues each direction's ppermute; the
+        # share of relays the send tables let it skip is
+        # 1 - (issued_fwd + issued_bwd) / (2 * num_ticks)
+        "relays_issued_fwd": int(prog.relay_fwd.sum()),
+        "relays_issued_bwd": int(prog.relay_bwd.sum()),
         "fwd_mail_slots": int(prog.n_fwd_slots),
         "bwd_mail_slots": int(prog.n_bwd_slots),
         "stash_slots": int(prog.n_stash_slots),
@@ -302,19 +336,28 @@ def program_comm_bytes(prog, spec, mubatch_size):
     — the pp-axis leg of the observability comms model
     (observability/program_audit.expected_comms).
 
-    The executor relays with TWO uniform ``lax.ppermute``s (one per
-    direction) EVERY tick, payload ``(mubatch_size, relay_width)`` f32 —
-    masked no-op ticks ship zero payloads, but they are shipped (that
-    uniformity is what makes the program SPMD), so the wire bytes each
-    device moves per step are ``2 * num_ticks * payload``. The useful
-    bytes (ticks whose send tables actually emit) ride alongside so the
-    relay's own padding tax is a recorded number too. Computed from the
-    ACTUAL tick tables, like ``program_stats``/``program_flops``.
+    The executor relays with one ``lax.ppermute`` per direction, payload
+    ``(mubatch_size, relay_width)`` f32, and the relays follow the send
+    tables: a direction's ``ppermute`` is issued only in the ticks in which
+    SOME device sends in it (``prog.relay_fwd`` / ``relay_bwd``) and only
+    over the device pairs on which some tick sends (``prog.relay_perms()``).
+    What makes the program SPMD is that every device issues the same
+    collectives in the same ticks — uniform across devices, not across
+    ticks. Within an issued relay a device that has nothing due still ships
+    a zero payload over each pair it is the source of, so the wire bytes a
+    device moves per step are ``payload x`` (issued forward relays if it is
+    a forward source + issued backward relays if it is a backward source).
+    The useful bytes (send-table entries) ride alongside so the relay's own
+    padding tax is a recorded number too. Computed from the ACTUAL tick
+    tables, like ``program_stats``/``program_flops``.
 
     Returns plain scalars (JSON-able as-is): ``relay_payload_bytes`` (one
-    direction, one tick), ``wire_bytes_per_device`` (2 x ticks x payload),
-    ``useful_bytes_per_device`` (mean over devices of the send-table
-    bytes), ``useful_sends`` (total send-table count), ``num_ticks``.
+    direction, one relay), ``relays_issued_fwd`` / ``relays_issued_bwd``,
+    ``wire_bytes_by_device`` (bytes each pp device sends per step) and
+    ``wire_bytes_per_device`` (their maximum: the busiest link's load, what
+    a bandwidth bound has to cover), ``useful_bytes_per_device`` (mean over
+    devices of the send-table bytes), ``useful_sends`` (total send-table
+    count), ``num_ticks``.
 
     This function covers the pp-axis relay only. The dp-axis gradient-sync
     leg — one anchor collective, or one collective PER BYTE-BUCKET when
@@ -326,10 +369,19 @@ def program_comm_bytes(prog, spec, mubatch_size):
 
     payload = 4 * mubatch_size * relay_width(spec)
     useful_sends = int(np.sum(prog.send_fwd) + np.sum(prog.send_bwd))
+    issued_fwd, issued_bwd = int(prog.relay_fwd.sum()), int(prog.relay_bwd.sum())
+    fwd_src, bwd_src = ({src for src, _ in perm} for perm in prog.relay_perms())
+    by_device = [
+        payload * (issued_fwd * (d in fwd_src) + issued_bwd * (d in bwd_src))
+        for d in range(prog.num_stages)
+    ]
     return {
         "relay_payload_bytes": int(payload),
         "num_ticks": int(prog.num_ticks),
-        "wire_bytes_per_device": int(2 * prog.num_ticks * payload),
+        "relays_issued_fwd": issued_fwd,
+        "relays_issued_bwd": issued_bwd,
+        "wire_bytes_by_device": [int(b) for b in by_device],
+        "wire_bytes_per_device": int(max(by_device)),
         "useful_sends": useful_sends,
         "useful_bytes_per_device": useful_sends * payload / prog.num_stages,
     }
@@ -985,7 +1037,7 @@ def lower_schedule(
             [[r[s][key] for s in range(num_stages)] for r in rows], np.int32
         )
 
-    return TickProgram(
+    prog = TickProgram(
         num_ticks=T,
         num_stages=num_stages,
         num_micro_batches=num_micro_batches,
@@ -1017,3 +1069,19 @@ def lower_schedule(
         xin_write=table("xw", K_x),
         xin_read=table("xr", K_x),
     )
+    # the executor relays a direction only in its due ticks and over its kept
+    # pairs: every payload the replay delivered must arrive in such a tick,
+    # over such a pair, or the executor would never write it to its slot
+    fwd_perm, bwd_perm = prog.relay_perms()
+    for name, slots, trash, due, perm in (
+        ("forward", prog.in_fwd_slot, K_f, prog.relay_fwd, fwd_perm),
+        ("backward", prog.in_bwd_slot, K_b, prog.relay_bwd, bwd_perm),
+    ):
+        receivers = {dst for _, dst in perm}
+        for ti, s in zip(*np.nonzero(slots != trash)):
+            if not due[ti] or s not in receivers:
+                raise ScheduleLoweringError(
+                    f"tick {ti}, stage {s}: a {name} payload arrives where "
+                    "no relay is due"
+                )
+    return prog

@@ -321,3 +321,66 @@ def test_stash_slots_are_feature_major_and_round_trip(dtype):
     np.testing.assert_array_equal(np.asarray(ring[1]), np.asarray(val).T)
     np.testing.assert_array_equal(np.asarray(E._unstash(ring, 1)), np.asarray(val))
     assert not np.asarray(ring[0]).any() and not np.asarray(ring[2]).any()
+
+
+def _ppermutes(jaxpr, under=()):
+    """``[(perm, names of the primitives it is nested under)]`` for every
+    ``ppermute`` of a (closed) jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "ppermute":
+            found.append((tuple(eqn.params["perm"]), under))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _ppermutes(sub, under + (eqn.primitive.name,))
+    return found
+
+
+RELAY_LAYOUTS = {
+    # id: (dp, pp, schedule, lower_schedule kwargs) -> the perms issued
+    "pipedream-pp2": (2, 2, S.PipeDreamFlushSchedule, {}),
+    "gpipe-pp4": (1, 4, S.GPipeSchedule, {}),
+    "interleaved-pp2-v2": (1, 2, S.InterleavedSchedule, dict(virtual=2)),
+    "inference-pp4": (1, 4, S.InferenceSchedule, dict(training=False)),
+    "dp-only": (2, 1, S.GPipeSchedule, {}),
+}
+
+
+@pytest.mark.parametrize("layout", list(RELAY_LAYOUTS))
+def test_relays_are_issued_where_the_send_tables_say(layout):
+    """The traced step holds one ``ppermute`` per direction that ever sends,
+    over that direction's sending pairs only (a flat program drops the
+    ring's wrap pair, an interleaved one keeps it, inference has no backward
+    relay, one pp device has none at all), each under a ``cond`` of its own:
+    the relay's predicate, outside the op-code ``switch``."""
+    dp, pp, sched, kw = RELAY_LAYOUTS[layout]
+    V = kw.get("virtual", 1)
+    training = kw.get("training", True)
+    mesh = make_mesh(dp, pp)
+    spec = Mo.make_model_spec(SMALL, pp * V, B)
+    prog = lower_schedule(sched, M, pp, **kw)
+    order = E.interleave_order(pp * V, pp) if V > 1 else None
+    stacked, flags = E.init_stacked(spec, mesh, order)
+    step = E.make_pipeline_step(
+        mesh, spec, prog, B // dp // M, SGD(LR) if training else None
+    )
+    X, Y = _data(SMALL)
+    args = (stacked, flags, (), X[0], Y[0]) if training else (stacked, flags, X[0])
+    found = _ppermutes(jax.make_jaxpr(step)(*args))
+
+    fwd_perm, bwd_perm = prog.relay_perms()
+    assert sorted(perm for perm, _ in found) == sorted(
+        tuple(p) for p in (fwd_perm, bwd_perm) if p
+    )
+    for _, under in found:
+        # scan (ticks) > cond (the relay's predicate); the switch is a cond
+        # too, and a relay nested in it would show two
+        assert under[-2:] == ("scan", "cond") and under.count("cond") == 1
+    wrap_fwd, wrap_bwd = (pp - 1, 0), (0, pp - 1)
+    if layout == "interleaved-pp2-v2":
+        assert wrap_fwd in fwd_perm and wrap_bwd in bwd_perm
+    elif pp > 1:
+        assert wrap_fwd not in fwd_perm and wrap_bwd not in bwd_perm
+    if layout == "inference-pp4":
+        assert len(found) == 1
+    if layout == "dp-only":
+        assert found == []

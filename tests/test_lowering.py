@@ -454,3 +454,81 @@ class TestSplitValidation:
     def test_interleaved_split_rejected(self):
         with pytest.raises(ScheduleLoweringError, match="interleaved"):
             lower_schedule(S.InterleavedSchedule, 4, 4, virtual=2, backward_split=True)
+
+
+# -- where a relay is due: the columns and perms the executor issues by ------
+
+
+def _relay_programs():
+    """One program of every schedule family the lowering takes, by id."""
+    progs = {
+        f"{cls.__name__}-m{M}-p{St}": (cls, M, St, {})
+        for cls in TRAIN
+        for M, St in [(4, 2), (8, 4), (4, 1)]
+    }
+    progs["inference-m4-p4"] = (S.InferenceSchedule, 4, 4, dict(training=False))
+    progs["interleaved-m4-p2-v2"] = (S.InterleavedSchedule, 4, 2, dict(virtual=2))
+    progs["interleaved-m8-p4-v2"] = (S.InterleavedSchedule, 8, 4, dict(virtual=2))
+    progs["split-m8-p4"] = (S.PipeDreamFlushSchedule, 8, 4, dict(backward_split=True))
+    progs["recompute-m4-p2"] = (S.GPipeSchedule, 4, 2, dict(recompute=True))
+    return progs
+
+
+RELAY_PROGRAMS = _relay_programs()
+
+
+def test_relay_columns_of_the_benchmarks_program():
+    """pipedream, M 4, pp 2 (the four-chip cell): 8 of the 20 relays are
+    due, and ticks 3, 5, 7 and 9 have none in either direction."""
+    p = lower_schedule(S.PipeDreamFlushSchedule, 4, 2)
+    assert p.num_ticks == 10
+    assert np.nonzero(p.relay_fwd)[0].tolist() == [0, 1, 4, 6]
+    assert np.nonzero(p.relay_bwd)[0].tolist() == [2, 4, 6, 8]
+    assert p.relay_perms() == ([(0, 1)], [(1, 0)])
+
+
+@pytest.mark.parametrize("name", list(RELAY_PROGRAMS))
+def test_relay_is_due_exactly_where_some_stage_sends(name):
+    """``relay_*[t]`` is true exactly in the ticks in which some device
+    sends, a perm holds exactly the pairs on which some tick sends, and
+    every payload that is stored (a receive slot that is not trash) arrives
+    in a due tick over a kept pair — so skipping the rest loses nothing.
+    ``program_stats`` counts the same columns."""
+    from shallowspeed_tpu.parallel.lowering import program_stats
+
+    cls, M, St, kw = RELAY_PROGRAMS[name]
+    p = lower_schedule(cls, M, St, **kw)
+    fwd_perm, bwd_perm = p.relay_perms()
+    for send, due, perm, slots, trash, step in (
+        (p.send_fwd, p.relay_fwd, fwd_perm, p.in_fwd_slot, p.n_fwd_slots, 1),
+        (p.send_bwd, p.relay_bwd, bwd_perm, p.in_bwd_slot, p.n_bwd_slots, -1),
+    ):
+        assert due.shape == (p.num_ticks,) and due.dtype == np.bool_
+        for t in range(p.num_ticks):
+            assert due[t] == bool(send[t].any())
+        senders = [d for d in range(St) if send[:, d].any()]
+        assert perm == [(d, (d + step) % St) for d in senders]
+        for t, s in zip(*np.nonzero(slots != trash)):
+            src = (s - step) % St
+            assert due[t] and (src, s) in perm and send[t, src] == 1
+    stats = program_stats(p)
+    assert stats["relays_issued_fwd"] == int(p.relay_fwd.sum())
+    assert stats["relays_issued_bwd"] == int(p.relay_bwd.sum())
+    assert stats["relays_issued_fwd"] + stats["relays_issued_bwd"] <= 2 * p.num_ticks
+    if not p.is_training:
+        assert stats["relays_issued_bwd"] == 0 and bwd_perm == []
+
+
+def test_wrap_pair_kept_only_by_an_interleaved_program():
+    """Without virtual chunks nothing ever sends on the ring's wrap link
+    (P-1 -> 0 forward, 0 -> P-1 backward) and the perms leave it out; with
+    V = 2 the wrap IS a stage boundary and stays. One device has no pair."""
+    flat = lower_schedule(S.PipeDreamFlushSchedule, 8, 4)
+    assert flat.relay_perms() == (
+        [(0, 1), (1, 2), (2, 3)], [(1, 0), (2, 1), (3, 2)]
+    )
+    ring = lower_schedule(S.InterleavedSchedule, 8, 4, virtual=2)
+    fwd_perm, bwd_perm = ring.relay_perms()
+    assert (3, 0) in fwd_perm and (0, 3) in bwd_perm
+    assert len(fwd_perm) == len(bwd_perm) == 4
+    assert lower_schedule(S.GPipeSchedule, 4, 1).relay_perms() == ([], [])

@@ -178,7 +178,7 @@ def test_compile_cache_dir_carries_the_scope_tag(tmp_path, monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
     assert scopes.cache_tag() == scopes.CACHE_TAG == scopes.cache_tag(scopes.SCOPES)
     assert scopes.cache_tag(scopes.SCOPES + ("new",)) != scopes.CACHE_TAG
-    assert scopes.cache_tag(salt="2") != scopes.CACHE_TAG
+    assert scopes.cache_tag(salt=scopes._SALT + "x") != scopes.CACHE_TAG
     assert re.fullmatch(r"s[0-9a-f]{8}", scopes.CACHE_TAG)
 
 
@@ -226,9 +226,11 @@ def v5e_mesh():
     return Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "pp"))
 
 
+@functools.lru_cache(maxsize=None)
 def _compiled_tick_step(mesh, sizes, batch, precision, M=4):
     """``Compiled.as_text()`` of the training step for ``mesh`` (described
-    devices: shapes in, no array), and the program's ring geometry."""
+    devices: shapes in, no array), and the program's ring geometry. One
+    compile per case, whichever test asks first."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -318,3 +320,65 @@ def test_forward_tick_copies_no_whole_stash_ring(v5e_mesh, case):
         assert not relayouts, (computation, relayouts)
     # also what shows that the rings were found at all
     assert len(ring_movers(backward)["copy"]) == cfg["backward_copies"]
+
+
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_relays_run_under_their_predicate_and_copy_no_mailbox(v5e_mesh, case):
+    """The relays follow the send tables: in the compiled tick body every
+    ``collective-permute`` sits in the taken branch of a two-way conditional
+    of its own (one per direction, beside the op-code ``switch``), whose
+    other branch hands the mailbox on untouched; and nowhere in the loop is a
+    whole mailbox copied or turned (a conditional that carried one through as
+    a copy would cost more than the relay it skips)."""
+    from shallowspeed_tpu.observability.program_audit import parse_hlo
+    from shallowspeed_tpu.parallel import lower_schedule
+    from shallowspeed_tpu.parallel.executor import relay_width
+    from shallowspeed_tpu import model as Mo
+    from shallowspeed_tpu.schedules import SCHEDULES
+
+    cfg = RING_CASES[case]
+    text, _, mb = _compiled_tick_step(
+        v5e_mesh, cfg["sizes"], cfg["batch"], cfg["precision"]
+    )
+    instrs, comps = parse_hlo(text)
+    conds = [i for i in instrs.values() if i["opcode"] == "conditional"]
+    (tick,) = [c for c in conds if len(c["called"]["branches"]) == 3]
+    relays = [c for c in conds if len(c["called"]["branches"]) == 2]
+    assert len(relays) == 2  # forward and backward
+    assert {c["computation"] for c in relays} == {tick["computation"]}
+    skipped = {c["called"]["branches"][0] for c in relays}
+    issued = {c["called"]["branches"][1] for c in relays}
+    for name in skipped:  # nothing but the mailbox it was given
+        assert [instrs[n]["opcode"] for n in comps[name]["instructions"]] == ["parameter"]
+    permutes = [
+        i for i in instrs.values() if i["opcode"].startswith("collective-permute")
+    ]
+    assert len(permutes) == 4  # a start and a done per direction
+    assert {i["computation"] for i in permutes} == issued
+
+    prog = lower_schedule(SCHEDULES["pipedream"], 4, 2)
+    width = relay_width(Mo.make_model_spec(cfg["sizes"], 2, cfg["batch"]))
+    mailboxes = {
+        (prog.n_fwd_slots + 1, width, mb),  # forward: feature-major slots
+        (prog.n_bwd_slots + 1, mb, width),
+    }
+    # the switch's backward branch is left out, as above: where a mailbox is
+    # small enough it parks the backward one in fast memory, turned, to read
+    # its slot (the parent did too); no relay is concerned in that
+    noop, forward, _ = tick["called"]["branches"]
+    held_to = skipped | issued | {tick["computation"], noop, forward}
+    seen = set()
+    for ins in instrs.values():
+        shapes = [
+            tuple(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"\[([\d,]*)\]", ins["type"])
+        ]
+        if not shapes or shapes[0] not in mailboxes:
+            continue
+        seen.add(ins["computation"])
+        if ins["computation"] not in held_to:
+            continue
+        assert ins["opcode"] not in ("copy", "transpose"), ins["name"]
+        if ins["opcode"] == "copy-start":
+            assert _moves_memory_space_only(ins["type"]), ins["name"]
+    assert issued <= seen  # the mailboxes were found where they are written

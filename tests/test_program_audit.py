@@ -112,7 +112,8 @@ def test_check_census_contract_rules():
     assert pa.check_census({"all_reduce": {"count": 3, "bytes": 1}}, seq)
 
     dp = {"required": ["all_reduce", "collective_permute"],
-          "forbidden": ["reduce_scatter", "all_gather"]}
+          "forbidden": ["reduce_scatter", "all_gather"],
+          "axes": {"pp": {"hlo_min_permute_ops": 2}}}
     ok = {"all_reduce": {"count": 14, "bytes": 1},
           "collective_permute": {"count": 2, "bytes": 1}}
     assert pa.check_census(ok, dp) == []
@@ -124,7 +125,10 @@ def test_check_census_contract_rules():
     # a one-directional relay is a broken pipeline, even though the kind
     # is present
     one_way = dict(ok, collective_permute={"count": 1, "bytes": 1})
-    assert any("BOTH directions" in m for m in pa.check_census(one_way, dp))
+    assert any("every direction" in m for m in pa.check_census(one_way, dp))
+    # unless the tick table sends one way only (an inference program)
+    fwd_only = dict(dp, axes={"pp": {"hlo_min_permute_ops": 1}})
+    assert pa.check_census(one_way, fwd_only) == []
 
 
 def test_verify_census_raises_loudly_on_mismatch():
@@ -168,8 +172,9 @@ def _mesh_session(data_dir, **kw):
 
 
 def test_expected_comms_pipeline_bytes_from_tick_tables(data_dir):
-    """The pp-axis wire bytes are 2 ppermutes x ticks x payload from the
-    ACTUAL lowered tables, with the send-table useful bytes alongside."""
+    """The pp-axis wire bytes are issued relays x payload x the pairs a
+    device is the source of, from the ACTUAL lowered tables, with the
+    send-table useful bytes alongside."""
     from shallowspeed_tpu.parallel.executor import relay_width
     from shallowspeed_tpu.parallel.lowering import program_comm_bytes
 
@@ -179,7 +184,16 @@ def test_expected_comms_pipeline_bytes_from_tick_tables(data_dir):
     payload = 4 * mb * relay_width(spec)
     comm = program_comm_bytes(prog, spec, mb)
     assert comm["relay_payload_bytes"] == payload
-    assert comm["wire_bytes_per_device"] == 2 * prog.num_ticks * payload
+    # gpipe M 4 pp 4: 7 ticks forward-due, 7 backward-due, of 16; a middle
+    # device is a source in both directions, the ends in one
+    issued = int(prog.relay_fwd.sum()), int(prog.relay_bwd.sum())
+    assert issued == (comm["relays_issued_fwd"], comm["relays_issued_bwd"])
+    assert sum(issued) < 2 * prog.num_ticks
+    assert comm["wire_bytes_by_device"] == [
+        issued[0] * payload, sum(issued) * payload, sum(issued) * payload,
+        issued[1] * payload,
+    ]
+    assert comm["wire_bytes_per_device"] == sum(issued) * payload
     sends = int(np.sum(prog.send_fwd) + np.sum(prog.send_bwd))
     assert comm["useful_sends"] == sends
     assert comm["useful_bytes_per_device"] == sends * payload / prog.num_stages
@@ -327,8 +341,8 @@ def test_check_census_bucketed_rules():
     [
         (dict(), (), ("all_reduce", "collective_permute", "reduce_scatter",
                       "all_gather")),
-        (dict(dp=2), ("all_reduce", "collective_permute"),
-         ("reduce_scatter", "all_gather")),
+        (dict(dp=2), ("all_reduce",),
+         ("collective_permute", "reduce_scatter", "all_gather")),
         (dict(pp=4, schedule="gpipe"), ("collective_permute",),
          ("reduce_scatter", "all_gather")),
         (dict(dp=2, pp=2, schedule="gpipe", zero1=True),
@@ -491,10 +505,11 @@ def test_session_audit_true_raises_on_contract_violation(data_dir, monkeypatch):
 
 
 def test_expected_comms_pp1_permutes_are_not_interconnect_traffic(data_dir):
-    """dp-only (pp=1) mesh layouts: the executor's relay permutes are
-    device-local self-loops — allowed in the census but neither required
-    nor counted as interconnect bytes, so the bandwidth bound reflects
-    only the real dp all-reduce traffic."""
+    """dp-only (pp=1) mesh layouts: no device pair ever sends, so the
+    executor emits no relay permute (the compiled census of the dp2 layout
+    above holds none) and the contract neither requires one nor counts
+    interconnect bytes for it: the bandwidth bound reflects only the real
+    dp all-reduce traffic."""
     run = _mesh_session(data_dir, dp=2)
     exp = run._expected_comms
     assert "collective_permute" not in exp["required"]

@@ -301,9 +301,13 @@ def test_inference_program_stats_per_rung():
         assert st["num_ticks"] == rung + 3  # M + P - 1 relay ticks
         comm = program_comm_bytes(prog, spec, mb)
         assert comm["relay_payload_bytes"] == 4 * mb * relay_width(spec)
+        # forward-only: no backward relay is ever issued, and the forward
+        # one in the ticks in which some stage has a payload due
+        assert st["relays_issued_bwd"] == comm["relays_issued_bwd"] == 0
+        assert st["relays_issued_fwd"] == int(prog.send_fwd.any(axis=1).sum())
         assert (
             comm["wire_bytes_per_device"]
-            == 2 * st["num_ticks"] * comm["relay_payload_bytes"]
+            == st["relays_issued_fwd"] * comm["relay_payload_bytes"]
         )
 
 
@@ -370,11 +374,11 @@ def test_inference_contract_rejects_training_census():
         "at most ONE all-reduce" in msg
         for msg in program_audit.check_census(doubled, expected)
     )
-    # a training program at the same layout still demands BOTH directions
+    # a training program at the same layout still demands both directions
     tprog = lower_schedule(S.SCHEDULES["gpipe"], 4, 4)
     texp = program_audit.expected_comms(spec, 1, 4, prog=tprog, mubatch_size=8)
     assert any(
-        "BOTH directions" in msg
+        "every direction" in msg
         for msg in program_audit.check_census(
             {"collective_permute": {"count": 1, "bytes": 128}}, texp
         )

@@ -358,8 +358,9 @@ def test_slope_timing_failures_dict_salvages_good_configs(monkeypatch):
 
 def test_tick_times_splits_a_tick_into_branch_wait_and_transfer():
     """scripts/tick_times.py on a hand-made plane: one whole step of two
-    ticks (a ``while`` holding two ``conditional``), a step loop around it
-    that is not a tick loop, and the relays that follow each branch."""
+    ticks (a ``while`` holding two events of the switch's ``conditional``), a
+    step loop around it that is not a tick loop, and the relays, each in a
+    conditional of its own that the second tick does not take."""
     scripts_dir = str(ROOT / "scripts")
     sys.path.insert(0, scripts_dir)
     try:
@@ -370,14 +371,29 @@ def test_tick_times_splits_a_tick_into_branch_wait_and_transfer():
     events = [
         ["while.9", 0, 100 * ms, ""],  # the step loop: one conditional-free level up
         ["while.1", 10 * ms, 40 * ms, ""],
-        ["conditional.1", 10 * ms, 5 * ms, ""],
+        ["conditional.1", 10 * ms, 5 * ms, ""],  # the switch: tick 0's branch
+        ["conditional.2", 15 * ms, 9 * ms, ""],  # a relay's, taken
         ["collective-permute-start.1", 15 * ms, 7 * ms, ""],  # waits for the partner
-        ["collective-permute-start.2", 22 * ms, 0, ""],
         ["collective-permute-done.1", 22 * ms, 2 * ms, ""],
-        ["conditional.1", 30 * ms, 12 * ms, ""],
-        ["collective-permute-done.1", 45 * ms, 3 * ms, ""],
+        ["conditional.3", 24 * ms, 1 * ms, ""],  # the other relay's, taken
+        ["collective-permute-start.2", 24 * ms, 0, ""],
+        ["collective-permute-done.2", 24 * ms, 1 * ms, ""],
+        ["conditional.1", 30 * ms, 12 * ms, ""],  # tick 1's branch
+        ["conditional.2", 42 * ms, 0, ""],  # nothing due: no relay issued
+        ["conditional.3", 42 * ms, 0, ""],
     ]
     plane = {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": events}]}
     rows = tick_times.tick_rows(plane, 2)
-    assert rows == [[(5.0, 7.0, 2.0, 20.0)], [(12.0, 0.0, 3.0, 20.0)]]
+    assert rows == [[(5.0, 2, 7.0, 3.0, 20.0)], [(12.0, 0, 0.0, 0.0, 20.0)]]
     assert tick_times.tick_rows(plane, 10) == [[] for _ in range(10)]
+    # a program that relays every tick at the loop's own level (before the
+    # relays followed the send tables) reads the same way
+    events = [e for e in events if e[0] not in ("conditional.2", "conditional.3")]
+    events += [
+        ["collective-permute-start.1", 42 * ms, 0, ""],
+        ["collective-permute-done.1", 45 * ms, 3 * ms, ""],
+    ]
+    plane = {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": events}]}
+    assert tick_times.tick_rows(plane, 2) == [
+        [(5.0, 2, 7.0, 3.0, 20.0)], [(12.0, 1, 0.0, 3.0, 20.0)]
+    ]
