@@ -7,9 +7,9 @@ CPU_MESH = env JAX_PLATFORMS=cpu \
 SHELL := /bin/bash
 
 .PHONY: test verify lint analyze-smoke metrics-smoke report-smoke \
-        audit-smoke overlap-smoke split-smoke tp-smoke recovery-smoke \
+        audit-smoke split-smoke tp-smoke recovery-smoke \
         diverge-smoke \
-        aot-smoke serve-smoke chaos-smoke alerts-smoke fleet-smoke trace-smoke \
+        serve-smoke chaos-smoke alerts-smoke fleet-smoke trace-smoke \
         mpmd-smoke bench-mpmd replay-smoke recompute-smoke \
         zero-smoke bench-zero \
         bench-serving bench-ckpt-aot data train train-mesh bench \
@@ -94,27 +94,6 @@ audit-smoke:
 	  grep -q "Comms (XLA program audit)" $$f.report.md; \
 	done
 	@echo "audit-smoke OK: census + memory + comms sections on all 4 layouts"
-
-# bucketed gradient-sync end-to-end: 1 CPU epoch each for DP=2 and ZeRO-1
-# with --grad-bucket-bytes 65536 --audit — train.py aborts (nonzero exit)
-# if the compiled program's bucket count / sizes violate the plan — then
-# assert the census verdict is clean and the report renders the
-# overlap-efficiency row + the bucketed sync line, exit 0 (needs data,
-# like metrics-smoke)
-overlap-smoke:
-	rm -f /tmp/overlap_dp.jsonl /tmp/overlap_z1.jsonl
-	$(CPU_MESH) python train.py --epochs 1 --no-eval --audit --dp 2 \
-	    --grad-bucket-bytes 65536 --metrics-out /tmp/overlap_dp.jsonl
-	$(CPU_MESH) python train.py --epochs 1 --no-eval --audit --dp 2 --pp 2 \
-	    --schedule gpipe --zero1 --grad-bucket-bytes 65536 \
-	    --metrics-out /tmp/overlap_z1.jsonl
-	set -e; for f in /tmp/overlap_dp /tmp/overlap_z1; do \
-	  python -c "import json,sys; p=sys.argv[1]; recs=[json.loads(l) for l in open(p) if l.strip()]; a=[r for r in recs if r.get('kind')=='xla_audit']; assert a, p+': no xla_audit record'; assert all(r.get('census_ok') for r in a), p+': census mismatch'; dp=[r['expected']['axes']['dp'] for r in a][-1]; assert dp['mode']=='bucketed' and dp['num_buckets']>=2, p+': plan not bucketed'; plans=[r for r in recs if r.get('kind')=='event' and r.get('name')=='grad_sync_plan']; assert plans, p+': no grad_sync_plan event'; print(p+': bucketed census clean ('+str(dp['num_buckets'])+' buckets)')" $$f.jsonl; \
-	  python -m shallowspeed_tpu.observability.report $$f.jsonl --format md > $$f.report.md; \
-	  grep -q "overlap efficiency" $$f.report.md; \
-	  grep -q "gradient sync: bucketed" $$f.report.md; \
-	done
-	@echo "overlap-smoke OK: bucketed census + overlap-efficiency row on dp2 and zero1"
 
 # split-backward end-to-end: 1 CPU epoch each for pp4 gpipe and pp4
 # pipedream with --backward-split --audit (train.py aborts nonzero if the
@@ -337,30 +316,6 @@ diverge-smoke:
 	done
 	@echo "diverge-smoke OK: twin streams identical (exit 0), flip@step=11 named at (step 11, layer 0, W) (exit 2), bisect replay reproduces the 1-ulp flip, Divergence section rendered, on dp2 and gpipe-pp4"
 
-# AOT executable cache end-to-end (docs/performance.md): cold-compile a
-# dp2 rung ladder into the cache, RESTART the process and assert every
-# rung is a cache hit re-verified by the audit census with ZERO jit
-# compiles (pinned by the counter) and bitwise-equal predictions, then
-# corrupt one cache entry on disk and assert a clean fallback-to-recompile
-# with a recorded aot_cache corrupt event + a rewrite. Exit 0.
-aot-smoke:
-	rm -rf /tmp/aotsmoke; mkdir -p /tmp/aotsmoke
-	python -c "import numpy as np; from pathlib import Path; d=Path('/tmp/aotsmoke/data'); d.mkdir(parents=True); rng=np.random.RandomState(0); [(np.save(d/('x_'+s+'.npy'), rng.rand(n,784).astype(np.float32)), np.save(d/('y_'+s+'.npy'), np.eye(10,dtype=np.float32)[rng.randint(0,10,n)])) for s,n in (('train',256),('val',96))]"
-	$(CPU_MESH) python scripts/aot_smoke.py --phase cold \
-	    --cache-dir /tmp/aotsmoke/aot --data-dir /tmp/aotsmoke/data \
-	    --ref /tmp/aotsmoke/ref.npz --metrics-out /tmp/aotsmoke/cold.jsonl
-	$(CPU_MESH) python scripts/aot_smoke.py --phase warm \
-	    --cache-dir /tmp/aotsmoke/aot --data-dir /tmp/aotsmoke/data \
-	    --ref /tmp/aotsmoke/ref.npz --metrics-out /tmp/aotsmoke/warm.jsonl
-	python -c "import sys; sys.path.insert(0, '.'); from pathlib import Path; from shallowspeed_tpu import faults; entries=sorted(Path('/tmp/aotsmoke/aot').glob('*.aotx')); assert entries, 'no cache entries on disk'; faults.corrupt_checkpoint_bytes(entries[0], seed=5); print('corrupted %s' % entries[0].name)"
-	$(CPU_MESH) python scripts/aot_smoke.py --phase corrupt \
-	    --cache-dir /tmp/aotsmoke/aot --data-dir /tmp/aotsmoke/data \
-	    --ref /tmp/aotsmoke/ref.npz --metrics-out /tmp/aotsmoke/corrupt.jsonl
-	python -m shallowspeed_tpu.observability.report /tmp/aotsmoke/warm.jsonl \
-	    --format md > /tmp/aotsmoke/warm.report.md
-	grep -q "aot executable cache: " /tmp/aotsmoke/warm.report.md
-	@echo "aot-smoke OK: restarted process warmed the ladder from cache with zero recompiles, every deserialized program re-audited, corrupt entry fell back to a clean recompile + rewrite"
-
 # inference serving end-to-end (docs/serving.md): on a CPU dp2 and a
 # gpipe-pp4 layout, drive 200 seeded Poisson requests through the serving
 # engine with --verify (every response bitwise-equal to a direct predict()
@@ -526,11 +481,10 @@ fleet-smoke:
 	    --data-dir /tmp/fleet/data --global-batch-size 32 \
 	    --checkpoint /tmp/fleet/ck/step-00000008.npz \
 	    --reload-dir /tmp/fleet/ck --kill-after 15 \
-	    --aot-cache /tmp/fleet/aot \
 	    --requests 120 --rates 300 --slo-ms 2000 --seed 0 \
 	    --fleet-out /tmp/fleet/FLEET_CHAOS.json \
 	    --metrics-out /tmp/fleet/fleet.jsonl
-	python -c "import json,sys; rec=json.load(open('/tmp/fleet/FLEET_CHAOS.json')); assert rec['bench']=='serving_fleet_chaos'; assert rec['silently_lost']==[], 'LOST '+str(rec['silently_lost']); assert rec['parity_mismatches']==0, 'parity mismatches'; assert rec['killed_replica'] is not None and rec['replicas_dead']>=1, 'SIGKILL never fired'; assert rec['failovers']>=1 or rec['killed_inflight']==0, 'kill destroyed in-flight work but no failover ran'; assert rec['scale_ups']==1 and rec['scale_up_s'] is not None, 'no measured scale-up'; assert rec['initial_ready_s_mean'] is not None, 'no cold ready baseline'; assert rec['recovery_s'] is not None, 'no measured recovery'; assert not rec['degraded_at_exit'], 'fleet degraded at exit'; v=rec['verdicts']; assert v.get('ok',0)>0, 'nothing served'; print('fleet chaos: %d submitted, verdicts %s, availability %.1f%%, kill stall %.1f ms, cache-warm replacement ready in %.2f s (initial cache-writing replicas: %.2f s mean)' % (rec['submitted'], v, 100*rec['availability'], 1e3*rec['kill_stall_s'], rec['scale_up_s'], rec['initial_ready_s_mean']))"
+	python -c "import json,sys; rec=json.load(open('/tmp/fleet/FLEET_CHAOS.json')); assert rec['bench']=='serving_fleet_chaos'; assert rec['silently_lost']==[], 'LOST '+str(rec['silently_lost']); assert rec['parity_mismatches']==0, 'parity mismatches'; assert rec['killed_replica'] is not None and rec['replicas_dead']>=1, 'SIGKILL never fired'; assert rec['failovers']>=1 or rec['killed_inflight']==0, 'kill destroyed in-flight work but no failover ran'; assert rec['scale_ups']==1 and rec['scale_up_s'] is not None, 'no measured scale-up'; assert rec['initial_ready_s_mean'] is not None, 'no cold ready baseline'; assert rec['recovery_s'] is not None, 'no measured recovery'; assert not rec['degraded_at_exit'], 'fleet degraded at exit'; v=rec['verdicts']; assert v.get('ok',0)>0, 'nothing served'; print('fleet chaos: %d submitted, verdicts %s, availability %.1f%%, kill stall %.1f ms, replacement ready in %.2f s (initial replicas: %.2f s mean)' % (rec['submitted'], v, 100*rec['availability'], 1e3*rec['kill_stall_s'], rec['scale_up_s'], rec['initial_ready_s_mean']))"
 	ls /tmp/fleet/fleet.jsonl.r0 /tmp/fleet/fleet.jsonl.r1 \
 	    /tmp/fleet/fleet.jsonl.r2 > /dev/null
 	python -m shallowspeed_tpu.observability.report '/tmp/fleet/fleet.jsonl*' \
@@ -555,11 +509,7 @@ fleet-smoke:
 # independent strict re-verification both gate it), and the report CLI
 # must render the Tracing section (aggregate + p99-conditional phase
 # attribution, per-replica clock alignment with uncertainty, worst-k
-# request waterfalls). Then the measured op-issue roofline: a 1-epoch
-# gpipe-pp4 training run with --dispatch-probe must leave a
-# dispatch_overhead bench record (measured share + provenance — the
-# number docs/performance.md's CPU caveats cite) and the report must
-# render its row. Exit 0.
+# request waterfalls). Exit 0.
 trace-smoke:
 	rm -rf /tmp/tsmoke; mkdir -p /tmp/tsmoke
 	python -c "import numpy as np; from pathlib import Path; d=Path('/tmp/tsmoke/data'); d.mkdir(parents=True); rng=np.random.RandomState(0); [(np.save(d/('x_'+s+'.npy'), rng.rand(n,784).astype(np.float32)), np.save(d/('y_'+s+'.npy'), np.eye(10,dtype=np.float32)[rng.randint(0,10,n)])) for s,n in (('train',256),('val',96))]"
@@ -578,15 +528,7 @@ trace-smoke:
 	grep -q "phase attribution (mean): " /tmp/tsmoke/trace.report.md
 	grep -q "p99-conditional" /tmp/tsmoke/trace.report.md
 	grep -q "slowest requests:" /tmp/tsmoke/trace.report.md
-	$(CPU_MESH) python train.py --data-dir /tmp/tsmoke/data --epochs 1 \
-	    --global-batch-size 32 --no-eval --pp 4 --schedule gpipe --mubatches 4 \
-	    --dispatch-probe --dispatch-probe-out /tmp/tsmoke/DISPATCH.json \
-	    --metrics-out /tmp/tsmoke/train.jsonl
-	python -c "import json; rec=json.load(open('/tmp/tsmoke/DISPATCH.json')); assert rec['bench']=='dispatch_overhead' and rec['bench_version']==1; v=rec['value']; assert v is not None and 0.0 <= v < 1.0, 'unmeasured share %r' % v; assert rec['op_events']>0 and rec['provenance'], 'no measurement evidence'; print('dispatch-overhead record: %.1f%% of epoch wall is host-side op issue (%d op events, %s)' % (100*v, rec['op_events'], rec['op_source']))"
-	python -m shallowspeed_tpu.observability.report /tmp/tsmoke/train.jsonl \
-	    --format md > /tmp/tsmoke/train.report.md
-	grep -q "dispatch overhead" /tmp/tsmoke/train.report.md
-	@echo "trace-smoke OK: 2-replica kill-injected soak left a complete clock-aligned span chain for every terminal request, Tracing attribution + waterfalls rendered, measured dispatch-overhead record written"
+	@echo "trace-smoke OK: 2-replica kill-injected soak left a complete clock-aligned span chain for every terminal request, Tracing attribution + waterfalls rendered"
 
 # capacity scoreboard end-to-end (docs/serving.md "Autoscaling & the
 # capacity scoreboard", ROADMAP item 4): measure the single-replica
@@ -613,7 +555,7 @@ replay-smoke:
 	python -c "import json; rec=json.load(open('/tmp/rpsmoke/sweep.json')); assert rec['knee_rps'] is not None, 'sweep found no saturation knee'; print('sweep: knee at %s rps/replica' % rec['knee_rps'])"
 	$(CPU_MESH) python -m shallowspeed_tpu.serving.bench_replay \
 	    --data-dir /tmp/rpsmoke/data --global-batch-size 32 \
-	    --max-slots 4 --dispatch-floor-ms 40 --aot-cache /tmp/rpsmoke/aot \
+	    --max-slots 4 --dispatch-floor-ms 40 \
 	    --knee-from /tmp/rpsmoke/sweep.json --day-s 40 \
 	    --out /tmp/rpsmoke/AUTOSCALE_r01.json \
 	    --metrics-out /tmp/rpsmoke/replay.jsonl
@@ -633,8 +575,7 @@ replay-smoke:
 # on every layout, the deadlock proof consulted before dispatch
 # (static_analysis record, deadlock pass), every per-stage program's
 # census clean (xla_audit mpmd_stage_program records, zero mismatches,
-# no collective-permute), and the measured dispatch-probe row rendered
-# by the report CLI
+# no collective-permute)
 mpmd-smoke:
 	rm -rf /tmp/msmoke; mkdir -p /tmp/msmoke
 	python -c "import numpy as np; from pathlib import Path; d=Path('/tmp/msmoke/data'); d.mkdir(parents=True); rng=np.random.RandomState(0); [(np.save(d/('x_'+s+'.npy'), rng.rand(n,784).astype(np.float32)), np.save(d/('y_'+s+'.npy'), np.eye(10,dtype=np.float32)[rng.randint(0,10,n)])) for s,n in (('train',256),('val',96))]"
@@ -645,10 +586,8 @@ mpmd-smoke:
 	  COMMON="--data-dir /tmp/msmoke/data --epochs 2 --global-batch-size 32 --no-eval"; \
 	  $(CPU_MESH) python train.py $$COMMON $$LFLAGS \
 	      > /tmp/msmoke/$$lay.lock.out; \
-	  if [ $$lay = gpipe ]; then PROBE="--dispatch-probe --dispatch-probe-out /tmp/msmoke/DISPATCH_MPMD.json"; \
-	  else PROBE=""; fi; \
 	  $(CPU_MESH) python train.py $$COMMON $$LFLAGS --runtime mpmd --audit \
-	      --metrics-out /tmp/msmoke/$$lay.mpmd.jsonl $$PROBE \
+	      --metrics-out /tmp/msmoke/$$lay.mpmd.jsonl \
 	      > /tmp/msmoke/$$lay.mpmd.out; \
 	  lock_h=$$(grep -o 'final model hash: [0-9a-f]*' /tmp/msmoke/$$lay.lock.out); \
 	  mpmd_h=$$(grep -o 'final model hash: [0-9a-f]*' /tmp/msmoke/$$lay.mpmd.out); \
@@ -657,11 +596,7 @@ mpmd-smoke:
 	  echo "$$lay: mpmd hash == lockstep twin hash"; \
 	  python -c "import json,sys; lay='$$lay'; recs=[json.loads(l) for l in open('/tmp/msmoke/'+lay+'.mpmd.jsonl')]; sa=[r for r in recs if r.get('kind')=='static_analysis' and 'deadlock' in (r.get('passes') or [])]; assert sa and all(r.get('findings')==0 for r in sa), lay+': deadlock proof missing or found findings'; audits=[r for r in recs if r.get('kind')=='xla_audit' and r.get('name')=='mpmd_stage_program']; assert len(audits) >= 8, lay+': only %d stage-program audits' % len(audits); bad=[r for r in audits if r.get('census_ok') is not True]; assert not bad, lay+': census mismatches %r' % [b.get('mismatches') for b in bad][:3]; perm=[r for r in audits if (r.get('census') or {}).get('collective_permute',{}).get('count',0)]; assert not perm, lay+': a stage program lowered a collective-permute'; print(lay+': deadlock proof consulted, %d stage programs census-clean, zero relays in-program' % len(audits))"; \
 	done
-	python -c "import json; rec=json.load(open('/tmp/msmoke/DISPATCH_MPMD.json')); assert rec['bench']=='dispatch_overhead'; v=rec['value']; assert v is not None and 0.0 <= v < 1.0, 'unmeasured share %r' % v; assert rec.get('runtime')=='mpmd' and rec['op_events']>0; print('mpmd dispatch-overhead record: %.1f%% of epoch wall is host-side op issue (%d op events)' % (100*v, rec['op_events']))"
-	python -m shallowspeed_tpu.observability.report /tmp/msmoke/gpipe.mpmd.jsonl \
-	    --format md > /tmp/msmoke/gpipe.report.md
-	grep -q "dispatch overhead" /tmp/msmoke/gpipe.report.md
-	@echo "mpmd-smoke OK: three schedules hash-equal to lockstep twins under --runtime mpmd --audit, deadlock proof consulted, per-stage census clean, dispatch-probe row rendered"
+	@echo "mpmd-smoke OK: three schedules hash-equal to lockstep twins under --runtime mpmd --audit, deadlock proof consulted, per-stage census clean"
 
 # activation recompute end-to-end (docs/lowering.md "Recompute ticks"):
 # 1 CPU epoch each for gpipe-pp4 and the split-backward pipedream-pp4
@@ -738,8 +673,8 @@ zero-smoke:
 bench-zero:
 	$(CPU_MESH) python scripts/bench_zero.py
 
-# the MPMD-vs-lockstep scoreboard (same-window epoch pair, dispatch-probe
-# pair, serving burst p99) — writes MPMD_r01.json on the flagship data
+# the MPMD-vs-lockstep scoreboard (same-window epoch pair, serving burst
+# p99) — writes MPMD_r01.json on the flagship data
 bench-mpmd:
 	$(CPU_MESH) python scripts/bench_mpmd.py
 
@@ -765,9 +700,9 @@ bench:
 bench-scaling:
 	$(CPU_MESH) python scripts/bench_scaling.py
 
-# the two production-path-stall scoreboards (PR 12): step-time checkpoint
-# overhead sync vs async (same-window interleaved legs), and fleet
-# scale_up_s cold vs aot-cache-warm — writes CKPT_AOT_r01.json
+# the production-path-stall scoreboard (PR 12): step-time checkpoint
+# overhead sync vs async (same-window interleaved legs) — writes
+# CKPT_AOT_r01.json
 bench-ckpt-aot:
 	$(CPU_MESH) python scripts/bench_ckpt_aot.py
 

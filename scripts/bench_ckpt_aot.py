@@ -1,21 +1,13 @@
-"""The two production-path-stall scoreboards (ROADMAP item 5, PR 12):
+"""The production-path-stall scoreboard (ROADMAP item 5, PR 12):
 
-1. **Checkpoint overhead fraction, sync vs async** — the same dp2
-   training run checkpointing every step, measured as checkpoint wall /
-   (checkpoint + train-dispatch wall): the report Reliability section's
-   exact formula, with the async leg charging only the ON-PATH cost
-   (device->host snapshot + bounded-queue enqueue). Trials interleave
-   sync/async so the pair is same-window (bench.py's slope protocol), and
-   the async leg drains its writer before the clock stops — nothing
-   off-path is hidden outside the window.
-
-2. **Fleet `scale_up_s`, cold vs cache-warm** — a real 1-replica
-   ``ServingFleet`` (spawned worker process, own JAX runtime, ladder
-   warmed before ready). The no-cache fleet's replacement recompiles the
-   ladder (cold); the aot-cache fleet's replacement deserializes what
-   the first replica compiled (warm). Both walls are the fleet's own
-   spawn-to-ready measurement — the same number `make fleet-smoke`
-   records.
+**Checkpoint overhead fraction, sync vs async** — the same dp2
+training run checkpointing every step, measured as checkpoint wall /
+(checkpoint + train-dispatch wall): the report Reliability section's
+exact formula, with the async leg charging only the ON-PATH cost
+(device->host snapshot + bounded-queue enqueue). Trials interleave
+sync/async so the pair is same-window (bench.py's slope protocol), and
+the async leg drains its writer before the clock stops — nothing
+off-path is hidden outside the window.
 
 Writes the versioned record beside bench_scaling's (CKPT_AOT_r01.json
 at the repo root by default). CPU-fallback caveat applies as everywhere:
@@ -36,7 +28,6 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 BENCH_VERSION = 1
-LADDER = (1, 2, 4, 8)
 
 
 def _make_data(d):
@@ -118,55 +109,6 @@ def bench_checkpoint_overhead(data_dir, work, steps=16, trials=3):
     return out
 
 
-def bench_fleet_scale_up(data_dir, work):
-    """Cold vs cache-warm replacement: two 1-replica fleets, each scaled
-    up once; the replacement's spawn-to-ready wall is the scoreboard."""
-    from shallowspeed_tpu.serving.fleet import (
-        ServingFleet,
-        fleet_workers_supported,
-    )
-
-    if not fleet_workers_supported():
-        return {"skipped": "platform cannot spawn fleet worker processes"}
-    out = {}
-    for name, cache in (("cold", None), ("aot_warm", work / "aot")):
-        # pp2 rung programs: pipeline-step compiles are the expensive
-        # ladder (seconds each on CPU XLA) — the shape where a serving
-        # replica's cold start is genuinely seconds-of-XLA
-        session = dict(
-            pp=2, schedule="gpipe", global_batch_size=32, mubatches=2,
-            data_dir=str(data_dir),
-            predict_slot_ladder=LADDER,
-        )
-        if cache is not None:
-            session["aot_cache_dir"] = str(cache)
-        fleet = ServingFleet({"session": session}, n_replicas=1)
-        try:
-            t0 = time.perf_counter()
-            fleet.start()  # first replica: compiles (and writes the cache)
-            first_ready = time.perf_counter() - t0
-            fleet.scale_up(wait_ready=True)
-            stats = fleet.stats()
-            walls = [
-                r.get("ready_wall_s")
-                for r in stats["per_replica"].values()
-                if r.get("ready_wall_s") is not None
-            ]
-            out[name] = {
-                "first_replica_ready_s": first_ready,
-                "scale_up_s": stats["scale_up_s"],
-                "ready_walls_s": walls,
-            }
-        finally:
-            fleet.stop()
-    if "cold" in out and "aot_warm" in out:
-        cold, warm = out["cold"]["scale_up_s"], out["aot_warm"]["scale_up_s"]
-        out["scale_up_speedup"] = (
-            cold / warm if cold is not None and warm else None
-        )
-    return out
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default=None,
@@ -174,7 +116,6 @@ def main(argv=None):
                     "repo root)")
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--trials", type=int, default=3)
-    ap.add_argument("--skip-fleet", action="store_true")
     ap.add_argument(
         "--archive-previous", action="store_true",
         help="snapshot the existing checkpoint_overhead section as a new "
@@ -203,15 +144,12 @@ def main(argv=None):
         ),
         "protocol": (
             "same-window: sync/async legs interleaved per trial, per-leg "
-            "minima; async leg drains its writer inside the window; fleet "
-            "walls are the fleet's own spawn-to-ready measurement"
+            "minima; async leg drains its writer inside the window"
         ),
         "checkpoint_overhead": bench_checkpoint_overhead(
             data_dir, work, steps=args.steps, trials=args.trials
         ),
     }
-    if not args.skip_fleet:
-        record["fleet_scale_up"] = bench_fleet_scale_up(data_dir, work)
     out = Path(
         args.out
         if args.out
@@ -219,8 +157,8 @@ def main(argv=None):
     )
     if out.exists():
         # preserve prior rounds instead of clobbering them: archived
-        # checkpoint_overhead_r<N> sections (and a skipped fleet leg's
-        # last measurement) carry forward, so the scoreboard the docs
+        # checkpoint_overhead_r<N> sections carry forward, so the
+        # scoreboard the docs
         # cite stays reproducible BY THIS SCRIPT; --archive-previous
         # additionally snapshots the current section as a new round
         # (used when a code change makes the old numbers a different
@@ -238,8 +176,6 @@ def main(argv=None):
             while f"checkpoint_overhead_r{n}" in record:
                 n += 1
             record[f"checkpoint_overhead_r{n}"] = old["checkpoint_overhead"]
-        if "fleet_scale_up" not in record and "fleet_scale_up" in old:
-            record["fleet_scale_up"] = old["fleet_scale_up"]
     out.write_text(json.dumps(record, indent=2) + "\n")
     co = record["checkpoint_overhead"]
     print(f"record written: {out}")
@@ -250,13 +186,6 @@ def main(argv=None):
         f"({co['sync']['per_save_ms']:.1f} -> "
         f"{co['async']['per_save_ms']:.1f} ms/save on-path)"
     )
-    fs = record.get("fleet_scale_up", {})
-    if fs.get("scale_up_speedup") is not None:
-        print(
-            f"fleet scale_up_s: cold {fs['cold']['scale_up_s']:.2f}s -> "
-            f"cache-warm {fs['aot_warm']['scale_up_s']:.2f}s "
-            f"({fs['scale_up_speedup']:.1f}x)"
-        )
     shutil.rmtree(work, ignore_errors=True)
     return 0
 

@@ -80,7 +80,7 @@ def bench_sequential(nb, reps, sizes=SIZES, act="relu"):
 
 def _pipeline_epoch_setup(
     dp, pp, sched_name, nb, virtual=1, sizes=SIZES, zero1=False,
-    optimizer=None, grad_bucket_bytes=0, backward_split=False, tp=1,
+    optimizer=None, backward_split=False, tp=1,
     digests=False, act="relu", recompute=False,
 ):
     """Build one mesh config's epoch fn + initial state + data: the shared
@@ -106,7 +106,7 @@ def _pipeline_epoch_setup(
     opt = make_optimizer(optimizer, 2e-4) if optimizer else SGD(LR)
     epoch = E.make_pipeline_epoch(
         mesh, spec, prog, B // dp // M, opt, zero1=zero1,
-        grad_bucket_bytes=grad_bucket_bytes, with_digests=digests,
+        with_digests=digests,
     )
     st = E.zero1_init_state(opt, spec, mesh) if zero1 else opt.init(stacked)
     X, Y = _data(nb, np.random.RandomState(0), sizes=sizes)
@@ -132,73 +132,9 @@ def bench_pipeline(
     return reps * nb * B / (time.perf_counter() - t0)
 
 
-# anchor-vs-bucketed gradient-sync pairs (dp and ZeRO-1): measured with
-# bench.py's interleaved-trial slope protocol so each pair shares its
-# contention window — the ratio is same-window, like the TPU captures'.
-# On emulated CPU devices these rows validate the machinery and record
-# the bucket plan; the RATIO only means something on a real multi-chip
-# mesh (one CPU host has no interconnect to overlap against).
-GRAD_SYNC_BUCKET_BYTES = 65536
-SYNC_PAIRS = [
-    ("dp2", dict(dp=2, pp=1, sched="gpipe")),
-    ("dp2-zero1", dict(dp=2, pp=1, sched="gpipe", zero1=True)),
-]
-
-
-def bench_sync_pair(name, cfg, nb, sizes=SIZES, act="relu", model=None):
-    """One anchor-vs-bucketed pair, same-window: returns a list of record
-    dicts (one per mode) carrying grad_bucket_bytes + bucket count so a
-    MULTICHIP capture of these rows is self-describing."""
-    from bench import make_run_k, slope_epoch_seconds_many
-
-    from shallowspeed_tpu import model as Mo
-    from shallowspeed_tpu.parallel import gradsync
-
-    dp, pp = cfg["dp"], cfg["pp"]
-    zero1 = cfg.get("zero1", False)
-    spec = Mo.make_model_spec(sizes, pp, B, act=act)
-    plan = gradsync.plan_buckets(
-        spec, dp, pp, GRAD_SYNC_BUCKET_BYTES, zero1=zero1
-    )
-    modes = {f"{name}-anchor": 0, f"{name}-bucketed": GRAD_SYNC_BUCKET_BYTES}
-    run_ks = {}
-    for label, gbb in modes.items():
-        _, epoch, stacked, flags, st, Xj, Yj = _pipeline_epoch_setup(
-            dp, pp, cfg["sched"], nb, zero1=zero1, grad_bucket_bytes=gbb,
-            sizes=sizes, act=act,
-        )
-
-        def epoch_fn(p, s, X, Y, _epoch=epoch, _flags=flags):
-            return _epoch(p, _flags, s, X, Y)
-
-        run_ks[label] = make_run_k(epoch_fn, stacked, st, Xj, Yj)
-    # min_delta_s=0: fixed short legs (no leg-size adaptation), trials
-    # still interleaved
-    slopes = slope_epoch_seconds_many(
-        run_ks, k1=1, k2=3, trials=2, min_delta_s=0
-    )
-    anchor_sps = nb * B / slopes[f"{name}-anchor"]
-    records = []
-    for label, gbb in modes.items():
-        sps = nb * B / slopes[label]
-        records.append(
-            {
-                "config": label,
-                "devices": dp * pp,
-                "samples_per_sec": round(sps, 1),
-                "model": model,
-                "grad_bucket_bytes": gbb,
-                "grad_buckets": plan.num_buckets if gbb else 0,
-                "zero1": zero1,
-                "same_window": True,
-                "vs_anchor": round(sps / anchor_sps, 4),
-            }
-        )
-    return records
-
-
-# digests-off vs digests-on pairs: same-window via the interleaved-trial
-# slope protocol. The digest aux (per-layer uint32 checksums + norms as
+# digests-off vs digests-on pairs: same-window via bench.py's
+# interleaved-trial slope protocol, so each pair shares its contention
+# window. The digest aux (per-layer uint32 checksums + norms as
 # extra scan ys, one psum over the pipeline axes — docs/numerics.md
 # § Divergence debugging) is designed to be cheap next to the matmuls;
 # this pair MEASURES that claim instead of asserting it. Records carry
@@ -254,7 +190,7 @@ def bench_digest_pair(name, cfg, nb):
 # device weight memory and matmul FLOPs drop by tp at 2 all-reduces per layer
 # pair); on emulated CPU devices the extra dispatch + memcpy "collectives"
 # are pure overhead against an op-issue-bound MLP, so — exactly like the
-# grad-bucket and split-backward pairs — expect seq to win here and the
+# split-backward pairs — expect seq to win here and the
 # ratio to mean something only on a real multi-chip mesh. Records carry tp,
 # vs_seq and the mesh placement note so an on-chip run re-measures
 # self-describing rows.
@@ -326,11 +262,11 @@ def bench_tp_pair(name, cfg, nb, sizes=SIZES, act="relu", model=None):
 
 
 # split-vs-unsplit backward pairs at pp4 (gpipe + 1F1B): same-window via the
-# interleaved-trial slope protocol, like the gradient-sync pairs. The split
+# interleaved-trial slope protocol. The split
 # schedule's win is FLOP-weighted bubble time (the record carries both
 # programs' weighted bubble fractions); on emulated CPU devices the extra
-# OP_BWD_W ticks are pure op-issue overhead with nothing to overlap, so —
-# exactly like grad bucketing — expect the unsplit row to win here and the
+# OP_BWD_W ticks are pure op-issue overhead with nothing to overlap, so
+# expect the unsplit row to win here and the
 # ratio to mean something only on a real multi-chip mesh.
 SPLIT_PAIRS = [
     ("pp4-gpipe-split", dict(dp=1, pp=4, sched="gpipe")),
@@ -527,40 +463,6 @@ CONFIGS = [
 ]
 
 
-def bench_dispatch_probe(nb, sizes, act, model):
-    """The measured op-issue share on this model (train.py
-    --dispatch-probe's machinery, bounded window): the number that says
-    whether a bench row on THIS model is compute- or dispatch-bound —
-    the compute-bound zoo exists so this drops below the toy MLP's
-    ~0.7."""
-    import tempfile
-
-    from shallowspeed_tpu.api import TrainingSession
-
-    with tempfile.TemporaryDirectory() as td:
-        rng = np.random.RandomState(0)
-        X, Y = _data(nb, rng, sizes=sizes)
-        np.save(Path(td) / "x_train.npy", X.reshape(-1, sizes[0]))
-        np.save(Path(td) / "y_train.npy", Y.reshape(-1, sizes[-1]))
-        np.save(Path(td) / "x_val.npy", X[0])
-        np.save(Path(td) / "y_val.npy", Y[0])
-        s = TrainingSession(
-            model=model, dp=1, pp=4, schedule="gpipe",
-            global_batch_size=B, mubatches=M, data_dir=td,
-        )
-        rec = s.measure_dispatch_overhead(repeats=2)
-    keep = (
-        "dispatch_overhead", "dispatch_overhead_instrumented",
-        "host_wall_s", "device_busy_s", "op_events", "op_source",
-        "profiler_inflation", "batches_per_epoch", "events_per_batch",
-        "window_valid", "window_invalid_reason",
-    )
-    row = {k: rec.get(k) for k in keep if rec.get(k) is not None}
-    row["config"] = "pp4-gpipe-dispatch-probe"
-    row["model"] = model
-    return row
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=64, help="batches per rep")
@@ -575,8 +477,7 @@ def main():
     ap.add_argument(
         "--pairs-only", action="store_true",
         help="skip the plain throughput rows; run only the same-window "
-        "pairs (+ the dispatch probe when --model is set) — the "
-        "COMPUTE_r01.json protocol",
+        "pairs — the COMPUTE_r01.json protocol",
     )
     ap.add_argument(
         "--out", default=None,
@@ -641,17 +542,6 @@ def main():
 
     pair_kwargs = dict(sizes=sizes, act=act, model=args.model)
 
-    # the anchor-vs-bucketed gradient-sync pairs (same-window per pair)
-    for name, cfg in SYNC_PAIRS:
-        need = cfg["dp"] * cfg["pp"]
-        if need > n_dev:
-            emit({"config": name, "skipped": f"needs {need} devices, have {n_dev}"})
-            continue
-        if args.pairs_only and cfg.get("zero1"):
-            continue  # COMPUTE protocol: the plain dp2 pair carries the story
-        for rec in bench_sync_pair(name, cfg, args.batches, **pair_kwargs):
-            emit(rec)
-
     # the unsplit-vs-split backward pairs (same-window per pair)
     for name, cfg in SPLIT_PAIRS:
         need = cfg["dp"] * cfg["pp"]
@@ -700,9 +590,6 @@ def main():
             continue
         for rec in bench_mpmd_pair(name, cfg, args.batches, **pair_kwargs):
             emit(rec)
-
-    if args.model:
-        emit(bench_dispatch_probe(args.batches, sizes, act, args.model))
 
     if args.out:
         Path(args.out).write_text(

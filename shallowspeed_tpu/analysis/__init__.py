@@ -25,7 +25,7 @@ Two coordinated halves (docs/static-analysis.md):
   HEAD clean.
 
 The third static check — the HLO dispatch-safety pass that refuses
-deserialized/serving-path programs that donate their buffers — lives in
+serving-path programs that donate their buffers — lives in
 ``observability/program_audit.py`` next to the collective census it
 extends (``parse_input_output_aliases`` / ``verify_dispatch_safety``).
 """

@@ -9,15 +9,14 @@ Every rule encodes an invariant this repo already enforces by review
 - ``SSP002``  ``json.dumps`` on metrics paths (``observability/``,
               ``serving/``) must pass ``allow_nan=False`` — every record
               line must be STRICT JSON (the ``_json_safe`` lesson);
-- ``SSP003``  modules owning durable on-disk formats (``checkpoint.py``,
-              ``aot_cache.py``) may only write through
+- ``SSP003``  the module owning the durable on-disk format
+              (``checkpoint.py``) may only write through
               ``checkpoint.atomic_write`` — no raw ``open(.., "w")``,
               ``os.fdopen`` write modes or ``Path.write_*`` outside the
               ``atomic_write`` body itself;
 - ``SSP004``  ``donate_argnums`` is allowed only in the whitelisted
               trainer/executor modules (the donation hazard PR 1/PR 12
-              document: a donating program must never be deserialized
-              and dispatched);
+              document: a donating program must never serve);
 - ``SSP005``  every dict literal handed to ``_emit`` must carry a
               ``"kind"`` that is a string literal registered in the
               ``metrics.SCHEMA_KINDS`` table (schema-version
@@ -75,7 +74,7 @@ class Scope:
     to exercise scoped rules on fixture files."""
 
     metrics_path: bool = False  # SSP002: observability/ + serving/
-    atomic_module: bool = False  # SSP003: checkpoint.py + aot_cache.py
+    atomic_module: bool = False  # SSP003: checkpoint.py
     donation_ok: bool = False  # SSP004: trainer.py + parallel/executor.py
 
 
@@ -87,9 +86,7 @@ def scope_for(path):
             "shallowspeed_tpu/observability/" in p
             or "shallowspeed_tpu/serving/" in p
         ),
-        atomic_module=p.endswith(
-            ("shallowspeed_tpu/checkpoint.py", "shallowspeed_tpu/aot_cache.py")
-        ),
+        atomic_module=p.endswith("shallowspeed_tpu/checkpoint.py"),
         donation_ok=p.endswith(
             ("shallowspeed_tpu/trainer.py", "shallowspeed_tpu/parallel/executor.py")
         ),
@@ -266,7 +263,7 @@ class _RuleVisitor(ast.NodeVisitor):
                     "SSP004", node,
                     "donate_argnums outside the whitelisted trainer/executor"
                     " modules (a donating program must never reach the"
-                    " serving or AOT-deserialize paths)",
+                    " serving path)",
                 )
 
     def _check_emit_kind(self, node):

@@ -61,7 +61,6 @@ from shallowspeed_tpu.optimizer import (
     split_state,
 )
 from shallowspeed_tpu.parallel import executor as E
-from shallowspeed_tpu.parallel import gradsync
 from shallowspeed_tpu.parallel import lower_schedule
 from shallowspeed_tpu.parallel.mesh import make_mesh_with_layout
 from shallowspeed_tpu.parallel.lowering import program_flops, program_stats
@@ -106,7 +105,6 @@ class TrainingSession:
         virtual_stages=1,
         zero1=False,
         zero=None,
-        grad_bucket_bytes=0,
         backward_split=False,
         recompute=False,
         scan_unroll=1,
@@ -127,7 +125,6 @@ class TrainingSession:
         async_checkpoint=False,
         checkpoint_queue=2,
         faults=None,
-        aot_cache_dir=None,
         predict_slot_rows=None,
         predict_slot_ladder=None,
         runtime="lockstep",
@@ -308,23 +305,6 @@ class TrainingSession:
                 "branch; the fused pallas flag kernels take whole resident "
                 "slots — use kernel_backend='xla' with --zero 3"
             )
-        if self._zero == 3 and grad_bucket_bytes:
-            raise ValueError(
-                "zero=3 syncs gradients per tick (one reduce-scatter per "
-                "layer slot inside the scan); grad_bucket_bytes shapes the "
-                "tail sync only and has nothing to bucket at stage 3"
-            )
-        if grad_bucket_bytes is None:
-            grad_bucket_bytes = 0
-        grad_bucket_bytes = int(grad_bucket_bytes)
-        if grad_bucket_bytes < 0:
-            raise ValueError("grad_bucket_bytes must be >= 0 (0 = anchor sync)")
-        if grad_bucket_bytes and self._sequential:
-            raise ValueError(
-                "grad_bucket_bytes buckets the dp-axis gradient collectives; "
-                "the sequential path has no gradient sync — use dp/pp > 1 "
-                "(0 keeps the legacy anchor psum on mesh layouts)"
-            )
         self._backward_split = bool(backward_split)
         if self._backward_split:
             if self._sequential:
@@ -379,7 +359,7 @@ class TrainingSession:
         # relays (parallel/mpmd.py) — bitwise-identical weights, measured
         # lower op-issue overhead. The MPMD feature envelope is enforced
         # here: the knobs whose lockstep implementations live in the fused
-        # program's tail (zero1, bucketed sync, the cross-stage clip norm,
+        # program's tail (zero1, the cross-stage clip norm,
         # the pallas tick backend, the per-step flight aux) stay
         # lockstep-only until the per-stage update learns their math.
         if runtime not in ("lockstep", "mpmd"):
@@ -402,13 +382,6 @@ class TrainingSession:
                     f"{self._zero}) yet: the ZeRO reduce-scatter/all-gather "
                     "update spans the whole sharded param layout, not one "
                     "stage — use runtime='lockstep'"
-                )
-            if grad_bucket_bytes:
-                raise ValueError(
-                    "runtime='mpmd' does not support grad_bucket_bytes: "
-                    "bucketed sync overlaps collectives inside the lockstep "
-                    "program's tail; the MPMD per-stage update is one psum "
-                    "per stage already — use runtime='lockstep'"
                 )
             if clip_norm is not None:
                 raise ValueError(
@@ -469,16 +442,6 @@ class TrainingSession:
         self._ckpt_queue = int(checkpoint_queue)
         self._ckpt_writer = None
         self._save_seq = 0
-        # AOT executable cache (shallowspeed_tpu/aot_cache.py): compile
-        # sites try it before .compile(); deserialized programs are
-        # re-audited before first dispatch, every failure falls back to
-        # a clean recompile + rewrite
-        self._aot = None
-        if aot_cache_dir is not None:
-            from shallowspeed_tpu.aot_cache import AotCache
-
-            self._aot = AotCache(aot_cache_dir, metrics=self._metrics)
-        self._slot_predict = None  # sequential slot-shaped predict program
         self.resumed_from = None  # path of the restored snapshot, if any
         self._recovery = None  # the recovery record's fields, if resume ran
         # per-epoch aggregation across train_steps() chunks. steps_counted
@@ -748,7 +711,6 @@ class TrainingSession:
                 optimizer=optimizer, momentum=momentum,
                 virtual_stages=virtual_stages, zero1=zero1,
                 zero=self._zero,
-                grad_bucket_bytes=grad_bucket_bytes,
                 backward_split=backward_split, recompute=recompute,
                 scan_unroll=scan_unroll,
                 tick_unroll=tick_unroll, weight_decay=weight_decay,
@@ -948,7 +910,6 @@ class TrainingSession:
                     with_grad_norm=self._epoch_aux,
                     with_step_stats=self._step_aux,
                     with_digests=self._digests,
-                    grad_bucket_bytes=grad_bucket_bytes,
                 )
             self._prog = prog
             self._mubatch_local = local_batch // mubatches
@@ -956,7 +917,6 @@ class TrainingSession:
                 precision=self.precision, unroll=scan_unroll,
                 tick_unroll=tick_unroll, zero=self._zero,
                 clip_norm=clip_norm, kernel_backend=kernel_backend,
-                grad_bucket_bytes=grad_bucket_bytes,
             )
 
         # analytical cost model + MFU accounting (observability/costmodel):
@@ -991,23 +951,7 @@ class TrainingSession:
         # the layout's analytical comms contract (required/forbidden
         # collective kinds + bytes/step per mesh axis, derived from the
         # lowered tick tables) — what the compiled program's collective
-        # census is audited against at jit time. The gradient-sync bucket
-        # plan is rebuilt here through the SAME gradsync planners the
-        # executor used, so contract and emitters can never disagree.
-        self._sync_plan = None
-        if grad_bucket_bytes and not self._sequential:
-            self._sync_plan = gradsync.plan_buckets(
-                self.spec, dp, pp, grad_bucket_bytes, zero=self._zero,
-                tp=self.tp,
-            )
-            if self._metrics.enabled:
-                # the plan is static telemetry, recorded once like the
-                # pipeline program stats: bucket count + sizes make every
-                # later throughput/audit record self-describing
-                self._metrics.event(
-                    "grad_sync_plan", dp=dp, pp=pp, tp=self.tp,
-                    zero=self._zero, **self._sync_plan.describe(),
-                )
+        # census is audited against at jit time.
         self._expected_comms = program_audit.expected_comms(
             self.spec,
             dp,
@@ -1018,7 +962,6 @@ class TrainingSession:
             platform=device.platform,
             device_kind=device.device_kind,
             precision=self._precision_name,
-            grad_bucket_plan=self._sync_plan,
             tp=self.tp,
             # only params-mirroring parts occupy per-layer bytes (Adam's
             # "t" is a scalar) — the forecast prices what actually shards
@@ -1046,16 +989,6 @@ class TrainingSession:
         if self._sequential:
             return (self._params, self._opt_state, self._Xe, self._Ye)
         return (self._stacked, self._flags, self._opt_state, self._X, self._Y)
-
-    def _aot_layout(self):
-        """The layout tuple half of the AOT cache key (the program CONTENT
-        hash over the lowered StableHLO does the real invalidation work;
-        this keeps distinct configurations from ever sharing a filename)."""
-        return (
-            tuple(self.spec.sizes), self._act, self.dp, self.pp, self.tp,
-            self.V, self.schedule, self.B, self.M, self._precision_name,
-            self._kernel_backend, self._slot_rows, self._recompute,
-        )
 
     def _record_static_analysis(self, prog, program):
         """The program-level static passes (shallowspeed_tpu/analysis)
@@ -1090,91 +1023,11 @@ class TrainingSession:
             )
         return verdict
 
-    def _aot_resolve(self, program, audit_label, jit_fn, args, expected,
-                     dedup, dispatch=False):
-        """Resolve one compiled program through the AOT executable cache
-        (shallowspeed_tpu/aot_cache.py): lower (milliseconds — tracing, no
-        XLA), key on (layout, backend fingerprint, lowered-program hash),
-        try the cache, and fall back to a clean ``.compile()`` + store on
-        any miss/stale/corrupt outcome.
-
-        The audit-at-compile contract survives the cache: a DESERIALIZED
-        program is censused against ``expected`` before this returns — it
-        can never reach a dispatch un-audited — and a census mismatch is
-        treated like corruption (recorded ``audit_mismatch`` + recompile),
-        because a bad cache entry is not a mislowered program; the
-        recompile re-audits under the normal strict rules. Returns
-        ``(compiled, from_cache)``; only a real compile bumps the
-        ``jit_compiles`` counter, which is how the zero-recompile warm
-        start is pinned.
-
-        ``dispatch=True`` declares that the RESOLVED EXECUTABLE is the
-        dispatch path (the inference rungs, the sequential slot-predict
-        program) — then the HLO dispatch-safety pass
-        (``program_audit.verify_dispatch_safety``) additionally proves
-        the program donates no buffers before it can ever run: a
-        donating CACHE entry is treated like corruption (recorded
-        ``audit_mismatch`` + clean recompile), and a donating RECOMPILE
-        raises ``AuditMismatchError`` unlatched, because executing a
-        deserialized donating program is the jax-0.4.x heap-corruption
-        hazard and a donating serving program is a use-after-free (the
-        PR 1/PR 12 rule, now proven instead of assumed; probe-only
-        resolutions like the epoch audit probe keep ``dispatch=False``
-        — they lawfully donate and are never executed)."""
-        aot = self._aot
-        lowered = jit_fn.lower(*args)
-        key = aot.key_for(program, self._aot_layout(), lowered.as_text())
-        compiled = aot.load(key, program=program)
-        if compiled is not None:
-            rec = program_audit.audit_compiled(
-                compiled,
-                expected=expected,
-                platform=self._cost_model.platform,
-                device_kind=self._cost_model.device_kind,
-                n_devices=self._cost_model.n_devices,
-            )
-            reason = None
-            if rec.get("census_ok") is False:
-                reason = "; ".join(rec.get("mismatches", ()))[:200]
-            elif dispatch:
-                try:
-                    program_audit.verify_dispatch_safety(
-                        compiled, context=program
-                    )
-                except program_audit.AuditMismatchError as e:
-                    reason = f"dispatch-safety: {e}"[:200]
-            if reason is not None:
-                aot.record(
-                    "audit_mismatch", program=program, key=key,
-                    reason=reason,
-                )
-                aot.record(
-                    "fallback", program=program, key=key,
-                    reason="audit_mismatch",
-                )
-                compiled = None
-            else:
-                if self._metrics.enabled:
-                    self._metrics.audit(audit_label, **rec)
-                self._audit_done.add(dedup)
-                return compiled, True
-        with self._metrics.span("jit_compile"):
-            compiled = lowered.compile()
-        self._metrics.counter("jit_compiles")
-        self._record_audit(compiled, audit_label, dedup=dedup,
-                           expected=expected)
-        if dispatch:
-            # a freshly-compiled dispatch-path program that donates is a
-            # real lowering bug, not a bad cache entry: refuse, unlatched
-            program_audit.verify_dispatch_safety(compiled, context=program)
-        aot.store(key, compiled, program=program)
-        return compiled, False
-
     def _ensure_epoch_compiled(self):
         """With metrics enabled, compile the epoch program once inside a
         ``jit_compile`` span (trace + lowering + XLA compile, timed as a
         first-class record) before the first dispatch. Steady-state dispatch
-        stays on the jit wrapper's C++ fast path — on this backend the AOT
+        stays on the jit wrapper's C++ fast path — on this backend a compiled
         executable's Python dispatch costs ~2-3% per epoch, so the compiled
         object is only the timing probe, not the call path. The probe does
         NOT warm the jit wrapper's own call cache (verified on jax 0.4.x:
@@ -1189,15 +1042,13 @@ class TrainingSession:
         collective contract before the first dispatch.
 
         On the MPMD runtime the "epoch program" is the per-stage program
-        set: the warm pass compiles (or AOT-loads) every planned stage
-        program, censuses each against its per-stage contract
+        set: the warm pass compiles every planned stage program,
+        censuses each against its per-stage contract
         (``mpmd.expected_stage_comms``) and proves it donation-free —
-        then swaps the dispatch path onto the resolved executables, so a
-        cache-warm MPMD start compiles zero stage programs."""
+        then swaps the dispatch path onto the compiled executables."""
         if self.runtime == "mpmd":
             if self._epoch_compiled or not (
                 self._metrics.enabled or self._audit_strict
-                or self._aot is not None
             ):
                 return
             self._mpmd.warm(
@@ -1208,22 +1059,6 @@ class TrainingSession:
             self._record_cost_model()
             return
         if self._epoch_compiled or not (self._metrics.enabled or self._audit_strict):
-            return
-        if self._aot is not None:
-            # the audit probe rides the AOT cache: a warm start deserializes
-            # the epoch program for its census + cost_analysis instead of
-            # paying the probe's XLA compile. The deserialized object is
-            # PROBE-ONLY — dispatch stays on the jit wrapper (which donates
-            # its buffers; executing a deserialized donating program is the
-            # jax-0.4.x hazard class this cache deliberately avoids)
-            compiled, _ = self._aot_resolve(
-                "epoch_probe", "epoch_program", self._epoch_fn,
-                self._epoch_args(), expected=self._expected_comms,
-                dedup="epoch_program",
-            )
-            self._cost_model.attach_compiled(compiled)
-            self._epoch_compiled = True
-            self._record_cost_model()
             return
         with self._metrics.span("jit_compile"):
             compiled = self._epoch_fn.lower(*self._epoch_args()).compile()
@@ -1240,20 +1075,13 @@ class TrainingSession:
         self._record_cost_model()
 
     def _mpmd_resolve(self, label, role, jit_fn, args, expected):
-        """The MPMD warm pass's per-stage-program hook: AOT-resolve (when
-        a cache is configured) or compile each stage program, census it
-        against its per-stage contract, and prove it donation-free
-        (``verify_dispatch_safety`` — every stage program IS a dispatch
-        path). Returns the executable the runner should dispatch, or
-        None to keep the plain jit wrapper (nothing to verify and no
-        cache to serve)."""
+        """The MPMD warm pass's per-stage-program hook: compile each stage
+        program, census it against its per-stage contract, and prove it
+        donation-free (``verify_dispatch_safety`` — every stage program IS
+        a dispatch path). Returns the executable the runner should
+        dispatch, or None to keep the plain jit wrapper (nothing to
+        verify)."""
         dedup = ("mpmd", label)
-        if self._aot is not None:
-            compiled, _ = self._aot_resolve(
-                label, "mpmd_stage_program", jit_fn, args,
-                expected=expected, dedup=dedup, dispatch=True,
-            )
-            return compiled
         if not (self._metrics.enabled or self._audit_strict):
             return None
         if dedup in self._audit_done:
@@ -1716,8 +1544,7 @@ class TrainingSession:
                     fields["queue_depth"] = queue_depth
                     fields["queued_s"] = result["queued_s"]
                     # the deferred logical-unstacking wall (off-path):
-                    # what the step path stopped paying (ROADMAP item 5
-                    # follow-on; CKPT_AOT_r01.json scoreboard)
+                    # what the step path stopped paying
                     fields["unstack_s"] = result.get("unstack_s", 0.0)
                 else:
                     fields["async"] = False
@@ -2049,7 +1876,7 @@ class TrainingSession:
             raise ValueError(
                 "warm_run() AOT-compiles the fused run program, which the "
                 "MPMD runtime does not dispatch — the per-stage programs "
-                "warm through the audit/AOT pass on the first epoch"
+                "warm through the audit pass on the first epoch"
             )
         if with_eval and self._vx is None:
             self._load_val()
@@ -2190,11 +2017,10 @@ class TrainingSession:
                 # exactly one program however many slots run, so the
                 # pure-padding rung tail would be wasted work
                 xb = np.pad(chunk, ((0, m * S_rows - chunk.shape[0]), (0, 0)))
-                slot_fn = self._slot_predict_fn()
                 preds = np.concatenate(
                     [
                         np.asarray(
-                            slot_fn(
+                            self._predict(
                                 self._params,
                                 jnp.asarray(xb[k * S_rows : (k + 1) * S_rows]),
                             )
@@ -2238,30 +2064,6 @@ class TrainingSession:
                 )
             outs.append(preds[: chunk.shape[0], :out_dim])
         return np.concatenate(outs, axis=0)
-
-    def _slot_predict_fn(self):
-        """The sequential path's slot-shaped predict program — the one
-        program ``predict()`` dispatches per occupied slot. Without an AOT
-        cache this is just the jit wrapper (today's exact path); with one,
-        the slot program rides the cache like the mesh rungs do, so a
-        sequential serving replica (the fleet's default worker shape)
-        cold-starts with zero compiles too — census-re-verified before
-        first dispatch, like every deserialized program."""
-        if self._slot_predict is None:
-            if self._aot is None:
-                self._slot_predict = self._predict
-            else:
-                x_shape = jax.ShapeDtypeStruct(
-                    (self._slot_rows, self.spec.sizes[0]), jnp.float32
-                )
-                self._slot_predict, _ = self._aot_resolve(
-                    "predict_seq", "inference_program", self._predict,
-                    (self._params, x_shape),
-                    expected=self._expected_comms,
-                    dedup=("inference", "seq"),
-                    dispatch=True,
-                )
-        return self._slot_predict
 
     def _lower_inference_prog(self, mubatches=1):
         """The layout's inference TickProgram (interleaved-aware) — shared by
@@ -2308,11 +2110,7 @@ class TrainingSession:
         step = self._predict_cache.get(n_slots)
         if step is None:
             prog = self._lower_inference_prog(n_slots)
-            need_audit = (
-                self._aot is not None
-                or self._metrics.enabled
-                or self._audit_strict
-            )
+            need_audit = self._metrics.enabled or self._audit_strict
             if need_audit:
                 # the serving rung's tick tables get the same lowering-
                 # time static passes as the epoch program — a malformed
@@ -2323,7 +2121,6 @@ class TrainingSession:
                 self._slot_rows // self.dp, precision=self.precision,
                 kernel_backend=self._kernel_backend,
             )
-            expected = None
             if need_audit:
                 expected = program_audit.expected_comms(
                     self.spec,
@@ -2336,24 +2133,10 @@ class TrainingSession:
                     precision=self._precision_name,
                     tp=self.tp,
                 )
-            x_shape = jax.ShapeDtypeStruct(
-                (n_slots * self._slot_rows, self.spec.sizes[0]),
-                jnp.float32,
-            )
-            if self._aot is not None:
-                # the dispatch path itself becomes the resolved executable:
-                # a warm start deserializes every rung with ZERO compiles
-                # (inference programs donate nothing, so dispatching a
-                # deserialized one stays clear of the jax-0.4.x hazard),
-                # and the census re-verifies it before this cache entry
-                # can serve a request
-                step, _ = self._aot_resolve(
-                    f"inference_r{n_slots}", "inference_program", step,
-                    (self._eval_stacked(), self._flags, x_shape),
-                    expected=expected, dedup=("inference", n_slots),
-                    dispatch=True,
+                x_shape = jax.ShapeDtypeStruct(
+                    (n_slots * self._slot_rows, self.spec.sizes[0]),
+                    jnp.float32,
                 )
-            elif self._metrics.enabled or self._audit_strict:
                 with self._metrics.span("jit_compile"):
                     compiled = step.lower(
                         self._eval_stacked(), self._flags, x_shape
@@ -2378,7 +2161,7 @@ class TrainingSession:
         """The streaming MPMD inference runner (mesh mpmd sessions): ONE
         slot-shaped per-stage forward chain, admission-gated at build
         (``analyze_program`` over the inference tick tables) and — when
-        metrics/audit/AOT are on — censused per stage program against
+        metrics/audit are on — censused per stage program against
         the forward-only contract before the first request."""
         if self._mpmd_infer is None:
             from shallowspeed_tpu.parallel import mpmd
@@ -2388,7 +2171,7 @@ class TrainingSession:
                 self.mesh, self.spec, prog, self._slot_rows // self.dp,
                 precision=self.precision,
             )
-            if self._metrics.enabled or self._audit_strict or self._aot:
+            if self._metrics.enabled or self._audit_strict:
                 runner.warm(self._stacked, self._flags, self._mpmd_resolve)
             self._mpmd_infer = runner
         return self._mpmd_infer
@@ -2457,178 +2240,6 @@ class TrainingSession:
             precision=self._precision_name,
             tp=self.tp,
         )
-
-    def measure_dispatch_overhead(self, repeats=2, program="epoch",
-                                  profile_dir=None):
-        """The measured op-issue roofline (docs/performance.md): dispatch
-        the compiled program under ``jax.profiler`` and split the host
-        wall into op-execution time vs everything else — scheduling,
-        Python/jax dispatch, the per-tick ``lax.switch`` issue cost the
-        lockstep executor pays. Returns (and records as a
-        ``dispatch_overhead`` event) the share of wall NOT covered by op
-        execution:
-
-            dispatch_overhead = 1 - op_busy_union / host_wall
-
-        where ``op_busy_union`` is ``trace_stats.dispatch_busy``'s
-        interval union of device ops (real accelerators) or HLO thunk
-        executions on the XLA executor threads (the CPU backend, which
-        emits no device timeline) — with the same comm/compute split
-        ``trace_stats.summarize`` applies. This is the number that turns
-        the "op-issue-bound" reading of the CPU bench rows
-        (split-backward 0.77x, tp2 0.45x) from a presumption into a
-        measurement.
-
-        The probe runs TWICE: once UNINSTRUMENTED (the honest wall —
-        ``host_wall_s``) and once under the profiler (the op-busy
-        evidence — ``host_wall_instrumented_s``). The profiler inflates
-        the host side (measured ~2-4x on the flagship epoch:
-        ``profiler_inflation`` records it), so the headline
-        ``dispatch_overhead`` divides the PROFILED busy union by the
-        UNPROFILED wall — instrumented ops only run longer, so this is a
-        conservative LOWER bound on the true host-issue share; the
-        in-window ``dispatch_overhead_instrumented`` is recorded beside
-        it as the upper companion.
-
-        ``program="epoch"``: the probe dispatches REAL training epochs —
-        the epoch program donates its state, so a side-effect-free
-        steady-state dispatch of it does not exist; callers own the fact
-        that weights advance by (up to one warm-up +) ``2 x repeats``
-        epochs. ``program="rung"``: dispatches the top inference rung on
-        zeros instead — weights untouched (the serving-side probe).
-
-        A trace with no attributable op events yields
-        ``dispatch_overhead: None`` with the reason — never a fabricated
-        0.
-
-        VALIDITY GUARD (the DISPATCH_r01 caveat from
-        ``scripts/bench_mpmd.py``, machine-checked): a long instrumented
-        window can saturate the profiler's trace buffer — op events drop
-        out of the tail, the busy union undercounts, and the "overhead"
-        share inflates. The record therefore carries ``events_per_batch``
-        (op events per dispatched batch — epoch programs normalize by
-        ``repeats x batches_per_epoch``, rung probes by ``repeats``) and
-        a ``window_valid`` flag: ``False``, with
-        ``window_invalid_reason``, when the instrumented window exceeds
-        the profiler budget or the trace attributed no ops at all. The
-        report CLI renders the flag on its dispatch row; consumers must
-        not quote an invalid window's share as a measurement."""
-        import tempfile
-
-        from shallowspeed_tpu.observability import trace_stats
-
-        if repeats < 1:
-            raise ValueError("repeats must be >= 1")
-        if program not in ("epoch", "rung"):
-            raise ValueError(f"program must be 'epoch' or 'rung', got {program!r}")
-
-        def dispatch_epoch():
-            self.train_epoch()
-
-        S_rows = self._slot_rows
-        top = self.slot_ladder[-1]
-        probe_x = np.zeros((top * S_rows, self.spec.sizes[0]), np.float32)
-
-        def dispatch_rung():
-            self.predict(probe_x)
-
-        if program == "epoch":
-            dispatch, label = dispatch_epoch, "epoch_program"
-            warm = not self._epoch_dispatched
-        else:
-            dispatch, label = dispatch_rung, "inference_rung"
-            warm = True
-        if warm:
-            dispatch()  # compile outside the probe windows
-        # the honest denominator: the SAME dispatch loop, uninstrumented
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            dispatch()
-        host_wall_s = time.perf_counter() - t0
-        tmp = None
-        if profile_dir is None:
-            tmp = tempfile.TemporaryDirectory(prefix="dispatch_probe_")
-            profile_dir = tmp.name
-        try:
-            with jax.profiler.trace(str(profile_dir)):
-                t1 = time.perf_counter()
-                for _ in range(repeats):
-                    dispatch()
-                wall_instrumented_s = time.perf_counter() - t1
-            traces = trace_stats.find_traces(profile_dir)
-            if not traces:
-                busy = {"op_events": 0, "busy_union_s": None,
-                        "comm_union_s": None, "compute_union_s": None,
-                        "source": "no-trace"}
-            else:
-                busy = trace_stats.dispatch_busy(traces[-1])
-        finally:
-            if tmp is not None:
-                tmp.cleanup()
-        share = trace_stats.dispatch_overhead_share(
-            busy["busy_union_s"], host_wall_s
-        )
-        # the validity guard (docstring): flag windows whose evidence
-        # can't be trusted — never fabricate, never silently quote
-        window_budget_s = 5.0  # past this the trace buffer may saturate
-        batches = repeats * (
-            self.batches_per_epoch if program == "epoch" else 1
-        )
-        events_per_batch = (
-            busy["op_events"] / batches if batches else None
-        )
-        window_valid = True
-        window_invalid_reason = None
-        if not busy["op_events"]:
-            window_valid = False
-            window_invalid_reason = "trace holds no attributable op events"
-        elif wall_instrumented_s > window_budget_s:
-            window_valid = False
-            window_invalid_reason = (
-                f"instrumented window {wall_instrumented_s:.2f}s exceeds "
-                f"the {window_budget_s:g}s profiler budget — the trace "
-                f"buffer may have saturated (undercounted ops inflate "
-                f"the overhead share)"
-            )
-        record = {
-            "program": label,
-            "runtime": self.runtime,
-            "repeats": int(repeats),
-            "host_wall_s": host_wall_s,
-            "host_wall_instrumented_s": wall_instrumented_s,
-            "profiler_inflation": (
-                wall_instrumented_s / host_wall_s if host_wall_s else None
-            ),
-            "device_busy_s": busy["busy_union_s"],
-            "device_comm_s": busy["comm_union_s"],
-            "device_compute_s": busy["compute_union_s"],
-            "op_events": busy["op_events"],
-            "op_source": busy["source"],
-            "events_per_batch": events_per_batch,
-            "window_valid": window_valid,
-            "window_invalid_reason": window_invalid_reason,
-            # the headline: profiled op busy over the UNPROFILED wall — a
-            # conservative lower bound (docstring); the in-window share
-            # rides beside it
-            "dispatch_overhead": share,
-            "dispatch_overhead_instrumented": (
-                trace_stats.dispatch_overhead_share(
-                    busy["busy_union_s"], wall_instrumented_s
-                )
-            ),
-            "platform": self._cost_model.platform,
-            "device_kind": self._cost_model.device_kind,
-            "provenance": (
-                "jax.profiler trace; op-interval union via "
-                "trace_stats.dispatch_busy over an uninstrumented wall "
-                "(lower bound — instrumented ops only run longer)"
-            ),
-        }
-        if share is None:
-            record["reason"] = "trace holds no attributable op events"
-        if self._metrics.enabled:
-            self._metrics.event("dispatch_overhead", **record)
-        return record
 
     def accuracy(self) -> float:
         """Argmax accuracy over the full validation split."""

@@ -240,8 +240,7 @@ def write_snapshot(path, arrays, meta, fsync=True, pre_rename_hook=None):
 def atomic_write(path, write_cb, suffix=".tmp", fsync=True,
                  pre_rename_hook=None):
     """The ONE durable-atomic-write sequence every on-disk artifact in this
-    repo shares (step checkpoints here, AOT cache entries in
-    aot_cache.py — a second hand-maintained copy would drift): mkstemp in
+    repo shares: mkstemp in
     the target directory, ``write_cb(file)``, ``fsync(file)``, atomic
     ``os.replace``, ``fsync(dir)`` — with the temp file removed on ANY
     failure, so a mid-stream exception never leaks a temp beside the

@@ -22,8 +22,7 @@ see a scope that MOVED under an unchanged name: rename the scope or bump
 not the cure: it keys on file paths and line numbers, so every checkout and
 every edited line would compile cold.)
 
-This is JAX's own store and is unrelated to ``aot_cache.py`` /
-``--aot-cache`` (an opt-in, separately keyed executable store).
+This is JAX's own store, and the only executable store the repository has.
 """
 
 import os

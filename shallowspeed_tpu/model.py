@@ -190,7 +190,7 @@ def make_model_spec(sizes, n_stages, global_batch_size, act="relu") -> ModelSpec
 # same ops/schedules/lowering/executor stack (docs/performance.md "--model").
 # ``mnist-mlp`` is the flagship reference model (api.FLAGSHIP_SIZES aliases
 # it); the others exist to make per-tick compute dominate dispatch on hosts
-# where the flagship epoch is op-issue-bound (DISPATCH_r01).
+# where the flagship epoch is op-issue-bound.
 # ---------------------------------------------------------------------------
 
 MODEL_ZOO = {

@@ -3,7 +3,7 @@ replay over per-layer digest streams (docs/numerics.md "Divergence
 debugging").
 
 Two runs that should match — a resumed run vs an uninterrupted one, MPMD
-vs lockstep, bucketed sync vs the anchor — historically compared ONE
+vs lockstep — historically compared ONE
 number: ``utils.model_hash`` at the end. A mismatch said "something,
 somewhere, at some point". The digest stream (schema v12,
 ``TrainingSession(digests=True)`` / ``train.py --digests``) records a
@@ -282,7 +282,6 @@ def _session_from_config(cfg, resume_path):
         momentum=cfg.get("momentum", 0.9),
         virtual_stages=cfg.get("virtual_stages", 1),
         zero1=cfg.get("zero1", False),
-        grad_bucket_bytes=cfg.get("grad_bucket_bytes", 0),
         backward_split=cfg.get("backward_split", False),
         recompute=cfg.get("recompute", False),
         scan_unroll=cfg.get("scan_unroll", 1),

@@ -76,10 +76,6 @@ Record shapes (all lines share ``v``/``ts``/``kind``/``name``):
      "replica_retired"|"scale_up"|"scale_down"|"fleet_degraded"|
      "fleet_recovered"|"reload_broadcast">, "replica_id": r,
      **fields}                                                       [v7+]
-    {"v": 8, "ts": ..., "kind": "aot_cache", "name": <event: "hit"|
-     "miss"|"store"|"stale"|"corrupt"|"audit_mismatch"|"fallback"|
-     "disabled">, "program": ..., "key": ..., "wall_s": ...,
-     "reason": ..., **fields}                                        [v8+]
     {"v": 9, "ts": ..., "kind": "static_analysis", "name": <program |
      "lint">, "passes": [...], "findings": n, **verdict}             [v9+]
     {"v": 10, "ts": ..., "kind": "trace",    "name": <span:
@@ -171,8 +167,9 @@ Schema compatibility rules (SCHEMA_VERSION history):
   cache decision, named by the event — ``hit``/``miss``/``store``/
   ``stale``/``corrupt``/``audit_mismatch``/``fallback``/``disabled`` —
   carrying the program label, cache key, wall time and the recorded
-  reason; shallowspeed_tpu/aot_cache.py), plus additive fields on the
-  EXISTING ``checkpoint`` kind for the async writer (``async``,
+  reason; its writer left with the cache itself, and the kind stays in
+  the table below so that an old v8 file still reads), plus additive
+  fields on the EXISTING ``checkpoint`` kind for the async writer (``async``,
   ``queue_depth`` at enqueue, off-path ``verify_s``/``write_s``/
   ``queued_s`` — for async saves ``wall_s`` is the ON-PATH cost only:
   snapshot + enqueue) and ``verify_s`` on the ``reload`` kind (the
@@ -415,9 +412,6 @@ class NullMetrics:
     def fleet_health(self, name, **fields):
         pass
 
-    def aot_cache(self, name, **fields):
-        pass
-
     def static_analysis(self, name, **fields):
         pass
 
@@ -535,9 +529,6 @@ class MetricsRecorder:
 
     def fleet_health(self, name, **fields):
         self._emit({"kind": "fleet_health", "name": name, **fields})
-
-    def aot_cache(self, name, **fields):
-        self._emit({"kind": "aot_cache", "name": name, **fields})
 
     def static_analysis(self, name, **fields):
         self._emit({"kind": "static_analysis", "name": name, **fields})

@@ -45,26 +45,6 @@ traffic), dp > 1
 without ZeRO-1 must all-reduce and must NOT reduce-scatter/all-gather,
 and ZeRO-1 must reduce-scatter AND all-gather (even at dp=1 — the
 chunked update always lowers both).
-
-BUCKETED gradient sync (``grad_bucket_bytes > 0``, parallel/gradsync.py)
-tightens the contract beyond kinds: each bucket is deliberately emitted
-as ONE flat collective, so every planned bucket must be ACCOUNTED FOR by
-the compiled sync ops — one op of exactly the bucket's result-byte size,
-or one op whose size is the sum of a merged run of ADJACENT buckets
-(backend collective-combiner passes may fuse neighboring small
-collectives; a merged program still syncs every planned byte and must
-not be refused). A tampered plan fails the match, as does the common
-unwired-knob shape on this jax (the legacy DP anchor lowers one
-all-reduce per LEAF, whose sizes cannot be partitioned into the planned
-bucket sums). Known evidence limit: ONE sync op of the total byte size
-is accepted — a combiner that merged every bucket and an unwired ZeRO-1
-anchor (one flat reduce-scatter) are byte-identical in the census, and
-refusing would abort healthy combiner-merged runs; wiring regressions
-of that shape are instead pinned by the CPU census tests, where no
-combiner runs and the per-bucket ops are visible individually
-(tests/test_program_audit.py::test_compiled_census_matches_bucket_plan).
-Total synced bytes are unchanged by bucketing; only the op granularity
-moves, which is exactly what this accounting pins down.
 """
 
 import math
@@ -216,13 +196,11 @@ def donation_census(hlo_text):
 
 
 def check_dispatch_safety(hlo_text, context="compiled program"):
-    """The dispatch-safety leg: a program that will be DISPATCHED from a
-    deserialized (AOT-cache) executable, or that serves requests, must
-    not donate its buffers — executing a deserialized donating program
-    is the jax-0.4.x heap-corruption hazard PR 1 hit (conftest's
-    segfault gate), and a serving program's params are reused by the
-    very next dispatch, so donation there is a use-after-free by
-    construction (serving/engine.py). Returns a list of human-readable
+    """The dispatch-safety leg: a program that serves requests, or whose
+    compiled executable is the dispatch path (the MPMD stage programs),
+    must not donate its buffers — its params are reused by the very next
+    dispatch, so donation there is a use-after-free by construction
+    (serving/engine.py). Returns a list of human-readable
     mismatch strings (empty = dispatch-safe)."""
     census = donation_census(hlo_text)
     if not census["aliased_outputs"]:
@@ -231,7 +209,7 @@ def check_dispatch_safety(hlo_text, context="compiled program"):
         f"{context}: program donates its input buffers "
         f"(input_output_alias: {census['aliased_outputs']} aliased "
         f"output(s) over params {census['donated_params']}, kinds "
-        f"{census['kinds']}) — dispatching it from a deserialized "
+        f"{census['kinds']}) — dispatching it from a compiled "
         "executable or a serving path is the documented use-after-free "
         "hazard (docs/static-analysis.md, docs/robustness.md)"
     ]
@@ -406,8 +384,7 @@ def interconnect_bytes_per_sec(platform, device_kind=None):
     return INTERCONNECT_BYTES_PER_SEC[row], source
 
 
-def zero_peak_forecast(spec, dp, pp, tp=1, state_parts=0, num_chunks=None,
-                       bucketed=False):
+def zero_peak_forecast(spec, dp, pp, tp=1, state_parts=0, num_chunks=None):
     """The analytical per-device PARAM-STATE footprint at every ZeRO
     stage — the structural model behind the OOM-forecast headroom claim
     ("params + grads + state ÷ dp"), priced from the SAME layout math the
@@ -421,14 +398,8 @@ def zero_peak_forecast(spec, dp, pp, tp=1, state_parts=0, num_chunks=None,
     or sharded), ``transient_bytes`` (stage 3 only: one chunk's gathered
     params live inside a tick), and their ``total_bytes``.
 
-    ``bucketed=True`` prices the overlap variant of stage 2 honestly: a
-    ``grad_bucket_bytes`` plan keeps the FULL-slab accumulators through
-    the scan (that is what makes its tail reduce-scatter bitwise-equal to
-    zero-1 at any microbatch count), so the bucketed stage-2 gradient
-    residency is the full ``f``, not the shard — only the anchor's
-    per-tick scatter into the persistent shard carry earns the ÷dp row. All figures are
-    f32 model-state bytes per device — activations, mailboxes and XLA
-    temps ride on top, so the measured ``peak_hbm_bytes`` exceeds the
+    All figures are f32 model-state bytes per device — activations,
+    mailboxes and XLA temps ride on top, so the measured ``peak_hbm_bytes`` exceeds the
     forecast by a (stage-independent) activation floor; what the forecast
     prices is the DELTA between stages, which is what the bench
     scoreboard verifies against measurements."""
@@ -449,8 +420,7 @@ def zero_peak_forecast(spec, dp, pp, tp=1, state_parts=0, num_chunks=None,
               "transient_bytes": 0},
         "1": {"params_bytes": f, "grads_bytes": f, "state_bytes": n * shard,
               "transient_bytes": 0},
-        "2": {"params_bytes": f,
-              "grads_bytes": f if bucketed else shard,
+        "2": {"params_bytes": f, "grads_bytes": shard,
               "state_bytes": n * shard, "transient_bytes": 0},
         "3": {"params_bytes": shard, "grads_bytes": shard,
               "state_bytes": n * shard,
@@ -480,7 +450,6 @@ def expected_comms(
     mubatch_size=None,
     platform="cpu",
     precision="highest",
-    grad_bucket_plan=None,
     tp=1,
     opt_state_parts=0,
     device_kind=None,
@@ -524,11 +493,7 @@ def expected_comms(
       * ``dp`` (zero1): reduce-scatter + all-gather of the padded flat
         param vector, ``2 * (dp-1)/dp x flat_bytes``;
 
-      the dp axis entry comes from ``gradsync.sync_comm_bytes`` and
-      carries the sync ``mode`` — with a ``grad_bucket_plan`` it also
-      carries the bucketed contract (``num_buckets`` + per-bucket
-      grad/census bytes; total bytes unchanged) that ``check_census``
-      verifies against the compiled ops;
+      the dp axis entry comes from ``gradsync.sync_comm_bytes``;
 
       * ``tp`` (tp > 1 only): the Megatron all-reduces — one psum over
         'tp' per row-parallel slot forward (plus the closing gather when
@@ -554,11 +519,10 @@ def expected_comms(
     - ``bound``: ``"comms"`` / ``"compute"`` — which lower bound dominates
       (None when either peak is unknown);
     - ``serial_bound_s`` / ``overlapped_bound_s``: the two step-time lower
-      bounds — ``comm + compute`` prices the legacy anchor (no gradient
+      bounds — ``comm + compute`` prices the anchor sync (no gradient
       communication can start until the whole backward ends, nothing
-      overlaps), ``max(comm, compute)`` prices perfectly-overlapped
-      bucketed sync; their gap is the overlap headroom the bucketing knob
-      exists to claim, and ``model_hidden_comm_share`` (``min(comm,
+      overlaps), ``max(comm, compute)`` a perfectly overlapped one; their
+      gap is the overlap headroom, and ``model_hidden_comm_share`` (``min(comm,
       compute) / comm``) is the share of communication a perfect overlap
       hides — the model-side number next to the MEASURED overlap
       efficiency the report derives from a trace's comm/compute split.
@@ -676,20 +640,18 @@ def expected_comms(
             else:
                 forbidden += ["reduce_scatter", "all_gather"]
                 if dp > 1:
-                    # "the DP all-reduce really is one psum" (or one per
-                    # bucket): the kind must be there (leaf-count fusion
-                    # makes exact UNBUCKETED op counts compiler noise — see
-                    # the module docstring; the bucketed contract pins
-                    # counts)
+                    # "the DP all-reduce really is one psum": the kind
+                    # must be there (leaf-count fusion makes exact op
+                    # counts compiler noise — see the module docstring)
                     required.append("all_reduce")
-            # the dp-axis byte model (anchor, per-bucket, or the stage-3
-            # per-tick schedule) has ONE definition, shared with the
-            # executor's emitters: gradsync.sync_comm_bytes. Stage 3's
+            # the dp-axis byte model (the tail anchor, or the stage-2/3
+            # per-tick schedule) has ONE definition:
+            # gradsync.sync_comm_bytes. Stage 3's
             # gather traffic scales with the microbatch passes — recompute
             # re-gathers the layer params inside the backward tick, a
             # third pass per (chunk, microbatch)
             axes["dp"] = sync_comm_bytes(
-                spec, dp, pp, zero=zero, plan=grad_bucket_plan, tp=tp,
+                spec, dp, pp, zero=zero, tp=tp,
                 mubatches=prog.num_micro_batches,
                 gather_passes=(
                     3 if getattr(prog, "recompute", False) else 2
@@ -713,7 +675,7 @@ def expected_comms(
     if comms_t is not None and compute_t is not None:
         bound = "comms" if comms_t > compute_t else "compute"
         # the two step-time lower bounds: the anchor's serial comm-then-
-        # compute chain vs the perfectly-overlapped bucketed sync
+        # compute chain vs a perfectly overlapped sync
         serial_t = comms_t + compute_t
         overlapped_t = max(comms_t, compute_t)
         if comms_t > 0:
@@ -723,7 +685,6 @@ def expected_comms(
         forecast = zero_peak_forecast(
             spec, dp, pp, tp=tp, state_parts=opt_state_parts,
             num_chunks=prog.num_chunks,
-            bucketed=bool(grad_bucket_plan) and int(zero or 0) == 2,
         )
     return {
         "dp": int(dp),
@@ -752,18 +713,10 @@ def expected_comms(
     }
 
 
-def check_census(census, expected, ops=None):
+def check_census(census, expected):
     """Compare a compiled program's collective census against the layout
     contract. Returns a list of human-readable mismatch strings (empty =
-    the census matches).
-
-    ``ops`` (optional): the per-op list from ``parse_collectives`` — when
-    the contract's dp axis is BUCKETED, the bucket-accounting check runs
-    against it (every planned bucket matched by a sync op of its exact
-    result size or by a combiner-merged adjacent run's sum — see the
-    module docstring; without ``ops`` there is no per-op size evidence
-    and only the kind legs run).
-    """
+    the census matches)."""
     mismatches = []
     for kind in expected.get("required", ()):
         if census.get(kind, {}).get("count", 0) < 1:
@@ -851,96 +804,12 @@ def check_census(census, expected, ops=None):
                 "(one JIT parameter gather per gather-bearing tick "
                 f"branch); compiled program has {n}"
             )
-    mismatches += _check_bucketed_sync(census, expected, ops)
     return mismatches
 
 
-def _check_bucketed_sync(census, expected, ops):
-    """The bucketed gradient-sync leg of the contract: the emitters
-    deliberately lower one flat collective per bucket, so every planned
-    bucket must be accounted for by the compiled sync ops — one op of
-    exactly the bucket's result size, or one op of a MERGED adjacent
-    run's summed size (backend collective combiners may fuse neighboring
-    small collectives; a merged program still syncs every planned byte
-    and must not be refused). A tampered plan fails; so does the
-    per-leaf unwired-anchor shape — but a SINGLE op of the total size is
-    accepted (indistinguishable from a full combiner merge; see the
-    module docstring for where that regression shape is pinned instead).
-    Checked only with per-op evidence (``ops``) and only when the
-    dp axis is real traffic (dp > 1 — at dp == 1 XLA may elide the
-    degenerate collectives entirely, which is not a lowering bug)."""
-    axis = (expected.get("axes") or {}).get("dp") or {}
-    if axis.get("mode") != "bucketed" or expected.get("dp", 1) <= 1:
-        return []
-    if ops is None:
-        return []  # census aggregates carry no per-op sizes: no evidence
-    # stages 1-2 both bucket their tail reduce-scatter (stage 3 has no
-    # plan: plan_buckets refuses); stage 0 buckets the anchor all-reduce
-    stage = expected.get("zero", 1 if expected.get("zero1") else 0)
-    kind = "reduce_scatter" if stage else "all_reduce"
-    planned = [int(b) for b in axis.get("bucket_census_bytes", ())]
-    compiled = sorted(op["bytes"] for op in ops if op["kind"] == kind)
-    if _buckets_accounted(planned, compiled):
-        return []
-
-    def _fmt(sizes):
-        s = ", ".join(str(v) for v in sizes[:12])
-        return f"[{s}{', ...' if len(sizes) > 12 else ''}]"
-
-    return [
-        f"bucketed sync: the compiled program's {kind} result sizes "
-        f"{_fmt(compiled)} cannot account for the planned bucket sizes "
-        f"{_fmt(planned)} (neither one op per bucket nor merged adjacent "
-        "runs)"
-    ]
-
-
-def _buckets_accounted(planned, compiled, node_budget=100_000):
-    """Can the ordered ``planned`` bucket sizes be partitioned into
-    contiguous runs whose sums each match a distinct ``compiled`` op
-    size? Run length 1 everywhere is the exact one-op-per-bucket case;
-    longer runs are combiner-merged neighbors (combiners fuse ops
-    adjacent in the schedule, i.e. consecutive buckets). Extra compiled
-    ops (loss psums, norm reductions) may go unused. Backtracking with a
-    node budget; when the search is infeasible (pathological many-equal-
-    size plans, or a plan deeper than Python's recursion limit) fall back
-    to the weaker total-bytes check rather than refusing a healthy
-    program on solver timeout."""
-    from collections import Counter
-
-    class _Exhausted(Exception):
-        pass
-
-    avail = Counter(compiled)
-    budget = [node_budget]
-
-    def match(i):
-        if budget[0] <= 0:
-            raise _Exhausted  # budget spent: no verdict either way
-        budget[0] -= 1
-        if i == len(planned):
-            return True
-        run = 0
-        for j in range(i, len(planned)):
-            run += planned[j]
-            if avail[run] > 0:
-                avail[run] -= 1
-                if match(j + 1):
-                    return True
-                avail[run] += 1
-        return False
-
-    try:
-        return match(0)
-    except (_Exhausted, RecursionError):
-        return sum(compiled) >= sum(planned)
-
-
-def verify_census(census, expected, context="compiled program", ops=None):
-    """``check_census`` that fails loudly — the tested layout invariant.
-    Pass ``ops`` (the ``parse_collectives`` list) to enforce the bucketed
-    size-accounting leg too; without it only the kind legs can fire."""
-    mismatches = check_census(census, expected, ops=ops)
+def verify_census(census, expected, context="compiled program"):
+    """``check_census`` that fails loudly — the tested layout invariant."""
+    mismatches = check_census(census, expected)
     if mismatches:
         raise AuditMismatchError(
             f"{context}: collective census disagrees with the layout "
@@ -965,8 +834,7 @@ def audit_compiled(
         text = compiled.as_text()
     except Exception:  # noqa: BLE001 — backend-optional surface
         text = None
-    ops = parse_collectives(text) if text else []
-    census = census_of_ops(ops)
+    census = collective_census(text) if text else {}
     rec = {
         "hlo_available": text is not None,
         "census": census,
@@ -984,7 +852,7 @@ def audit_compiled(
             rec["peak_hbm_per_chip_bytes"] = mem["peak_hbm_bytes"]
             rec["hbm_headroom_fraction"] = 1.0 - mem["peak_hbm_bytes"] / cap
     if expected is not None:
-        mismatches = check_census(census, expected, ops=ops) if text else []
+        mismatches = check_census(census, expected) if text else []
         rec["expected"] = expected
         rec["mismatches"] = mismatches
         # no HLO text -> nothing to audit; None, not a silent pass/fail

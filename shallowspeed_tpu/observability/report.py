@@ -18,8 +18,7 @@ human or a bench gate actually asks of a run:
   COMMS section (collective census vs the layout contract, analytical
   bytes/step per device, bandwidth-bound lower-bound step time vs the
   compute lower bound -> comms- vs compute-bound verdict, the serial
-  ``comm + compute`` vs overlapped ``max(comm, compute)`` step bounds,
-  and the gradient-sync mode — anchor or N byte-buckets);
+  ``comm + compute`` vs overlapped ``max(comm, compute)`` step bounds);
 - an OVERLAP EFFICIENCY row — the hidden-comm share
   ``1 - exposed_comm / total_comm``: measured from a profiler trace's
   comm/compute split when ``--trace`` points at one
@@ -70,11 +69,7 @@ human or a bench gate actually asks of a run:
   attribution — mean and p99-CONDITIONAL (which phase dominates the
   slowest 1%, the makespan-quantization scoreboard) — SLO burn per
   phase, and per-request text waterfalls for the worst-k requests.
-  Trace-free files render unchanged. A ``dispatch_overhead`` event (the
-  ``train.py --dispatch-probe`` measured op-issue roofline) renders as
-  its own summary row, flagged ``WINDOW INVALID`` when the probe's
-  machine-checked validity guard refused the window (saturated trace
-  buffer / no op events — the share must not be quoted clean);
+  Trace-free files render unchanged;
 - an ALERTS section (schema-v11 ``rollup``/``alert`` records,
   docs/observability.md § Live telemetry & alerting): the SLO alert
   firing→resolved timeline with peak burn rates and the still-firing
@@ -291,13 +286,6 @@ def build_report(records, source="", trace=None, slo_ms=None):
     divergence = _divergence_info(records)
     capacity = _capacity_info(records)
 
-    dispatch_overhead = None
-    for r in records:
-        if r.get("kind") == "event" and r.get("name") == "dispatch_overhead":
-            dispatch_overhead = {
-                k: v for k, v in r.items() if k not in ("v", "ts", "kind", "name")
-            }
-
     return {
         "source": source,
         "schema_versions": sorted({r.get("v", 0) for r in records}),
@@ -346,7 +334,6 @@ def build_report(records, source="", trace=None, slo_ms=None):
         "rollups": rollups,
         "divergence": divergence,
         "capacity": capacity,
-        "dispatch_overhead": dispatch_overhead,
     }
 
 
@@ -584,7 +571,6 @@ def _reliability_info(records, spans):
     landed on. Without that evidence the field stays None (rendered as
     unknown)."""
     ckpts = [r for r in records if r.get("kind") == "checkpoint"]
-    aot = _aot_cache_info(records)
     recoveries = []
     max_step_before = None
     last_step = None
@@ -594,7 +580,7 @@ def _reliability_info(records, spans):
         elif r.get("kind") == "recovery":
             recoveries.append(r)
             max_step_before = last_step
-    if not ckpts and not recoveries and aot is None:
+    if not ckpts and not recoveries:
         return None
     # for async saves (schema v8) wall_s is the ON-PATH cost only — the
     # snapshot + bounded-queue enqueue — so the overhead fraction below
@@ -653,37 +639,7 @@ def _reliability_info(records, spans):
         "last_checkpoint_bytes": ckpts[-1].get("bytes") if ckpts else None,
         "checkpoints_async": len(async_ckpts),
         "checkpoint_off_path_s": round(off_path_s, 4),
-        "aot_cache": aot,
         "recovery": recovery,
-    }
-
-
-def _aot_cache_info(records):
-    """Fold the schema-v8 ``aot_cache`` records into the hit/miss story;
-    None when the run recorded none (pre-v8 files render unchanged)."""
-    recs = [r for r in records if r.get("kind") == "aot_cache"]
-    if not recs:
-        return None
-    counts = {}
-    for r in recs:
-        counts[r.get("name")] = counts.get(r.get("name"), 0) + 1
-    lookups = counts.get("hit", 0) + counts.get("miss", 0)
-    hit_walls = [
-        r["wall_s"] for r in recs
-        if r.get("name") == "hit" and _finite(r.get("wall_s"))
-    ]
-    disabled = [r.get("reason") for r in recs if r.get("name") == "disabled"]
-    return {
-        "hits": counts.get("hit", 0),
-        "misses": counts.get("miss", 0),
-        "stores": counts.get("store", 0),
-        "stale": counts.get("stale", 0),
-        "corrupt": counts.get("corrupt", 0),
-        "audit_mismatches": counts.get("audit_mismatch", 0),
-        "fallbacks": counts.get("fallback", 0),
-        "hit_rate": (counts.get("hit", 0) / lookups) if lookups else None,
-        "hit_wall_s": sum(hit_walls) if hit_walls else None,
-        "disabled_reason": disabled[0] if disabled else None,
     }
 
 
@@ -930,14 +886,11 @@ def _overlap_info(audit, trace):
     exp = (audit or {}).get("expected") or {}
     info = None
     if _finite(exp.get("model_hidden_comm_share")):
-        axis = (exp.get("axes") or {}).get("dp") or {}
         info = {
             "source": "model",
             "hidden_comm_share": exp["model_hidden_comm_share"],
             "serial_bound_s": exp.get("serial_bound_s"),
             "overlapped_bound_s": exp.get("overlapped_bound_s"),
-            "sync_mode": axis.get("mode"),
-            "num_buckets": axis.get("num_buckets"),
         }
     if trace and _finite(trace.get("overlap_efficiency")):
         info = dict(info or {})
@@ -1073,33 +1026,8 @@ def _rows(report):
                 f"{_fmt_num(ov.get('comm_ms'))} ms comm)"
             )
         else:
-            mode = ov.get("sync_mode")
-            sync = (
-                f"{ov.get('num_buckets')} buckets" if mode == "bucketed"
-                else "anchor sync"
-            )
-            detail = f"{share} of comm hideable (model bound; {sync})"
+            detail = f"{share} of comm hideable (model bound; anchor sync)"
         rows.append(("overlap efficiency", detail))
-    do = report.get("dispatch_overhead")
-    if do is not None:
-        share = do.get("dispatch_overhead")
-        if share is None:
-            detail = "unmeasurable — " + str(do.get("reason", "no op events"))
-        else:
-            detail = (
-                f">= {_fmt_num(share, pct=True)} of {do.get('program')} "
-                f"wall is host-side op issue (op busy "
-                f"{_fmt_time_s(do.get('device_busy_s'))} of "
-                f"{_fmt_time_s(do.get('host_wall_s'))} uninstrumented "
-                f"wall; measured lower bound, {do.get('op_source')})"
-            )
-        if do.get("window_valid") is False:
-            # the machine-checked probe-validity guard (api.py): an
-            # invalid window's share is flagged, never quoted clean
-            detail += "  [WINDOW INVALID: " + str(
-                do.get("window_invalid_reason") or "unknown"
-            ) + "]"
-        rows.append(("dispatch overhead", detail))
     sa = report.get("static_analysis")
     if sa is not None:
         if sa["findings"]:
@@ -1347,18 +1275,6 @@ def _comms_lines(audit, md):
             else:
                 line += " + post-update param all-gather of the updated chunk"
             lines.append(line)
-        if dp_axis.get("mode") == "bucketed":
-            # "budget", not "<=": a single leaf larger than the budget
-            # gets its own oversized bucket (the planner never splits one)
-            sizes = dp_axis.get("bucket_grad_bytes") or []
-            lines.append(
-                f"gradient sync: bucketed — {dp_axis.get('num_buckets')} "
-                f"collectives, budget "
-                f"{format_bytes(dp_axis.get('grad_bucket_bytes'))}/bucket "
-                f"(largest bucket "
-                f"{format_bytes(max(sizes) if sizes else None)}); "
-                "total bytes unchanged vs the anchor"
-            )
         ct, xt = exp.get("comms_time_per_step_s"), exp.get("compute_time_per_step_s")
         if ct is not None or xt is not None:
             bound = exp.get("bound")
@@ -1372,7 +1288,7 @@ def _comms_lines(audit, md):
             if st is not None and ot is not None:
                 lines.append(
                     f"step-time bounds: serial (anchor) {_fmt_time_s(st)} "
-                    f"= comm + compute; overlapped (bucketed, perfect) "
+                    f"= comm + compute; overlapped (perfect) "
                     f"{_fmt_time_s(ot)} = max(comm, compute)"
                 )
     lines.append("")
@@ -1408,42 +1324,6 @@ def _reliability_lines(rel, md):
                 f"overhead above; verify+write "
                 f"{_fmt_time_s(rel.get('checkpoint_off_path_s'))} ran in "
                 "the background writer)"
-            )
-    aot = rel.get("aot_cache")
-    if aot is not None:
-        if aot.get("hit_rate") is not None:
-            line = (
-                f"aot executable cache: {aot['hits']} hit(s) / "
-                f"{aot['misses']} miss(es) "
-                f"(hit rate {aot['hit_rate'] * 100:.0f}%"
-                + (
-                    f", deserialize {_fmt_time_s(aot['hit_wall_s'])} vs "
-                    "a cold recompile"
-                    if aot.get("hit_wall_s") is not None
-                    else ""
-                )
-                + ")"
-            )
-        else:
-            line = "aot executable cache: no lookups"
-        if aot.get("stores"):
-            line += f", {aot['stores']} entr(ies) written"
-        lines.append(line)
-        bad = []
-        if aot.get("stale"):
-            bad.append(f"{aot['stale']} stale")
-        if aot.get("corrupt"):
-            bad.append(f"{aot['corrupt']} corrupt")
-        if aot.get("audit_mismatches"):
-            bad.append(f"{aot['audit_mismatches']} audit-mismatched")
-        if bad:
-            lines.append(
-                "  " + ", ".join(bad)
-                + " entr(ies) fell back to a clean recompile"
-            )
-        if aot.get("disabled_reason"):
-            lines.append(
-                f"  cache disabled on this backend: {aot['disabled_reason']}"
             )
     rec = rel.get("recovery")
     if rec is not None:

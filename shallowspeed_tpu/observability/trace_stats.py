@@ -70,120 +70,6 @@ def find_traces(path):
     return sorted(p.rglob("*.trace.json.gz"))
 
 
-# CPU-backend fallback for the dispatch-overhead probe: jax's TFRT CPU
-# client emits NO "/device:" pid — the HLO thunk executions land on the
-# host pid's XLA executor threads instead (the Eigen worker pool plus the
-# client thread, all named "tf_XLA...").
-CPU_EXECUTOR_THREAD_PREFIX = "tf_XLA"
-
-
-def _is_hlo_thunk_event(name):
-    """True when an executor-thread event is an HLO op execution (e.g.
-    ``dot.14``, ``broadcast_maximum_fusion.clone``, ``call.1``) rather
-    than runtime plumbing: C++ internals carry ``::`` (including the
-    ``ThunkExecutor::Execute (wait for completion)`` WAIT, which is idle
-    time, not compute), python frames are prefixed ``$``, and
-    ``ParseArguments`` is argument marshalling."""
-    n = str(name)
-    return not (n.startswith("$") or "::" in n or n == "ParseArguments")
-
-
-def dispatch_busy(trace_path):
-    """Op-execution interval UNION of one trace — the device-compute side
-    of the dispatch-overhead probe (``api.measure_dispatch_overhead``).
-
-    On a real accelerator trace this is the ``/device:`` pids' op stream
-    (the same filter ``summarize`` uses). On the CPU backend — no
-    ``/device:`` pid at all — it falls back to the HLO thunk events on
-    the ``tf_XLA*`` executor threads. Either way the result is an
-    interval union, not a busy-time sum: parallel Eigen workers (or
-    overlapping functional units) must not let summed busy time exceed
-    the wall and understate the dispatch share. The union is also split
-    by ``is_comm_op`` so the probe's record carries the same
-    comm/compute attribution as ``summarize``.
-
-    Returns ``{"op_events", "busy_union_s", "comm_union_s",
-    "compute_union_s", "source": "device"|"host-executor", "trace"}`` —
-    ``op_events == 0`` (with ``busy_union_s`` None) when the trace holds
-    nothing attributable, which callers must surface, not paper over.
-    """
-    with gzip.open(trace_path) as f:
-        tr = json.load(f)
-    events = tr.get("traceEvents", [])
-    dev_pids = {
-        e["pid"]
-        for e in events
-        if e.get("ph") == "M"
-        and e.get("name") == "process_name"
-        and "/device:" in str(e.get("args", {}).get("name", ""))
-    }
-    module_tids = {
-        (e["pid"], e["tid"])
-        for e in events
-        if e.get("ph") == "M"
-        and e.get("name") == "thread_name"
-        and "Modules" in str(e.get("args", {}).get("name", ""))
-    }
-    if dev_pids:
-        ops = [
-            e
-            for e in events
-            if e.get("ph") == "X"
-            and e.get("pid") in dev_pids
-            and (e["pid"], e.get("tid")) not in module_tids
-        ]
-        source = "device"
-    else:
-        executor_tids = {
-            (e["pid"], e["tid"])
-            for e in events
-            if e.get("ph") == "M"
-            and e.get("name") == "thread_name"
-            and str(e.get("args", {}).get("name", "")).startswith(
-                CPU_EXECUTOR_THREAD_PREFIX
-            )
-        }
-        ops = [
-            e
-            for e in events
-            if e.get("ph") == "X"
-            and (e.get("pid"), e.get("tid")) in executor_tids
-            and _is_hlo_thunk_event(e.get("name"))
-        ]
-        source = "host-executor"
-    if not ops:
-        return {
-            "trace": str(trace_path),
-            "op_events": 0,
-            "busy_union_s": None,
-            "comm_union_s": None,
-            "compute_union_s": None,
-            "source": source,
-        }
-    busy_us = _union_us(ops)
-    comm_us = _union_us(e for e in ops if is_comm_op(e["name"]))
-    compute_us = _union_us(e for e in ops if not is_comm_op(e["name"]))
-    return {
-        "trace": str(trace_path),
-        "op_events": len(ops),
-        "busy_union_s": busy_us / 1e6,
-        "comm_union_s": comm_us / 1e6,
-        "compute_union_s": compute_us / 1e6,
-        "source": source,
-    }
-
-
-def dispatch_overhead_share(busy_union_s, host_wall_s):
-    """The measured op-issue roofline number: the share of the host wall
-    NOT covered by op execution — ``1 - busy/wall``, clamped at 0 (timer
-    jitter must not report negative overhead). ``None`` when either side
-    is unmeasured; a probe that cannot attribute must say so instead of
-    reporting a perfect 0."""
-    if not host_wall_s or busy_union_s is None:
-        return None
-    return max(0.0, 1.0 - busy_union_s / host_wall_s)
-
-
 def summarize(trace_path):
     """Device-op statistics for one chrome trace (dict, JSON-able).
 
@@ -198,7 +84,7 @@ def summarize(trace_path):
     MEASURED communication share of a capture is directly comparable
     against the analytical comms model's verdict
     (program_audit.expected_comms). From the same split come the overlap
-    numbers the bucketed gradient sync exists to move:
+    numbers:
     ``exposed_comm_ms`` — timeline time where communication ran with NO
     compute op in flight on the same device (a per-pid interval-union
     sweep: ``|union(comm) \\ union(compute)|`` summed over device pids —

@@ -49,25 +49,20 @@ optimizer step — is ONE jitted XLA computation:
   pytree over ``dp`` at the tail anchor, every replica repeats the full
   update. Stage 1 (ZeRO-1): the tail reduce-scatters the FLAT gradient,
   each replica updates its 1/dp chunk with its optimizer-state shard, and
-  one deferred all-gather rebuilds the params. Stage 2 (ZeRO-2): the tail
-  reduce-scatters PER LAYER SLOT straight from the accumulator slabs into
-  the block-cyclic shard layout below — the flat gradient concat never
-  materializes, the post-sync gradient lives only as this rank's shard,
+  one deferred all-gather rebuilds the params. Stage 2 (ZeRO-2): every
+  backward tick reduce-scatters its slots' gradients straight into this
+  rank's persistent shard in the block-cyclic layout below — neither the
+  full gradient slabs nor the flat gradient concat ever materialize —
   and per-slot all-gathers rebuild the updated params. Stage 3 (ZeRO-3):
   params REST in the block-cyclic shard and every tick branch all-gathers
   just the active chunk's slots on demand (gathered copies die with the
   branch), while the backward reduce-scatters each tick's slot gradients
   immediately — peak live params is one stage chunk, not the model.
-  ``grad_bucket_bytes`` composes at stages 0-2: byte-bucketed collectives
-  (parallel/gradsync.py) split the anchor sync into backward-ordered
-  buckets, one collective each, so XLA's latency-hiding scheduler can
-  overlap bucket k's communication with the consumers of already-synced
-  buckets — the reference's per-parameter Iallreduce engine
-  (pipe.py:302-327) with the bucketing its docstring wishes for. Stages
-  0-2 are bitwise identical to each other modulo norm-scalar
-  reassociation (elementwise collectives; see the ZeRO sections below);
-  stage 3's per-tick sync reassociates the microbatch/replica sum order
-  and carries the standard cross-layout tolerance instead;
+  Stages 0-1 are bitwise identical to each other modulo norm-scalar
+  reassociation (elementwise collectives; see the ZeRO sections below),
+  and stage 2 joins them at ``mubatches=1``; the per-tick sync of stages
+  2-3 reassociates the microbatch/replica sum order and carries the
+  standard cross-layout tolerance otherwise;
 - the optimizer step happens on-device on the padded params (padded regions
   receive exactly-zero gradients, so they stay zero — see tests);
 - on a mesh with a ``tp`` axis (parallel/mesh.py, ``--tp``), every slot's
@@ -77,7 +72,7 @@ optimizer step — is ONE jitted XLA computation:
   pair per pass; see the tp stage functions below). Slot dims round up to
   tp multiples (``slot_shapes(spec, tp)``), per-device weight memory /
   optimizer state / matmul FLOPs divide by tp, and tp composes with DP,
-  ZeRO-1, grad bucketing, the split backward and every schedule. At
+  ZeRO-1, the split backward and every schedule. At
   ``tp == 1`` none of this code is traced: the historical 2-axis programs
   are byte-identical.
 
@@ -431,8 +426,8 @@ def init_stacked(spec: ModelSpec, mesh: Mesh, order=None):
 def stacked_flat_len(spec: ModelSpec, pp: int, tp: int = 1) -> int:
     """Per-DEVICE flattened param count of the stacked layout (every W slot
     then every b slot, V virtual rows each; this rank's tp shard of each) —
-    the ONE definition of the flat layout's size. ``zero1_flat_len``, the
-    gradsync bucket planners and the audit's comms model all read it, so a
+    the ONE definition of the flat layout's size. ``zero1_flat_len`` and
+    the audit's comms model (``gradsync.sync_comm_bytes``) read it, so a
     layout change here propagates to every consumer at once. Under tp the
     per-device count shrinks by exactly tp (slot dims are tp-rounded, and
     both the column and row shard of a slot hold ``o*i/tp`` elements)."""
@@ -672,18 +667,17 @@ def zero1_state_from_logical(logical, opt, spec: ModelSpec, mesh: Mesh, order=No
 # (V, sz) reduce-scatters DIRECTLY into this layout (pad the row, deal the
 # column blocks — one collective per slot, no flat concat), and a single
 # row's gradient reduce-scatters into ONE (k,) segment of the shard — which
-# is what lets ZeRO-3 sync per tick from inside the scan. The column-block
-# deal is exactly the (dp, chunk) column view ``gradsync.
-# psum_scatter_bucketed`` already emits, so byte-bucket plans compose
-# (mode "zero2": ranges within a slot's [0, V*k) columns).
+# is what lets ZeRO-2 and ZeRO-3 sync per tick from inside the scan.
 #
-# ZeRO-2 = params still replicated (stacked {W, b} as ever) + gradients
-# reduce-scattered per slot at the tail anchor + optimizer state sharded in
-# this layout. Elementwise collectives: each element's dp-sum lands with
-# identical bits wherever it is scattered, so ZeRO-2 weights are BITWISE
-# equal to ZeRO-1's at a fixed layout for elementwise optimizer math (the
+# ZeRO-2 = params still replicated (stacked {W, b} as ever) + each tick's
+# slot gradients reduce-scattered into the persistent (csz3,) gradient
+# shard + optimizer state sharded in this layout. Elementwise collectives:
+# each element's dp-sum lands with identical bits wherever it is
+# scattered, so at ``mubatches=1`` ZeRO-2 weights are BITWISE equal to
+# ZeRO-1's at a fixed layout for elementwise optimizer math (the
 # clip/grad-norm scalar partitions its partial sums differently — pin
-# bitwise equality on clip-free runs).
+# bitwise equality on clip-free runs); with more microbatches the shard
+# sums microbatch-outer and carries ZeRO-3's tolerance.
 #
 # ZeRO-3 = params AT REST in this layout ({"P": (pp*tp, dp*csz3)} under
 # ``zero1_part_spec``) — each tick branch all-gathers just the active
@@ -1113,7 +1107,7 @@ def _stage_bwd_weight(active, dims, xs, g_effs, precision):
 #   cross-layout tolerance (exactly like a different dp width reassociating
 #   the gradient all-reduce — docs/numerics.md), while tp=1 stays byte-
 #   identical (these functions are never traced at tp == 1) and same-layout
-#   A/B knobs at fixed tp (bucketed vs anchor sync, split vs combined
+#   A/B knobs at fixed tp (split vs combined
 #   backward, fused-run vs step loop) remain bitwise;
 # - these psums sit inside ``lax.switch`` branches; the branch predicate is
 #   the stage's op code, identical for every member of a tp group (same
@@ -1299,7 +1293,6 @@ def make_pipeline_step(
     with_grad_norm=False,
     with_step_stats=False,
     with_digests=False,
-    grad_bucket_bytes=0,
 ):
     """Build the jitted SPMD step executing one TickProgram over the mesh.
 
@@ -1335,17 +1328,7 @@ def make_pipeline_step(
     sum is psum'd over ``pp`` (and, under zero1, over ``dp`` where the
     summed gradient lives chunked) — padded entries are exactly zero, so the
     stacked norm equals the logical norm. The norm always reads the
-    POST-SYNC gradient, so it is identical under both sync modes.
-
-    ``grad_bucket_bytes``: 0 (default) keeps the legacy gradient-sync
-    anchor — one whole-tree ``lax.psum`` over ``dp`` (one flat
-    ``psum_scatter`` under zero1). A positive byte budget switches to the
-    bucketed sync (parallel/gradsync.py): the gradient is greedily packed
-    into backward-ordered buckets of at most this many bytes and each
-    bucket is synced by its OWN collective, giving XLA's scheduler
-    independent communication ops to overlap with the update's compute.
-    Bitwise identical to the anchor on every layout (elementwise
-    reductions; tested).
+    POST-SYNC gradient.
 
     ``with_grad_norm`` (training only): telemetry aux — the step returns a
     FOURTH output, the pre-clip global gradient norm (replicated scalar,
@@ -1415,12 +1398,6 @@ def make_pipeline_step(
             "branch; the fused pallas flag kernels take whole resident "
             "slots — use kernel_backend='xla' with --zero 3"
         )
-    if zero == 3 and grad_bucket_bytes:
-        raise ValueError(
-            "zero=3 syncs gradients per tick (one reduce-scatter per layer "
-            "slot inside the scan); the grad_bucket_bytes knob shapes the "
-            "tail sync only and has nothing to bucket at stage 3"
-        )
     tp_n = mesh_tp(mesh)
     if tp_n > 1 and kernel_backend == "pallas":
         raise ValueError(
@@ -1489,17 +1466,6 @@ def make_pipeline_step(
     assert prog.num_stages == P_, "program/mesh device-count mismatch"
     assert S_ == P_ * V, "model stages must equal devices x virtual chunks"
     dp_n = mesh.shape["dp"]
-    # gradient-sync plan: None = legacy anchor collective; a BucketPlan =
-    # per-bucket collectives (derived deterministically from spec + knob,
-    # so the session's audit contract rebuilds the identical plan)
-    if grad_bucket_bytes and training:
-        from shallowspeed_tpu.parallel import gradsync
-
-        sync_plan = gradsync.plan_buckets(
-            spec, dp_n, P_, grad_bucket_bytes, zero=zero, tp=tp_n
-        )
-    else:
-        sync_plan = None
     if zero >= 2 and with_digests:
         raise ValueError(
             "with_digests reads the zero1 flat-chunk segment map; the "
@@ -1527,16 +1493,12 @@ def make_pipeline_step(
         if z1_stateful:
             z1_layout = opt.state_layout()
 
-    # ZeRO-2/3 persistent gradient shard: the anchor zero-2 program and
-    # every zero-3 program accumulate the dp-summed gradient as this
-    # rank's (csz3,) block-cyclic shard, reduce-scattered per tick
-    # (canonical ZeRO-2 ordering: the shard sums microbatch-outer). A
-    # bucketed zero-2 plan keeps the full-slab accumulators and the
-    # byte-bucketed tail reduce-scatter instead — the overlap trade,
-    # which also stays bitwise equal to zero-1 at any microbatch count
-    # (the sharded accumulator's reassociated (dp x microbatch) sum is
-    # bitwise only at mubatches=1; see docs/performance.md).
-    shard_grads = zero == 3 or (zero == 2 and sync_plan is None)
+    # ZeRO-2/3 persistent gradient shard: every zero-2 and zero-3 program
+    # accumulates the dp-summed gradient as this rank's (csz3,)
+    # block-cyclic shard, reduce-scattered per tick (canonical ZeRO-2
+    # ordering: the shard sums microbatch-outer, so it is bitwise equal to
+    # zero-1's dp-outer sum only at mubatches=1; see docs/performance.md).
+    shard_grads = zero >= 2
 
     if with_digests:
         # the digest-grid builders (see the docstring): per-slot columns of
@@ -1733,7 +1695,7 @@ def make_pipeline_step(
                 loss=jnp.zeros((), jnp.float32),
             )
             if shard_grads:
-                # ZeRO-2 (anchor) and ZeRO-3 accumulate the dp-summed
+                # ZeRO-2 and ZeRO-3 accumulate the dp-summed
                 # gradient directly as this rank's persistent (csz3,)
                 # shard — reduce-scattered per tick, never as full
                 # (V, o, i) slabs: the stage's gradient-residency claim
@@ -2110,41 +2072,8 @@ def make_pipeline_step(
 
         if zero >= 2:
             # ZeRO-2/3 tail: the dp-summed gradient lives as this rank's
-            # block-cyclic (csz3,) shard. The anchor zero-2 program and
-            # every zero-3 program accumulated it per tick (shard_grads);
-            # a bucketed zero-2 plan reduce-scatters its full-slab
-            # accumulators HERE, one byte-bucket at a time (elementwise
-            # over the same (dp, chunk) column deal, so the bucketed
-            # shard is zero-1's update input, bitwise).
-            if shard_grads:
-                gsh = carry["gz"]
-            else:
-                mats = [
-                    _zb_scatter_rows(g.reshape(s.rows, s.sz), dp_n, s.k)
-                    for s, g in zip(
-                        zb_slots, list(carry["gW"]) + list(carry["gb"])
-                    )
-                ]
-                # byte-bucketed: one collective per (slot, column
-                # range) bucket in backward emission order; the
-                # reassembled shard is the anchor's column deal, bitwise
-                pieces = [[] for _ in zb_slots]
-                for si, a, b in sync_plan.buckets:
-                    with scope("sync/dp"):
-                        piece = lax.psum_scatter(
-                            mats[si][:, a:b],
-                            "dp",
-                            scatter_dimension=0,
-                            tiled=False,
-                        )
-                    pieces[si].append((a, piece))
-                gsh = jnp.concatenate(
-                    [
-                        p
-                        for ps in pieces
-                        for _, p in sorted(ps, key=lambda t: t[0])
-                    ]
-                )
+            # block-cyclic (csz3,) shard, accumulated per tick.
+            gsh = carry["gz"]
             if with_grad_norm:
                 # shards partition the dp-summed gradient across every
                 # sharded axis; per-slot padding is exactly zero
@@ -2244,19 +2173,12 @@ def make_pipeline_step(
                 [g.reshape(-1) for g in carry["gW"]]
                 + [g.reshape(-1) for g in carry["gb"]]
             )
-            # the gradient sync: one flat reduce-scatter at the anchor, or
-            # one per byte-bucket (column ranges of the (dp, chunk) view —
-            # the concatenated outputs ARE the anchor chunk, bitwise)
+            # the gradient sync: one flat reduce-scatter at the anchor
             gpad = jnp.pad(gvec, (0, pad))
-            if sync_plan is None:
-                with scope("sync/dp"):
-                    gsh = lax.psum_scatter(
-                        gpad, "dp", scatter_dimension=0, tiled=True
-                    )
-            else:
-                from shallowspeed_tpu.parallel import gradsync
-
-                gsh = gradsync.psum_scatter_bucketed(gpad, sync_plan)
+            with scope("sync/dp"):
+                gsh = lax.psum_scatter(
+                    gpad, "dp", scatter_dimension=0, tiled=True
+                )
             if with_grad_norm:
                 # chunks partition the dp-summed gradient across every
                 # sharded axis, so the pre-clip global norm is one
@@ -2346,23 +2268,14 @@ def make_pipeline_step(
                 outs += (_digest_grids(new_stacked, dgsq_w, dgsq_b),)
             return outs
 
-        # the BackwardGradAllReduce anchor, in one of two bitwise-identical
-        # forms (reference pipe.py:302-327): legacy — one SUM-psum of the
-        # whole gradient pytree over dp per batch — or bucketed — one psum
-        # per backward-ordered byte-bucket, so XLA can overlap each
-        # bucket's all-reduce with the rest of the tail. The clip-norm /
-        # grad-norm consumers below always read the POST-SYNC tree.
-        if sync_plan is None:
-            with scope("sync/dp"):
-                gW = lax.psum(carry["gW"], "dp")
-                gb = lax.psum(carry["gb"], "dp")
-            grads = {"W": gW, "b": gb}  # (V, ...) leaves, mirroring the shards
-        else:
-            from shallowspeed_tpu.parallel import gradsync
-
-            grads = gradsync.psum_bucketed(
-                {"W": carry["gW"], "b": carry["gb"]}, sync_plan
-            )
+        # the BackwardGradAllReduce anchor (reference pipe.py:302-327): one
+        # SUM-psum of the whole gradient pytree over dp per batch. The
+        # clip-norm / grad-norm consumers below always read the POST-SYNC
+        # tree.
+        with scope("sync/dp"):
+            gW = lax.psum(carry["gW"], "dp")
+            gb = lax.psum(carry["gb"], "dp")
+        grads = {"W": gW, "b": gb}  # (V, ...) leaves, mirroring the shards
         if with_grad_norm:
             from shallowspeed_tpu.optimizer import global_norm
 
@@ -2525,7 +2438,6 @@ def make_pipeline_epoch(
     with_grad_norm=False,
     with_step_stats=False,
     with_digests=False,
-    grad_bucket_bytes=0,
 ):
     """Scan the pipeline train step over all batches of an epoch: one XLA
     program per epoch. X: (num_batches, global_batch, in_dim), batch axis
@@ -2545,16 +2457,12 @@ def make_pipeline_epoch(
     ``(num_batches, S, L)`` — see make_pipeline_step's digest contract);
     ``zero`` selects the full dp-axis ZeRO stage {0..3} (supersedes the
     ``zero1`` boolean; see make_pipeline_step — at stage 3 ``stacked`` is
-    the ``{"P"}`` shard layout throughout the epoch);
-    ``grad_bucket_bytes`` selects the gradient-
-    sync mode (0 = anchor collective, >0 = byte-bucketed — see
-    make_pipeline_step)."""
+    the ``{"P"}`` shard layout throughout the epoch)."""
     step = make_pipeline_step(
         mesh, spec, prog, mubatch_size, opt, precision, jit=False,
         tick_unroll=tick_unroll, zero1=zero1, zero=zero, clip_norm=clip_norm,
         kernel_backend=kernel_backend, with_grad_norm=with_grad_norm,
         with_step_stats=with_step_stats, with_digests=with_digests,
-        grad_bucket_bytes=grad_bucket_bytes,
     )
     return jax.jit(
         _make_pipeline_epoch_core(
@@ -2631,7 +2539,6 @@ def make_pipeline_run(
     eval_mubatch_size=None,
     kernel_backend="xla",
     with_grad_norm=False,
-    grad_bucket_bytes=0,
 ):
     """Epochs-outer scan around the pipeline epoch: the whole multi-epoch run
     as ONE XLA program over the mesh (the pipeline counterpart of
@@ -2653,8 +2560,7 @@ def make_pipeline_run(
     (ordinary scan outputs, so the run stays one fused program; this closes
     the mesh-fused-run gap docs/observability.md used to document).
 
-    ``n_epochs`` is static (one compile per value); ``grad_bucket_bytes``
-    selects the gradient-sync mode (see make_pipeline_step).
+    ``n_epochs`` is static (one compile per value).
     """
     if zero is not None and int(zero) == 3:
         raise ValueError(
@@ -2666,7 +2572,6 @@ def make_pipeline_run(
         mesh, spec, prog, mubatch_size, opt, precision, jit=False,
         tick_unroll=tick_unroll, zero1=zero1, zero=zero, clip_norm=clip_norm,
         kernel_backend=kernel_backend, with_grad_norm=with_grad_norm,
-        grad_bucket_bytes=grad_bucket_bytes,
     )
     eval_step = None
     if eval_prog is not None:

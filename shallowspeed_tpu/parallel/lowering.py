@@ -360,10 +360,7 @@ def program_comm_bytes(prog, spec, mubatch_size):
     count), ``num_ticks``.
 
     This function covers the pp-axis relay only. The dp-axis gradient-sync
-    leg — one anchor collective, or one collective PER BYTE-BUCKET when
-    ``grad_bucket_bytes > 0`` — is modeled by
-    ``parallel/gradsync.sync_comm_bytes`` (same per-bucket numbers the
-    executor's emitters lower and the program audit verifies).
+    leg is modeled by ``parallel/gradsync.sync_comm_bytes``.
     """
     from shallowspeed_tpu.parallel.executor import relay_width
 

@@ -2,9 +2,7 @@
 
 The lockstep executor (parallel/executor.py) runs the whole dp x pp x tp
 lattice as ONE SPMD program: every tick costs the maximum op across
-stages, pipeline bubbles are real ``lax.switch`` noop dispatches, and the
-measured op-issue roofline (DISPATCH_r01.json: >= 72.8% of the flagship
-gpipe-pp4 CPU epoch wall has NO op executing) eats every scheduling win.
+stages, and pipeline bubbles are real ``lax.switch`` noop dispatches.
 This module is the MPMD form of arXiv 2412.14374 (Scaling Deep Learning
 Training with MPMD Pipeline Parallelism): one compiled program per STAGE
 ROLE — a stage's forward, its backward (or split B-input / B-weight
@@ -39,7 +37,7 @@ in-program ``ppermute`` shifts:
 
 Feature envelope: the runtime refuses (loudly, at construction) the
 knobs whose lockstep implementations live in the fused program's tail —
-``zero1``, ``grad_bucket_bytes``, ``clip_norm`` (cross-stage global
+``zero1``, ``clip_norm`` (cross-stage global
 norm), the pallas kernel backend, and the fused-run/step-stats aux.
 Those stay lockstep-only until a follow-up teaches the per-stage update
 their math; ``TrainingSession(runtime=...)`` enforces the envelope.
@@ -345,10 +343,9 @@ def stage_cells(prog):
 class _StagePrograms:
     """Lazily-built jitted per-stage programs for one (mesh, spec, prog)
     triple. Programs are keyed ``(stage, role, variant)``; ``resolve``
-    (optional) intercepts compilation — the session points it at the AOT
-    cache + per-stage audit, so a warm MPMD start compiles zero stage
-    programs and every one is census/donation-verified before its first
-    dispatch."""
+    (optional) intercepts compilation — the session points it at the
+    per-stage audit, so every program is census/donation-verified before
+    its first dispatch."""
 
     def __init__(self, mesh, spec, prog, mubatch_size, opt=None,
                  precision=ops.DEFAULT_PRECISION):
@@ -961,7 +958,7 @@ class _StagePrograms:
         return fn
 
     def label(self, s, role, variant=()):
-        """Audit/AOT label for one stage program. The inference program
+        """Audit label for one stage program. The inference program
         set gets its own namespace — its pack programs are content-
         identical to the trainer's, but the session's audit dedup is
         label-keyed, and a shared label would skip the second runner's
@@ -975,7 +972,7 @@ class _StagePrograms:
 def _resolve_program(programs, s, role, variant, args, expected, resolve):
     """The one resolve-and-swap step both runners' warm passes share:
     skip programs already swapped onto an executable, otherwise hand the
-    jit wrapper to the session hook (audit/AOT) and install whatever it
+    jit wrapper to the session hook (audit) and install whatever it
     returns. Returns True when the hook ran."""
     key = (s, role, variant)
     fn = programs._fns.get(key)
@@ -1425,7 +1422,7 @@ class MpmdTrainRunner:
     def example_args(self, s, role, variant, stacked, flags, opt_state,
                      cache=None):
         """Shape-correct example arguments for one planned program (the
-        lower/compile inputs of the warm/audit/AOT pass). ``cache`` (a
+        lower/compile inputs of the warm/audit pass). ``cache`` (a
         dict the warm loop owns) memoizes the per-stage views and pack
         dispatches across the ~6 planned programs of each stage."""
         subs = self.programs.submeshes
@@ -1552,10 +1549,10 @@ class MpmdTrainRunner:
         raise ValueError(f"unknown role {role!r}")
 
     def warm(self, stacked, flags, opt_state, resolve):
-        """Compile (or AOT-load) + audit every planned stage program and
+        """Compile + audit every planned stage program and
         swap the dispatch path onto the resolved executables. ``resolve``
         is the session's hook ``(label, role, jit_fn, args, expected) ->
-        compiled`` — it owns the AOT cache, the per-stage census and the
+        compiled`` — it owns the per-stage census and the
         donation-safety proof. Returns the number of programs resolved."""
         n = 0
         view_cache = {}
@@ -1661,7 +1658,7 @@ class MpmdInferenceRunner:
         return preds
 
     def warm(self, stacked, flags, resolve):
-        """Resolve (audit/AOT) every program this chain can dispatch —
+        """Resolve (audit) every program this chain can dispatch —
         the pack boundary first, then each chain cell — and swap the
         dispatch path onto the executables; the serving-side mirror of
         ``MpmdTrainRunner.warm``. Returns the number resolved."""
@@ -1697,7 +1694,7 @@ class MpmdInferenceRunner:
 
     def example_args(self, c, params, flag_views):
         """Shape/sharding-correct lower() arguments for one chain cell's
-        program (the warm/audit/AOT pass)."""
+        program (the warm/audit pass)."""
         s = c["s"]
         width = self.spec.sizes[0] if c["load"] else self.programs.W_rel
         shape = (self.dp * self.mb_sz, width)

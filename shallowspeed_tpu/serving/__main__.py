@@ -237,18 +237,6 @@ def main(argv=None):
         "(reason fleet_queue_full); default unbounded",
     )
     ap.add_argument(
-        "--aot-cache",
-        default=None,
-        metavar="DIR",
-        help="AOT executable cache: the rung ladder warm-up deserializes "
-        "compiled inference programs from this directory instead of "
-        "recompiling (cold start in milliseconds; entries are written on "
-        "the first cold compile, re-verified by the audit census before "
-        "serving, and fall back to a clean recompile on corruption). In "
-        "fleet mode every replica shares the directory — a scale-up "
-        "replacement warms from what the first replicas compiled",
-    )
-    ap.add_argument(
         "--verify",
         action="store_true",
         help="re-compute every 'ok' response with a direct predict() of the "
@@ -291,7 +279,6 @@ def main(argv=None):
         resume=args.checkpoint,
         metrics=metrics,
         audit=args.audit,
-        aot_cache_dir=args.aot_cache,
         predict_slot_rows=args.slot_rows,
         predict_slot_ladder=(
             tuple(int(r) for r in args.slot_ladder.split(","))
@@ -453,7 +440,6 @@ def _fleet_main(args):
             data_dir=args.data_dir,
             resume=args.checkpoint,
             audit=args.audit,
-            aot_cache_dir=args.aot_cache,
             predict_slot_rows=args.slot_rows,
             predict_slot_ladder=(
                 tuple(int(r) for r in args.slot_ladder.split(","))

@@ -433,7 +433,6 @@ def main(argv=None):
     )
     ap.add_argument("--global-batch-size", type=int, default=8)
     ap.add_argument("--mubatches", type=int, default=1)
-    ap.add_argument("--aot-cache", default=None, metavar="DIR")
     ap.add_argument("--max-slots", type=int, default=None)
     ap.add_argument(
         "--dispatch-floor-ms",
@@ -600,7 +599,6 @@ def main(argv=None):
             mubatches=args.mubatches,
             data_dir=args.data_dir,
             resume=args.checkpoint,
-            aot_cache_dir=args.aot_cache,
         ),
         "engine": dict(
             max_slots=args.max_slots,

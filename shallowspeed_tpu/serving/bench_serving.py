@@ -414,9 +414,6 @@ def fleet_chaos_soak(
         fleet.start()  # every ladder warmed before traffic
         # the initial replicas' spawn->ready walls: the cold-start
         # baseline the replacement's scale_up_s is compared against
-        # (with a shared --aot-cache the FIRST replicas write the cache
-        # entries concurrently, so the replacement deserializes instead
-        # of recompiling — the cache-warm scoreboard)
         initial_ready = [
             w
             for w in (
@@ -571,9 +568,8 @@ def fleet_chaos_soak(
         "reroutes": stats.get("reroutes"),
         "scale_ups": stats.get("scale_ups"),
         "scale_up_s": stats.get("scale_up_s"),
-        # spawn->ready walls of the INITIAL replicas (cold start, or
-        # cache-writing start when an aot cache is configured): the
-        # baseline a cache-warm replacement's scale_up_s reads against
+        # spawn->ready walls of the INITIAL replicas (cold start): the
+        # baseline a replacement's scale_up_s reads against
         "initial_ready_s": initial_ready,
         "initial_ready_s_mean": (
             sum(initial_ready) / len(initial_ready) if initial_ready else None
@@ -708,16 +704,6 @@ def main(argv=None):
         "--fleet-out", default=None, help="write the fleet chaos JSON here"
     )
     ap.add_argument(
-        "--aot-cache",
-        default=None,
-        metavar="DIR",
-        help="AOT executable cache directory (shared by every fleet "
-        "replica: the first replicas write entries on their cold "
-        "compile, a scale-up replacement deserializes — cache-warm "
-        "scale_up_s vs the initial replicas' cold ready walls is the "
-        "record's scoreboard)",
-    )
-    ap.add_argument(
         "--metrics-out",
         default=None,
         help="JSONL sink for the chaos pass's request/serving_health/"
@@ -742,7 +728,6 @@ def main(argv=None):
         data_dir=args.data_dir,
         resume=args.checkpoint,
         metrics=metrics,
-        aot_cache_dir=args.aot_cache,
     )
     if args.chaos is not None or args.reload_dir is not None:
         # a session restored from a step snapshot seeds the watcher's
@@ -855,7 +840,6 @@ def _fleet_main(args, metrics):
             mubatches=args.mubatches,
             data_dir=args.data_dir,
             resume=args.checkpoint,
-            aot_cache_dir=args.aot_cache,
         ),
         "engine": dict(
             max_slots=args.max_slots,

@@ -39,13 +39,8 @@ The per-request contract is the engine's, lifted fleet-wide:
   the newest ``checkpoint.find_latest_good`` snapshot (its ladder warmed
   before it takes traffic), ``scale_down()`` drains-and-retires,
   ``watch_reload()`` broadcasts the per-replica hot-reload poll — the
-  zero-downtime deploy path. With an ``aot_cache_dir`` in the worker's
-  session kwargs (shallowspeed_tpu/aot_cache.py) the warm-up
-  deserializes the ladder the first replicas compiled instead of
-  recompiling it, so a replacement's measured ``scale_up_s`` drops from
-  seconds-of-XLA to the session build + deserialize (every deserialized
-  program re-audited before it serves; per-replica ``ready_wall_s`` in
-  the summary is the scoreboard);
+  zero-downtime deploy path (per-replica ``ready_wall_s`` in the summary
+  is a replacement's measured cold-start cost);
 - **quorum**: the fleet refuses admission (verdict ``"dropped"``, reason
   ``"fleet_degraded"``) while fewer than a majority of its target
   replicas are healthy (``router.quorum``); the serve CLI exits 3 when

@@ -131,9 +131,7 @@ class ReplicaInfo:
             "inflight": self.inflight,
             "loaded_step": self.loaded_step,
             # spawn -> ready wall (None until ready): the per-replica
-            # cold-start cost — the scoreboard the AOT executable cache
-            # moves (cache-warm replicas ready in a fraction of the
-            # cold-compile wall; docs/performance.md)
+            # cold-start cost
             "ready_wall_s": (
                 self.ready_t - self.spawn_t
                 if self.ready_t is not None and self.spawn_t is not None

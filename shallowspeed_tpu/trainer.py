@@ -12,13 +12,10 @@ no host round-trips.
 Gradient-correctness ledger (identical to the reference, SURVEY §3.3): the
 loss gradient is scaled once by the GLOBAL batch size; each Linear backward
 sums over its microbatch rows; the scan sums over microbatches; (under DP
-the executor sums over replicas — either one whole-tree psum at the
-gradient-sync anchor or, with ``grad_bucket_bytes > 0``, one psum per
-backward-ordered byte-bucket; both are elementwise sums and therefore the
-same ledger entry bit for bit — parallel/gradsync.py). Three sums, no
+the executor sums over replicas — one whole-tree psum at the
+gradient-sync anchor, an elementwise sum). Three sums, no
 averaging — bitwise the same ledger as sequential full-batch training. The
-sequential path itself has no replicas and no collectives, so the bucketing
-knob is a mesh-layout concept only.
+sequential path itself has no replicas and no collectives.
 """
 
 from functools import partial

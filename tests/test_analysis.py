@@ -420,59 +420,3 @@ def test_report_renders_lint_record_with_full_evidence():
     text = render(report, "md")
     assert "2 finding(s)" in text
     assert "a.py:7:4" in text and "b.py:5:11" in text
-
-
-def test_aot_deserialized_donating_program_refused(data_dir, tmp_path):
-    """The PR 1/PR 12 hazard as a proven property: poison an AOT cache
-    entry for a DISPATCH-path program with a donating executable — the
-    load is refused (audit_mismatch + fallback recompile), the serving
-    path never dispatches it, and predictions stay correct."""
-    import jax
-    import jax.numpy as jnp
-
-    from shallowspeed_tpu.api import TrainingSession
-    from shallowspeed_tpu.observability import MetricsRecorder
-
-    cache = tmp_path / "aot"
-    m = _Rec().r
-    sess = TrainingSession(
-        sizes=SIZES, dp=2, mubatches=2, global_batch_size=32,
-        data_dir=data_dir, metrics=m, audit=True, aot_cache_dir=str(cache),
-    )
-    if not sess._aot.supported:
-        pytest.skip(f"backend cannot serialize: {sess._aot.disabled_reason}")
-    rng = np.random.RandomState(2)
-    X = rng.rand(sess.slot_rows, SIZES[0]).astype(np.float32)
-    ref = sess.predict(X)
-    assert sess._aot.counts["store"] >= 1
-    # replace the stored rung entry with a DONATING executable under the
-    # same key (what a buggy writer — or the pre-PR-13 trust model —
-    # could have left there)
-    entries = sorted(cache.glob("*.aotx"))
-    assert entries
-    donating = (
-        jax.jit(lambda a, b: (a + b, a * b), donate_argnums=(0,))
-        .lower(jnp.zeros((4, 4)), jnp.ones((4, 4)))
-        .compile()
-    )
-    for e in entries:
-        key = e.stem
-        e.unlink()
-        sess._aot.store(key, donating, program="poisoned")
-    # a fresh session over the poisoned cache must refuse the entry and
-    # recompile — never dispatch the donating executable
-    m2 = _Rec().r
-    sess2 = TrainingSession(
-        sizes=SIZES, dp=2, mubatches=2, global_batch_size=32,
-        data_dir=data_dir, metrics=m2, audit=True, aot_cache_dir=str(cache),
-    )
-    out = sess2.predict(X)
-    counts = sess2._aot.counts
-    assert counts["audit_mismatch"] >= 1, counts
-    assert counts["fallback"] >= 1
-    events = [
-        r for r in m2.records
-        if r["kind"] == "aot_cache" and r["name"] == "audit_mismatch"
-    ]
-    assert events
-    assert np.array_equal(out, ref)

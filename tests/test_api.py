@@ -192,34 +192,6 @@ def test_invalid_config_rejected(data_dir):
         _session(data_dir, global_batch_size=4096)  # > training split
 
 
-def test_grad_bucket_bytes_validation(data_dir):
-    with pytest.raises(ValueError, match="sequential path has no gradient"):
-        _session(data_dir, grad_bucket_bytes=4096)  # dp=pp=1: no sync
-    with pytest.raises(ValueError, match=">= 0"):
-        _session(data_dir, dp=2, grad_bucket_bytes=-1)
-    # 0 / None are the legacy anchor, valid anywhere
-    _session(data_dir, grad_bucket_bytes=0)
-    _session(data_dir, dp=2, grad_bucket_bytes=None)
-
-
-def test_grad_bucket_bytes_session_matches_anchor(data_dir):
-    """Bucketed vs anchor THROUGH the session surface (per-epoch loop and
-    the fused run): identical model hashes — the API plumbing changes
-    nothing about the training computation."""
-    runs = {}
-    for gbb in (0, 2048):
-        run = _session(data_dir, dp=2, pp=2, schedule="gpipe",
-                       grad_bucket_bytes=gbb)
-        run.train_epoch()
-        runs[gbb] = run.model_hash()
-        fused = _session(data_dir, dp=2, pp=2, schedule="gpipe",
-                         grad_bucket_bytes=gbb, zero1=True)
-        fused.train_run(1, with_eval=False)
-        runs[f"z1-{gbb}"] = fused.model_hash()
-    assert runs[0] == runs[2048]
-    assert runs["z1-0"] == runs["z1-2048"]
-
-
 def test_backward_split_validation(data_dir):
     with pytest.raises(ValueError, match="sequential path has no schedule"):
         _session(data_dir, backward_split=True)  # dp=pp=1: no schedule

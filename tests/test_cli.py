@@ -102,24 +102,6 @@ def test_mesh_cli_dp2_pp2(tiny_data):
     assert re.search(r"final model hash: [0-9a-f]{40}", out)
 
 
-def test_mesh_cli_grad_bucket_bytes_matches_anchor(tiny_data):
-    """--grad-bucket-bytes through the real CLI (with --audit enforcing
-    the bucketed census): the final model hash must equal the anchor
-    run's — the knob is a scheduling choice, never a numerics one."""
-    common = [
-        "--dp", "2", "--epochs", "1", "--global-batch-size", "32",
-        "--mubatches", "2", "--no-eval", "--audit",
-    ]
-    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-    anchor = _run(common, tiny_data, extra_env=env)
-    bucketed = _run(
-        common + ["--grad-bucket-bytes", "65536"], tiny_data, extra_env=env
-    )
-    h = re.compile(r"final model hash: ([0-9a-f]{40})")
-    assert h.search(anchor).group(1) == h.search(bucketed).group(1)
-    assert "DP replicas in sync" in bucketed
-
-
 def test_mesh_cli_backward_split_matches_unsplit(tiny_data):
     """--backward-split through the real CLI (with --audit enforcing the
     split program's collective contract): the final model hash must equal
@@ -178,27 +160,100 @@ def test_mesh_cli_zero23_hash_pin(tiny_data):
     assert re.search(r"final model hash: [0-9a-f]{40}", out)
 
 
-def test_cli_zero_refusals_exit_2(tiny_data):
-    """The six fail-fast lattice refusals, all at argparse time (exit 2,
-    pre-backend): stage conflicts and the combinations the executor has
-    no program for."""
-    cases = [
-        (["--zero1", "--zero", "2"], "conflicting dp-stage selectors"),
-        (["--zero", "3", "--dp", "2", "--fused-run"],
-         "incompatible with --fused-run"),
-        (["--zero", "3", "--dp", "2", "--kernel-backend", "pallas"],
-         "incompatible with --kernel-backend pallas"),
-        (["--zero", "3", "--dp", "2", "--grad-bucket-bytes", "1024"],
-         "syncs gradients per tick"),
-        (["--zero", "2", "--dp", "2", "--pp", "2", "--runtime", "mpmd"],
-         "does not support --zero"),
-        (["--zero", "2", "--dp", "2", "--digests"],
-         "--digests is incompatible"),
-    ]
-    for args, msg in cases:
-        r = _run_raw(args, tiny_data)
-        assert r.returncode == 2, (args, r.stderr[-500:])
-        assert msg in r.stderr, (args, r.stderr[-500:])
+CLI_REFUSALS = {
+    # id -> (flags, the refusal's own words on stderr[, environment])
+    "ckpt-every-steps-negative": (
+        ["--checkpoint-every-steps", "-1"],
+        "--checkpoint-every-steps must be >= 0",
+    ),
+    "ckpt-every-steps-no-dir": (
+        ["--checkpoint-every-steps", "2"],
+        "--checkpoint-every-steps needs --checkpoint-dir",
+    ),
+    "ckpt-every-steps-fused-run": (
+        ["--fused-run", "--checkpoint-every-steps", "2",
+         "--checkpoint-dir", "/nonexistent/ck"],
+        "--checkpoint-every-steps is incompatible with --fused-run",
+    ),
+    "resume-auto-no-dir": (
+        ["--resume", "auto"],
+        "--resume auto discovers snapshots in --checkpoint-dir",
+    ),
+    "async-checkpoint-no-dir": (
+        ["--async-checkpoint"], "--async-checkpoint needs --checkpoint-dir",
+    ),
+    "resume-auto-fused-run": (
+        ["--fused-run", "--resume", "auto",
+         "--checkpoint-dir", "/nonexistent/ck"],
+        "the fused run has no mid-epoch entry point",
+    ),
+    "keep-below-one": (
+        ["--keep", "0", "--checkpoint-dir", "/nonexistent/ck"],
+        "--keep must be >= 1",
+    ),
+    "mpmd-fused-run": (
+        ["--runtime", "mpmd", "--pp", "2", "--fused-run"],
+        "the fused ONE-dispatch run is a lockstep contract",
+    ),
+    "digests-fused-run": (
+        ["--digests", "--fused-run"],
+        "--digests rides the epoch/step scan aux",
+    ),
+    "mpmd-no-mesh": (
+        ["--runtime", "mpmd"], "--runtime mpmd needs a mesh layout",
+    ),
+    "recompute-no-mesh": (
+        ["--recompute"], "--recompute drops pipeline activation stashes",
+    ),
+    "recompute-virtual-stages": (
+        ["--recompute", "--pp", "2", "--schedule", "interleaved",
+         "--virtual-stages", "2"],
+        "--recompute is not supported with interleaved virtual stages",
+    ),
+    "zero1-against-zero": (
+        ["--zero1", "--zero", "2"], "conflicting dp-stage selectors",
+    ),
+    "zero3-fused-run": (
+        ["--zero", "3", "--dp", "2", "--fused-run"],
+        "--zero 3 is incompatible with --fused-run",
+    ),
+    "zero3-pallas": (
+        ["--zero", "3", "--dp", "2", "--kernel-backend", "pallas"],
+        "--zero 3 is incompatible with --kernel-backend pallas",
+    ),
+    "zero-mpmd": (
+        ["--zero", "2", "--dp", "2", "--pp", "2", "--runtime", "mpmd"],
+        "--runtime mpmd does not support --zero 2",
+    ),
+    "zero2-digests": (
+        ["--zero", "2", "--dp", "2", "--digests"],
+        "--digests is incompatible with --zero 2",
+    ),
+    # an active env fault plan needs the step loop — silently completing
+    # the uninjected fused run would fake a survived crash
+    "faults-env-fused-run": (
+        ["--fused-run", "--epochs", "1", "--no-eval"],
+        "the fault harness needs the step loop",
+        {"SHALLOWSPEED_FAULTS": "die@step=3:mode=sigkill"},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CLI_REFUSALS))
+def test_cli_refusals_exit_2(case):
+    """Every argparse-time refusal train.py has, by name: exit code 2 and
+    the refusal's own words on stderr, before any data or backend is
+    touched — the data directory does not exist, and the platform named
+    would fail the first backend call with another exit code."""
+    flags, words, *env = CLI_REFUSALS[case]
+    r = _run_raw(
+        flags, "/nonexistent/data",
+        extra_env={"JAX_PLATFORMS": "no_such_platform", **(env[0] if env else {})},
+    )
+    assert r.returncode == 2, (flags, r.stderr[-500:])
+    # argparse wraps its message: compare on single-spaced text
+    assert words in " ".join(r.stderr.split()), (flags, r.stderr[-500:])
+    assert "Traceback" not in r.stderr
 
 
 def test_mesh_cli_kernel_backend_pallas_matches_xla(tiny_data):
@@ -313,9 +368,9 @@ def test_sequential_cli_run_kernel_matches_fused(tiny_data):
 
 def test_fused_run_checkpoint_contract(tiny_data, tmp_path):
     """The pinned --checkpoint x --fused-run contract: the fused run is ONE
-    dispatch, so --checkpoint saves exactly once, after it returns — and
-    the STEP-checkpoint flags (which need a host step boundary) fail fast
-    at argparse time with a message naming the conflict."""
+    dispatch, so --checkpoint saves exactly once, after it returns (the
+    STEP-checkpoint flags, which need a host step boundary, are refused at
+    argparse time: ``test_cli_refusals_exit_2``)."""
     ck = tmp_path / "fused.npz"
     out = _run(
         ["--epochs", "2", "--global-batch-size", "32", "--mubatches", "2",
@@ -328,36 +383,6 @@ def test_fused_run_checkpoint_contract(tiny_data, tmp_path):
     # one snapshot, of the post-run state: epoch = last COMPLETED epoch
     assert verify_checkpoint(ck)["epoch"] == 1
     assert re.search(r"final model hash: [0-9a-f]{40}", out)
-
-    # step checkpointing and auto-resume have no fused-run entry point
-    r = _run_raw(
-        ["--fused-run", "--checkpoint-every-steps", "2",
-         "--checkpoint-dir", str(tmp_path / "d")],
-        tiny_data,
-    )
-    assert r.returncode == 2  # argparse contract violation, pre-backend
-    assert "incompatible with --fused-run" in r.stderr
-    r = _run_raw(
-        ["--fused-run", "--resume", "auto", "--checkpoint-dir",
-         str(tmp_path / "d")],
-        tiny_data,
-    )
-    assert r.returncode == 2
-    assert "no mid-epoch entry point" in r.stderr
-    # incoherent flag combinations fail the same fast way
-    r = _run_raw(["--checkpoint-every-steps", "2"], tiny_data)
-    assert r.returncode == 2 and "--checkpoint-dir" in r.stderr
-    r = _run_raw(["--resume", "auto"], tiny_data)
-    assert r.returncode == 2 and "--checkpoint-dir" in r.stderr
-    # an active env fault plan needs the step loop — silently completing
-    # the uninjected fused run would fake a survived crash
-    r = _run_raw(
-        ["--fused-run", "--epochs", "1", "--no-eval"],
-        tiny_data,
-        extra_env={"SHALLOWSPEED_FAULTS": "die@step=3:mode=sigkill"},
-    )
-    assert r.returncode == 2
-    assert "SHALLOWSPEED_FAULTS" in r.stderr and "step loop" in r.stderr
 
 
 def test_fused_run_rejects_explicit_mid_epoch_resume(tiny_data, tmp_path):

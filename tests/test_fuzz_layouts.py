@@ -129,7 +129,7 @@ def _random_case_r2(seed):
 
 def _assert_lattice_case_matches_sequential(
     sizes, dp, pp, V, M, B, opt, zero1, sched, clip, fused, data_seed,
-    kb="xla", label_extra="", gbb=0, bsplit=False, tp=1, act="relu",
+    kb="xla", label_extra="", bsplit=False, tp=1, act="relu",
     recompute=False, zero=None,
 ):
     """The ONE sequential-vs-pipeline comparison harness behind the r2, r3
@@ -185,13 +185,13 @@ def _assert_lattice_case_matches_sequential(
         # same two batches as one epoch inside the fused whole-run program
         run = E.make_pipeline_run(
             mesh, spec_pp, prog, B // dp // M, opt, zero=zstage,
-            clip_norm=clip, kernel_backend=kb, grad_bucket_bytes=gbb,
+            clip_norm=clip, kernel_backend=kb,
         )
         stacked, ost, _ = run(stacked, flags, ost, jnp.asarray(X), jnp.asarray(Y), 1)
     else:
         step = E.make_pipeline_step(
             mesh, spec_pp, prog, B // dp // M, opt, zero=zstage,
-            clip_norm=clip, kernel_backend=kb, grad_bucket_bytes=gbb,
+            clip_norm=clip, kernel_backend=kb,
         )
         for i in range(2):
             stacked, ost, _ = step(
@@ -206,7 +206,7 @@ def _assert_lattice_case_matches_sequential(
     label = (
         f"sizes={sizes} dp={dp} pp={pp} tp={tp} V={V} M={M} B={B} "
         f"{type(opt).__name__} zero={zstage} clip={clip} fused={fused} "
-        f"gbb={gbb} bsplit={bsplit} act={act} rec={recompute} "
+        f"bsplit={bsplit} act={act} rec={recompute} "
         f"{sched.__name__}{label_extra}"
     )
     # Adam's early update direction is ~g/|g| per element: near-zero second
@@ -243,19 +243,17 @@ def test_random_r2_feature_combo_matches_sequential(seed):
 def _random_case_r3(seed):
     """Round-5 feature fuzz (round-4 verdict #3), round-10 extension: the
     full lattice — optimizer x zero1 x kernel_backend x virtual stages x
-    epoch-vs-step x gradient-sync bucketing x backward splitting x TENSOR
+    epoch-vs-step x backward splitting x TENSOR
     PARALLELISM — from independent seed bits, so pallas-backend
-    interactions (e.g. zero1 x pallas x interleaved), bucketed-sync,
+    interactions (e.g. zero1 x pallas x interleaved),
     split-backward and Megatron-tp interactions get randomized coverage,
     not just their dedicated tests. tp rides its own bit wherever it is
     supported (the xla backend; the pallas flag kernels compute whole
-    slots), so it crosses dp/pp/zero1/bucketing/clip/fused-run and the
+    slots), so it crosses dp/pp/zero1/clip/fused-run and the
     split backward across the seeds."""
     rng = np.random.RandomState(3000 + seed)
     kb = ["xla", "pallas"][seed % 2]
-    # bucketed gradient sync rides an independent bit + a random byte
-    # budget, so bucketing meets every other feature across the seeds
-    gbb = [0, int(rng.choice([256, 1024, 8192]))][(seed + seed // 5) % 2]
+    rng.choice(3)  # a retired draw: keeps the seed's later draws in place
     V = [1, 2][(seed // 2) % 2]
     dp, pp = [(2, 2), (1, 4), (2, 1)][(seed // 4) % 3]
     opt = OPTS[(seed + seed // 2) % 3]
@@ -263,8 +261,8 @@ def _random_case_r3(seed):
     clip = [None, 0.05][(seed // 6) % 2]
     fused = bool((seed + seed // 4) % 2)  # per-step loop vs whole-run program
     # split backward rides its own bit wherever it is supported (flat
-    # schedules on the xla backend), so it meets zero1, clipping,
-    # bucketing and the fused-run path across the seeds
+    # schedules on the xla backend), so it meets zero1, clipping
+    # and the fused-run path across the seeds
     bsplit = bool((seed + seed // 3) % 2) and V == 1 and kb == "xla"
     # the tp axis: every (dp, pp) block here fits x2 on the 8 emulated
     # devices ((2,2)->8, (1,4)->8, (2,1)->4)
@@ -278,8 +276,7 @@ def _random_case_r3(seed):
     B = int(dp * M * rng.choice([4, 8]))
     sched = S.InterleavedSchedule if V > 1 else SCHEDS[seed % 3]
     return (
-        sizes, dp, pp, V, M, B, opt, zero1, kb, sched, clip, fused, gbb,
-        bsplit, tp,
+        sizes, dp, pp, V, M, B, opt, zero1, kb, sched, clip, fused, bsplit, tp,
     )
 
 
@@ -291,17 +288,16 @@ def _random_case_r3(seed):
 )
 def test_random_r3_kernel_backend_combo_matches_sequential(seed):
     """Random (optimizer, zero1, kernel_backend, virtual, epoch-vs-step,
-    grad-bucket-bytes, backward-split, tp) combinations must still equal
-    sequential training — the pallas executor backend, the bucketed
-    gradient sync, the split backward and Megatron tensor parallelism
+    backward-split, tp) combinations must still equal
+    sequential training — the pallas executor backend,
+    the split backward and Megatron tensor parallelism
     compose with every other feature, not just dp=pp=1."""
     (
-        sizes, dp, pp, V, M, B, opt, zero1, kb, sched, clip, fused, gbb,
-        bsplit, tp,
+        sizes, dp, pp, V, M, B, opt, zero1, kb, sched, clip, fused, bsplit, tp,
     ) = _random_case_r3(seed)
     _assert_lattice_case_matches_sequential(
         sizes, dp, pp, V, M, B, opt, zero1, sched, clip, fused,
-        data_seed=4000 + seed, kb=kb, label_extra=f" kb={kb}", gbb=gbb,
+        data_seed=4000 + seed, kb=kb, label_extra=f" kb={kb}",
         bsplit=bsplit, tp=tp,
     )
 
@@ -310,7 +306,7 @@ def _random_case_r4(seed):
     """Round-19 feature fuzz: the MODEL and RECOMPUTE dimensions —
     activation family (relu vs the transformer-style gelu+residual
     slots) and pipeline activation recompute — from independent seed
-    bits, crossed with dp x pp x tp x zero1 x grad-bucketing x
+    bits, crossed with dp x pp x tp x zero1 x
     backward-split x epoch-vs-step, so recompute meets every shipped
     feature across the 12 seeds, not just its dedicated twins. Recompute
     needs a flat pipeline schedule (pp > 1, V == 1); gelu is excluded
@@ -323,7 +319,7 @@ def _random_case_r4(seed):
     zero1 = bool((seed // 3) % 2)
     clip = [None, 0.05][(seed + seed // 2) % 2]
     fused = bool((seed + seed // 4) % 2)
-    gbb = [0, int(rng.choice([256, 8192]))][(seed // 5) % 2]
+    rng.choice(2)  # a retired draw: keeps the seed's later draws in place
     bsplit = bool((seed + seed // 6) % 2)
     tp = 2 if (seed + seed // 5) % 2 and dp * pp <= 4 else 1
     per = int(rng.randint(2, 4))
@@ -337,7 +333,7 @@ def _random_case_r4(seed):
     B = int(dp * M * rng.choice([4, 8]))
     sched = SCHEDS[seed % 3]
     return (
-        sizes, dp, pp, M, B, opt, zero1, sched, clip, fused, gbb, bsplit,
+        sizes, dp, pp, M, B, opt, zero1, sched, clip, fused, bsplit,
         tp, act, recompute,
     )
 
@@ -352,16 +348,16 @@ def _random_case_r4(seed):
 )
 def test_random_r4_model_recompute_combo_matches_sequential(seed):
     """Random (activation family, recompute) combinations crossed with
-    dp/pp/tp/zero1/bucketing/backward-split must still equal sequential
+    dp/pp/tp/zero1/backward-split must still equal sequential
     training — the model zoo and the recompute tick are invisible to the
     math on every layout, not just the flagship relu-MLP."""
     (
-        sizes, dp, pp, M, B, opt, zero1, sched, clip, fused, gbb, bsplit,
+        sizes, dp, pp, M, B, opt, zero1, sched, clip, fused, bsplit,
         tp, act, recompute,
     ) = _random_case_r4(seed)
     _assert_lattice_case_matches_sequential(
         sizes, dp, pp, 1, M, B, opt, zero1, sched, clip, fused,
-        data_seed=8000 + seed, gbb=gbb, bsplit=bsplit, tp=tp, act=act,
+        data_seed=8000 + seed, bsplit=bsplit, tp=tp, act=act,
         recompute=recompute,
     )
 
@@ -369,9 +365,9 @@ def test_random_r4_model_recompute_combo_matches_sequential(seed):
 def _random_case_r5(seed):
     """Round-20 feature fuzz: the ZeRO STAGE dimension — ``zero`` in
     {0,1,2,3} cycling every 4 seeds so each stage meets three different
-    feature draws — crossed with tp x grad-bucketing x backward-split x
+    feature draws — crossed with tp x backward-split x
     interleaved virtual stages x epoch-vs-step. Stage constraints mirror
-    the executor's refusals: stage 3 syncs per tick (no bucket plan) and
+    the executor's refusals: stage 3
     keeps params sharded at rest (the fused whole-run program's eval
     view is an API-level refusal, so the fused bit only rides stages
     0-2)."""
@@ -381,10 +377,8 @@ def _random_case_r5(seed):
     V = 2 if (seed // 2) % 2 and pp > 1 else 1
     opt = OPTS[(seed + seed // 3) % 3]
     clip = [None, 0.05][(seed + seed // 2) % 2]
-    gbb = (
-        [0, int(rng.choice([256, 8192]))][(seed // 5) % 2]
-        if zero != 3 else 0
-    )
+    if zero != 3:
+        rng.choice(2)  # a retired draw: keeps the seed's later draws in place
     bsplit = bool((seed + seed // 6) % 2) and V == 1 and pp > 1
     tp = 2 if (seed + seed // 5) % 2 and dp * pp <= 4 else 1
     fused = bool((seed + seed // 4) % 2) and zero != 3
@@ -396,7 +390,7 @@ def _random_case_r5(seed):
     B = int(dp * M * rng.choice([4, 8]))
     sched = S.InterleavedSchedule if V > 1 else (
         S.PipeDreamFlushSchedule if bsplit else SCHEDS[seed % 3])
-    return sizes, dp, pp, V, M, B, opt, zero, sched, clip, fused, gbb, bsplit, tp
+    return sizes, dp, pp, V, M, B, opt, zero, sched, clip, fused, bsplit, tp
 
 
 @pytest.mark.parametrize(
@@ -409,77 +403,25 @@ def _random_case_r5(seed):
      for s in range(12)],
 )
 def test_random_r5_zero_stage_combo_matches_sequential(seed):
-    """Random ZeRO-stage draws crossed with tp/bucketing/backward-split/
+    """Random ZeRO-stage draws crossed with tp/backward-split/
     interleaved must still equal sequential training — the dp-axis
     residency lattice is invisible to the math on every layout."""
     (
-        sizes, dp, pp, V, M, B, opt, zero, sched, clip, fused, gbb, bsplit,
-        tp,
+        sizes, dp, pp, V, M, B, opt, zero, sched, clip, fused, bsplit, tp,
     ) = _random_case_r5(seed)
     _assert_lattice_case_matches_sequential(
         sizes, dp, pp, V, M, B, opt, False, sched, clip, fused,
-        data_seed=9500 + seed, gbb=gbb, bsplit=bsplit, tp=tp, zero=zero,
+        data_seed=9500 + seed, bsplit=bsplit, tp=tp, zero=zero,
     )
 
 
-BUCKET_LAYOUTS = {
-    # layout -> (dp, pp, zero1, schedule)
-    "dp2": (2, 1, False, S.GPipeSchedule),
-    "zero1": (2, 2, True, S.GPipeSchedule),
-    "gpipe-dp": (2, 2, False, S.GPipeSchedule),
-}
-
-
-@pytest.mark.parametrize("layout", sorted(BUCKET_LAYOUTS))
-def test_bucketed_sync_bitwise_identical_to_anchor(layout):
-    """The bucketing acceptance criterion: per-bucket gradient sync is
-    BITWISE identical to the anchor collective — final weights, loss AND
-    the pre-clip global grad norm (which must read post-sync buckets) —
-    on dp-only, ZeRO-1 and pipeline+dp layouts, across bucket budgets,
-    with global-norm clipping active the whole time."""
-    dp, pp, zero1, sched = BUCKET_LAYOUTS[layout]
-    sizes = (40, 36, 32, 28, 24, 20, 14, 10)
-    M, B = 4, 32
-    spec = Mo.make_model_spec(sizes, pp, B)
-    mesh = make_mesh(dp, pp)
-    prog = lower_schedule(sched, M, pp)
-    rng = np.random.RandomState(7)
-    X = rng.randn(2, B, sizes[0]).astype(np.float32)
-    Y = np.eye(sizes[-1], dtype=np.float32)[rng.randint(0, sizes[-1], (2, B))]
-
-    def train(gbb):
-        opt = SGD(0.01)
-        stacked, flags = E.init_stacked(spec, mesh)
-        ost = E.zero1_init_state(opt, spec, mesh) if zero1 else opt.init(stacked)
-        step = E.make_pipeline_step(
-            mesh, spec, prog, B // dp // M, opt, zero1=zero1,
-            clip_norm=0.05, with_grad_norm=True, grad_bucket_bytes=gbb,
-        )
-        for i in range(2):
-            stacked, ost, loss, gnorm = step(
-                stacked, flags, ost, jnp.asarray(X[i]), jnp.asarray(Y[i])
-            )
-        return jax.device_get(stacked), float(loss), float(gnorm)
-
-    anchor_w, anchor_loss, anchor_gn = train(0)
-    for gbb in (512, 8192):
-        w, loss, gn = train(gbb)
-        label = f"{layout} gbb={gbb}"
-        assert loss == anchor_loss, label
-        assert gn == anchor_gn, label  # the norm reads post-sync buckets
-        for a, b in zip(jax.tree.leaves(anchor_w), jax.tree.leaves(w)):
-            np.testing.assert_array_equal(
-                np.asarray(a), np.asarray(b), err_msg=label
-            )
-
-
 BSPLIT_LAYOUTS = {
-    # layout -> (dp, pp, zero1, schedule, clip, grad_bucket_bytes)
-    "pp4-gpipe": (1, 4, False, S.GPipeSchedule, None, 0),
-    "pp4-pipedream-clip": (1, 4, False, S.PipeDreamFlushSchedule, 0.05, 0),
-    "dp2pp2-bucketed": (2, 2, False, S.GPipeSchedule, 0.05, 1024),
-    "zero1": (2, 2, True, S.PipeDreamFlushSchedule, None, 0),
-    "dp2-naive": (2, 1, False, S.NaiveParallelSchedule, None, 8192),
+    # layout -> (dp, pp, zero1, schedule, clip)
+    "pp4-gpipe": (1, 4, False, S.GPipeSchedule, None),
+    "pp4-pipedream-clip": (1, 4, False, S.PipeDreamFlushSchedule, 0.05),
+    "dp2pp2-clip": (2, 2, False, S.GPipeSchedule, 0.05),
+    "zero1": (2, 2, True, S.PipeDreamFlushSchedule, None),
+    "dp2-naive": (2, 1, False, S.NaiveParallelSchedule, None),
 }
 
 
@@ -488,10 +430,10 @@ def test_backward_split_bitwise_identical_to_unsplit(layout):
     """The split-backward acceptance criterion: two-stage backward (B-input
     at the combined backward's tick, B-weight deferred into bubbles) is
     BITWISE identical to the unsplit schedule — final weights, loss AND
-    the pre-clip global grad norm — across dp x pp x clip x grad-bucket
+    the pre-clip global grad norm — across dp x pp x clip
     combinations, GPipe and 1F1B (and naive) alike. The lowering enforces
     the weight-grad accumulation order this equality depends on."""
-    dp, pp, zero1, sched, clip, gbb = BSPLIT_LAYOUTS[layout]
+    dp, pp, zero1, sched, clip = BSPLIT_LAYOUTS[layout]
     sizes = (40, 36, 32, 28, 24, 20, 14, 10)
     M, B = 4, 32
     spec = Mo.make_model_spec(sizes, pp, B)
@@ -507,7 +449,7 @@ def test_backward_split_bitwise_identical_to_unsplit(layout):
         ost = E.zero1_init_state(opt, spec, mesh) if zero1 else opt.init(stacked)
         step = E.make_pipeline_step(
             mesh, spec, prog, B // dp // M, opt, zero1=zero1,
-            clip_norm=clip, with_grad_norm=True, grad_bucket_bytes=gbb,
+            clip_norm=clip, with_grad_norm=True,
         )
         for i in range(2):
             stacked, ost, loss, gnorm = step(
@@ -524,14 +466,14 @@ def test_backward_split_bitwise_identical_to_unsplit(layout):
 
 
 RECOMPUTE_LAYOUTS = {
-    # layout -> (dp, pp, tp, zero1, schedule, bsplit, gbb, act)
-    "pp4-gpipe": (1, 4, 1, False, S.GPipeSchedule, False, 0, "relu"),
+    # layout -> (dp, pp, tp, zero1, schedule, bsplit, act)
+    "pp4-gpipe": (1, 4, 1, False, S.GPipeSchedule, False, "relu"),
     "pp4-pipedream-split": (
-        1, 4, 1, False, S.PipeDreamFlushSchedule, True, 0, "relu",
+        1, 4, 1, False, S.PipeDreamFlushSchedule, True, "relu",
     ),
-    "dp2pp2-bucketed": (2, 2, 1, False, S.GPipeSchedule, False, 1024, "gelu"),
-    "zero1": (2, 2, 1, True, S.PipeDreamFlushSchedule, False, 0, "relu"),
-    "tp2-gelu": (1, 2, 2, False, S.GPipeSchedule, False, 0, "gelu"),
+    "dp2pp2-gelu": (2, 2, 1, False, S.GPipeSchedule, False, "gelu"),
+    "zero1": (2, 2, 1, True, S.PipeDreamFlushSchedule, False, "relu"),
+    "tp2-gelu": (1, 2, 2, False, S.GPipeSchedule, False, "gelu"),
 }
 
 
@@ -549,7 +491,7 @@ def test_recompute_bitwise_identical_to_stashed(layout):
     forward activation stash and re-running the stage forward inside the
     backward tick is BITWISE identical to stashed training — final
     weights, loss AND the pre-clip global grad norm — across dp x pp x
-    tp x zero1 x bucketing x split-backward and both activation
+    tp x zero1 x split-backward and both activation
     families, with global-norm clipping active the whole time. The
     recompute forward re-executes character-identical slot expressions,
     so there is no tolerance to hide behind. The same pair of lowered
@@ -558,7 +500,7 @@ def test_recompute_bitwise_identical_to_stashed(layout):
     stash peak is strictly below its stashed twin's."""
     from shallowspeed_tpu.analysis.stash import assert_recompute_peak_drop
 
-    dp, pp, tp, zero1, sched, bsplit, gbb, act = RECOMPUTE_LAYOUTS[layout]
+    dp, pp, tp, zero1, sched, bsplit, act = RECOMPUTE_LAYOUTS[layout]
     sizes = (40, 36, 32, 28, 24, 20, 14, 10)
     M, B = 4, 32
     spec = Mo.make_model_spec(sizes, pp, B, act=act)
@@ -582,7 +524,7 @@ def test_recompute_bitwise_identical_to_stashed(layout):
         ost = E.zero1_init_state(opt, spec, mesh) if zero1 else opt.init(stacked)
         step = E.make_pipeline_step(
             mesh, spec, progs[rec], B // dp // M, opt, zero1=zero1,
-            clip_norm=0.05, with_grad_norm=True, grad_bucket_bytes=gbb,
+            clip_norm=0.05, with_grad_norm=True,
         )
         for i in range(2):
             stacked, ost, loss, gnorm = step(
@@ -611,10 +553,6 @@ KILL_RESUME_LAYOUTS = {
     "zero1": (
         dict(dp=2, pp=2, schedule="gpipe", zero1=True, optimizer="momentum"),
         dict(dp=2, pp=2, schedule="gpipe", zero1=True, optimizer="momentum"),
-    ),
-    "bucketed": (
-        dict(dp=2, grad_bucket_bytes=1024),
-        dict(dp=2, grad_bucket_bytes=1024),
     ),
     "bsplit": (
         dict(pp=4, schedule="pipedream", backward_split=True, mubatches=4),
@@ -678,7 +616,7 @@ def test_kill_and_resume_bitwise_identical_to_uninterrupted(
     layout, session_data_dir, tmp_path
 ):
     """The kill-and-resume lattice dimension (docs/robustness.md): on every
-    feature layout — dp, pipeline, ZeRO-1, bucketed grad sync, split
+    feature layout — dp, pipeline, ZeRO-1, split
     backward — a run killed by an injected fault at a mid-epoch step and
     resumed from its last step snapshot finishes on exactly the bits of
     the uninterrupted twin. The ELASTIC dp=2 -> dp=4 restore is exact at

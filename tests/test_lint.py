@@ -115,7 +115,6 @@ def test_scope_for_real_paths():
     assert scope_for("shallowspeed_tpu/observability/metrics.py").metrics_path
     assert scope_for("shallowspeed_tpu/serving/engine.py").metrics_path
     assert scope_for("shallowspeed_tpu/checkpoint.py").atomic_module
-    assert scope_for("shallowspeed_tpu/aot_cache.py").atomic_module
     assert scope_for("shallowspeed_tpu/trainer.py").donation_ok
     assert scope_for("shallowspeed_tpu/parallel/executor.py").donation_ok
     neutral = scope_for("shallowspeed_tpu/api.py")

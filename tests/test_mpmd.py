@@ -348,26 +348,38 @@ def test_async_checkpoint_defers_unstacking_bitwise(mpmd_data_dir, tmp_path):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_mpmd_refuses_unsupported_knobs(mpmd_data_dir):
-    """The feature envelope is enforced loudly at construction, and the
-    fused-run contract is refused at call time."""
+MPMD_REFUSED_KNOBS = {
+    "zero1": dict(zero1=True),
+    "clip_norm": dict(clip_norm=0.1),
+    "pallas": dict(kernel_backend="pallas"),
+    "record_steps": dict(record_steps=True),
+}
+
+
+@pytest.mark.parametrize("knob", sorted(MPMD_REFUSED_KNOBS))
+def test_mpmd_refuses_unsupported_knobs(mpmd_data_dir, knob):
+    """The feature envelope is enforced loudly at construction, knob by
+    knob."""
     from shallowspeed_tpu.api import TrainingSession
 
-    base = dict(
-        global_batch_size=32, mubatches=4, data_dir=mpmd_data_dir,
-        runtime="mpmd",
-    )
+    with pytest.raises(ValueError, match="mpmd"):
+        TrainingSession(
+            global_batch_size=32, mubatches=4, data_dir=mpmd_data_dir,
+            runtime="mpmd", pp=4, schedule="gpipe",
+            **MPMD_REFUSED_KNOBS[knob],
+        )
+
+
+def test_mpmd_refuses_no_mesh_and_fused_run(mpmd_data_dir):
+    """MPMD needs stages (refused on the sequential path at construction),
+    and the fused-run contract is refused at call time."""
+    from shallowspeed_tpu.api import TrainingSession
+
     with pytest.raises(ValueError, match="sequential"):
-        TrainingSession(**base)  # dp=pp=tp=1
-    for bad in (
-        dict(pp=4, schedule="gpipe", zero1=True),
-        dict(pp=4, schedule="gpipe", grad_bucket_bytes=1024),
-        dict(pp=4, schedule="gpipe", clip_norm=0.1),
-        dict(pp=4, schedule="gpipe", kernel_backend="pallas"),
-        dict(pp=4, schedule="gpipe", record_steps=True),
-    ):
-        with pytest.raises(ValueError, match="mpmd"):
-            TrainingSession(**base, **bad)
+        TrainingSession(
+            global_batch_size=32, mubatches=4, data_dir=mpmd_data_dir,
+            runtime="mpmd",
+        )  # dp=pp=tp=1
     run = _session(mpmd_data_dir, "mpmd")
     with pytest.raises(ValueError, match="train_epoch"):
         run.train_run(1)

@@ -799,24 +799,15 @@ def test_schema_v7_fleet_kinds(tmp_path):
     n.fleet_health("replica_dead", replica_id=0)
 
 
-def test_schema_v8_async_ckpt_and_aot(tmp_path):
-    """Schema v8 (additive): the aot_cache kind plus the async-writer
-    fields on checkpoint and verify_s on reload — round-trip with the
-    version stamp, the v8 reader accepts v1-v7 files unchanged, and
-    NullMetrics no-ops the new hook. (Version pin + one-ahead refusal
-    live with the newest schema's test, per the bump convention.)"""
+def test_schema_v8_async_ckpt(tmp_path):
+    """Schema v8 (additive): the async-writer fields on checkpoint and
+    verify_s on reload — round-trip with the version stamp — and the v8
+    reader accepts v1-v7 files unchanged. The kind v8 added has no writer
+    any more; a line of it from an old file still reads. (Version pin +
+    one-ahead refusal live with the newest schema's test, per the bump
+    convention.)"""
     path = tmp_path / "v8.jsonl"
     with JsonlMetrics(path) as m:
-        m.aot_cache("miss", program="inference_r4", key="ab12")
-        m.aot_cache(
-            "store", program="inference_r4", key="ab12", wall_s=0.01,
-            bytes=2048,
-        )
-        m.aot_cache("hit", program="inference_r4", key="ab12", wall_s=0.002)
-        m.aot_cache(
-            "corrupt", program="inference_r4", key="ab12",
-            reason="payload sha256 mismatch — torn or bit-rotted",
-        )
         m.checkpoint(
             "step", path="ck/step-00000004.npz", global_step=4, bytes=100,
             wall_s=0.001, **{"async": True}, queue_depth=1,
@@ -826,18 +817,12 @@ def test_schema_v8_async_ckpt_and_aot(tmp_path):
                  wall_s=0.01, verify_s=0.004)
     recs = read_jsonl(path)
     kinds = [r["kind"] for r in recs]
-    assert kinds == [
-        "meta", "aot_cache", "aot_cache", "aot_cache", "aot_cache",
-        "checkpoint", "reload",
-    ]
+    assert kinds == ["meta", "checkpoint", "reload"]
     assert all(r["v"] == SCHEMA_VERSION for r in recs)
-    assert [r["name"] for r in recs if r["kind"] == "aot_cache"] == [
-        "miss", "store", "hit", "corrupt",
-    ]
-    ck = recs[5]
+    ck = recs[1]
     assert ck["async"] is True and ck["queue_depth"] == 1
     assert ck["verify_s"] == 0.0005 and ck["write_s"] == 0.002
-    assert recs[6]["verify_s"] == 0.004
+    assert recs[2]["verify_s"] == 0.004
     # v1-v7 files load unchanged under the v8 reader
     for v, rec in (
         (4, {"kind": "checkpoint", "name": "step", "global_step": 2}),
@@ -847,7 +832,15 @@ def test_schema_v8_async_ckpt_and_aot(tmp_path):
         p = tmp_path / f"old-v{v}.jsonl"
         p.write_text(json.dumps({"v": v, "ts": 0.0, **rec}) + "\n")
         assert read_jsonl(p)[0]["kind"] == rec["kind"]
-    NullMetrics().aot_cache("hit", program="x")
+    # a v8 file written while the executable cache existed still reads,
+    # strictly: the kind stays in the kind -> version table
+    old = tmp_path / "old-v8.jsonl"
+    old.write_text(
+        json.dumps({"v": 8, "ts": 0.0, "kind": "aot_cache", "name": "hit",
+                    "program": "inference_r4", "key": "ab12"}) + "\n"
+    )
+    assert read_jsonl(old, strict=True)[0]["name"] == "hit"
+    assert not hasattr(NullMetrics(), "aot_cache")
 
 
 def test_schema_v9_static_analysis(tmp_path):

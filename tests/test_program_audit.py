@@ -146,15 +146,6 @@ def test_verify_census_raises_loudly_on_mismatch():
         )
     # matching census passes silently
     pa.verify_census({"all_reduce": {"count": 5, "bytes": 4}}, expected)
-    # with per-op evidence, verify_census enforces the bucketed leg too
-    bucketed = {
-        "dp": 2, "zero1": False, "required": ["all_reduce"], "forbidden": [],
-        "axes": {"dp": {"mode": "bucketed", "num_buckets": 2,
-                        "bucket_census_bytes": [1024, 512]}},
-    }
-    leafy = [{"kind": "all_reduce", "bytes": b} for b in (700, 836)]
-    with pytest.raises(pa.AuditMismatchError, match="bucketed sync"):
-        pa.verify_census(pa.census_of_ops(leafy), bucketed, ops=leafy)
 
 
 # ---------------------------------------------------------------------------
@@ -280,57 +271,6 @@ def test_bandwidth_and_hbm_provenance(monkeypatch):
     assert cap == 456.0 and src == f"env:{pa.ENV_HBM}"
 
 
-def test_check_census_bucketed_rules():
-    """The bucketed-sync contract leg: every planned bucket accounted for
-    by a sync op of its exact size, or by a combiner-merged ADJACENT
-    run's summed size; unaccountable sizes fail; skipped at dp=1 (XLA
-    may elide degenerate collectives) and without the per-op list
-    (census aggregates cannot carry sizes)."""
-    expected = {
-        "dp": 2, "zero1": False, "required": ["all_reduce"], "forbidden": [],
-        "axes": {"dp": {"mode": "bucketed", "num_buckets": 3,
-                        "bucket_census_bytes": [1024, 512, 256]}},
-    }
-    ops = [{"kind": "all_reduce", "bytes": 1024},
-           {"kind": "all_reduce", "bytes": 512},
-           {"kind": "all_reduce", "bytes": 256},
-           {"kind": "all_reduce", "bytes": 4}]  # loss psum: ignored extra
-    assert pa.check_census(pa.census_of_ops(ops), expected, ops=ops) == []
-    # combiner-merged neighbors: [1024+512, 256] and the fully-merged
-    # [1024+512+256] both account for every planned byte — accepted
-    for merged_sizes in ([1536, 256], [1792]):
-        merged = [{"kind": "all_reduce", "bytes": b} for b in merged_sizes]
-        assert pa.check_census(
-            pa.census_of_ops(merged), expected, ops=merged
-        ) == [], merged_sizes
-    # a NON-adjacent merge (1024+256, skipping the middle bucket) is not
-    # a combiner shape — fails, naming both size lists
-    wrong = [{"kind": "all_reduce", "bytes": 1280},
-             {"kind": "all_reduce", "bytes": 512}]
-    msgs = pa.check_census(pa.census_of_ops(wrong), expected, ops=wrong)
-    assert any("cannot account" in m and "512" in m for m in msgs)
-    # the unwired-knob shape: per-leaf anchor ops whose sizes cannot be
-    # partitioned into the planned bucket sums
-    leafy = [{"kind": "all_reduce", "bytes": b} for b in (700, 324, 400, 112, 200, 56)]
-    assert pa.check_census(pa.census_of_ops(leafy), expected, ops=leafy)
-    # zero1 buckets check reduce_scatter, not all_reduce
-    z1 = {
-        "dp": 2, "zero1": True,
-        "required": ["reduce_scatter", "all_gather"], "forbidden": [],
-        "axes": {"dp": {"mode": "bucketed", "num_buckets": 2,
-                        "bucket_census_bytes": [256, 128]}},
-    }
-    zops = [{"kind": "reduce_scatter", "bytes": 256},
-            {"kind": "reduce_scatter", "bytes": 128},
-            {"kind": "all_gather", "bytes": 512}]
-    assert pa.check_census(pa.census_of_ops(zops), z1, ops=zops) == []
-    # dp=1: the bucketed legs are skipped entirely
-    exp1 = dict(expected, dp=1, required=[])
-    assert pa.check_census({}, exp1, ops=[]) == []
-    # without ops only the kind legs run (no size evidence, no claim)
-    assert pa.check_census(pa.census_of_ops(ops), expected) == []
-
-
 # ---------------------------------------------------------------------------
 # real compiled programs: the invariant across layouts (acceptance criterion)
 # ---------------------------------------------------------------------------
@@ -409,32 +349,19 @@ def test_compiled_split_backward_census_and_tick_model(data_dir):
     )
 
 
-def test_expected_comms_bucketed_contract_and_overlap_bounds(data_dir):
-    """A bucketed session's contract: the dp axis carries the plan
-    (mode/num_buckets/per-bucket bytes), TOTAL bytes are unchanged vs the
-    anchor session, and the two step-time lower bounds hold their
-    defining relations (serial = comm + compute, overlapped = max)."""
-    anchor = _mesh_session(data_dir, dp=2, pp=2, schedule="gpipe")
-    bucketed = _mesh_session(
-        data_dir, dp=2, pp=2, schedule="gpipe", grad_bucket_bytes=2048
-    )
-    a, b = anchor._expected_comms, bucketed._expected_comms
-    assert a["axes"]["dp"]["mode"] == "anchor"
-    dpax = b["axes"]["dp"]
-    assert dpax["mode"] == "bucketed"
-    assert dpax["grad_bucket_bytes"] == 2048
-    assert dpax["num_buckets"] == bucketed._sync_plan.num_buckets >= 2
-    assert sum(dpax["bucket_grad_bytes"]) == dpax["grad_bytes_per_device"]
-    # bucketing moves op granularity, never bytes
-    assert b["bytes_per_step_per_device"] == a["bytes_per_step_per_device"]
-    for exp in (a, b):
-        ct, xt = exp["comms_time_per_step_s"], exp["compute_time_per_step_s"]
-        assert exp["serial_bound_s"] == pytest.approx(ct + xt)
-        assert exp["overlapped_bound_s"] == pytest.approx(max(ct, xt))
-        assert exp["model_hidden_comm_share"] == pytest.approx(
-            min(ct, xt) / ct
-        )
-        assert exp["serial_bound_s"] >= exp["overlapped_bound_s"]
+def test_expected_comms_overlap_bounds(data_dir):
+    """The two step-time lower bounds of a session's contract hold their
+    defining relations (serial = comm + compute, overlapped = max), and
+    the dp axis carries the anchor's byte model and nothing per bucket."""
+    exp = _mesh_session(data_dir, dp=2, pp=2, schedule="gpipe")._expected_comms
+    dpax = exp["axes"]["dp"]
+    assert dpax["kind"] == "all_reduce" and dpax["zero"] == 0
+    assert "mode" not in dpax and "num_buckets" not in dpax
+    ct, xt = exp["comms_time_per_step_s"], exp["compute_time_per_step_s"]
+    assert exp["serial_bound_s"] == pytest.approx(ct + xt)
+    assert exp["overlapped_bound_s"] == pytest.approx(max(ct, xt))
+    assert exp["model_hidden_comm_share"] == pytest.approx(min(ct, xt) / ct)
+    assert exp["serial_bound_s"] >= exp["overlapped_bound_s"]
 
 
 @pytest.mark.parametrize(
@@ -443,49 +370,29 @@ def test_expected_comms_bucketed_contract_and_overlap_bounds(data_dir):
         (dict(dp=2), "all_reduce"),
         (dict(dp=2, pp=2, schedule="gpipe", zero1=True), "reduce_scatter"),
     ],
-    ids=["dp2-bucketed", "zero1-bucketed"],
+    ids=["dp2", "zero1"],
 )
-def test_compiled_census_matches_bucket_plan(data_dir, kw, kind):
-    """The bucketed acceptance criterion, positive leg: the COMPILED
-    bucketed program really contains one sync collective per planned
-    bucket at exactly the planned result sizes (the emitters lower one
-    flat op per bucket; XLA does not merge them) — and audit_compiled
+def test_compiled_census_matches_anchor_sync(data_dir, kw, kind):
+    """The COMPILED program really contains the layout's tail sync: the
+    stage-0 all-reduces carry every gradient byte of the contract (XLA
+    lowers the pytree psum per leaf or fused), ZeRO-1 lowers ONE flat
+    reduce-scatter whose result is this rank's chunk — and audit_compiled
     agrees (census_ok)."""
-    from collections import Counter
-
-    run = _mesh_session(data_dir, grad_bucket_bytes=2048, **kw)
-    plan = run._sync_plan
-    assert plan is not None and plan.num_buckets >= 2
+    run = _mesh_session(data_dir, **kw)
     compiled = run._epoch_fn.lower(*run._epoch_args()).compile()
     rec = pa.audit_compiled(
         compiled, expected=run._expected_comms, platform="cpu",
         n_devices=run._cost_model.n_devices,
     )
     assert rec["census_ok"] is True, rec["mismatches"]
-    assert rec["census"][kind]["count"] >= plan.num_buckets
+    grad_bytes = run._expected_comms["axes"]["dp"]["grad_bytes_per_device"]
     ops = pa.parse_collectives(compiled.as_text())
-    compiled_sizes = Counter(o["bytes"] for o in ops if o["kind"] == kind)
-    planned = Counter(plan.bucket_census_bytes())
-    assert not (planned - compiled_sizes), (planned, compiled_sizes)
-
-
-def test_session_audit_raises_on_bucket_plan_mismatch(data_dir):
-    """The bucketed negative leg: a deliberate plan/program mismatch (a
-    contract demanding bucket sizes the emitters never lowered) raises
-    AuditMismatchError BEFORE the first dispatch — and is never latched,
-    so a retry re-refuses."""
-    run = _mesh_session(data_dir, dp=2, audit=True, grad_bucket_bytes=2048)
-    dpax = dict(run._expected_comms["axes"]["dp"])
-    dpax["num_buckets"] = dpax["num_buckets"] + 7
-    dpax["bucket_census_bytes"] = list(dpax["bucket_census_bytes"]) + [12345]
-    run._expected_comms = dict(
-        run._expected_comms,
-        axes=dict(run._expected_comms["axes"], dp=dpax),
-    )
-    with pytest.raises(pa.AuditMismatchError, match="bucketed sync"):
-        run.train_epoch()
-    with pytest.raises(pa.AuditMismatchError, match="bucketed sync"):
-        run.train_epoch()
+    sizes = [o["bytes"] for o in ops if o["kind"] == kind]
+    if kind == "all_reduce":
+        # the gradient tree plus the scalar loss psums
+        assert grad_bytes <= sum(sizes) <= grad_bytes + 64, sizes
+    else:
+        assert sizes == [grad_bytes // 2], sizes
 
 
 def test_session_audit_true_raises_on_contract_violation(data_dir, monkeypatch):
@@ -614,37 +521,29 @@ def test_report_renders_contract_mismatch_and_oom_forecast(tmp_path, capsys):
     assert "OOM FORECAST" in out
 
 
-def test_bucketed_run_jsonl_and_overlap_report(data_dir, tmp_path, capsys):
-    """End-to-end for the bucketed observability loop: the JSONL carries
-    the grad_sync_plan event and a census-clean bucketed audit, and the
-    report renders the overlap-efficiency row plus the serial-vs-
-    overlapped step bounds and the bucketed sync line."""
+def test_audited_run_jsonl_and_overlap_report(data_dir, tmp_path, capsys):
+    """End-to-end for the overlap observability loop: the JSONL carries a
+    census-clean audit with the anchor's byte model, and the report
+    renders the overlap-efficiency row plus the serial-vs-overlapped step
+    bounds."""
     from shallowspeed_tpu.observability.report import main as report_main
 
-    path = tmp_path / "bucketed.jsonl"
+    path = tmp_path / "audited.jsonl"
     with JsonlMetrics(path) as m:
-        run = _mesh_session(
-            data_dir, dp=2, metrics=m, audit=True, grad_bucket_bytes=2048
-        )
+        run = _mesh_session(data_dir, dp=2, metrics=m, audit=True)
         run.train_epoch()
     recs = read_jsonl(path)
-    plans = [
-        r for r in recs
-        if r.get("kind") == "event" and r.get("name") == "grad_sync_plan"
-    ]
-    assert len(plans) == 1
-    assert plans[0]["mode"] == "dp" and plans[0]["num_buckets"] >= 2
-    assert sum(plans[0]["bucket_grad_bytes"]) == plans[0]["total_grad_bytes"]
+    assert not [r for r in recs if r.get("name") == "grad_sync_plan"]
     audit = [r for r in recs if r.get("kind") == "xla_audit"][-1]
     assert audit["census_ok"] is True
-    assert audit["expected"]["axes"]["dp"]["mode"] == "bucketed"
+    assert audit["expected"]["axes"]["dp"]["kind"] == "all_reduce"
 
     assert report_main([str(path), "--format", "md"]) == 0
     out = capsys.readouterr().out
     assert "overlap efficiency" in out
-    assert "comm hideable (model bound" in out and "buckets" in out
-    assert "gradient sync: bucketed" in out
-    assert "serial (anchor)" in out and "overlapped (bucketed, perfect)" in out
+    assert "comm hideable (model bound; anchor sync)" in out
+    assert "gradient sync: bucketed" not in out
+    assert "serial (anchor)" in out and "overlapped (perfect)" in out
 
 
 def test_fused_run_audits_run_program(data_dir, tmp_path):

@@ -9,9 +9,9 @@ docs/lowering.md "Per-axis comms"):
   standard CROSS-LAYOUT float tolerance: the row-parallel forward and
   column-parallel backward psums split a contraction across ranks, which
   reassociates the fp sum exactly like a different dp width reassociates
-  the gradient all-reduce (docs/numerics.md). Same-layout A/B knobs at a
-  FIXED tp — bucketed vs anchor gradient sync, split vs combined
-  backward — stay BITWISE, and those legs are asserted with array_equal;
+  the gradient all-reduce (docs/numerics.md). The same-layout A/B knob at
+  a FIXED tp — split vs combined backward — stays BITWISE, and that leg
+  is asserted with array_equal;
 - the compiled program's collective census carries the per-axis contract:
   the tp axis demands >= (fwd sites + bwd sites) all-reduce ops
   (executor.tp_allreduce_sites), the dp payload shrinks by tp, and the
@@ -29,7 +29,7 @@ from shallowspeed_tpu import trainer
 from shallowspeed_tpu.observability import program_audit
 from shallowspeed_tpu.optimizer import SGD, MomentumSGD
 from shallowspeed_tpu.parallel import executor as E
-from shallowspeed_tpu.parallel import gradsync, lower_schedule, make_mesh
+from shallowspeed_tpu.parallel import lower_schedule, make_mesh
 from shallowspeed_tpu.parallel.mesh import make_mesh_with_layout, mesh_tp
 
 SIZES = (40, 36, 32, 28, 24, 20, 14, 10)  # 7 Linears; pp in {1, 2} below
@@ -104,7 +104,7 @@ def _data(seed=7):
 
 
 def _train_mesh(
-    dp, pp, tp, sched=S.GPipeSchedule, zero1=False, gbb=0, bsplit=False,
+    dp, pp, tp, sched=S.GPipeSchedule, zero1=False, bsplit=False,
     clip=0.05, opt=None,
 ):
     spec = Mo.make_model_spec(SIZES, pp, B)
@@ -115,7 +115,7 @@ def _train_mesh(
     ost = E.zero1_init_state(opt, spec, mesh) if zero1 else opt.init(stacked)
     step = E.make_pipeline_step(
         mesh, spec, prog, B // dp // M, opt, zero1=zero1, clip_norm=clip,
-        with_grad_norm=True, grad_bucket_bytes=gbb,
+        with_grad_norm=True,
     )
     X, Y = _data()
     for i in range(2):
@@ -175,20 +175,6 @@ def test_tp_matches_sequential(layout):
             np.asarray(a["b"]).reshape(-1), b["b"].reshape(-1),
             rtol=5e-4, atol=5e-6, err_msg=layout,
         )
-
-
-def test_tp_bucketed_sync_bitwise_identical_to_anchor():
-    """The bit-identity contract where it GENUINELY holds at tp > 1:
-    bucketed vs anchor gradient sync on the same tp2 layout — weights,
-    loss AND the pre-clip grad norm are array_equal (the dp collectives
-    sum the same per-shard elements either way)."""
-    base_w, base_loss, base_gn = _train_mesh(2, 1, 2)
-    for gbb in (512, 8192):
-        w, loss, gn = _train_mesh(2, 1, 2, gbb=gbb)
-        assert loss == base_loss and gn == base_gn, gbb
-        for a, b in zip(base_w, w):
-            np.testing.assert_array_equal(a["W"], b["W"], err_msg=str(gbb))
-            np.testing.assert_array_equal(a["b"], b["b"], err_msg=str(gbb))
 
 
 def test_tp_backward_split_bitwise_identical_to_unsplit():
@@ -262,15 +248,14 @@ def _compiled_census(dp, pp, tp, training=True, zero1=False):
         compiled = step.lower(
             stacked, flags, jax.ShapeDtypeStruct((B, SIZES[0]), jnp.float32)
         ).compile()
-    ops = program_audit.parse_collectives(compiled.as_text())
     expected = program_audit.expected_comms(
         spec, dp, pp, prog=prog, zero1=zero1, mubatch_size=mb, tp=tp
     )
-    return ops, program_audit.census_of_ops(ops), expected
+    return program_audit.collective_census(compiled.as_text()), expected
 
 
 def test_tp_training_census_matches_contract():
-    ops, census, expected = _compiled_census(2, 2, 2)
+    census, expected = _compiled_census(2, 2, 2)
     assert "tp" in expected["axes"]
     tp_axis = expected["axes"]["tp"]
     assert tp_axis["hlo_min_all_reduce_ops"] == (
@@ -279,7 +264,7 @@ def test_tp_training_census_matches_contract():
     # the compiled program really holds the Megatron psums (plus the dp
     # sync, loss and clip reductions — the floor is a lower bound)
     assert census["all_reduce"]["count"] >= tp_axis["hlo_min_all_reduce_ops"]
-    program_audit.verify_census(census, expected, ops=ops)
+    program_audit.verify_census(census, expected)
     # dp payload shrinks: each device syncs only its Megatron shard
     spec = Mo.make_model_spec(SIZES, 2, B)
     dp_axis = expected["axes"]["dp"]
@@ -293,7 +278,7 @@ def test_tp_training_census_matches_contract():
 def test_tp_census_floor_catches_dropped_collectives():
     """A contract whose tp floor exceeds the compiled census must refuse:
     the enforcement leg is real, not decorative."""
-    ops, census, expected = _compiled_census(1, 1, 2)
+    census, expected = _compiled_census(1, 1, 2)
     tampered = dict(expected)
     tampered["axes"] = dict(expected["axes"])
     tampered["axes"]["tp"] = dict(expected["axes"]["tp"])
@@ -301,9 +286,9 @@ def test_tp_census_floor_catches_dropped_collectives():
         census["all_reduce"]["count"] + 7
     )
     with pytest.raises(program_audit.AuditMismatchError, match="tensor-parallel"):
-        program_audit.verify_census(census, tampered, ops=ops)
+        program_audit.verify_census(census, tampered)
     # and the honest contract passes the same census
-    program_audit.verify_census(census, expected, ops=ops)
+    program_audit.verify_census(census, expected)
 
 
 def test_tp_inference_census_forward_only():
@@ -312,19 +297,19 @@ def test_tp_inference_census_forward_only():
     training lowering leaked into the serving path) while requiring the
     per-layer-pair forward psums — and the compiled inference program at
     pp2 x tp2 satisfies it."""
-    ops, census, expected = _compiled_census(1, 2, 2, training=False)
+    census, expected = _compiled_census(1, 2, 2, training=False)
     assert expected["inference"] is True
     assert "reduce_scatter" in expected["forbidden"]
     assert "all_gather" in expected["forbidden"]
     assert expected["axes"]["tp"]["sites_bwd"] == 0
-    program_audit.verify_census(census, expected, ops=ops)
+    program_audit.verify_census(census, expected)
     # a leaked gradient collective is refused — both kinds: the ZeRO
     # collectives by prohibition, and an EXTRA all-reduce (the anchor-mode
     # dp sync's shape) by the tp upper pin (at most sites + the preds psum)
     leaky = dict(census)
     leaky["reduce_scatter"] = {"count": 1, "bytes": 1024}
     with pytest.raises(program_audit.AuditMismatchError, match="reduce_scatter"):
-        program_audit.verify_census(leaky, expected, ops=ops)
+        program_audit.verify_census(leaky, expected)
     need = expected["axes"]["tp"]["hlo_min_all_reduce_ops"]
     leaky_ar = dict(census)
     leaky_ar["all_reduce"] = {
@@ -334,30 +319,7 @@ def test_tp_inference_census_forward_only():
     with pytest.raises(
         program_audit.AuditMismatchError, match="leaked into the serving path"
     ):
-        program_audit.verify_census(leaky_ar, expected, ops=ops)
-
-
-def test_tp_bucket_plan_sizes_are_local_shards():
-    """The gradsync planners bucket THIS DEVICE's Megatron shards: total
-    planned bytes at tp2 are exactly half the tp1 plan's, and the
-    emitters' leaf shapes match the executor's local gradient shapes."""
-    spec = Mo.make_model_spec(SIZES, 1, B)
-    p1 = gradsync.plan_buckets(spec, 2, 1, 4096, tp=1)
-    p2 = gradsync.plan_buckets(spec, 2, 1, 4096, tp=2)
-    dims2 = E.slot_shapes(spec, 2)
-    w_dims, b_widths, _, _ = E.tp_local_dims(dims2, 2)
-    for group in p2.buckets:
-        for leaf in group:
-            if leaf.kind == "W":
-                assert tuple(leaf.shape)[1:] == w_dims[leaf.slot]
-            else:
-                assert tuple(leaf.shape)[1] == b_widths[leaf.slot]
-    total1 = p1.total_grad_bytes()
-    total2 = p2.total_grad_bytes()
-    # tp2 dims are rounded up before halving, so <= holds with equality
-    # whenever no rounding occurred
-    assert total2 <= total1
-    assert total2 == 4 * E.stacked_flat_len(spec, 1, 2)
+        program_audit.verify_census(leaky_ar, expected)
 
 
 # ---------------------------------------------------------------------------

@@ -138,7 +138,7 @@ def test_train_cli_help():
     assert r.returncode == 0
     for flag in (
         "--dp", "--pp", "--schedule", "--checkpoint", "--resume",
-        "--precision", "--grad-bucket-bytes",
+        "--precision", "--backward-split",
     ):
         assert flag in r.stdout
 

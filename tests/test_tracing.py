@@ -1,6 +1,6 @@
 """Distributed request tracing tests: the span Tracer, the chain reader
 (assembly, clock alignment, completeness refusal), phase attribution and
-waterfalls, the dispatch-overhead probe — and the fleet-level legs:
+waterfalls — and the fleet-level legs:
 handshake-aligned joins against an artificially skewed worker clock, and
 the SIGKILL failover chain (docs/observability.md § Tracing).
 
@@ -8,8 +8,6 @@ Multi-process tests carry the ``fleet`` marker and skip-with-reason when
 the platform cannot spawn worker processes (the test_fleet convention).
 """
 
-import gzip
-import json
 import os
 import time
 
@@ -307,126 +305,6 @@ def test_engine_failed_dispatch_exhaustion_chain(data_dir, tmp_path):
     (chain,) = chains.values()
     assert chain.verdict == "error"
     assert [s["name"] for s in chain.spans] == ["worker.queue", "ack"]
-
-
-# ---------------------------------------------------------------------------
-# dispatch-overhead probe (trace_stats + session)
-# ---------------------------------------------------------------------------
-
-
-def _write_trace(path, events):
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
-
-
-def test_dispatch_busy_host_executor_fallback(tmp_path):
-    """The CPU backend emits no /device: pid — dispatch_busy falls back
-    to the HLO thunk events on the tf_XLA* executor threads, takes the
-    interval UNION (parallel workers must not exceed wall), and excludes
-    runtime plumbing (C++ ``::`` internals incl. the ThunkExecutor WAIT,
-    python ``$`` frames, ParseArguments)."""
-    p = tmp_path / "cpu.trace.json.gz"
-    _write_trace(p, [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "M", "pid": 1, "tid": 2, "name": "thread_name",
-         "args": {"name": "tf_XLAEigen/12345"}},
-        {"ph": "M", "pid": 1, "tid": 3, "name": "thread_name",
-         "args": {"name": "tf_XLATfrtCpuClient/999"}},
-        {"ph": "M", "pid": 1, "tid": 4, "name": "thread_name",
-         "args": {"name": "python-main"}},
-        # two overlapping thunks on parallel workers: union is 15us
-        {"ph": "X", "pid": 1, "tid": 2, "name": "dot.14", "ts": 0, "dur": 10},
-        {"ph": "X", "pid": 1, "tid": 3, "name": "fusion.1.clone", "ts": 5,
-         "dur": 10},
-        # a comm thunk, disjoint: union grows to 20us, comm 5us
-        {"ph": "X", "pid": 1, "tid": 3, "name": "all-reduce.2", "ts": 30,
-         "dur": 5},
-        # excluded plumbing
-        {"ph": "X", "pid": 1, "tid": 3,
-         "name": "ThunkExecutor::Execute (wait for completion)", "ts": 0,
-         "dur": 1000},
-        {"ph": "X", "pid": 1, "tid": 3, "name": "ParseArguments", "ts": 0,
-         "dur": 50},
-        {"ph": "X", "pid": 1, "tid": 2,
-         "name": "ThreadpoolListener::Record", "ts": 0, "dur": 40},
-        {"ph": "X", "pid": 1, "tid": 4, "name": "$profiler.py:226 trace",
-         "ts": 0, "dur": 99999},
-    ])
-    from shallowspeed_tpu.observability import trace_stats
-
-    busy = trace_stats.dispatch_busy(p)
-    assert busy["source"] == "host-executor"
-    assert busy["op_events"] == 3
-    assert busy["busy_union_s"] == pytest.approx(20e-6)
-    assert busy["comm_union_s"] == pytest.approx(5e-6)
-    assert busy["compute_union_s"] == pytest.approx(15e-6)
-    # the share: 20us busy of 100us wall -> 80% dispatch overhead
-    share = trace_stats.dispatch_overhead_share(busy["busy_union_s"], 100e-6)
-    assert share == pytest.approx(0.8)
-    # unmeasurable sides stay None, never a fabricated perfect 0
-    assert trace_stats.dispatch_overhead_share(None, 1.0) is None
-    assert trace_stats.dispatch_overhead_share(1.0, None) is None
-    # clamped: op union exceeding wall (timer jitter) reads as 0, not < 0
-    assert trace_stats.dispatch_overhead_share(2.0, 1.0) == 0.0
-
-
-def test_dispatch_busy_prefers_device_pids(tmp_path):
-    """With a real device timeline present, dispatch_busy uses it (and
-    ignores host executor threads)."""
-    p = tmp_path / "dev.trace.json.gz"
-    _write_trace(p, [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 2, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "M", "pid": 2, "tid": 9, "name": "thread_name",
-         "args": {"name": "tf_XLAEigen/1"}},
-        {"ph": "X", "pid": 1, "tid": 1, "name": "fusion.7", "ts": 0,
-         "dur": 30},
-        {"ph": "X", "pid": 2, "tid": 9, "name": "dot.1", "ts": 0, "dur": 500},
-    ])
-    from shallowspeed_tpu.observability import trace_stats
-
-    busy = trace_stats.dispatch_busy(p)
-    assert busy["source"] == "device"
-    assert busy["op_events"] == 1
-    assert busy["busy_union_s"] == pytest.approx(30e-6)
-
-
-def test_session_dispatch_overhead_probe(data_dir, tmp_path):
-    """The measured op-issue roofline end to end on the CPU backend: the
-    probe dispatches real epochs under the profiler, attributes op busy
-    time via the executor-thread union, and emits the evidence event.
-    The share is a genuine measurement: in (0, 1], with op events
-    attributed and the provenance stamped."""
-    from shallowspeed_tpu.api import TrainingSession
-
-    path = tmp_path / "probe.jsonl"
-    m = JsonlMetrics(path)
-    session = TrainingSession(
-        sizes=SIZES, global_batch_size=GBS, lr=0.01, data_dir=data_dir,
-        metrics=m,
-    )
-    rec = session.measure_dispatch_overhead(repeats=1)
-    m.close()
-    assert rec["program"] == "epoch_program" and rec["repeats"] == 1
-    assert rec["op_events"] > 0 and rec["op_source"] == "host-executor"
-    assert rec["device_busy_s"] is not None
-    assert 0.0 < rec["host_wall_s"]
-    assert rec["dispatch_overhead"] is not None
-    assert 0.0 <= rec["dispatch_overhead"] < 1.0
-    assert "jax.profiler" in rec["provenance"]
-    events = [
-        r for r in read_jsonl(path)
-        if r["kind"] == "event" and r["name"] == "dispatch_overhead"
-    ]
-    assert len(events) == 1
-    assert events[0]["dispatch_overhead"] == rec["dispatch_overhead"]
-    with pytest.raises(ValueError, match="repeats"):
-        session.measure_dispatch_overhead(repeats=0)
-    with pytest.raises(ValueError, match="program"):
-        session.measure_dispatch_overhead(program="nope")
 
 
 # ---------------------------------------------------------------------------

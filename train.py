@@ -29,7 +29,6 @@ any layout also runs on emulated CPU devices:
 
 import argparse
 import contextlib
-import json
 import os
 import sys
 import time
@@ -49,7 +48,7 @@ def main(argv=None):
         "row-parallel (W split on the input dim, one all-reduce over tp) — "
         "so each fwd+bwd pass costs 2 all-reduces per layer pair and "
         "per-device weight memory/matmul FLOPs drop by tp. Composes with "
-        "--dp/--pp/--zero1/--grad-bucket-bytes/--backward-split into a "
+        "--dp/--pp/--zero1/--backward-split into a "
         "dp x pp x tp lattice (needs dp*pp*tp devices; --audit verifies "
         "the per-axis collective census; see docs/performance.md for "
         "when it pays)",
@@ -101,25 +100,12 @@ def main(argv=None):
         help="ZeRO stage on the dp axis (mesh layouts; supersedes --zero1): "
         "0 = replicate everything (the anchor all-reduce sync); 1 = shard "
         "the optimizer state + update; 2 = gradients also live as "
-        "persistent reduce-scattered per-rank shards (composes with "
-        "--grad-bucket-bytes; bitwise-equal weights to --zero 1 at the "
-        "same layout); 3 = parameters sharded at rest too, all-gathered "
+        "persistent reduce-scattered per-rank shards (bitwise-equal "
+        "weights to --zero 1 at the same layout with --mubatches 1); "
+        "3 = parameters sharded at rest too, all-gathered "
         "just-in-time per layer inside the tick scan (per-tick gradient "
         "reduce-scatter; cross-stage tolerance numerics, same-layout "
         "determinism). See docs/performance.md for when each stage pays",
-    )
-    ap.add_argument(
-        "--grad-bucket-bytes",
-        type=int,
-        default=0,
-        help="mesh layouts: bucket the DP gradient sync — the backward-"
-        "ordered gradient tree is greedily packed into buckets of at most "
-        "this many bytes and each bucket is synced by its OWN collective "
-        "(all-reduce; reduce-scatter slice under --zero1), so XLA can "
-        "overlap bucket communication with the update's compute. 0 "
-        "(default) keeps the single whole-tree anchor psum. Bitwise-"
-        "identical numerics either way; --audit verifies the bucket count "
-        "and sizes in the compiled program (see docs/performance.md)",
     )
     ap.add_argument(
         "--backward-split",
@@ -218,18 +204,6 @@ def main(argv=None):
         "run drains the writer before exiting",
     )
     ap.add_argument(
-        "--aot-cache",
-        default=None,
-        metavar="DIR",
-        help="AOT executable cache directory: compiled programs (the "
-        "inference rung ladder, the epoch audit probe) are serialized "
-        "here and cold starts deserialize instead of recompiling — "
-        "keyed by layout + jaxlib/backend fingerprint + lowered-program "
-        "hash, re-verified by the audit census before first dispatch, "
-        "falling back to a clean recompile on any corruption "
-        "(docs/performance.md)",
-    )
-    ap.add_argument(
         "--resume",
         default=None,
         help="checkpoint to resume from (any layout -> any layout), or "
@@ -245,25 +219,6 @@ def main(argv=None):
         "--profile-dir",
         default=None,
         help="write a jax.profiler trace of one training epoch to this directory",
-    )
-    ap.add_argument(
-        "--dispatch-probe",
-        action="store_true",
-        help="after training, measure the op-issue roofline: dispatch "
-        "extra training epochs under the jax profiler and report the "
-        "share of host wall NOT covered by op execution "
-        "(docs/performance.md 'The measured dispatch-overhead share'). "
-        "The probe TRAINS the epochs it times (the epoch program donates "
-        "its state) — it runs after the final model hash is printed, so "
-        "the hash stays the training result",
-    )
-    ap.add_argument(
-        "--dispatch-probe-out",
-        default=None,
-        metavar="JSON",
-        help="also write the probe's measurement as a versioned bench "
-        "record (bench: dispatch_overhead) to this file; implies "
-        "--dispatch-probe",
     )
     ap.add_argument(
         "--metrics-out",
@@ -382,10 +337,9 @@ def main(argv=None):
         "correctness oracle); 'mpmd' compiles one program per stage role "
         "and dispatches them asynchronously from the host with device-to-"
         "device relays (arXiv 2412.14374) — bitwise-identical weights, "
-        "no noop-tick dispatches (the measured op-issue roofline, "
-        "docs/performance.md). mpmd drives the epoch loop (no "
-        "--fused-run) and excludes --zero1/--grad-bucket-bytes/"
-        "--clip-norm/--kernel-backend pallas for now",
+        "no noop-tick dispatches (docs/performance.md). mpmd drives the "
+        "epoch loop (no --fused-run) and excludes --zero1/--clip-norm/"
+        "--kernel-backend pallas for now",
     )
     ap.add_argument(
         "--kernel-backend",
@@ -452,9 +406,6 @@ def main(argv=None):
             "stages (the chunked stash rotation is its own lifetime "
             "discipline)"
         )
-    # "plan is active" mirrors faults.FaultPlan.parse: any non-empty
-    # comma-separated part is an injection (checked without importing the
-    # package — argparse time stays jax-free)
     if args.zero1 and args.zero is not None and args.zero != 1:
         ap.error(
             f"conflicting dp-stage selectors: --zero1 and --zero {args.zero} "
@@ -475,12 +426,6 @@ def main(argv=None):
             "stage 3 materializes parameters per tick via all-gather — "
             "drop one of the two flags"
         )
-    if zero_stage == 3 and args.grad_bucket_bytes:
-        ap.error(
-            "--zero 3 syncs gradients per tick (reduce-scatter into the "
-            "persistent shard carry) — there is no tail collective for "
-            "--grad-bucket-bytes to bucket; drop one of the two flags"
-        )
     if zero_stage and args.runtime == "mpmd":
         ap.error(
             f"--runtime mpmd does not support --zero {zero_stage} yet: the "
@@ -494,6 +439,9 @@ def main(argv=None):
             "stages 2-3 replace with the block-cyclic shard layout — drop "
             "one of the two flags"
         )
+    # "plan is active" mirrors faults.FaultPlan.parse: any non-empty
+    # comma-separated part is an injection (checked without importing the
+    # package — argparse time stays jax-free)
     faults_env = os.environ.get("SHALLOWSPEED_FAULTS", "")
     if args.fused_run and any(p.strip() for p in faults_env.split(",")):
         ap.error(
@@ -537,7 +485,6 @@ def main(argv=None):
             momentum=args.momentum,
             virtual_stages=args.virtual_stages,
             zero=zero_stage,
-            grad_bucket_bytes=args.grad_bucket_bytes,
             backward_split=args.backward_split,
             recompute=args.recompute,
             scan_unroll=args.scan_unroll,
@@ -548,7 +495,6 @@ def main(argv=None):
             checkpoint_dir=args.checkpoint_dir,
             checkpoint_keep=args.keep,
             async_checkpoint=args.async_checkpoint,
-            aot_cache_dir=args.aot_cache,
             runtime=args.runtime,
             digests=args.digests,
         )
@@ -751,72 +697,6 @@ def main(argv=None):
     if args.dp > 1:
         print("DP replicas in sync ✓")
     print("final model hash:", run.model_hash())
-    if args.dispatch_probe or args.dispatch_probe_out:
-        # the measured op-issue roofline (docs/performance.md): extra
-        # profiled epochs AFTER the hash print, so the hash above stays
-        # the training result the drivers compare
-        rec = run.measure_dispatch_overhead()
-        share = rec["dispatch_overhead"]
-        if share is None:
-            print(
-                "dispatch overhead: unmeasurable — "
-                + rec.get("reason", "no op events")
-            )
-        else:
-            print(
-                f"dispatch overhead: >= {share * 100:.1f}% of epoch wall "
-                f"is host-side op issue (op busy "
-                f"{rec['device_busy_s'] * 1e3:.1f} ms of "
-                f"{rec['host_wall_s'] * 1e3:.1f} ms uninstrumented wall "
-                f"over {rec['repeats']} epoch(s); {rec['op_events']} op "
-                f"events, source {rec['op_source']}, profiler inflation "
-                f"{rec['profiler_inflation']:.2f}x)"
-            )
-        if not rec["window_valid"]:
-            # the machine-checked DISPATCH_r01 caveat: an invalid probe
-            # window's share must never be quoted as a measurement
-            print(
-                "dispatch-probe window INVALID: "
-                + (rec["window_invalid_reason"] or "unknown")
-            )
-        if args.dispatch_probe_out:
-            bench_rec = {
-                "bench": "dispatch_overhead",
-                "bench_version": 1,
-                "config": {
-                    "dp": args.dp,
-                    "pp": args.pp,
-                    "tp": args.tp,
-                    "schedule": args.schedule,
-                    "global_batch_size": args.global_batch_size,
-                    "mubatches": args.mubatches,
-                    "backward_split": args.backward_split,
-                    "grad_bucket_bytes": args.grad_bucket_bytes,
-                    "platform": rec["platform"],
-                },
-                "value": share,
-                "unit": "fraction of epoch wall not covered by op execution",
-                **{
-                    k: rec[k]
-                    for k in (
-                        "program", "runtime", "repeats", "host_wall_s",
-                        "host_wall_instrumented_s", "profiler_inflation",
-                        "device_busy_s", "device_comm_s",
-                        "device_compute_s", "op_events", "op_source",
-                        "events_per_batch", "window_valid",
-                        "window_invalid_reason",
-                        "dispatch_overhead_instrumented", "provenance",
-                    )
-                },
-            }
-            from shallowspeed_tpu.observability.metrics import json_safe
-
-            with open(args.dispatch_probe_out, "w", encoding="utf-8") as f:
-                f.write(
-                    json.dumps(json_safe(bench_rec), indent=2, allow_nan=False)
-                    + "\n"
-                )
-            print(f"dispatch-overhead record written: {args.dispatch_probe_out}")
     if metrics is not None:
         metrics.close()
         print(f"telemetry written: {metrics.path}")
