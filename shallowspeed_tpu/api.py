@@ -493,6 +493,29 @@ class TrainingSession:
                 f"training split has {self._train_ds.raw_len} samples — fewer "
                 f"than one global batch of {self.B}"
             )
+        # the orientation in which the training set is resident: the
+        # sequential microbatch scan's choice, from the microbatch's shape
+        # against the chip's tile, the first Linear's and the precision
+        # (trainer.data_layout); a mesh places and slices its own batches
+        # (executor.py) and stays row-major.
+        # Provenance like the mesh's layout: a run's record has to say
+        # which of the two epoch programs it timed
+        mubatch_rows = local_batch // mubatches
+        self._data_layout = (
+            trainer.data_layout(
+                mubatch_rows, sizes, self.precision,
+                scanned=not (
+                    fuse_mubatches or megakernel or epoch_kernel or run_kernel
+                ),
+            )
+            if self._sequential
+            else "row_major"
+        )
+        if self._metrics.enabled:
+            self._metrics.event(
+                "data_layout",
+                layout=self._data_layout, mb=mubatch_rows, F=int(sizes[0]),
+            )
         Xb, Yb = self._train_ds.epoch_arrays()
         if self.runtime == "mpmd":
             # the MPMD host scheduler feeds per-microbatch device_puts to
@@ -502,7 +525,14 @@ class TrainingSession:
             self._Y = Yb.reshape(nb, self.B, Yb.shape[-1])
         else:
             with self._metrics.span("device_put"):
-                self._X = jnp.asarray(Xb.reshape(nb, self.B, Xb.shape[-1]))
+                if self._data_layout != "feature_major":
+                    Xb = Xb.reshape(nb, self.B, Xb.shape[-1])
+                # else placed by microbatch, as the dataset hands it over:
+                # the chip stores THAT shape features-major, so
+                # trainer.feature_major below is one straight copy; from
+                # (nb, B, F) it compiles to two passes and a third
+                # set-sized buffer
+                self._X = jnp.asarray(Xb)
                 self._Y = jnp.asarray(Yb.reshape(nb, self.B, Yb.shape[-1]))
         self.batches_per_epoch = nb
 
@@ -755,14 +785,21 @@ class TrainingSession:
                 with_grad_norm=self._epoch_aux,
                 with_step_stats=self._step_aux,
                 with_digests=self._digests,
+                x_layout=self._data_layout,
             )
             self._predict = trainer.make_predict(self.spec, precision=self.precision)
             self._run_kwargs = dict(
                 precision=self.precision, fuse_mubatches=fuse_mubatches,
                 unroll=scan_unroll, clip_norm=clip_norm, megakernel=megakernel,
                 epoch_kernel=epoch_kernel or run_kernel,
+                x_layout=self._data_layout,
             )
-            self._Xe = self._X.reshape(nb, self.M, self.B // self.M, -1)
+            # one device program either way, the set in and the set out:
+            # the peak of two sets is this transient (PERF.md §4)
+            if self._data_layout == "feature_major":
+                self._Xe = trainer.feature_major(self._X)
+            else:
+                self._Xe = self._X.reshape(nb, self.M, self.B // self.M, -1)
             self._Ye = self._Y.reshape(nb, self.M, self.B // self.M, -1)
             self._X = self._Y = None  # the microbatched views are the only users
         else:
@@ -2370,6 +2407,14 @@ class TrainingSession:
 
     def model_hash(self) -> str:
         return utils.model_hash(self.params())
+
+    @property
+    def data_layout(self):
+        """``"feature_major"`` or ``"row_major"``: the orientation in which
+        this session keeps its training set resident, hence which epoch
+        program it runs (``trainer.data_layout``; the ``data_layout``
+        metrics event carries the same with ``mb`` and ``F``)."""
+        return self._data_layout
 
     def placement(self):
         """Where the mesh put the parameters — None on the sequential path.

@@ -61,9 +61,60 @@ def _digest_aux(params, grads):
     }
 
 
+def data_layout(mubatch_rows, sizes, precision, scanned=True):
+    """The orientation in which the sequential path keeps its training set
+    resident: ``"feature_major"``, X as ``(num_batches, M, in_dim, mubatch)``,
+    or ``"row_major"``, X as ``(num_batches, M, mubatch, in_dim)``.
+
+    The TPU runtime stores an array in the compact (8, 128)-tiled layout
+    that pads least, whatever its shape says: with ``in_dim`` 784 (no
+    multiple of 128) and 2,048-row microbatches (one) it lays the ROW axis
+    minor, the set is resident feature-major under a row-major shape, and
+    the scan re-lays-out each step's slab of X before the first Linear may
+    read it (a 26 MB transpose per step at B 8,192). Feature-major in shape
+    too, the scan reads ``x.T`` as it lies: the transposition moves out of
+    a copy of its own and into the first Linear's two matmuls, off an
+    ``in_dim``-wide operand and onto ``sizes[1]``-wide results. All four
+    conditions are what a v5e measured (PERF.md section 6, PR 31):
+
+    - a microbatch tiles rows-minor without padding (``mubatch_rows`` a
+      multiple of 128 lanes, ``in_dim`` of 8 sublanes): at 32 rows the shape
+      would pad 32 rows to 128 lanes, four times the set;
+    - the first Linear is narrower than its input by half or more: an
+      epoch at 784 -> 128, 256, 384 took 0.85, 0.98, 0.96 of the row-major
+      time, at 512 0.99, at 768 and above 1.04 to 1.05;
+    - ``Precision.HIGHEST``, six passes over an operand read once: at
+      ``DEFAULT`` the set is packed to bfloat16 first and the result swung
+      with the shape (0.90x the time at 2,048 rows, 1.52x at 16,384);
+    - ``scanned``: the fused and kernel paths reshape a whole batch to
+      ``(rows, in_dim)`` and stay row-major.
+    """
+    in_dim, first_out = sizes[0], sizes[1]
+    if (
+        scanned
+        and precision == lax.Precision.HIGHEST
+        and mubatch_rows % 128 == 0
+        and in_dim % 8 == 0
+        and 2 * first_out <= in_dim
+    ):
+        return "feature_major"
+    return "row_major"
+
+
+@jax.jit
+def feature_major(X):
+    """``(num_batches, M, mubatch, in_dim)`` -> ``(num_batches, M, in_dim,
+    mubatch)``, the resident argument of an ``x_layout="feature_major"``
+    program. Where ``data_layout`` engages, the chip already stores the
+    argument features-major and this compiles to one copy with no temporary
+    (tests/test_op_index.py): the set in and the set out."""
+    return X.swapaxes(2, 3)
+
+
 def _make_batch_step(
     spec: ModelSpec, opt, precision, fuse_mubatches=False, clip_norm=None,
     megakernel=False, with_grad_norm=False, with_digests=False,
+    x_layout="row_major",
 ):
     """The shared per-batch body: microbatch gradient accumulation + optimizer
     apply. Used by both the per-batch step and the epoch scan.
@@ -91,7 +142,16 @@ def _make_batch_step(
     kernel (pallas_ops.fused_train_call). Identical float math; exists
     because the epoch is op-issue-latency bound (docs/performance.md
     roofline) and one op per batch is the shortest possible serial chain.
+
+    ``x_layout`` (``data_layout``'s answer, scanned path only): with
+    ``"feature_major"`` ``xb`` is ``(M, in_dim, mubatch)`` and each
+    microbatch is read transposed, as it lies.
     """
+    if x_layout != "row_major" and (fuse_mubatches or megakernel):
+        raise ValueError(
+            f"x_layout={x_layout!r} is the microbatch scan's: the fused and "
+            f"kernel paths reshape a batch to (rows, in_dim)"
+        )
     if megakernel:
         if with_grad_norm or with_digests:
             raise ValueError(
@@ -161,6 +221,12 @@ def _make_batch_step(
         def accumulate(carry, mxy):
             acc, loss = carry
             x, y = mxy
+            if x_layout == "feature_major":
+                # AT the microbatch: a swapaxes of the whole set at the top
+                # of the program is the bitcast the compiler makes anyway,
+                # and gives the per-step transpose back
+                with scope("batch"):
+                    x = x.T
             out, res = model_forward(params, spec, x, precision=precision)
             _, grads = model_backward(params, spec, res, y, precision=precision)
             with scope("loss"):
@@ -325,11 +391,13 @@ def make_train_epoch(
     with_grad_norm=False,
     with_step_stats=False,
     with_digests=False,
+    x_layout="row_major",
 ):
     """Whole-epoch scan: ``epoch(params, opt_state, X, Y) -> (params,
-    opt_state, mean_loss)`` with X: (num_batches, M, mubatch, in_dim). One
-    XLA program per epoch; mean_loss is the true mean batch training loss
-    (same definition as the pipeline executor's).
+    opt_state, mean_loss)`` with X: (num_batches, M, mubatch, in_dim), or
+    (num_batches, M, in_dim, mubatch) under ``x_layout="feature_major"``
+    (see ``data_layout``). One XLA program per epoch; mean_loss is the true
+    mean batch training loss (same definition as the pipeline executor's).
 
     ``unroll``: lax.scan unroll factor over batches — for this model each
     batch body is a handful of small matmuls, so unrolling amortizes the
@@ -371,6 +439,7 @@ def make_train_epoch(
         batch_step = _make_batch_step(
             spec, opt, precision, fuse_mubatches, clip_norm, megakernel,
             with_grad_norm or with_step_stats, with_digests,
+            x_layout=x_layout,
         )
         epoch_core = _make_epoch_core(
             batch_step, unroll, with_grad_norm, with_step_stats, with_digests
@@ -446,6 +515,7 @@ def make_train_run(
     epoch_kernel=False,
     run_kernel=False,
     with_grad_norm=False,
+    x_layout="row_major",
 ):
     """Whole-RUN scan: every epoch (and its validation accuracy) in ONE program.
 
@@ -478,6 +548,8 @@ def make_train_run(
     returns one EXTRA trailing output, an aux dict whose ``"grad_norm"``
     is the (n_epochs,) vector of per-epoch mean pre-clip global gradient
     norms — ordinary scan outputs, so the run stays one fused program.
+
+    ``x_layout``: as ``make_train_epoch``'s (X is scanned on axis 0 only).
     """
     if with_grad_norm and (megakernel or epoch_kernel or run_kernel):
         raise ValueError(
@@ -524,7 +596,7 @@ def make_train_run(
     else:
         batch_step = _make_batch_step(
             spec, opt, precision, fuse_mubatches, clip_norm, megakernel,
-            with_grad_norm,
+            with_grad_norm, x_layout=x_layout,
         )
         epoch_core = _make_epoch_core(batch_step, unroll, with_grad_norm)
 

@@ -226,12 +226,26 @@ def v5e_mesh():
     return Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "pp"))
 
 
+def _compile_off_cache(lowered):
+    """``lowered.compile()`` with the persistent cache off: a compile for a
+    described chip is written to it but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 @functools.lru_cache(maxsize=None)
 def _compiled_tick_step(mesh, sizes, batch, precision, M=4):
     """``Compiled.as_text()`` of the training step for ``mesh`` (described
     devices: shapes in, no array), and the program's ring geometry. One
     compile per case, whichever test asks first."""
-    from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from shallowspeed_tpu import model as Mo
@@ -256,16 +270,7 @@ def _compiled_tick_step(mesh, sizes, batch, precision, M=4):
     step = E.make_pipeline_step(
         mesh, spec, prog, mb, SGD(0.006), precision=PRECISIONS[precision]
     )
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep it out
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = step.lower(stacked, flags, (), x, y).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    text = _compile_off_cache(step.lower(stacked, flags, (), x, y)).as_text()
     return text, prog.n_stash_slots + 1, mb
 
 
@@ -382,3 +387,89 @@ def test_relays_run_under_their_predicate_and_copy_no_mailbox(v5e_mesh, case):
         if ins["opcode"] == "copy-start":
             assert _moves_memory_space_only(ins["type"]), ins["name"]
     assert issued <= seen  # the mailboxes were found where they are written
+
+
+# -- the sequential path's resident set ------------------------------------
+#
+# The runtime stores ``X: f32[96,4,2048,784]`` rows-minor (``{2,3,1,0}``:
+# 784 is no multiple of 128, 2,048 is), so a scan that asks for row-major
+# microbatches re-lays-out each step's slab: a 26 MB ``copy`` per step in
+# ``mnist-mlp.seq-b8192``. ``trainer.data_layout`` keeps such a set
+# feature-major in shape too, and the microbatch scan reads it as it lies.
+
+SEQ_CASES = {
+    # mnist-mlp.seq-b8192: engages
+    "b8192": dict(batch=8192, steps=96, layout="feature_major", temporaries=0),
+    # mnist-mlp.seq-b128: 32-row microbatches, the bypass. Its temporaries
+    # are the whole set, re-laid-out row-major on every call (the runtime
+    # stores it step-axis minor, PERF.md section 4): a later PR lowers this pin
+    "b128": dict(
+        batch=128, steps=6144, layout="row_major", temporaries=3_021_189_120
+    ),
+}
+SEQ_SIZES, SEQ_M = (784, 128, 127, 126, 125, 124, 123, 10), 4
+SEQ_ARGUMENT_BYTES = 2_498_448_896  # X, Y and the parameters: nothing padded
+
+
+@pytest.mark.parametrize("case", list(SEQ_CASES))
+def test_sequential_step_copies_no_slab_of_x(v5e_mesh, case):
+    """The epoch program at the benchmark's shapes, built the way the session
+    builds it (``data_layout`` decides, ``feature_major`` re-orients): where
+    the orientation engages no ``copy`` anywhere in the program has a step's
+    slab of X for its result, in either orientation, and nothing is padded;
+    at 32-row microbatches the program keeps the shapes and the bytes it had."""
+    from jax.sharding import SingleDeviceSharding
+
+    from shallowspeed_tpu import model as Mo
+    from shallowspeed_tpu import trainer
+    from shallowspeed_tpu.api import PRECISIONS
+    from shallowspeed_tpu.observability.program_audit import parse_hlo
+    from shallowspeed_tpu.optimizer import SGD
+
+    cfg = SEQ_CASES[case]
+    one_chip = SingleDeviceSharding(v5e_mesh.devices.flat[0])
+
+    def described(shape):
+        return jax.ShapeDtypeStruct(shape, np.float32, sharding=one_chip)
+
+    nb, mb, F = cfg["steps"], cfg["batch"] // SEQ_M, SEQ_SIZES[0]
+    layout = trainer.data_layout(mb, SEQ_SIZES, PRECISIONS["highest"])
+    assert layout == cfg["layout"]
+    placed = (nb, SEQ_M, mb, F)  # what the session places, by microbatch
+    x_shape = placed
+    if layout == "feature_major":
+        turn = _compile_off_cache(trainer.feature_major.lower(described(placed)))
+        x_shape = tuple(turn.out_info.shape)
+        assert x_shape == (nb, SEQ_M, F, mb)
+        # the set in and the set out: the transient peak of two sets that
+        # the reshape it replaces had (PERF.md section 4), and no third
+        assert turn.memory_analysis().temp_size_in_bytes == 0
+
+    spec = Mo.make_model_spec(SEQ_SIZES, 1, cfg["batch"])
+    params = jax.tree.map(lambda a: described(a.shape), Mo.init_model(spec))
+    epoch = trainer.make_train_epoch(
+        spec, SGD(0.006), precision=PRECISIONS["highest"], x_layout=layout
+    )
+    compiled = _compile_off_cache(
+        epoch.lower(
+            params, (), described(x_shape),
+            described((nb, SEQ_M, mb, SEQ_SIZES[-1])),
+        )
+    )
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == SEQ_ARGUMENT_BYTES
+    assert memory.temp_size_in_bytes == cfg["temporaries"]
+    if layout == "row_major":
+        return  # the program the bypass always ran
+    instrs, _ = parse_hlo(compiled.as_text())
+
+    def dims(ins):
+        found = re.search(r"\[([\d,]*)\]", ins["type"])
+        return tuple(int(d) for d in found.group(1).split(",") if d)
+
+    slabs = {(SEQ_M, mb, F), (SEQ_M, F, mb), (1, SEQ_M, mb, F), (1, SEQ_M, F, mb)}
+    slab_copies = [
+        i["name"] for i in instrs.values()
+        if i["opcode"] in ("copy", "transpose") and dims(i) in slabs
+    ]
+    assert not slab_copies, slab_copies
