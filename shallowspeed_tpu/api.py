@@ -44,7 +44,7 @@ from shallowspeed_tpu.checkpoint import (
     save_checkpoint,
     step_checkpoint_path,
 )
-from shallowspeed_tpu.data import Dataset, default_data_dir
+from shallowspeed_tpu.data import Dataset, default_data_dir, packed_counts
 from shallowspeed_tpu.observability import NullMetrics, costmodel, program_audit
 from shallowspeed_tpu.observability import scopes
 from shallowspeed_tpu.observability import span as host_span
@@ -88,6 +88,7 @@ class TrainingSession:
         self,
         sizes=FLAGSHIP_SIZES,
         model=None,
+        seq_len=None,
         dp=1,
         pp=1,
         tp=1,
@@ -166,8 +167,26 @@ class TrainingSession:
         # gelu family adds residual slots and f32 grad-multiplier masks),
         # and every zoo model keeps the 784-wide MNIST input so the data
         # pipeline, checkpoints and serving slots compose unchanged.
+        # A TOKEN model (model.TOKEN_MODELS, or the keys of a published
+        # config.json given as a dictionary) is a function of token ids of
+        # ``seq_len`` a row: it has no ``sizes``, trains on a packed token
+        # set, and runs the sequential path only (checked below, once the
+        # layout is known).
         self.model_name = model
-        if model is not None:
+        token_config = (
+            Mo.token_model_config(model) if Mo.is_token_model(model) else None
+        )
+        self._token = token_config is not None
+        if self._token:
+            if isinstance(model, dict):
+                self.model_name = model.get("model_type", "token-model")
+            sizes, act = (), "relu"
+        elif seq_len is not None:
+            raise ValueError(
+                "seq_len is a token model's sequence length; "
+                f"model={model!r} takes rows of features"
+            )
+        elif model is not None:
             sizes, act = Mo.resolve_model(model)
         else:
             act = "relu"
@@ -237,6 +256,28 @@ class TrainingSession:
         self.V = virtual_stages
         self._sequential = dp == 1 and pp == 1 and virtual_stages == 1 and tp == 1
         self._kernel_backend = kernel_backend
+        if self._token:
+            wanted = {
+                "a mesh layout (dp, pp, tp or virtual_stages > 1)": not self._sequential,
+                "zero": bool(zero) or zero1,
+                "fuse_mubatches": fuse_mubatches,
+                "the pallas kernels (megakernel, epoch_kernel, run_kernel, "
+                "kernel_backend='pallas')": megakernel or epoch_kernel
+                or run_kernel or kernel_backend == "pallas",
+                "runtime='mpmd'": runtime != "lockstep",
+                "digests": digests,
+                "checkpoints (resume, checkpoint_dir)": resume is not None
+                or checkpoint_dir is not None,
+            }
+            refused = [what for what, asked in wanted.items() if asked]
+            if refused:
+                raise ValueError(
+                    f"token model {self.model_name!r} runs the sequential "
+                    f"one-chip path (dp = pp = tp = 1) only; asked for: "
+                    f"{'; '.join(refused)}. The mesh executor's stage "
+                    "functions, its kernels and the checkpoint format are "
+                    "written for stacks of {W, b} Linears (ROADMAP R0a, D2)"
+                )
         if kernel_backend == "pallas" and act != "relu":
             raise ValueError(
                 "kernel_backend='pallas' hard-codes the relu/identity slot "
@@ -456,7 +497,10 @@ class TrainingSession:
 
         data_dir = data_dir or default_data_dir()
         self._data_dir = data_dir
-        self._train_ds = Dataset(data_dir, self.B, mubatch_size=local_batch // mubatches)
+        self._train_ds = Dataset(
+            data_dir, self.B, mubatch_size=local_batch // mubatches,
+            tokens=self._token,
+        )
         self._train_ds.load(0, 1)
         # validation split is loaded lazily on the first accuracy() call, so
         # eval-free runs (train.py --no-eval, benchmarks) pay neither the host
@@ -508,15 +552,28 @@ class TrainingSession:
                     fuse_mubatches or megakernel or epoch_kernel or run_kernel
                 ),
             )
-            if self._sequential
+            if self._sequential and not self._token
             else "row_major"
         )
+        Xb, Yb = self._train_ds.epoch_arrays()
         if self._metrics.enabled:
             self._metrics.event(
                 "data_layout",
-                layout=self._data_layout, mb=mubatch_rows, F=int(sizes[0]),
+                layout=self._data_layout, mb=mubatch_rows, F=int(Xb.shape[-1]),
             )
-        Xb, Yb = self._train_ds.epoch_arrays()
+        if self._token:
+            if seq_len is None or Xb.shape[-1] != seq_len + 1:
+                raise ValueError(
+                    f"the token set's rows hold {Xb.shape[-1]} ids; a token "
+                    f"model needs seq_len (got {seq_len}) and rows of "
+                    f"seq_len + 1"
+                )
+            # what the resident set holds, for the readers of a trace: plain
+            # numbers, handed to observability.scopes with the epoch
+            # program (``_run_epoch_program``)
+            self._token_counts = packed_counts(
+                self._train_ds.target_y[: nb * self.B]
+            )
         if self.runtime == "mpmd":
             # the MPMD host scheduler feeds per-microbatch device_puts to
             # the endpoint stages' sub-meshes itself; the epoch arrays
@@ -537,7 +594,20 @@ class TrainingSession:
         self.batches_per_epoch = nb
 
         n_model_stages = pp * virtual_stages
-        self.spec = Mo.make_model_spec(sizes, n_model_stages, self.B, act=act)
+        if self._token:
+            self.spec = Mo.make_token_spec(
+                token_config, seq_len, self.B, mubatch_rows=mubatch_rows
+            )
+            # an id outside the table raises nothing on the device (the
+            # lookup clamps it, the scatter-add drops it)
+            ids = self._train_ds.input_X
+            if ids.min() < 0 or ids.max() >= self.spec.vocab_size:
+                raise ValueError(
+                    f"token ids span {ids.min()}..{ids.max()}; the model "
+                    f"holds a vocabulary of {self.spec.vocab_size}"
+                )
+        else:
+            self.spec = Mo.make_model_spec(sizes, n_model_stages, self.B, act=act)
         # device-major stage placement for virtual chunks (identity otherwise)
         self._order = (
             E.interleave_order(n_model_stages, pp) if virtual_stages > 1 else None
@@ -684,6 +754,8 @@ class TrainingSession:
                 # legacy epoch-boundary snapshot: ``epoch`` is the last
                 # COMPLETED epoch
                 self.epoch = meta["epoch"] + 1
+        elif self._token:
+            host_params = Mo.init_token_model(self.spec)
         else:
             host_params = Mo.init_model(self.spec)
 
@@ -787,7 +859,10 @@ class TrainingSession:
                 with_digests=self._digests,
                 x_layout=self._data_layout,
             )
-            self._predict = trainer.make_predict(self.spec, precision=self.precision)
+            self._predict = (
+                None if self._token
+                else trainer.make_predict(self.spec, precision=self.precision)
+            )
             self._run_kwargs = dict(
                 precision=self.precision, fuse_mubatches=fuse_mubatches,
                 unroll=scan_unroll, clip_norm=clip_norm, megakernel=megakernel,
@@ -976,7 +1051,15 @@ class TrainingSession:
                 * dp
             )
         self._cost_model = costmodel.CostModel(
-            sizes=self.spec.sizes,
+            sizes=sizes if self._token else self.spec.sizes,
+            flops_per_sample=(
+                # attention over the pairs this set's packing admits
+                costmodel.token_train_flops_per_sample(
+                    self.spec,
+                    self._token_counts["pairs"] / self._token_counts["tokens"],
+                )
+                if self._token else None
+            ),
             global_batch=self.B,
             batches_per_epoch=self.batches_per_epoch,
             n_devices=1 if self._sequential else dp * pp * self.tp,
@@ -1020,6 +1103,17 @@ class TrainingSession:
             )
 
     # -- training -----------------------------------------------------------
+
+    def _mlp_only(self, what):
+        """Refuse what is written for a stack of Linears on rows of features
+        (inference slots, the validation split, the checkpoint format, the
+        fused multi-epoch run) when the session trains a token model."""
+        if self._token:
+            raise ValueError(
+                f"{what} is not available for token model "
+                f"{self.model_name!r}: it is written for stacks of {{W, b}} "
+                f"Linears on rows of features (ROADMAP R0a)"
+            )
 
     def _epoch_args(self):
         """The layout's runtime argument tuple for one epoch."""
@@ -1359,8 +1453,10 @@ class TrainingSession:
         the MPMD runtime's epoch is a host loop over stage programs, not one
         program, and is not registered."""
         if args[-1].shape != self._registered_shape and self.runtime != "mpmd":
-            scopes.register_program(self._epoch_fn, args)
+            name = scopes.register_program(self._epoch_fn, args)
             self._registered_shape = args[-1].shape
+            if self._token:
+                scopes.record_counts(name, self._token_counts)
         metrics = self._metrics if self._metrics.enabled else None
         with host_span("epoch/dispatch", metrics):
             out = self._epoch_fn(*args)
@@ -1533,6 +1629,7 @@ class TrainingSession:
         snapshots above non-finite/corrupt ones, so when rotation does
         fire it reclaims the stale unusable pile, never a healthy
         snapshot.)"""
+        self._mlp_only("save_step_checkpoint()")
         if self._ckpt_dir is None:
             raise ValueError(
                 "no checkpoint_dir configured on this session"
@@ -1803,6 +1900,7 @@ class TrainingSession:
         readbacks are gone. Matches the reference's epoch structure,
         train.py:132-137.
         """
+        self._mlp_only("train_run()")
         if epochs <= 0:
             raise ValueError("epochs must be positive")
         if self.runtime == "mpmd":
@@ -1907,6 +2005,7 @@ class TrainingSession:
         ``train_run(epochs, with_eval)``, so e.g. a profiler trace around
         that call captures steady-state device execution, not compilation.
         """
+        self._mlp_only("warm_run()")
         if epochs <= 0:
             raise ValueError("epochs must be positive")
         if self.runtime == "mpmd":
@@ -2034,6 +2133,7 @@ class TrainingSession:
         at most len(ladder) compiled programs ever, and each slot computes
         bitwise-identically in every rung program (the serving engine's
         parity contract rides on exactly this property)."""
+        self._mlp_only("predict()")
         x = np.asarray(x, np.float32)
         n = x.shape[0]
         out_dim = self.spec.out_dim
@@ -2238,6 +2338,7 @@ class TrainingSession:
         stage 0 while request k-1 occupies a later stage. This is the
         measured tail-latency payoff next to the rung program's
         makespan-quantized dispatch (MPMD_r01.json)."""
+        self._mlp_only("predict_async()")
         if self._sequential or self.runtime != "mpmd":
             raise ValueError(
                 "predict_async streams through the MPMD per-stage chain — "
@@ -2280,6 +2381,7 @@ class TrainingSession:
 
     def accuracy(self) -> float:
         """Argmax accuracy over the full validation split."""
+        self._mlp_only("accuracy()")
         if self._vx is None:
             self._load_val()
         with self._metrics.span("eval"):
@@ -2356,6 +2458,7 @@ class TrainingSession:
         and the discovery->load TOCTOU window (the serving engine's
         watcher polls a directory a concurrent trainer keeps rotating)
         is closed by construction."""
+        self._mlp_only("load_weights()")
         if verified is not None:
             host_params, loaded_spec, meta = assemble_checkpoint(
                 path, verified[0], verified[1], self.pp * self.V, self.B
@@ -2406,6 +2509,8 @@ class TrainingSession:
         return meta
 
     def model_hash(self) -> str:
+        if self._token:
+            return utils.tree_hash(self.params())
         return utils.model_hash(self.params())
 
     @property
@@ -2517,6 +2622,7 @@ class TrainingSession:
         return self._logical_state_from_raw(self._opt_state)
 
     def save(self, path):
+        self._mlp_only("save()")
         save_checkpoint(
             path,
             self.params(),

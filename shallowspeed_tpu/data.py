@@ -35,15 +35,64 @@ def _read_features(save_dir: Path, suffix: str) -> np.ndarray:
     )
 
 
+def _read_tokens(save_dir: Path, suffix: str):
+    """A packed token set: ``tokens_<suffix>.npy`` and ``segments_<suffix>
+    .npy``, both int32 ``(rows, seq_len + 1)`` (what benchmarks/datasets/
+    packed_tokens.py writes): a row's inputs are ``[:-1]``, its targets
+    ``[1:]``, and ``segments`` numbers the row's documents from 0."""
+    found = []
+    for name in ("tokens", "segments"):
+        path = save_dir / f"{name}_{suffix}.npy"
+        if not path.exists():
+            raise FileNotFoundError(
+                f"No token set at {path}: a token model trains on "
+                f"tokens_{suffix}.npy and segments_{suffix}.npy"
+            )
+        found.append(np.load(path).astype(np.int32))
+    tokens, segments = found
+    if tokens.shape != segments.shape or tokens.ndim != 2:
+        raise ValueError(
+            f"tokens {tokens.shape} and segments {segments.shape} must be one "
+            f"(rows, seq_len + 1) shape"
+        )
+    return tokens, segments
+
+
+def packed_counts(segments):
+    """What a packed token set holds: ``{"tokens", "documents", "pairs"}``
+    of the rows' INPUTS (``segments[:, :-1]``); ``pairs`` counts the (query,
+    key) pairs a causal, same-document mask admits, the query itself
+    included: a token at offset ``i`` of its document (within the row)
+    admits ``i + 1``."""
+    seg = np.asarray(segments)[:, :-1]
+    first = np.ones(seg.shape, bool)
+    first[:, 1:] = seg[:, 1:] != seg[:, :-1]
+    at = np.broadcast_to(np.arange(seg.shape[1]), seg.shape)
+    start = np.maximum.accumulate(np.where(first, at, 0), axis=1)
+    return {
+        "tokens": int(seg.size),
+        "documents": int(first.sum()),
+        "pairs": int((at - start + 1).sum()),
+    }
+
+
 class Dataset:
     """One split (train or val) of the MNIST-784-format dataset.
 
     Construction mirrors the reference's signature
     (dataset.py:19-31): ``mubatch_size`` is the per-DP-replica microbatch and
     must divide the local batch ``global_batch_size // DP_size``.
+
+    ``tokens=True`` reads a packed token set instead (``_read_tokens``):
+    ``input_X`` holds the rows' token ids and ``target_y`` their document
+    numbers, int32, and everything below (drop-last, shard, microbatch
+    slicing, ``epoch_arrays``) treats them as it treats features and labels.
     """
 
-    def __init__(self, save_dir, global_batch_size, mubatch_size, validation=False):
+    def __init__(
+        self, save_dir, global_batch_size, mubatch_size, validation=False,
+        tokens=False,
+    ):
         self.save_dir = Path(save_dir)
         if not self.save_dir.is_dir():
             raise FileNotFoundError(
@@ -53,6 +102,7 @@ class Dataset:
         self.mubatch_size = int(mubatch_size)
         self.local_batch_size = None
         self._val = validation
+        self._tokens = bool(tokens)
         self.input_X = None
         self.target_y = None
 
@@ -68,8 +118,11 @@ class Dataset:
             raise ValueError("microbatch size must divide the local batch size")
 
         suffix = "val" if self._val else "train"
-        X = _read_features(self.save_dir, suffix)
-        y = np.load(self.save_dir / f"y_{suffix}.npy").astype(np.float32)
+        if self._tokens:
+            X, y = _read_tokens(self.save_dir, suffix)
+        else:
+            X = _read_features(self.save_dir, suffix)
+            y = np.load(self.save_dir / f"y_{suffix}.npy").astype(np.float32)
         if len(X) != len(y):
             raise ValueError("feature/target length mismatch")
 
@@ -113,7 +166,8 @@ class Dataset:
     # -- TPU-friendly bulk access -------------------------------------------
 
     def epoch_arrays(self):
-        """Whole local shard as (num_batches, M, mubatch, dim) fp32 arrays.
+        """Whole local shard as (num_batches, M, mubatch, dim) fp32 arrays
+        (int32 ids and document numbers for a token set).
 
         Row order is identical to sequential microbatch iteration, so feeding
         these to a scanned step reproduces the reference's data order exactly.

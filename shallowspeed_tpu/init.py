@@ -9,6 +9,8 @@ the NumPy reference's loss" a checkable statement, and what makes the
 layout-independent model hash (utils.py) meaningful.
 """
 
+import zlib
+
 import numpy as np
 
 
@@ -26,3 +28,33 @@ def linear_init(in_dim: int, out_dim: int):
     )
     b = np.zeros((1, out_dim), dtype=np.float32)
     return np.asarray(w, dtype=np.float32), b
+
+
+def token_leaf_init(layer_index: int, name: str, shape, kind: str):
+    """One leaf of a token model, float32, from a stream seeded by the
+    layer's index and the leaf's name (so two layers of one shape differ, and
+    no layout or size of anything else moves a leaf's values).
+
+    ``weight``: N(0, 0.02), the family's initializer; ``ones``: a norm's
+    scale; ``taps``: U(-1/sqrt(K), 1/sqrt(K)) for K taps; ``a_log``: log of
+    U(1, 16) and ``dt_bias``: the inverse softplus of exp(U(log 0.001, log
+    0.1)), the Gated DeltaNet's published initial decay (assumed: the
+    configuration does not state them)."""
+    rs = np.random.Generator(
+        np.random.PCG64(zlib.crc32(f"{layer_index}/{name}".encode()))
+    )
+    if kind == "weight":  # drawn in float32: a billion of them, at set-up
+        leaf = rs.standard_normal(shape, dtype=np.float32) * np.float32(0.02)
+    elif kind == "ones":
+        leaf = np.ones(shape)
+    elif kind == "taps":
+        bound = shape[-1] ** -0.5
+        leaf = rs.uniform(-bound, bound, size=shape)
+    elif kind == "a_log":
+        leaf = np.log(rs.uniform(1.0, 16.0, size=shape))
+    elif kind == "dt_bias":
+        dt = np.exp(rs.uniform(np.log(0.001), np.log(0.1), size=shape))
+        leaf = dt + np.log(-np.expm1(-dt))
+    else:
+        raise ValueError(f"unknown leaf kind {kind!r}")
+    return np.asarray(leaf, dtype=np.float32)

@@ -29,12 +29,16 @@ holds whenever the last stage has at least one Linear.
 """
 
 import dataclasses
+import json
+from pathlib import Path
 from typing import Sequence
 
 import jax.numpy as jnp
+from jax import lax
 
 from shallowspeed_tpu import ops
-from shallowspeed_tpu.init import linear_init
+from shallowspeed_tpu.init import linear_init, token_leaf_init
+from shallowspeed_tpu.observability.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -385,3 +389,353 @@ def model_backward(
             head_group_rows=head_group_rows,
         )
     return g, grads_list
+
+
+# ---------------------------------------------------------------------------
+# Token models: a function of token ids, built from the keys of a published
+# ``config.json`` (not from a tuple of ``sizes``). One family so far,
+# ``olmo_hybrid``: layers of Gated DeltaNet (``linear_attention``) and of
+# full attention in the pattern ``layer_types`` gives, an embedding, a final
+# RMSNorm, an untied head, mean cross-entropy over the (sliced) vocabulary.
+# The equations are written out in benchmarks/references/olmo_hybrid.py.
+# The sequential path only (trainer.py); the mesh executor's stage functions
+# know Linears and nothing else (ROADMAP R0a, D2).
+#
+# Parameters: ONE stage whose layers are dictionaries of arrays: the
+# embedding ``{"E"}``, one dictionary per layer, the head ``{"norm", "W"}``.
+# Weights are (out, in), as every Linear's.
+# ---------------------------------------------------------------------------
+
+# name -> the configuration file, relative to the checkout: the published
+# keys live in ONE place, the file the benchmark's configuration names too
+TOKEN_MODELS = {"olmo-hybrid-7b": "benchmarks/configs/olmo-hybrid-7b.json"}
+
+_TOKEN_KEYS = (
+    "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
+    "num_attention_heads", "num_key_value_heads", "rms_norm_eps", "layer_types",
+    "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+    "linear_value_head_dim", "linear_conv_kernel_dim", "linear_allow_neg_eigval",
+)
+# what a config.json may say beside them, and the one value this code covers
+_TOKEN_FIXED = {
+    "model_type": "olmo_hybrid", "hidden_act": "silu", "attention_bias": False,
+    "tie_word_embeddings": False,
+}
+# residual bytes of one microbatch above which a layer's forward is run
+# again in the backward instead of kept
+_RECOMPUTE_ABOVE_BYTES = 1 << 30
+
+
+def is_token_model(model):
+    """A name of ``TOKEN_MODELS`` or a dictionary of a config.json's keys."""
+    return isinstance(model, dict) or model in TOKEN_MODELS
+
+
+def token_model_config(model):
+    """``model`` (a name of ``TOKEN_MODELS`` or the keys themselves) -> the
+    dictionary of the published keys."""
+    if isinstance(model, dict):
+        return model
+    path = Path(__file__).resolve().parent.parent / TOKEN_MODELS[model]
+    return json.loads(path.read_text())
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenModelSpec:
+    """Static description of a token model and the job's shape."""
+
+    vocab_size: int
+    hidden_size: int
+    intermediate_size: int
+    layer_types: tuple
+    num_attention_heads: int
+    rms_norm_eps: float
+    linear_num_heads: int
+    linear_key_head_dim: int
+    linear_value_head_dim: int
+    linear_conv_kernel_dim: int
+    linear_allow_neg_eigval: bool
+    seq_len: int
+    global_batch_size: int
+    recompute: bool = True  # run a layer's forward again in its backward
+    scan_chunk: int = ops.SCAN_CHUNK
+    attn_block: int = ops.ATTN_BLOCK
+    n_stages = 1
+
+    @property
+    def step_tokens(self):
+        return self.global_batch_size * self.seq_len
+
+
+def _layer_residual_bytes(cfg, tokens):
+    """Float32 bytes one layer keeps between its forward and its backward
+    where nothing is recomputed, roughly: the widest of its intermediates,
+    a handful of times."""
+    widest = max(cfg["intermediate_size"], 2 * cfg["hidden_size"])
+    return 4 * 8 * widest * tokens
+
+
+def make_token_spec(
+    config, seq_len, global_batch_size, mubatch_rows=None, recompute=None
+) -> TokenModelSpec:
+    """``config``: the published keys (``token_model_config``). Refuses what
+    the equations here do not cover. ``recompute=None`` decides from the
+    microbatch: kept where one microbatch's residuals of all layers stay
+    under ``_RECOMPUTE_ABOVE_BYTES``."""
+    missing = [k for k in _TOKEN_KEYS if k not in config]
+    if missing:
+        raise ValueError(f"token model configuration lacks {missing}")
+    for key, value in _TOKEN_FIXED.items():
+        if config.get(key, value) != value:
+            raise ValueError(f"token models cover {key}={value!r} only, got {config[key]!r}")
+    layer_types = tuple(config["layer_types"])
+    if len(layer_types) != config["num_hidden_layers"]:
+        raise ValueError("layer_types does not list num_hidden_layers layers")
+    unknown = set(layer_types) - {"linear_attention", "full_attention"}
+    if unknown:
+        raise ValueError(f"unknown layer types {sorted(unknown)}")
+    if config["num_key_value_heads"] != config["num_attention_heads"]:
+        raise ValueError("grouped key/value heads are not covered (ROADMAP R8)")
+    if config["linear_num_key_heads"] != config["linear_num_value_heads"]:
+        raise ValueError("linear_num_key_heads != linear_num_value_heads is not covered")
+    if (config.get("rope_parameters") or {}).get("rope_theta") is not None:
+        raise ValueError("rotary embeddings are not covered (ROADMAP R8)")
+    if config["hidden_size"] % config["num_attention_heads"]:
+        raise ValueError("num_attention_heads must divide hidden_size")
+    if seq_len is None or seq_len < 1:
+        raise ValueError("a token model needs seq_len (train.py --seq-len)")
+    if recompute is None:
+        rows = mubatch_rows or global_batch_size
+        recompute = (
+            len(layer_types) * _layer_residual_bytes(config, rows * seq_len)
+            > _RECOMPUTE_ABOVE_BYTES
+        )
+    return TokenModelSpec(
+        vocab_size=config["vocab_size"],
+        hidden_size=config["hidden_size"],
+        intermediate_size=config["intermediate_size"],
+        layer_types=layer_types,
+        num_attention_heads=config["num_attention_heads"],
+        rms_norm_eps=config["rms_norm_eps"],
+        linear_num_heads=config["linear_num_value_heads"],
+        linear_key_head_dim=config["linear_key_head_dim"],
+        linear_value_head_dim=config["linear_value_head_dim"],
+        linear_conv_kernel_dim=config["linear_conv_kernel_dim"],
+        linear_allow_neg_eigval=bool(config["linear_allow_neg_eigval"]),
+        seq_len=int(seq_len),
+        global_batch_size=int(global_batch_size),
+        recompute=bool(recompute),
+    )
+
+
+def token_layer_shapes(spec: TokenModelSpec):
+    """The model's layers in order, each ``{leaf name: (shape, kind)}``;
+    ``kind`` picks the leaf's initial values (``init.token_leaf_init``)."""
+    d, ff, v = spec.hidden_size, spec.intermediate_size, spec.vocab_size
+    h, dk, dv = spec.linear_num_heads, spec.linear_key_head_dim, spec.linear_value_head_dim
+    taps = spec.linear_conv_kernel_dim
+    mlp = {
+        "attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones"),
+        "W_gate": ((ff, d), "weight"), "W_up": ((ff, d), "weight"),
+        "W_down": ((d, ff), "weight"),
+    }
+    kinds = {
+        "linear_attention": {
+            "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
+            "Wv": ((h * dv, d), "weight"), "Wg": ((h * dv, d), "weight"),
+            "Wo": ((d, h * dv), "weight"),
+            "Wb": ((h, d), "weight"), "Wa": ((h, d), "weight"),
+            "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
+            "conv_v": ((h * dv, taps), "taps"),
+            "A_log": ((h,), "a_log"), "dt_bias": ((h,), "dt_bias"),
+            "o_norm": ((dv,), "ones"), **mlp,
+        },
+        "full_attention": {
+            "Wq": ((d, d), "weight"), "Wk": ((d, d), "weight"),
+            "Wv": ((d, d), "weight"), "Wo": ((d, d), "weight"),
+            "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"), **mlp,
+        },
+    }
+    return (
+        [{"E": ((v, d), "weight")}]
+        + [kinds[kind] for kind in spec.layer_types]
+        + [{"norm": ((d,), "ones"), "W": ((v, d), "weight")}]
+    )
+
+
+def init_token_model(spec: TokenModelSpec):
+    """Host-side deterministic init: one stage, a list of layers, each a
+    dictionary of float32 arrays seeded by the layer's index and the leaf's
+    name."""
+    return [[
+        {
+            name: token_leaf_init(index, name, shape, kind)
+            for name, (shape, kind) in layer.items()
+        }
+        for index, layer in enumerate(token_layer_shapes(spec))
+    ]]
+
+
+def _heads(x, heads):
+    """(rows, seq, heads * d) -> (rows, seq, heads, d)."""
+    return x.reshape(*x.shape[:2], heads, -1)
+
+
+def _linear_attention_mix(p, x, seg, spec, precision):
+    """The Gated DeltaNet mixer. -> ``(out, back)``; ``back(dout) -> (dx,
+    grads)``."""
+    h = spec.linear_num_heads
+    projected = {
+        name: ops.dense(x, p[name], precision)
+        for name in ("Wq", "Wk", "Wv", "Wg", "Wb", "Wa")
+    }
+    q1, conv_q = ops.conv_silu(projected["Wq"][0], p["conv_q"], seg)
+    k1, conv_k = ops.conv_silu(projected["Wk"][0], p["conv_k"], seg)
+    v1, conv_v = ops.conv_silu(projected["Wv"][0], p["conv_v"], seg)
+    (q2, k2), unit = ops.qk_l2norm(_heads(q1, h), _heads(k1, h))
+    (beta, log_decay), gates = ops.delta_gates(
+        projected["Wb"][0], projected["Wa"][0], p["A_log"], p["dt_bias"],
+        spec.linear_allow_neg_eigval,
+    )
+    o, scan = ops.gated_delta_scan(
+        q2, k2, _heads(v1, h), beta, log_decay, seg, chunk=spec.scan_chunk
+    )
+    gated, gate = ops.gated_head_norm(
+        o, _heads(projected["Wg"][0], h), p["o_norm"], spec.rms_norm_eps
+    )
+    out, out_back = ops.dense(gated.reshape(*x.shape[:2], -1), p["Wo"], precision)
+
+    def back(dout):
+        grads = {}
+        dgated, grads["Wo"] = out_back(dout)
+        do, dg, grads["o_norm"] = gate(dgated.reshape(gated.shape))
+        dq2, dk2, dv1, dbeta, dlog_decay = scan(do)
+        db, da, grads["A_log"], grads["dt_bias"] = gates((dbeta, dlog_decay))
+        dq1, dk1 = unit((dq2, dk2))
+        dq0, grads["conv_q"] = conv_q(dq1.reshape(q1.shape))
+        dk0, grads["conv_k"] = conv_k(dk1.reshape(k1.shape))
+        dv0, grads["conv_v"] = conv_v(dv1.reshape(v1.shape))
+        parts = []
+        for name, d in (
+            ("Wq", dq0), ("Wk", dk0), ("Wv", dv0), ("Wg", dg.reshape(v1.shape)),
+            ("Wb", db), ("Wa", da),
+        ):
+            dx_part, grads[name] = projected[name][1](d)
+            parts.append(dx_part)
+        return ops.fan_in(*parts), grads
+
+    return out, back
+
+
+def _full_attention_mix(p, x, seg, spec, precision):
+    """Full attention with QK-norm over the whole projection, no rotary
+    embedding, masked by document. -> ``(out, back)``."""
+    h = spec.num_attention_heads
+    q0, q_back = ops.dense(x, p["Wq"], precision)
+    k0, k_back = ops.dense(x, p["Wk"], precision)
+    v0, v_back = ops.dense(x, p["Wv"], precision)
+    q1, q_norm = ops.rms_norm(q0, p["q_norm"], spec.rms_norm_eps)
+    k1, k_norm = ops.rms_norm(k0, p["k_norm"], spec.rms_norm_eps)
+
+    def split(a):  # (rows, seq, hidden) -> (rows, heads, seq, head_dim)
+        with scope("attn/core"):
+            return _heads(a, h).transpose(0, 2, 1, 3)
+
+    def merge(a):
+        with scope("attn/core"):
+            return a.transpose(0, 2, 1, 3).reshape(x.shape)
+
+    o, core = ops.attention(
+        split(q1), split(k1), split(v0), seg, precision, spec.attn_block
+    )
+    out, out_back = ops.dense(merge(o), p["Wo"], precision)
+
+    def back(dout):
+        grads = {}
+        do, grads["Wo"] = out_back(dout)
+        dq1, dk1, dv0 = map(merge, core(split(do)))
+        dq0, grads["q_norm"] = q_norm(dq1)
+        dk0, grads["k_norm"] = k_norm(dk1)
+        dx_q, grads["Wq"] = q_back(dq0)
+        dx_k, grads["Wk"] = k_back(dk0)
+        dx_v, grads["Wv"] = v_back(dv0)
+        return ops.fan_in(dx_q, dx_k, dx_v), grads
+
+    return out, back
+
+
+def token_layer(p, x, seg, kind, spec, precision):
+    """One layer: ``h = x + norm(mix(x))``, ``y = h + norm(mlp(h))`` (the
+    family's norms sit on the branches). -> ``(y, back)``; ``back(dy) ->
+    (dx, grads)`` with ``grads`` shaped like ``p``."""
+    mix = _linear_attention_mix if kind == "linear_attention" else _full_attention_mix
+    mixed, mix_back = mix(p, x, seg, spec, precision)
+    hid, add1 = ops.residual_norm(x, mixed, p["attn_norm"], spec.rms_norm_eps)
+    gate, gate_back = ops.dense(hid, p["W_gate"], precision)
+    up, up_back = ops.dense(hid, p["W_up"], precision)
+    act, act_back = ops.swiglu(gate, up)
+    down, down_back = ops.dense(act, p["W_down"], precision)
+    y, add2 = ops.residual_norm(hid, down, p["mlp_norm"], spec.rms_norm_eps)
+
+    def back(dy):
+        grads = {}
+        dhid, ddown, grads["mlp_norm"] = add2(dy)
+        dact, grads["W_down"] = down_back(ddown)
+        dgate, dup = act_back(dact)
+        dhid_g, grads["W_gate"] = gate_back(dgate)
+        dhid_u, grads["W_up"] = up_back(dup)
+        dx, dmixed, grads["attn_norm"] = add1(ops.fan_in(dhid, dhid_g, dhid_u))
+        dx_mix, mix_grads = mix_back(dmixed)
+        return ops.fan_in(dx, dx_mix), {**grads, **mix_grads}
+
+    return y, back
+
+
+def token_loss_and_grads(
+    params, spec: TokenModelSpec, tokens, segments, precision, acc=None
+):
+    """One microbatch: ``tokens``, ``segments``: (rows, seq_len + 1) int32;
+    inputs are ``[:, :-1]``, targets ``[:, 1:]``, every position a target.
+    -> ``(loss, grads)``: this microbatch's share of the STEP's mean
+    cross-entropy and its gradient, ``grads`` shaped like ``params``. With
+    ``spec.recompute`` the forward keeps each layer's input only and a
+    layer's forward runs again, behind an optimization barrier, when its
+    backward is due. ``acc`` (shaped like ``params``): returned instead of
+    the gradient is ``acc + gradient``, each layer's added as soon as its
+    backward has made it, so that no second tree of gradients exists beside
+    the accumulator (a model's worth of memory)."""
+    embedding, *layers, head = params[0]
+
+    def made(index, layer_grads):
+        if acc is None:
+            return layer_grads
+        with scope("acc"):
+            return {k: acc[0][index][k] + g for k, g in layer_grads.items()}
+
+    with scope("batch"):
+        inputs, targets, seg = tokens[:, :-1], tokens[:, 1:], segments[:, :-1]
+    x, embed_back = ops.embed(embedding["E"], inputs)
+    kept = []
+    for p, kind in zip(layers, spec.layer_types):
+        y, back = token_layer(p, x, seg, kind, spec, precision)
+        kept.append(x if spec.recompute else back)
+        x = y
+    normed, norm_back = ops.rms_norm(x, head["norm"], spec.rms_norm_eps)
+    logits, logits_back = ops.dense(normed, head["W"], precision)
+    loss, loss_back = ops.cross_entropy(logits, targets, spec.step_tokens)
+
+    (dlogits,) = loss_back(jnp.ones((), logits.dtype))
+    dnormed, d_head_w = logits_back(dlogits)
+    dx, d_head_norm = norm_back(dnormed)
+    grads = [made(len(layers) + 1, {"norm": d_head_norm, "W": d_head_w})]
+    for index in reversed(range(len(layers))):
+        p, kind, keep = layers[index], spec.layer_types[index], kept[index]
+        if spec.recompute:
+            # the barrier keeps the compiler from merging this forward with
+            # the first one, which would keep every layer's residuals alive
+            x_in, dx = lax.optimization_barrier((keep, dx))
+            _, keep = token_layer(p, x_in, seg, kind, spec, precision)
+        dx, layer_grads = keep(dx)
+        grads.append(made(index + 1, layer_grads))
+    grads.append(made(0, {"E": embed_back(dx)}))
+    return loss, [grads[::-1]]

@@ -76,6 +76,43 @@ def mlp_train_flops_per_sample(sizes):
     return 6 * sum(sizes[i] * sizes[i + 1] for i in range(len(sizes) - 1))
 
 
+def token_train_flops_per_token(spec, pairs_per_token=None):
+    """Analytical training FLOPs per TOKEN of a token model
+    (``model.TokenModelSpec``), forward and backward, recomputation not
+    counted: 6 per weight of every matrix product (projections, SwiGLU, the
+    head over the held vocabulary slice), the gated delta rule in its
+    recurrence form (9 d_k d_v per head forward, twice that backward), and
+    attention's two products forward and four backward over the (query, key)
+    pairs the mask admits: ``pairs_per_token``, which depends on how the
+    documents were packed (``data.packed_counts``); the causal mask's
+    ``(seq_len + 1) / 2`` where it is not given. The embedding is a lookup;
+    norms, gates and the convolution are O(width) noise and not counted."""
+    d, ff = spec.hidden_size, spec.intermediate_size
+    h, dk, dv = spec.linear_num_heads, spec.linear_key_head_dim, spec.linear_value_head_dim
+    if pairs_per_token is None:
+        pairs_per_token = (spec.seq_len + 1) / 2
+    mlp = 3 * d * ff
+    per_layer = {
+        "full_attention": 6 * (4 * d * d + mlp) + 3 * 4 * d * pairs_per_token,
+        "linear_attention": 6 * (
+            d * (2 * h * dk + 2 * h * dv) + h * dv * d + 2 * d * h + mlp
+        ) + 3 * 9 * h * dk * dv,
+    }
+    return 6 * d * spec.vocab_size + sum(per_layer[kind] for kind in spec.layer_types)
+
+
+def token_train_flops_per_sample(spec, pairs_per_token=None):
+    """Per row of ``seq_len`` tokens (a token model's sample)."""
+    return token_train_flops_per_token(spec, pairs_per_token) * spec.seq_len
+
+
+def train_flops_per_sample(spec):
+    """Either kind of model spec -> analytical training FLOPs per sample."""
+    if hasattr(spec, "layer_types"):
+        return token_train_flops_per_sample(spec)
+    return mlp_train_flops_per_sample(spec.sizes)
+
+
 def device_row(platform, device_kind):
     """-> ``(row, unknown_tag)``: the key every per-chip table in this
     package is looked up by, and the source tag to report when the table
@@ -188,6 +225,7 @@ class CostModel:
         precision="highest",
         padded_flops_per_batch=None,
         device_kind=None,
+        flops_per_sample=None,
     ):
         self.sizes = tuple(sizes)
         self.global_batch = int(global_batch)
@@ -196,7 +234,11 @@ class CostModel:
         self.platform = platform
         self.device_kind = device_kind
         self.precision = precision
-        self.flops_per_sample = mlp_train_flops_per_sample(sizes)
+        # a token model has no ``sizes``: its count is handed in
+        self.flops_per_sample = (
+            mlp_train_flops_per_sample(sizes)
+            if flops_per_sample is None else flops_per_sample
+        )
         self.flops_per_batch = self.flops_per_sample * self.global_batch
         self.flops_per_epoch = self.flops_per_batch * self.batches_per_epoch
         # hardware work actually dispatched per batch on padded-stack

@@ -54,8 +54,8 @@ import re
 from shallowspeed_tpu.observability.costmodel import (
     TPU_V5E,
     device_row,
-    mlp_train_flops_per_sample,
     peak_flops_per_chip,
+    train_flops_per_sample,
 )
 
 # Collective HLO op names, in the spelling ``Compiled.as_text()`` uses.
@@ -536,7 +536,7 @@ def expected_comms(
     if sequential:
         # one device, one program: ANY collective is a contract violation
         forbidden = [k.replace("-", "_") for k in COLLECTIVE_KINDS]
-        flops_per_step = mlp_train_flops_per_sample(spec.sizes) * spec.global_batch_size
+        flops_per_step = train_flops_per_sample(spec) * spec.global_batch_size
     else:
         from shallowspeed_tpu.parallel.lowering import (
             program_comm_bytes,

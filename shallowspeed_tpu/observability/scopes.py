@@ -18,6 +18,19 @@ scope belongs to a class, the unit the benchmark's class table
     sync       sync/dp, sync/pp      (gradsync, executor step tail)
     update     update                (optimizer.py: apply, clip, norms)
     batch      batch                 (one step's / microbatch's rows)
+    gdn_scan   gdn/scan              (ops.py: the chunked gated delta rule)
+    attn       attn/core             (ops.py: blocked attention under the
+                                      causal, same-document mask)
+    token_mix  gdn/conv, gdn/gate, norm, swiglu, fanin
+                                     (ops.py: the token model's pointwise work;
+                                      ``fanin`` sums the cotangents that meet
+                                      at one value)
+    head       embed, head/xent      (ops.py: embedding, cross-entropy)
+
+The token model's ops trace forward and backward under the op's scope; where
+a backward is ``jax.vjp``'s, the transform wraps what FOLLOWS the scope in
+the path (``gdn/scan/transpose(jvp(...))/mul``), so the scope is still the
+last well-formed one.
 
 ``tick`` (the executor's whole tick body) is a parent of the others and has
 no class of its own. What no scope covers (compiler-inserted copies, loop
@@ -60,6 +73,15 @@ _CLASS_OF = {
     "update": "update",
     "batch": "batch",
     "tick": None,
+    "gdn/scan": "gdn_scan",
+    "attn/core": "attn",
+    "gdn/conv": "token_mix",
+    "gdn/gate": "token_mix",
+    "norm": "token_mix",
+    "swiglu": "token_mix",
+    "fanin": "token_mix",
+    "embed": "head",
+    "head/xent": "head",
 }
 SCOPES = tuple(_CLASS_OF)
 
@@ -116,6 +138,7 @@ def scope_of(op_name):
 
 _programs = {}  # module name -> (jitted callable, abstract arguments)
 _indexes = {}  # module name -> op index, built on demand
+_counts = {}  # module name -> what the program's resident set holds
 
 
 def _abstract(leaf):
@@ -136,6 +159,19 @@ def register_program(jit_fn, args):
     _programs[name] = (jit_fn, jax.tree.map(_abstract, tuple(args)))
     _indexes.pop(name, None)
     return name
+
+
+def record_counts(name, counts):
+    """Remember what the resident set of the program called ``name`` holds
+    (a token model's: ``tokens``, ``documents`` and ``pairs``, the (query,
+    key) pairs the causal, same-document mask admits, per epoch). Plain
+    numbers, readable after the session is gone, as the op index is."""
+    _counts[name] = dict(counts)
+
+
+def program_counts(name):
+    """What ``record_counts`` was given for ``name``, or ``None``."""
+    return _counts.get(name)
 
 
 def registered(name):

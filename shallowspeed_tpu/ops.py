@@ -108,11 +108,13 @@ def gelu_grad(g, z):
 
 @scoped("linear/fwd")
 def linear(x, w, b, precision=DEFAULT_PRECISION):
-    """y = x @ w.T + b with w: (out, in), b: (1, out) or (out,).
+    """y = x @ w.T + b with w: (out, in), b: (1, out) or (out,), or ``None``
+    for a product without a bias (the token model's).
 
     Reference: functional.py:13-17.
     """
-    return jnp.matmul(x, w.T, precision=precision) + jnp.reshape(b, (1, -1))
+    y = jnp.matmul(x, w.T, precision=precision)
+    return y if b is None else y + jnp.reshape(b, (1, -1))
 
 
 @scoped("linear/dgrad")
@@ -271,3 +273,458 @@ def softmax_mse_head_grad(z, t, batch_size, valid_mask=None, group_rows=None):
     p = softmax(z, valid_mask, group_rows)
     g = mse_loss_grad(p, t, batch_size)
     return softmax_grad(g, z, valid_mask, group_rows)
+
+
+# ---------------------------------------------------------------------------
+# Token-model ops (model.py's ``token_*``): an embedding, RMSNorm, SwiGLU,
+# cross-entropy over the (sliced) vocabulary, blocked attention under a causal
+# and same-document mask, the short causal convolution, the gated delta rule
+# as a chunked scan. Matrix products with a weight go through ``linear`` and
+# its two gradient halves above. Each op hands back its output and the
+# function that pulls a cotangent back; both trace under the op's scope, so
+# the class table attributes forward and backward alike. Pointwise ops
+# recompute their forward inside the backward (``jax.vjp`` at the time the
+# cotangent arrives): what is kept between the passes is the op's inputs.
+# Attention's backward is written out (its blocking IS the algorithm); the
+# scan's is ``jax.vjp`` of the chunked form, which keeps one state per chunk.
+# ---------------------------------------------------------------------------
+
+ATTN_BLOCK = 512  # queries and keys per block of the attention core
+SCAN_CHUNK = 128  # tokens per chunk of the gated delta rule
+SCAN_BLOCK = 4  # chunks whose matrices are made (and, backward, rebuilt) at once
+# The scan's own matrix products, whatever the session's precision: float32
+# passes. The gradient that reaches W_q and W_k through the normalised q and k
+# is about ten times as sensitive to rounding as the rest of the model
+# (PERF.md section 6: moving the plain reference from bfloat16 operands to
+# float32 moves their update by 11% and most other tensors' by 1 to 3%); with
+# bfloat16 operands in the scan the system's update of them stood 10% from
+# the reference's, with float32 passes 4%. (The attention core, given the
+# same, moved nothing and cost 48 ms a step: it keeps the session's.)
+SCAN_PRECISION = lax.Precision.HIGHEST
+INVERSE_LEAF = 32  # rows of the diagonal blocks ``_unit_lower_inverse`` starts from
+L2_EPS = 1e-6
+
+
+def _pointwise(name, fn, *args):
+    """``fn(*args)`` under ``scope(name)`` and the function that pulls a
+    cotangent back to ``args`` (a tuple), which recomputes ``fn``."""
+    with scope(name):
+        out = fn(*args)
+
+    def back(dout):
+        with scope(name):
+            return jax.vjp(fn, *args)[1](dout)
+
+    return out, back
+
+
+def fan_in(*cotangents):
+    """The sum of the cotangents that reach one value from the ops that
+    read it (a residual stream's branches, a projection's inputs)."""
+    with scope("fanin"):
+        total = cotangents[0]
+        for more in cotangents[1:]:
+            total = total + more
+        return total
+
+
+def _as_bfloat16(a):
+    """``a`` rounded to bfloat16, still float32."""
+    return a.astype(jnp.bfloat16).astype(a.dtype)
+
+
+def dense(x, w, precision=DEFAULT_PRECISION):
+    """``x @ w.T`` for ``x``: (..., in), ``w``: (out, in), through ``linear``;
+    ``back(dy) -> (dx, dw)`` through ``linear_grad_input`` / ``_weight``.
+
+    Under ``Precision.DEFAULT`` the operands of all three products are
+    rounded to bfloat16 HERE, with float32 accumulation left to the product:
+    the policy stated outright, the same on a CPU as on the chip. On the
+    chip the compiler rounds the same operands (one layer's gradients agree
+    to the last digit printed with and without this, PERF.md section 6), but
+    it then keeps the operand it was handed: rounded here, the activation
+    kept for the weight gradient is the rounded one, and the cell's step fell
+    from 1,798 to 1,521 ms. The compiler folds the second conversion away."""
+    lead, x2 = x.shape[:-1], x.reshape(-1, x.shape[-1])
+    rounds = precision == lax.Precision.DEFAULT
+    if rounds:
+        with scope("linear/fwd"):
+            x2, w = _as_bfloat16(x2), _as_bfloat16(w)
+    y = linear(x2, w, None, precision=precision)
+
+    def back(dy):
+        g = dy.reshape(-1, dy.shape[-1])
+        if rounds:
+            with scope("linear/dgrad"):
+                g = _as_bfloat16(g)
+        dx = linear_grad_input(g, w, precision=precision)
+        dw, _ = linear_grad_weight(g, x2, precision=precision)
+        return dx.reshape(*lead, -1), dw
+
+    return y.reshape(*lead, -1), back
+
+
+def embed(table, tokens):
+    """Rows of ``table`` (vocab, hidden) for ``tokens``; the gradient is a
+    scatter-add of the rows' cotangents."""
+    with scope("embed"):
+        x = jnp.take(table, tokens, axis=0)
+
+    def back(dx):
+        with scope("embed"):
+            return jnp.zeros_like(table).at[tokens.reshape(-1)].add(
+                dx.reshape(-1, dx.shape[-1])
+            )
+
+    return x, back
+
+
+def _rms(x, w, eps):
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def _silu(x):
+    return x * jax.nn.sigmoid(x)
+
+
+def rms_norm(x, w, eps):
+    """``x / rms(x) * w`` over the last axis."""
+    return _pointwise("norm", lambda x, w: _rms(x, w, eps), x, w)
+
+
+def residual_norm(x, branch, w, eps):
+    """``x + rms_norm(branch)``: the family's norm sits on the branch."""
+    return _pointwise("norm", lambda x, b, w: x + _rms(b, w, eps), x, branch, w)
+
+
+def swiglu(gate, up):
+    """``silu(gate) * up``, the SwiGLU's pointwise part."""
+    return _pointwise("swiglu", lambda g, u: _silu(g) * u, gate, up)
+
+
+def cross_entropy(logits, targets, step_tokens):
+    """Sum over positions of ``logsumexp(logits) - logits[target]`` over
+    ``step_tokens`` (the STEP's tokens, so microbatches add up to the step's
+    mean, as ``mse_loss``'s global batch size does)."""
+
+    def fn(z):
+        picked = jnp.take_along_axis(z, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(jax.nn.logsumexp(z, axis=-1) - picked) / step_tokens
+
+    return _pointwise("head/xent", fn, logits)
+
+
+def conv_silu(x, taps, seg):
+    """``silu(conv(x))``: a causal depthwise convolution along axis 1 of
+    ``x`` (rows, seq, channels) with ``taps`` (channels, K), tap ``j`` on the
+    token ``j`` back, reading zero for a token of another document."""
+
+    def fn(x, taps):
+        y = jnp.zeros_like(x)
+        for j in range(taps.shape[1]):
+            back_x = jnp.pad(x, ((0, 0), (j, 0), (0, 0)))[:, : x.shape[1]]
+            back_seg = jnp.pad(seg, ((0, 0), (j, 0)), constant_values=-1)[
+                :, : x.shape[1]
+            ]
+            y = y + jnp.where((back_seg == seg)[..., None], back_x, 0.0) * taps[:, j]
+        return _silu(y)
+
+    return _pointwise("gdn/conv", fn, x, taps)
+
+
+def qk_l2norm(q, k):
+    """Per head (last axis): ``q / |q| / sqrt(d_k)``, ``k / |k|``."""
+
+    def fn(q, k):
+        unit = lambda a: a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + L2_EPS)  # noqa: E731
+        return unit(q) * q.shape[-1] ** -0.5, unit(k)
+
+    return _pointwise("gdn/gate", fn, q, k)
+
+
+def delta_gates(b, a, a_log, dt_bias, neg_eigval):
+    """``beta = sigmoid(b)`` (doubled under ``neg_eigval``) and the LOG of
+    the decay, ``-exp(A_log) softplus(a + dt_bias)``."""
+
+    def fn(b, a, a_log, dt_bias):
+        beta = jax.nn.sigmoid(b) * (2.0 if neg_eigval else 1.0)
+        return beta, -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+
+    return _pointwise("gdn/gate", fn, b, a, a_log, dt_bias)
+
+
+def gated_head_norm(o, gate, w, eps):
+    """``rms_norm(o) * silu(gate)`` per head (last axis)."""
+    return _pointwise(
+        "gdn/gate", lambda o, g, w: _rms(o, w, eps) * _silu(g), o, gate, w
+    )
+
+
+def _block_len(length, want):
+    """The largest divisor of ``length`` that is at most ``want``."""
+    return next(c for c in range(min(want, length), 0, -1) if length % c == 0)
+
+
+def _first_key_block(segb):
+    """For each block of queries, the first block of keys that holds a token
+    of a document some query of it belongs to. ``segb``: (rows, n, c),
+    documents numbered upwards along a row. Over all rows: the earliest."""
+    first_doc = segb[:, :, 0]  # of the queries of block i
+    last_doc = segb[:, :, -1]  # of the keys of block j
+    return jnp.min(
+        jnp.sum(last_doc[:, None, :] < first_doc[:, :, None], axis=-1), axis=0
+    ).astype(jnp.int32)
+
+
+def _attn_mask(seg_q, seg_k, at_q, at_k):
+    """(rows, 1, c, c): same document and key not after query."""
+    return ((seg_q[:, :, None] == seg_k[:, None, :])
+            & (at_q[:, None] >= at_k[None, :])[None])[:, None]
+
+
+def attention(q, k, v, seg, precision=DEFAULT_PRECISION, block=ATTN_BLOCK):
+    """``softmax(q k^T / sqrt(d) + M) v`` with ``M`` = causal and same
+    document, by blocks: ``q, k, v``: (rows, heads, seq, d), ``seg``: (rows,
+    seq). For each block of queries a loop over the blocks of keys from the
+    first that holds one of its documents up to its own (an online softmax),
+    so no score matrix wider than a block exists and blocks the mask empties
+    are not visited. The backward recomputes each visited block's scores from
+    the saved log-sum-exp. -> ``o, back``; ``back(do) -> (dq, dk, dv)``."""
+    rows, heads, seq, d = q.shape
+    c = _block_len(seq, block)
+    n = seq // c
+    scale = d**-0.5
+
+    def blocks(a):  # (rows, heads, seq, d) -> (n, rows, heads, c, d)
+        return jnp.moveaxis(a.reshape(rows, heads, n, c, d), 2, 0)
+
+    def unblocks(a):
+        return jnp.moveaxis(a, 0, 2).reshape(rows, heads, seq, d)
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=precision)
+
+    with scope("attn/core"):
+        qb, kb, vb = blocks(q), blocks(k), blocks(v)
+        segb = seg.reshape(rows, n, c)
+        seg_n = jnp.moveaxis(segb, 1, 0)  # (n, rows, c)
+        at = jnp.arange(seq).reshape(n, c)
+        first_key = _first_key_block(segb)
+
+        def query_block(i):
+            q_i = qb[i]
+
+            def key_block(j, carry):
+                m, l, acc = carry
+                s = dot("bhqd,bhkd->bhqk", q_i, kb[j]) * scale
+                mask = _attn_mask(seg_n[i], seg_n[j], at[i], at[j])
+                m_new = jnp.maximum(m, jnp.max(jnp.where(mask, s, _NEG_MASK), -1))
+                p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+                fix = jnp.exp(m - m_new)
+                acc = acc * fix[..., None] + dot("bhqk,bhkd->bhqd", p, vb[j])
+                return m_new, l * fix + p.sum(-1), acc
+
+            m, l, acc = lax.fori_loop(
+                first_key[i], i + 1, key_block,
+                (
+                    jnp.full((rows, heads, c), _NEG_MASK, q.dtype),
+                    jnp.zeros((rows, heads, c), q.dtype),
+                    jnp.zeros((rows, heads, c, d), q.dtype),
+                ),
+            )
+            return acc / l[..., None], m + jnp.log(l)
+
+        ob, lse = lax.map(query_block, jnp.arange(n))
+        o = unblocks(ob)
+
+    def back(do):
+        with scope("attn/core"):
+            dob = blocks(do)
+            delta = jnp.sum(dob * ob, axis=-1)  # (n, rows, heads, c)
+
+            def query_block(i, grads):
+                q_i, do_i = qb[i], dob[i]
+
+                def key_block(j, carry):
+                    dq_i, dk, dv = carry
+                    s = dot("bhqd,bhkd->bhqk", q_i, kb[j]) * scale
+                    mask = _attn_mask(seg_n[i], seg_n[j], at[i], at[j])
+                    p = jnp.where(mask, jnp.exp(s - lse[i][..., None]), 0.0)
+                    dp = dot("bhqd,bhkd->bhqk", do_i, vb[j])
+                    ds = p * (dp - delta[i][..., None]) * scale
+                    dq_i = dq_i + dot("bhqk,bhkd->bhqd", ds, kb[j])
+                    dk = dk.at[j].add(dot("bhqk,bhqd->bhkd", ds, q_i))
+                    dv = dv.at[j].add(dot("bhqk,bhqd->bhkd", p, do_i))
+                    return dq_i, dk, dv
+
+                dq, dk, dv = grads
+                dq_i, dk, dv = lax.fori_loop(
+                    first_key[i], i + 1, key_block, (jnp.zeros_like(q_i), dk, dv)
+                )
+                return dq.at[i].set(dq_i), dk, dv
+
+            zeros = jnp.zeros_like(qb)
+            dq, dk, dv = lax.fori_loop(0, n, query_block, (zeros, zeros, zeros))
+            return unblocks(dq), unblocks(dk), unblocks(dv)
+
+    return o, back
+
+
+def _diagonal_blocks(a, b):
+    """(..., c, c) -> (..., c // b, b, b): the blocks on the diagonal."""
+    return jnp.stack(
+        [a[..., i : i + b, i : i + b] for i in range(0, a.shape[-1], b)], axis=-3
+    )
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for strictly lower-triangular ``a`` (..., c, c), in
+    matrix products only (the chip's own triangular solve goes row by row).
+    The diagonal blocks of at most ``INVERSE_LEAF`` rows (on the chip 32
+    took 0.63 of the time of 16, and 64 and 128 the time of 32) are inverted
+    together by
+    the finite series ``sum_k (-a)^k`` (``a`` is nilpotent), as the product
+    ``(I - a)(I + a^2)(I + a^4)...``; then neighbouring blocks are merged,
+    all pairs of a level at once, ``[[T11, 0], [-T22 a21 T11, T22]]``, until
+    one is left. Float32 passes throughout: the products are tiny and
+    everything after them inherits their error."""
+    c = a.shape[-1]
+
+    def dot(x, y):
+        return jnp.matmul(x, y, precision=lax.Precision.HIGHEST)
+
+    b = c
+    while b > INVERSE_LEAF and b % 2 == 0:
+        b //= 2
+    power = -_diagonal_blocks(a, b)
+    inverse = jnp.eye(b, dtype=a.dtype) + power
+    done = 2  # powers 0 .. done - 1 are in ``inverse``
+    while done < b:
+        power = dot(power, power)
+        inverse = inverse + dot(inverse, power)
+        done *= 2
+    while b < c:
+        t11, t22 = inverse[..., 0::2, :, :], inverse[..., 1::2, :, :]
+        a21 = _diagonal_blocks(a, 2 * b)[..., b:, :b]
+        t21 = -dot(dot(t22, a21), t11)
+        inverse = jnp.concatenate(
+            [
+                jnp.concatenate([t11, jnp.zeros_like(t11)], axis=-1),
+                jnp.concatenate([t21, t22], axis=-1),
+            ],
+            axis=-2,
+        )
+        b *= 2
+    return inverse[..., 0, :, :]
+
+
+def gated_delta_scan(
+    q, k, v, beta, log_decay, seg, precision=None, chunk=SCAN_CHUNK,
+    block=SCAN_BLOCK,
+):
+    """The gated delta rule ``S_t = a_t S_{t-1} (I - b_t k_t k_t^T) + b_t v_t
+    k_t^T``, ``o_t = S_t q_t``, ``S`` zero at a document's first token, in
+    its chunked (WY) form: within a chunk of ``chunk`` tokens everything is
+    matrix products (the inverse of one unit-triangular matrix gives every
+    token's corrected value from the chunk's entering state), and only the
+    (d_k x d_v) state
+    goes from chunk to chunk, in a scan. ``q, k``: (rows, seq, heads, d_k),
+    ``v``: (rows, seq, heads, d_v), ``beta, log_decay``: (rows, seq, heads),
+    ``seg``: (rows, seq). A document that starts inside a chunk masks the
+    chunk's decay matrix (no pair across the start) and cuts the entering
+    state off from the tokens after it.
+
+    The chunks are taken ``block`` at a time: a block's matrices are made in
+    one batch (the work is matrix products over all its chunks at once), its
+    chunks' states follow one another in an inner scan, and the blocks in an
+    outer scan whose body is a ``jax.checkpoint``. -> ``o, back``; ``back(do)
+    -> (dq, dk, dv, dbeta, dlog_decay)``: ``jax.vjp`` of that, which keeps
+    the state entering each BLOCK between the passes, rebuilds one block's
+    matrices at a time in the backward, and holds nothing per token."""
+    rows, seq, heads, dk = q.shape
+    dv = v.shape[-1]
+    c = _block_len(seq, chunk)
+    n = seq // c
+    per = _block_len(n, block)  # chunks per block
+    if precision is None:
+        precision = SCAN_PRECISION
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=precision)
+
+    def chunks(a):  # (rows, seq, heads, ...) -> (blocks, per, rows, heads, c, ...)
+        a = a.reshape(rows, n, c, heads, *a.shape[3:])
+        a = jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+        return a.reshape(n // per, per, *a.shape[1:])
+
+    def blocks(a):  # (n, ...) -> (blocks, per, ...)
+        return a.reshape(n // per, per, *a.shape[1:])
+
+    with scope("gdn/scan"):
+        segc = jnp.moveaxis(seg.reshape(rows, n, c), 1, 0)  # (n, rows, c)
+        first = jnp.concatenate(
+            [jnp.ones((rows, 1), bool), seg[:, 1:] != seg[:, :-1]], axis=1
+        )
+        # the document of the token before the chunk: the state entering a chunk
+        # reaches the tokens of that document only (the first chunk's is zero)
+        entering = jnp.concatenate([seg[:, :1], seg[:, c - 1 : -1 : c]], axis=1).T
+        same = blocks((segc[:, :, :, None] == segc[:, :, None, :])[:, :, None])
+        carried = blocks((segc == entering[:, :, None])[:, :, None, :])  # (.., r, 1, c)
+        to_last = blocks((segc == segc[:, :, -1:])[:, :, None, :])
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        strict = jnp.tril(jnp.ones((c, c), bool), -1)
+
+    @jax.checkpoint
+    def block_of_chunks(state, xs):
+        qc, kc, vc, bc, gc, same, carried, to_last = xs  # (per, rows, heads, c, ..)
+        g = jnp.cumsum(gc, axis=-1)
+        pair = same & lower
+        decay = jnp.where(
+            pair, jnp.exp(jnp.where(pair, g[..., :, None] - g[..., None, :], 0.0)), 0.0
+        )
+        a = jnp.where(strict, bc[..., None] * dot("nbhid,nbhjd->nbhij", kc, kc) * decay, 0.0)
+        g_in = jnp.where(carried, jnp.exp(g), 0.0)  # decay from the entering state
+        solved = dot(
+            "nbhij,nbhjd->nbhid", _unit_lower_inverse(a),
+            jnp.concatenate([bc[..., None] * vc, (bc * g_in)[..., None] * kc], axis=-1),
+        )
+        qk = dot("nbhid,nbhjd->nbhij", qc, kc) * decay
+        g_out = jnp.where(to_last, jnp.exp(g[..., -1:] - g), 0.0)
+
+        def step(state, xs):  # state: (rows, heads, d_k, d_v)
+            u0, w, qk, q_in, k_out, keep = xs
+            u = u0 - dot("bhik,bhkv->bhiv", w, state)
+            o = dot("bhik,bhkv->bhiv", q_in, state) + dot("bhij,bhjv->bhiv", qk, u)
+            state = state * keep[..., None, None] + dot("bhik,bhiv->bhkv", k_out, u)
+            return state, o
+
+        return lax.scan(
+            step, state,
+            (
+                solved[..., :dv], solved[..., dv:], qk, qc * g_in[..., None],
+                kc * g_out[..., None], g_in[..., -1],
+            ),
+        )
+
+    def fn(q, k, v, beta, log_decay):
+        # a document's first token takes no decay: its state starts from zero
+        decays = jnp.where(first[..., None], 0.0, log_decay)
+        _, o = lax.scan(
+            block_of_chunks, jnp.zeros((rows, heads, dk, dv), q.dtype),
+            (
+                chunks(q), chunks(k), chunks(v), chunks(beta), chunks(decays),
+                same, carried, to_last,
+            ),
+        )
+        # (blocks, per, rows, heads, c, d_v) -> (rows, seq, heads, d_v)
+        o = o.reshape(n, rows, heads, c, dv)
+        return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(rows, seq, heads, dv)
+
+    with scope("gdn/scan"):
+        o, pull = jax.vjp(fn, q, k, v, beta, log_decay)
+
+    def back(do):
+        with scope("gdn/scan"):
+            return pull(do)
+
+    return o, back

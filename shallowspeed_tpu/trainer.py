@@ -25,7 +25,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from shallowspeed_tpu import ops
-from shallowspeed_tpu.model import ModelSpec, model_backward, model_forward
+from shallowspeed_tpu.model import (
+    ModelSpec,
+    TokenModelSpec,
+    model_backward,
+    model_forward,
+    token_loss_and_grads,
+)
 from shallowspeed_tpu.observability.scopes import scope
 
 
@@ -146,7 +152,20 @@ def _make_batch_step(
     ``x_layout`` (``data_layout``'s answer, scanned path only): with
     ``"feature_major"`` ``xb`` is ``(M, in_dim, mubatch)`` and each
     microbatch is read transposed, as it lies.
+
+    A ``TokenModelSpec`` takes its microbatches one after another (unrolled,
+    see ``batch_step``): ``xb`` is a step's token ids and ``yb`` its document
+    numbers, both ``(M, mubatch, seq_len + 1)`` int32; a microbatch's loss
+    and gradients are ``model.token_loss_and_grads``'s, which adds each
+    layer's gradient into the accumulator as it is made; the update is
+    applied like an MLP's.
     """
+    token = isinstance(spec, TokenModelSpec)
+    if token and (fuse_mubatches or megakernel or x_layout != "row_major"):
+        raise ValueError(
+            "a token model runs the row-major microbatch loop only: the "
+            "fused and kernel paths are written for stacks of Linears"
+        )
     if x_layout != "row_major" and (fuse_mubatches or megakernel):
         raise ValueError(
             f"x_layout={x_layout!r} is the microbatch scan's: the fused and "
@@ -204,6 +223,22 @@ def _make_batch_step(
         batch-scaled MSE of the batch under the pre-update params. With
         ``with_grad_norm`` a fourth output carries the pre-clip global
         gradient norm."""
+        if token:
+            # the microbatches one after another in ONE straight-line
+            # program, not a scan: as a while loop's carry the accumulator
+            # is held twice (measured with the chip's compiler: a model's
+            # worth of memory, which this model's cell does not have), and
+            # the first microbatch's gradient IS the accumulator, unzeroed
+            acc, loss = None, jnp.zeros(())
+            for m in range(xb.shape[0]):
+                with scope("batch"):
+                    tokens, segments = xb[m], yb[m]
+                mb_loss, acc = token_loss_and_grads(
+                    params, spec, tokens, segments, precision, acc=acc
+                )
+                with scope("loss"):
+                    loss = loss + mb_loss
+            return finish(params, opt_state, acc, loss)
         if fuse_mubatches:
             rows = xb.shape[1]
             with scope("batch"):

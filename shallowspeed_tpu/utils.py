@@ -52,6 +52,19 @@ def model_hash(params_list) -> str:
     return sha1(acc.encode("utf-8")).hexdigest()
 
 
+def tree_hash(params_list) -> str:
+    """``model_hash`` for a parameter tree that is not ``{W, b}`` slots (a
+    token model's): SHA1 over the per-leaf SHA1s of the float32 bytes, layers
+    in order, a layer's leaves by sorted name."""
+    acc = ""
+    for stage in params_list:
+        for layer in stage:
+            for key in sorted(layer):
+                arr = np.ascontiguousarray(jax.device_get(layer[key]), np.float32)
+                acc += sha1(arr.tobytes()).hexdigest()
+    return sha1(acc.encode("utf-8")).hexdigest()
+
+
 def block_checksum(arr) -> int:
     """The host-side digest checksum of one logical block: the uint32
     wrap-around sum of the block's float32 bytes reinterpreted as uint32

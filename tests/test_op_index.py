@@ -473,3 +473,53 @@ def test_sequential_step_copies_no_slab_of_x(v5e_mesh, case):
         if i["opcode"] in ("copy", "transpose") and dims(i) in slabs
     ]
     assert not slab_copies, slab_copies
+
+
+TOKEN_MODEL = dict(
+    model_type="olmo_hybrid", vocab_size=1024, hidden_size=256, intermediate_size=512,
+    num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+    rms_norm_eps=1e-6, layer_types=["linear_attention", "full_attention"],
+    linear_num_key_heads=2, linear_num_value_heads=2, linear_key_head_dim=96,
+    linear_value_head_dim=192, linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+)
+
+
+def test_token_epoch_program_lands_in_classes_on_the_chip(v5e_mesh):
+    """The token model's epoch program as the chip's compiler makes it (two
+    microbatches of one 1,024-token row, head sizes as published, recomputed
+    layers): it compiles (the blocked attention's loops with bounds read from
+    the documents, the scan's matrix-product inverse), every class the model
+    adds is there, and of the instructions that run on their own (outside
+    fusions, containers aside) the unattributed hold under 1% of the bytes."""
+    from jax.sharding import SingleDeviceSharding
+
+    from shallowspeed_tpu import model as Mo
+    from shallowspeed_tpu import trainer
+    from shallowspeed_tpu.api import PRECISIONS
+    from shallowspeed_tpu.optimizer import SGD
+
+    one_chip = SingleDeviceSharding(v5e_mesh.devices.flat[0])
+    spec = Mo.make_token_spec(TOKEN_MODEL, 1024, 2, recompute=True)
+    params = [[
+        {
+            name: jax.ShapeDtypeStruct(shape, np.float32, sharding=one_chip)
+            for name, (shape, _) in layer.items()
+        }
+        for layer in Mo.token_layer_shapes(spec)
+    ]]
+    rows = jax.ShapeDtypeStruct((1, 2, 1, 1025), np.int32, sharding=one_chip)
+    epoch = trainer.make_train_epoch(spec, SGD(0.05), precision=PRECISIONS["default"])
+    text = _compile_off_cache(epoch.lower(params, (), rows, rows)).as_text()
+    index = op_index(text)
+    alone = [
+        e for e in index.values()
+        if not e["container"] and "fused_computation" not in e["computation"]
+        and e["opcode"] not in ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
+    ]
+    classes = {e["cls"] for e in alone}
+    assert {"gdn_scan", "attn", "token_mix", "head", "linear", "grad_acc", "update"} <= classes
+    total = sum(e["bytes"] for e in alone)
+    loose = sum(e["bytes"] for e in alone if e["cls"] == "unattributed")
+    assert loose < 0.01 * total, (loose, total, [
+        (e["opcode"], e["type"]) for e in alone if e["cls"] == "unattributed"
+    ][:10])
