@@ -545,6 +545,7 @@ class TrainingSession:
         # Provenance like the mesh's layout: a run's record has to say
         # which of the two epoch programs it timed
         mubatch_rows = local_batch // mubatches
+        self._scan_path = None
         self._data_layout = (
             trainer.data_layout(
                 mubatch_rows, sizes, self.precision,
@@ -598,6 +599,16 @@ class TrainingSession:
             self.spec = Mo.make_token_spec(
                 token_config, seq_len, self.B, mubatch_rows=mubatch_rows
             )
+            # which form of the gated delta rule's scan the epoch program
+            # holds (ops.scan_path, from the shapes alone): provenance like
+            # ``data_layout``'s, an event and a count beside the program's
+            scan_plan = Mo.token_scan_plan(self.spec, mubatches)
+            self._scan_path = scan_plan["path"]
+            self._token_counts["scan_kernel_calls"] = (
+                scan_plan["kernel_calls_per_step"] * nb
+            )
+            if self._metrics.enabled:
+                self._metrics.event("scan_path", **scan_plan)
             # an id outside the table raises nothing on the device (the
             # lookup clamps it, the scatter-add drops it)
             ids = self._train_ds.input_X
@@ -2520,6 +2531,14 @@ class TrainingSession:
         program it runs (``trainer.data_layout``; the ``data_layout``
         metrics event carries the same with ``mb`` and ``F``)."""
         return self._data_layout
+
+    @property
+    def scan_path(self):
+        """``"pallas"`` or ``"xla"``: the form in which a token model's
+        Gated DeltaNet layers run their chunked scan (``ops.scan_path``; the
+        ``scan_path`` metrics event carries the same with the chunk, the
+        head sizes and the kernel launches a step). ``None`` for an MLP."""
+        return self._scan_path
 
     def placement(self):
         """Where the mesh put the parameters — None on the sequential path.

@@ -467,6 +467,27 @@ class TokenModelSpec:
         return self.global_batch_size * self.seq_len
 
 
+def token_scan_plan(spec: "TokenModelSpec", mubatches):
+    """Which form the Gated DeltaNet layers' scan runs at this spec's shapes
+    (``ops.scan_path``) and the kernel launches one optimizer step makes:
+    layers x microbatches x passes (a forward, the forward again where the
+    layer is recomputed, a backward; none where the XLA form runs). -> the
+    ``scan_path`` event's fields."""
+    path = ops.scan_path(
+        spec.seq_len, spec.scan_chunk, spec.linear_key_head_dim,
+        spec.linear_value_head_dim, jnp.float32,
+    )
+    layers = sum(kind == "linear_attention" for kind in spec.layer_types)
+    passes = (3 if spec.recompute else 2) if path == "pallas" else 0
+    return {
+        "path": path,
+        "chunk": ops._block_len(spec.seq_len, spec.scan_chunk),
+        "d_k": spec.linear_key_head_dim,
+        "d_v": spec.linear_value_head_dim,
+        "kernel_calls_per_step": layers * mubatches * passes,
+    }
+
+
 def _layer_residual_bytes(cfg, tokens):
     """Float32 bytes one layer keeps between its forward and its backward
     where nothing is recomputed, roughly: the widest of its intermediates,

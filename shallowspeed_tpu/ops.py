@@ -285,8 +285,13 @@ def softmax_mse_head_grad(z, t, batch_size, valid_mask=None, group_rows=None):
 # the class table attributes forward and backward alike. Pointwise ops
 # recompute their forward inside the backward (``jax.vjp`` at the time the
 # cotangent arrives): what is kept between the passes is the op's inputs.
-# Attention's backward is written out (its blocking IS the algorithm); the
-# scan's is ``jax.vjp`` of the chunked form, which keeps one state per chunk.
+# Attention's backward is written out (its blocking IS the algorithm). The
+# scan has two forms and ``scan_path`` between them, by shape alone: where
+# the shapes tile, two Pallas kernels (``pallas_ops.gdn_scan_fwd`` / ``_bwd``,
+# the state in VMEM across the chunks, the backward written out, the state
+# entering each chunk and the chunk's inverse kept between the passes);
+# everywhere else the chunked form in ``jax.numpy`` with ``jax.vjp`` for its
+# backward, which keeps one state per block of chunks.
 # ---------------------------------------------------------------------------
 
 ATTN_BLOCK = 512  # queries and keys per block of the attention core
@@ -618,6 +623,100 @@ def _unit_lower_inverse(a):
     return inverse[..., 0, :, :]
 
 
+def scan_path(seq, chunk, d_k, d_v, dtype, precision=SCAN_PRECISION):
+    """Which form ``gated_delta_scan`` runs for these shapes: ``"pallas"``
+    (the kernels of ``pallas_ops.gdn_scan_fwd`` / ``_bwd``) where they tile,
+    ``"xla"`` (the chunked form in ``jax.numpy``) everywhere else. The
+    kernels take float32, chunks of exactly 128 tokens (a (128 x 128)
+    matrix is the MXU's own tile, and the inverse's block masks want a
+    power of two), head sizes that are multiples of 8 (a sublane tile; the
+    state's rows) up to 256, and a precision Mosaic lowers (``HIGHEST`` and
+    ``DEFAULT``; it refuses ``HIGH``). Compiled and run on the chip against
+    the XLA form at nine shapes from (8, 8) to (256, 256), the cell's (96,
+    192) among them (PERF.md section 6, PR 33)."""
+    tiles = (
+        _block_len(seq, chunk) == 128
+        and all(d % 8 == 0 and 8 <= d <= 256 for d in (d_k, d_v))
+        and jnp.dtype(dtype) == jnp.float32
+        and precision in (lax.Precision.HIGHEST, lax.Precision.DEFAULT)
+    )
+    return "pallas" if tiles else "xla"
+
+
+def _document_masks(seg, n, c):
+    """-> ``first`` (rows, seq), ``segc`` (n, rows, c), ``carried`` and
+    ``to_last`` (n, rows, c). ``carried``: the token belongs to the document
+    of the token before its chunk, so the state entering the chunk reaches
+    it (the first chunk's is zero); ``to_last``: to the document of the
+    chunk's last token, so it reaches the state that leaves."""
+    rows = seg.shape[0]
+    segc = jnp.moveaxis(seg.reshape(rows, n, c), 1, 0)
+    first = jnp.concatenate(
+        [jnp.ones((rows, 1), bool), seg[:, 1:] != seg[:, :-1]], axis=1
+    )
+    entering = jnp.concatenate([seg[:, :1], seg[:, c - 1 : -1 : c]], axis=1).T
+    return first, segc, segc == entering[:, :, None], segc == segc[:, :, -1:]
+
+
+def _gated_delta_scan_pallas(q, k, v, beta, log_decay, seg, precision):
+    """``gated_delta_scan`` through the kernels. Left in XLA, under the same
+    scope: the layout (heads before tokens, so that a block is a (128, d)
+    tile of one head), the per-token rows a chunk needs (the running sum of
+    the log decay, the decay from the entering state and to the leaving one
+    under the document masks) packed 8 to a block, and their pull-back."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    rows, seq, heads, _ = q.shape
+    c = 128  # what ``scan_path`` admits
+    n = seq // c
+
+    def heads_first(a):  # (rows, seq, heads, d) -> (rows * heads, seq, d)
+        return jnp.moveaxis(a, 2, 1).reshape(rows * heads, seq, a.shape[-1])
+
+    def rows_first(a):
+        return jnp.moveaxis(a.reshape(rows, heads, seq, a.shape[-1]), 1, 2)
+
+    def per_head(a):  # (n, rows, c) -> (rows, 1, n, c)
+        return jnp.moveaxis(a, 0, 1)[:, None]
+
+    with scope("gdn/scan"):
+        first, segc, carried, to_last = _document_masks(seg, n, c)
+
+        def packed(beta, log_decay):  # (rows, seq, heads) -> (rows * heads, n, 8, c)
+            chunks = lambda a: jnp.moveaxis(a, 2, 1).reshape(rows, heads, n, c)  # noqa: E731
+            # a document's first token takes no decay: its state starts from zero
+            g = jnp.cumsum(chunks(jnp.where(first[..., None], 0.0, log_decay)), -1)
+            g_in = jnp.where(per_head(carried), jnp.exp(g), 0.0)
+            g_out = jnp.where(per_head(to_last), jnp.exp(g[..., -1:] - g), 0.0)
+            keep = jnp.pad(g_in[..., -1:], ((0, 0),) * 3 + ((0, c - 1),))
+            by_row = {
+                K.GDN_G: g, K.GDN_BETA: chunks(beta),
+                K.GDN_SEG: jnp.broadcast_to(per_head(segc).astype(g.dtype), g.shape),
+                K.GDN_G_IN: g_in, K.GDN_G_OUT: g_out, K.GDN_KEEP: keep,
+            }
+            zero = jnp.zeros_like(g)
+            return jnp.stack(
+                [by_row.get(r, zero) for r in range(K.GDN_ROWS)], axis=-2
+            ).reshape(rows * heads, n, K.GDN_ROWS, c)
+
+        p, pull_rows = jax.vjp(packed, beta, log_decay)
+        qh, kh, vh = heads_first(q), heads_first(k), heads_first(v)
+        o, states, inverses = K.gdn_scan_fwd(
+            qh, kh, vh, p, leaf=INVERSE_LEAF, precision=precision
+        )
+        o = rows_first(o)
+
+    def back(do):
+        with scope("gdn/scan"):
+            dq, dk, dv, dp = K.gdn_scan_bwd(
+                qh, kh, vh, p, states, inverses, heads_first(do), precision=precision
+            )
+            dbeta, dlog_decay = pull_rows(dp)
+            return rows_first(dq), rows_first(dk), rows_first(dv), dbeta, dlog_decay
+
+    return o, back
+
+
 def gated_delta_scan(
     q, k, v, beta, log_decay, seg, precision=None, chunk=SCAN_CHUNK,
     block=SCAN_BLOCK,
@@ -627,13 +726,29 @@ def gated_delta_scan(
     its chunked (WY) form: within a chunk of ``chunk`` tokens everything is
     matrix products (the inverse of one unit-triangular matrix gives every
     token's corrected value from the chunk's entering state), and only the
-    (d_k x d_v) state
-    goes from chunk to chunk, in a scan. ``q, k``: (rows, seq, heads, d_k),
-    ``v``: (rows, seq, heads, d_v), ``beta, log_decay``: (rows, seq, heads),
-    ``seg``: (rows, seq). A document that starts inside a chunk masks the
-    chunk's decay matrix (no pair across the start) and cuts the entering
-    state off from the tokens after it.
+    (d_k x d_v) state goes from chunk to chunk. ``q, k``: (rows, seq, heads,
+    d_k), ``v``: (rows, seq, heads, d_v), ``beta, log_decay``: (rows, seq,
+    heads), ``seg``: (rows, seq). A document that starts inside a chunk masks
+    the chunk's decay matrix (no pair across the start) and cuts the entering
+    state off from the tokens after it. -> ``o, back``; ``back(do) -> (dq, dk,
+    dv, dbeta, dlog_decay)``. Every product is ``precision`` (float32 passes
+    by default, ``SCAN_PRECISION``) in either form.
 
+    Two forms, the same mathematics, and ``scan_path`` between them from the
+    shapes alone (no flag, no environment variable): the kernels of
+    ``_gated_delta_scan_pallas`` where the shapes tile, the ``jax.numpy``
+    form of ``_gated_delta_scan_xla`` (which ``block`` belongs to) everywhere
+    else. The second is the first's oracle (tests/test_token_ops.py)."""
+    if precision is None:
+        precision = SCAN_PRECISION
+    shapes = (q.shape[1], chunk, q.shape[-1], v.shape[-1], q.dtype)
+    if scan_path(*shapes, precision) == "pallas":
+        return _gated_delta_scan_pallas(q, k, v, beta, log_decay, seg, precision)
+    return _gated_delta_scan_xla(q, k, v, beta, log_decay, seg, precision, chunk, block)
+
+
+def _gated_delta_scan_xla(q, k, v, beta, log_decay, seg, precision, chunk, block):
+    """``gated_delta_scan`` in ``jax.numpy``, for any chunk and head size.
     The chunks are taken ``block`` at a time: a block's matrices are made in
     one batch (the work is matrix products over all its chunks at once), its
     chunks' states follow one another in an inner scan, and the blocks in an
@@ -646,8 +761,6 @@ def gated_delta_scan(
     c = _block_len(seq, chunk)
     n = seq // c
     per = _block_len(n, block)  # chunks per block
-    if precision is None:
-        precision = SCAN_PRECISION
 
     def dot(spec, a, b):
         return jnp.einsum(spec, a, b, precision=precision)
@@ -661,16 +774,10 @@ def gated_delta_scan(
         return a.reshape(n // per, per, *a.shape[1:])
 
     with scope("gdn/scan"):
-        segc = jnp.moveaxis(seg.reshape(rows, n, c), 1, 0)  # (n, rows, c)
-        first = jnp.concatenate(
-            [jnp.ones((rows, 1), bool), seg[:, 1:] != seg[:, :-1]], axis=1
-        )
-        # the document of the token before the chunk: the state entering a chunk
-        # reaches the tokens of that document only (the first chunk's is zero)
-        entering = jnp.concatenate([seg[:, :1], seg[:, c - 1 : -1 : c]], axis=1).T
+        first, segc, carried, to_last = _document_masks(seg, n, c)
         same = blocks((segc[:, :, :, None] == segc[:, :, None, :])[:, :, None])
-        carried = blocks((segc == entering[:, :, None])[:, :, None, :])  # (.., r, 1, c)
-        to_last = blocks((segc == segc[:, :, -1:])[:, :, None, :])
+        carried = blocks(carried[:, :, None, :])  # (.., rows, 1, c)
+        to_last = blocks(to_last[:, :, None, :])
         lower = jnp.tril(jnp.ones((c, c), bool))
         strict = jnp.tril(jnp.ones((c, c), bool), -1)
 

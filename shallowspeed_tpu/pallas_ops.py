@@ -1,4 +1,7 @@
-"""Pallas TPU kernels for the hot op: fused linear + ReLU, forward & backward.
+"""Pallas TPU kernels: the MLP's fused linear + ReLU, forward & backward
+(opt-in, below), and the gated delta rule's chunked scan (``gdn_scan_fwd`` /
+``gdn_scan_bwd``, at the end of the file: what ``ops.gated_delta_scan`` runs
+wherever ``ops.scan_path`` says the shapes tile, with no switch of its own).
 
 The framework's compute path is XLA-compiled jax.numpy (ops.py) — for this
 model class XLA already fuses bias-add and ReLU into the matmul. These Pallas
@@ -45,6 +48,16 @@ kernels auto-dispatch between single-block and grid-tiled per shape.
 Executor opt-in: ``make_pipeline_step(..., kernel_backend="pallas")``, or
 through the product surface: ``TrainingSession(kernel_backend="pallas")`` /
 ``train.py --kernel-backend pallas``.
+
+None of these switches reaches the SCAN's kernels. A token model's Gated
+DeltaNet layers run them by shape (chunks of 128 tokens, float32, head sizes
+that are multiples of 8): one grid step is one chunk of two heads, the chunk
+axis runs in order with the (d_k x d_v) state in a VMEM scratch, and a
+chunk's (128 x 128) matrices never leave VMEM. On the chip one layer-row of
+``olmo-hybrid-7b`` (8,192 tokens, 30 heads, 96 / 192) takes 8.8 ms forward
+and 10.8 ms backward, the XLA around the kernels included, where the XLA
+form took 10.9 and 31.6 (PERF.md section 6, PR 33). A token model still refuses ``kernel_backend="pallas"``: that
+names the MLP flag kernels.
 """
 
 import functools
@@ -888,3 +901,270 @@ def _kernel_bytes(batch_rows, sizes, state_mirrors=0):
     masks = batch_rows * sum(widths[1:-1])
     io = batch_rows * (widths[0] + widths[-1])
     return 4 * (2 * params + state + acts + masks + io)
+
+
+# ---------------------------------------------------------------------------
+# The gated delta rule's chunked scan (``ops.gated_delta_scan``'s kernel
+# form). One grid step is one chunk of ``heads_per_step`` heads of one row:
+# the chunk axis is the grid's last and runs in order, the (d_k x d_v) state
+# of each head lives in a VMEM scratch across it, and a chunk's (c x c)
+# matrices are values that never leave VMEM. The forward hands back, beside
+# ``o``, the state ENTERING each chunk and the chunk's triangular inverse;
+# the backward walks the chunks in reverse with the state's cotangent in the
+# scratch, rebuilds the rest from ``q, k, v`` and the packed per-token rows,
+# and differentiates the inverse by ``dA = -T^T dT T^T``. The wrapper in
+# ``ops.py`` makes the layout (heads before tokens), the per-token rows and
+# their pull-back in XLA.
+# ---------------------------------------------------------------------------
+
+# rows of the packed per-token block (8, chunk) of one chunk and head
+GDN_G, GDN_BETA, GDN_SEG, GDN_G_IN, GDN_G_OUT, GDN_KEEP = range(6)
+GDN_ROWS = 8
+GDN_HEADS_PER_STEP = 2
+
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _gdn_dot(precision):
+    def dot(a, b, dims=_NN):
+        return jax.lax.dot_general(
+            a, b, (dims, ((), ())), precision=precision,
+            preferred_element_type=jnp.float32,
+        )
+
+    return dot
+
+
+def _gdn_chunk(k, p, dot):
+    """What both passes build of one chunk of one head, before the inverse:
+    the per-token rows as columns, the masked decay matrix ``D``, ``k k^T``
+    and the strictly lower ``A``. ``p``: (8, c), rows ``GDN_*``."""
+    c = k.shape[0]
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    cols = p.T  # (c, 8): the rows as columns
+    col = {r: cols[:, r : r + 1] for r in range(GDN_KEEP)}
+    pair = (col[GDN_SEG] == p[GDN_SEG : GDN_SEG + 1]) & (i >= j)
+    decay = jnp.where(
+        pair, jnp.exp(jnp.where(pair, col[GDN_G] - p[GDN_G : GDN_G + 1], 0.0)), 0.0
+    )
+    kk = dot(k, k, _NT)
+    a = jnp.where(i > j, col[GDN_BETA] * kk * decay, 0.0)
+    return i, j, col, decay, kk, a
+
+
+def _gdn_inverse(a, i, j, leaf, dot):
+    """``(I + a)^-1`` for the strictly lower (c, c) ``a``, by
+    ``ops._unit_lower_inverse``'s steps: the ``leaf``-row diagonal blocks by
+    the finite series ``(I - a)(I + a^2)(I + a^4)...``, then neighbouring
+    blocks merged, ``t21 = -(t22 a21) t11``, until one is left. A product
+    costs the MXU its left operand's ROWS (on the chip 32 rows against a
+    (128 x 128) right operand take a quarter of the time of 128), so the
+    series runs on the blocks laid side by side, (leaf, c), against the
+    same blocks as one block-diagonal right operand, and a merge multiplies
+    only the rows of each pair's second block. ``leaf`` and ``c`` are
+    powers of two, so ``i ^ j < b`` says "same block of b rows"."""
+    c = a.shape[0]
+    same_leaf = (i ^ j) < leaf
+
+    def side_by_side(m):  # block-diagonal (c, c) -> (leaf, c)
+        out = m[:leaf]
+        for r in range(leaf, c, leaf):
+            out = out + m[r : r + leaf]
+        return out
+
+    def diagonal(m):  # (leaf, c) -> block-diagonal (c, c)
+        return jnp.where(same_leaf, jnp.concatenate([m] * (c // leaf), axis=0), 0.0)
+
+    power_d = jnp.where(same_leaf, -a, 0.0)
+    power = side_by_side(power_d)
+    at = jax.lax.broadcasted_iota(jnp.int32, (leaf, c), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (leaf, c), 1)
+    inverse = ((lane & (leaf - 1)) == at).astype(a.dtype) + power
+    done = 2  # powers 0 .. done - 1 are in ``inverse``
+    while done < leaf:
+        power = dot(power, power_d)
+        power_d = diagonal(power)
+        inverse = inverse + dot(inverse, power_d)
+        done *= 2
+    t = diagonal(inverse)
+    b = leaf
+    while b < c:
+        a21 = jnp.where(((i ^ j) < 2 * b) & ((i ^ j) >= b), a, 0.0)
+        pairs = range(0, c, 2 * b)
+        t22 = jnp.concatenate([t[s + b : s + 2 * b] for s in pairs], axis=0)
+        t2 = t22 - dot(dot(t22, a21), t)  # the rows [t21 | t22] of every pair
+        t = jnp.concatenate(
+            [
+                part
+                for n, s in enumerate(pairs)
+                for part in (t[s : s + b], t2[n * b : (n + 1) * b])
+            ],
+            axis=0,
+        )
+        b *= 2
+    return t
+
+
+def _gdn_fwd_kernel(
+    q_ref, k_ref, v_ref, p_ref, o_ref, s_ref, t_ref, state, *, leaf, precision
+):
+    dot = _gdn_dot(precision)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    for h in range(q_ref.shape[0]):
+        q, k, v, p = q_ref[h], k_ref[h], v_ref[h], p_ref[h, 0]
+        i, j, col, decay, _, a = _gdn_chunk(k, p, dot)
+        # float32 passes whatever ``precision``, as ``ops._unit_lower_inverse``
+        t = _gdn_inverse(a, i, j, leaf, _gdn_dot(jax.lax.Precision.HIGHEST))
+        s = state[h]
+        s_ref[h, 0] = s
+        t_ref[h] = t
+        w = dot(t, (col[GDN_BETA] * col[GDN_G_IN]) * k)
+        u = dot(t, col[GDN_BETA] * v) - dot(w, s)
+        o_ref[h] = dot(q * col[GDN_G_IN], s) + dot(dot(q, k, _NT) * decay, u)
+        keep = jnp.sum(p[GDN_KEEP : GDN_KEEP + 1])  # lane 0 holds it, the rest 0
+        state[h] = s * keep + dot(k * col[GDN_G_OUT], u, _TN)
+
+
+def _gdn_bwd_kernel(
+    q_ref, k_ref, v_ref, p_ref, s_ref, t_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dp_ref, dstate, *, precision,
+):
+    dot = _gdn_dot(precision)
+
+    @pl.when(pl.program_id(1) == 0)  # the LAST chunk: the index maps run reversed
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    def lanes(x):  # (c, d) -> (c, 1)
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    for h in range(q_ref.shape[0]):
+        q, k, v, p = q_ref[h], k_ref[h], v_ref[h], p_ref[h, 0]
+        s, t, do, ds_out = s_ref[h, 0], t_ref[h], do_ref[h], dstate[h]
+        i, j, col, decay, kk, _ = _gdn_chunk(k, p, dot)
+        beta, g_in, g_out = col[GDN_BETA], col[GDN_G_IN], col[GDN_G_OUT]
+        keep = jnp.sum(p[GDN_KEEP : GDN_KEEP + 1])
+        # the forward's values again
+        u0 = dot(t, beta * v)
+        w = dot(t, (beta * g_in) * k)
+        u = u0 - dot(w, s)
+        qk = dot(q, k, _NT)
+        # O = (q g_in) S + (qk . D) U;  S' = keep S + (k g_out)^T U
+        du = dot(qk * decay, do, _TN) + dot(k * g_out, ds_out)
+        dqk = dot(do, u, _NT)
+        dq_in = dot(do, s, _NT)
+        dk_out = dot(u, ds_out, _NT)
+        dw = -dot(du, s, _NT)  # U = U0 - W S
+        dstate[h] = keep * ds_out + dot(q * g_in, do, _TN) - dot(w, du, _TN)
+        dkeep = jnp.sum(ds_out * s)
+        # [U0 | W] = T [beta v | beta g_in k];  dA = -T^T dT T^T, strictly lower
+        dr_v = dot(t, du, _TN)
+        dr_k = dot(t, dw, _TN)
+        da = jnp.where(i > j, -(dot(dr_v, u0, _NT) + dot(dr_k, w, _NT)), 0.0)
+        dkk = da * beta * decay  # A = beta (k k^T) D
+        dpair = dqk * decay  # of q k^T
+        e = (da * beta * kk + dqk * qk) * decay  # dD . D: g_i takes rows, g_j columns
+        dk_rk = lanes(dr_k * k)
+        dq_ref[h] = dq_in * g_in + dot(dpair, k)
+        dk_ref[h] = (
+            dk_out * g_out + (beta * g_in) * dr_k
+            + dot(dkk + dkk.T, k) + dot(dpair, q, _TN)
+        )
+        dv_ref[h] = beta * dr_v
+        # the per-token cotangents, columns -> the rows of ``p``
+        z = (
+            jnp.where(j == GDN_G, lanes(e), 0.0)
+            + jnp.where(
+                j == GDN_BETA, lanes(da * kk * decay) + lanes(dr_v * v) + g_in * dk_rk, 0.0
+            )
+            + jnp.where(j == GDN_G_IN, lanes(dq_in * q) + beta * dk_rk, 0.0)
+            + jnp.where(j == GDN_G_OUT, lanes(dk_out * k), 0.0)
+        )
+        row = jax.lax.broadcasted_iota(jnp.int32, (GDN_ROWS, z.shape[1]), 0)
+        dp_ref[h, 0] = (
+            z.T[:GDN_ROWS]
+            - jnp.where(row == GDN_G, jnp.sum(e, axis=0, keepdims=True), 0.0)
+            + jnp.where(row == GDN_KEEP, dkeep, 0.0)
+        )
+
+
+def _gdn_specs(per, c, chunk_of):
+    """Block specs by operand kind, ``per`` heads to a block: arrays per
+    token (rows * heads, seq, d), and arrays per chunk, the packed rows
+    (rows * heads, n, 8, c) and the states (rows * heads, n, d_k, d_v);
+    ``chunk_of(j)`` is the chunk grid step ``j`` works on."""
+    def tokens(d):
+        return pl.BlockSpec((per, c, d), lambda i, j: (i, chunk_of(j), 0))
+
+    def per_chunk(*dims):
+        return pl.BlockSpec((per, 1, *dims), lambda i, j: (i, chunk_of(j), 0, 0))
+
+    return tokens, per_chunk
+
+
+def _gdn_heads_per_step(rh):
+    return GDN_HEADS_PER_STEP if rh % GDN_HEADS_PER_STEP == 0 else 1
+
+
+def gdn_scan_fwd(q, k, v, p, *, leaf, precision):
+    """``q, k``: (rows * heads, seq, d_k), ``v``: (rows * heads, seq, d_v),
+    ``p``: (rows * heads, n, 8, c) the packed per-token rows. -> ``o``
+    (rows * heads, seq, d_v), the state entering each chunk (rows * heads,
+    n, d_k, d_v) and each chunk's inverse (rows * heads, seq, c)."""
+    rh, seq, dk = q.shape
+    dv, n, c = v.shape[-1], p.shape[1], p.shape[-1]
+    per = _gdn_heads_per_step(rh)
+    tokens, per_chunk = _gdn_specs(per, c, lambda j: j)
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, leaf=leaf, precision=precision),
+        grid=(rh // per, n),
+        in_specs=[tokens(dk), tokens(dk), tokens(dv), per_chunk(GDN_ROWS, c)],
+        out_specs=[tokens(dv), per_chunk(dk, dv), tokens(c)],
+        out_shape=[
+            jax.ShapeDtypeStruct((rh, seq, dv), q.dtype),
+            jax.ShapeDtypeStruct((rh, n, dk, dv), q.dtype),
+            jax.ShapeDtypeStruct((rh, seq, c), q.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((per, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=_interpret(),
+        name="gdn_scan_fwd",
+    )(q, k, v, p)
+
+
+def gdn_scan_bwd(q, k, v, p, states, inverses, do, *, precision):
+    """The pull-back of ``gdn_scan_fwd``'s ``o``: -> ``dq, dk, dv`` shaped
+    like ``q, k, v`` and ``dp`` shaped like ``p`` (the cotangents of its
+    rows ``g``, ``beta``, ``g_in``, ``g_out`` and ``keep``)."""
+    rh, seq, dk = q.shape
+    dv, n, c = v.shape[-1], p.shape[1], p.shape[-1]
+    per = _gdn_heads_per_step(rh)
+    tokens, per_chunk = _gdn_specs(per, c, lambda j: n - 1 - j)
+    return pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, precision=precision),
+        grid=(rh // per, n),
+        in_specs=[
+            tokens(dk), tokens(dk), tokens(dv), per_chunk(GDN_ROWS, c),
+            per_chunk(dk, dv), tokens(c), tokens(dv),
+        ],
+        out_specs=[tokens(dk), tokens(dk), tokens(dv), per_chunk(GDN_ROWS, c)],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
+            jax.ShapeDtypeStruct(p.shape, q.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((per, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=_interpret(),
+        name="gdn_scan_bwd",
+    )(q, k, v, p, states, inverses, do)

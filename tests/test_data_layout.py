@@ -172,6 +172,9 @@ def test_the_record_says_which_orientation_ran(data_dir, tmp_path, kw, want):
     assert event["kind"] == "event" and event["layout"] == want == run.data_layout
     assert event["mb"] == GBS // kw.get("dp", 1) // M and event["F"] == SIZES[0]
     assert np.isfinite(loss)
+    # an MLP has no scan: no path, and no event of that name
+    assert run.scan_path is None
+    assert not [r for r in read_jsonl(path) if r.get("name") == "scan_path"]
 
 
 def test_trainer_refuses_the_orientation_off_the_scanned_path():

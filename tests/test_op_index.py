@@ -523,3 +523,59 @@ def test_token_epoch_program_lands_in_classes_on_the_chip(v5e_mesh):
     assert loose < 0.01 * total, (loose, total, [
         (e["opcode"], e["type"]) for e in alone if e["cls"] == "unattributed"
     ][:10])
+
+
+# -- a Pallas kernel traced under a scope (the scan's, ops.gated_delta_scan) --
+
+# as the chip's compiler prints the scan's two kernels (compiled for a
+# described v5e, PR 33): a ``custom-call`` whose ``op_name`` is the scope's
+# path, then the kernel's ``name=`` and ``pallas_call``
+KERNEL_TEXT = """HloModule jit_epoch_core, is_scheduled=true
+
+ENTRY %main.9 (q.1: f32[30,8192,96], p.1: f32[30,64,8,128]) -> f32[30,8192,192] {
+  %q.1 = f32[30,8192,96]{2,1,0:T(8,128)} parameter(0), metadata={op_name="q"}
+  %p.1 = f32[30,64,8,128]{3,2,1,0:T(8,128)} parameter(1), metadata={op_name="p"}
+  %gdn_scan_fwd.1 = (f32[30,8192,192]{2,1,0:T(8,128)}, f32[30,64,96,192]{3,2,1,0:T(8,128)}) custom-call(f32[30,8192,96]{2,1,0:T(8,128)} %q.1, f32[30,64,8,128]{3,2,1,0:T(8,128)} %p.1), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[30,8192,96]{2,1,0}, f32[30,64,8,128]{3,2,1,0}}, metadata={op_name="jit(epoch_core)/while/body/closed_call/gdn/scan/gdn_scan_fwd/pallas_call" source_file="pallas_ops.py" source_line=1}
+  %copy.7 = f32[30,8192,96]{2,1,0:T(8,128)} copy(f32[30,8192,96]{2,1,0:T(8,128)} %q.1), metadata={op_name="jit(epoch_core)/while/body/closed_call/gdn/scan/transpose"}
+  ROOT %get-tuple-element.1 = f32[30,8192,192]{2,1,0:T(8,128)} get-tuple-element((f32[30,8192,192]{2,1,0:T(8,128)}, f32[30,64,96,192]{3,2,1,0:T(8,128)}) %gdn_scan_fwd.1), index=0, metadata={op_name="jit(epoch_core)/while/body/closed_call/gdn/scan/gdn_scan_fwd/pallas_call"}
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "name,want",
+    [
+        ("gdn_scan_fwd.1", dict(opcode="custom-call", cls="gdn_scan", scope="gdn/scan")),
+        ("copy.7", dict(opcode="copy", cls="gdn_scan", scope="gdn/scan")),
+        ("get-tuple-element.1", dict(opcode="get-tuple-element", cls="gdn_scan", scope="gdn/scan")),
+    ],
+)
+def test_a_kernel_under_a_scope_takes_the_scopes_class(name, want):
+    entry = op_index(KERNEL_TEXT)[name]
+    assert {key: entry.get(key) for key in want} == want
+    assert not entry["container"]
+
+
+def test_the_scan_kernels_trace_under_the_scans_scope():
+    """The two ``pallas_call``s of the scan carry ``gdn/scan`` in their
+    path, after the scope and before the kernel's own name: what the chip's
+    compiler copies into the ``custom-call``'s ``op_name``."""
+    import jax.numpy as jnp
+
+    from shallowspeed_tpu import ops
+
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+
+    def both(q, k, v, beta, log_decay, seg, do):
+        o, back = ops.gated_delta_scan(q, k, v, beta, log_decay, seg)
+        return o, back(do)
+
+    text = jax.jit(both).lower(
+        shape(1, 256, 2, 8), shape(1, 256, 2, 8), shape(1, 256, 2, 16),
+        shape(1, 256, 2), shape(1, 256, 2),
+        jax.ShapeDtypeStruct((1, 256), jnp.int32), shape(1, 256, 2, 16),
+    ).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]+)"', text))
+    for kernel in ("gdn_scan_fwd", "gdn_scan_bwd"):
+        (path,) = [p for p in paths if p.endswith(f"{kernel}/pallas_call")]
+        assert scopes.scope_of(path) == ("gdn/scan", "gdn_scan"), path

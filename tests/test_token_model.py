@@ -243,7 +243,8 @@ def test_one_microbatch_against_two(token_set, trained):
 
 
 def test_session_leaves_the_sets_counts_with_the_program(token_set, trained):
-    counts = scopes.program_counts("jit_epoch_core")
+    counts = dict(scopes.program_counts("jit_epoch_core"))
+    assert counts.pop("scan_kernel_calls") == 0  # 6 and 12 wide: the XLA form
     assert counts == packed_counts(token_set[2]) == ref.packed_counts(token_set[2])
     assert counts["tokens"] == 8 * SEQ
     assert counts["documents"] <= counts["tokens"] <= counts["pairs"]
@@ -252,6 +253,126 @@ def test_session_leaves_the_sets_counts_with_the_program(token_set, trained):
 def test_packed_counts_by_hand():
     seg = np.array([[0, 0, 0, 1, 1, 9], [0, 1, 2, 2, 2, 9]])  # the last column is no input
     assert packed_counts(seg) == {"tokens": 10, "documents": 5, "pairs": (1 + 2 + 3 + 1 + 2) + (1 + 1 + 1 + 2 + 3)}
+
+
+# -- which form the scan runs: the session says, by the shapes alone ---------
+
+TILING = {**TINY, "linear_key_head_dim": 8, "linear_value_head_dim": 16}
+SCAN_SEQ = 128  # one chunk of exactly 128 tokens
+
+
+@pytest.fixture(scope="module")
+def on_kernels(tmp_path_factory):
+    """One step of a session whose head sizes and sequence the kernels take
+    (interpreted here), through the reference from the same start, and what
+    the session wrote."""
+    from shallowspeed_tpu.observability import JsonlMetrics, read_jsonl
+
+    packed = _load(ROOT / "benchmarks" / "datasets" / "packed_tokens.py", "packed_tokens")
+    data_dir = tmp_path_factory.mktemp("tokens128")
+    tokens, segments = packed.make_dataset(
+        5, 2, {"seq_len": SCAN_SEQ}, {"vocab_size": TILING["vocab_size"]}, data_dir
+    )
+    with JsonlMetrics(data_dir / "run.jsonl") as metrics:
+        session = _session(
+            data_dir, model=TILING, seq_len=SCAN_SEQ, global_batch_size=2,
+            metrics=metrics,
+        )
+        start = check.layers(session.params())
+        steps, loss = session.train_steps(1)
+    config = {"session": dict(model=TILING, optimizer="sgd", lr=0.5, precision="highest")}
+    want, want_losses = ref.make_reference(config)(
+        start, *check.prefix([np.array(tokens), np.array(segments)], 1, 2, 2)
+    )
+    return dict(
+        session=session, start=start, after=check.layers(session.params()),
+        loss=loss, want=want, want_losses=want_losses,
+        events=[r for r in read_jsonl(data_dir / "run.jsonl") if r.get("name") == "scan_path"],
+        counts=scopes.program_counts("jit_epoch_core"),
+    )
+
+
+def test_session_on_the_kernels_trains_the_references_step(on_kernels):
+    report = check.compare(
+        on_kernels["after"], on_kernels["want"], on_kernels["start"],
+        dict(update_rtol=1e-3, weight_ulps=2, loss_rtol=1e-5),
+        loss=on_kernels["loss"], ref_loss=on_kernels["want_losses"][0],
+    )
+    assert report["ok"], report
+
+
+def test_session_says_which_form_the_scan_runs(on_kernels, trained):
+    assert on_kernels["session"].scan_path == "pallas"
+    assert trained["session"].scan_path == "xla"
+
+
+def test_scan_path_event_and_the_programs_count(on_kernels):
+    spec = on_kernels["session"].spec
+    # 3 Gated DeltaNet layers x 2 microbatches x (forward [+ forward again] + backward)
+    calls = 3 * 2 * (3 if spec.recompute else 2)
+    (event,) = on_kernels["events"]
+    fields = {k: event[k] for k in ("path", "chunk", "d_k", "d_v", "kernel_calls_per_step")}
+    assert fields == dict(path="pallas", chunk=128, d_k=8, d_v=16, kernel_calls_per_step=calls)
+    assert on_kernels["counts"]["scan_kernel_calls"] == calls  # an epoch of one step
+    assert on_kernels["counts"]["tokens"] == 2 * SCAN_SEQ
+
+
+@pytest.mark.parametrize(
+    "changes,seq,recompute,want",
+    [
+        ({}, SEQ, True, dict(path="xla", chunk=48, kernel_calls_per_step=0)),
+        ({}, 64, False, dict(path="xla", chunk=64, kernel_calls_per_step=0)),  # the rehearsal's
+        (TILING, 128, False, dict(path="pallas", chunk=128, kernel_calls_per_step=12)),
+        (TILING, 256, True, dict(path="pallas", chunk=128, kernel_calls_per_step=18)),
+        # the cell's: 3 layers x 2 microbatches x (2 forwards + 1 backward)
+        (dict(linear_key_head_dim=96, linear_value_head_dim=192), 8192, True,
+         dict(path="pallas", chunk=128, d_k=96, d_v=192, kernel_calls_per_step=18)),
+    ],
+)
+def test_scan_plan_counts_layers_microbatches_and_passes(changes, seq, recompute, want):
+    spec = Mo.make_token_spec({**TINY, **changes}, seq, 2, recompute=recompute)
+    plan = Mo.token_scan_plan(spec, mubatches=2)
+    assert {k: plan[k] for k in want} == want
+
+
+@pytest.mark.parametrize(
+    "sizes,batch,layout",
+    [
+        ((784, 128, 127, 126, 125, 124, 123, 10), 128, "row_major"),  # mnist-mlp, narrow
+        ((784, 128, 127, 126, 125, 124, 123, 10), 2048, "feature_major"),
+        ((784,) + (64,) * 6 + (10,), 256, "row_major"),  # mlp-deep's form, thin
+    ],
+)
+def test_mlp_epoch_program_never_asks_the_scans_rule(monkeypatch, sizes, batch, layout):
+    """The MLP's lowered text with the scan's rule, its wrapper and the
+    kernels' module broken is the text without: nothing of them is traced."""
+    import sys
+
+    def lowered():
+        spec = Mo.make_model_spec(sizes, 1, batch)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), Mo.init_model(spec)
+        )
+        mb = batch // 4
+        x = (2, 4, sizes[0], mb) if layout == "feature_major" else (2, 4, mb, sizes[0])
+        epoch = trainer.make_train_epoch(spec, SGD(0.006), precision=HIGHEST, x_layout=layout)
+        return epoch.lower(
+            params, (), jax.ShapeDtypeStruct(x, jnp.float32),
+            jax.ShapeDtypeStruct((2, 4, mb, sizes[-1]), jnp.float32),
+        ).as_text()
+
+    plain = lowered()
+
+    def broken(*a, **kw):
+        raise AssertionError("an MLP reached the scan")
+
+    from shallowspeed_tpu import ops
+
+    for name in ("scan_path", "gated_delta_scan", "_gated_delta_scan_pallas"):
+        monkeypatch.setattr(ops, name, broken)
+    monkeypatch.setitem(sys.modules, "shallowspeed_tpu.pallas_ops", None)
+    assert lowered() == plain
+    assert "gdn" not in plain and "custom_call" not in plain
 
 
 REFUSED = {

@@ -5,6 +5,7 @@ gradients, against the expressions of the plain reference the benchmark
 keeps, ``benchmarks/references/olmo_hybrid.py``, and ``jax.grad`` of them.
 tests/test_token_model.py holds the whole model and the session."""
 
+import functools
 import importlib.util
 from pathlib import Path
 
@@ -171,3 +172,111 @@ def test_chunked_scan_is_the_token_by_token_rule(chunk, block, neg_eigval):
     for got, expected in zip(back(d), grads):
         # a document's first token takes no decay in either form
         _close(got, expected)
+
+
+# -- the scan's kernel form (pallas_ops.gdn_scan_fwd / _bwd), interpreted ----
+
+KERNEL_CASES = {
+    # name: (rows, seq, neg_eigval, document starts by row)
+    "two-rows-two-chunks-neg": (
+        2, 256, True,
+        # inside a chunk; at a chunk's last token, at the next chunk's first
+        # (one document of one token across the boundary); then none at all
+        [[40, 127, 128, 200], []],
+    ),
+    "one-row-one-chunk": (1, 128, False, [[1, 77, 127]]),
+    # the state crosses two boundaries and is cut at a third; a start at a
+    # chunk's first token and one at the row's last
+    "one-row-three-chunks": (1, 384, False, [[256, 300, 383]]),
+}
+KERNEL_OUTPUTS = ("o", "dq", "dk", "dv", "dbeta", "dlog_decay")
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_run(case):
+    """``{form: {output: array}}`` for the kernels, the XLA form and the
+    token-by-token rule on one case's inputs. One run per case."""
+    rows, seq, neg_eigval, starts = KERNEL_CASES[case]
+    heads, dk, dv = 2, 8, 16
+    rng = np.random.default_rng(7)
+    q, k = _rand(rng, rows, seq, heads, dk), _rand(rng, rows, seq, heads, dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v, d = _rand(rng, rows, seq, heads, dv), _rand(rng, rows, seq, heads, dv)
+    beta = jax.nn.sigmoid(_rand(rng, rows, seq, heads)) * (2.0 if neg_eigval else 1.0)
+    log_decay = -jax.nn.softplus(_rand(rng, rows, seq, heads))
+    first = np.zeros((rows, seq), bool)
+    for r, at in enumerate(starts):
+        first[r, at] = True
+    seg = jnp.asarray(np.cumsum(first, axis=1).astype(np.int32))
+    first[:, 0] = True  # a row's first token starts a document
+    assert ops.scan_path(seq, ops.SCAN_CHUNK, dk, dv, q.dtype) == "pallas"
+
+    def rule(q, k, v, beta, log_decay):
+        return jnp.stack([
+            ref.delta_rule(q[r], k[r], v[r], beta[r], jnp.exp(log_decay[r]), jnp.asarray(first[r]))
+            for r in range(rows)
+        ])
+
+    args = (q, k, v, beta, log_decay)
+    runs = {}
+    for form, fn in (
+        ("kernels", lambda *a: ops.gated_delta_scan(*a, seg)),
+        ("xla", lambda *a: ops._gated_delta_scan_xla(
+            *a, seg, ops.SCAN_PRECISION, ops.SCAN_CHUNK, ops.SCAN_BLOCK)),
+    ):
+        o, back = fn(*args)
+        runs[form] = dict(zip(KERNEL_OUTPUTS, (o, *back(d))))
+    grads = jax.grad(lambda *a: jnp.sum(rule(*a) * d), (0, 1, 2, 3, 4))(*args)
+    runs["rule"] = dict(zip(KERNEL_OUTPUTS, (rule(*args), *grads)))
+    return runs
+
+
+@pytest.mark.parametrize("output", KERNEL_OUTPUTS)
+@pytest.mark.parametrize("oracle", ["rule", "xla"])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_scan_kernels_are_the_rule_and_the_xla_form(case, oracle, output):
+    """Documents start inside a chunk, at a chunk's last and first token and
+    at a row's first; a row without a start carries its state across the
+    chunk boundary."""
+    runs = _kernel_run(case)
+    _close(runs["kernels"][output], runs[oracle][output])
+
+
+@pytest.mark.parametrize(
+    "seq,chunk,dk,dv,dtype,want",
+    [
+        (8192, 128, 96, 192, jnp.float32, "pallas"),  # the cell
+        (256, 128, 8, 16, jnp.float32, "pallas"),  # the kernels' tests
+        (128, 128, 256, 256, jnp.float32, "pallas"),
+        (64, 128, 6, 12, jnp.float32, "xla"),  # the rehearsal: one chunk of 64
+        (48, 8, 6, 12, jnp.float32, "xla"),  # this file's chunkings
+        (48, 16, 6, 12, jnp.float32, "xla"),
+        (8192, 256, 96, 192, jnp.float32, "xla"),  # a chunk of 256
+        (8192, 64, 96, 192, jnp.float32, "xla"),
+        (8192, 128, 100, 192, jnp.float32, "xla"),  # d_k off the sublane tile
+        (8192, 128, 96, 264, jnp.float32, "xla"),  # d_v beyond what was measured
+        (8192, 128, 96, 192, jnp.bfloat16, "xla"),
+        (8200, 128, 96, 192, jnp.float32, "xla"),  # 8200 = 82 chunks of 100
+    ],
+)
+def test_the_kernels_engage_by_shape_alone(seq, chunk, dk, dv, dtype, want):
+    assert ops.scan_path(seq, chunk, dk, dv, dtype) == want
+
+
+@pytest.mark.parametrize(
+    "precision,want",
+    [(lax.Precision.HIGHEST, "pallas"), (lax.Precision.DEFAULT, "pallas"),
+     (lax.Precision.HIGH, "xla")],  # Mosaic lowers no three-pass product
+)
+def test_the_kernels_take_the_precisions_mosaic_lowers(precision, want):
+    assert ops.scan_path(8192, 128, 96, 192, jnp.float32, precision) == want
+
+
+def test_nothing_but_the_shapes_selects_the_kernels(monkeypatch):
+    """No environment variable and no flag: the MLP kernels' switch leaves
+    the scan's rule alone."""
+    monkeypatch.setenv("SHALLOWSPEED_PALLAS", "0")
+    monkeypatch.setattr(ops, "_PALLAS", False)
+    assert ops.scan_path(8192, 128, 96, 192, jnp.float32) == "pallas"
+    monkeypatch.setattr(ops, "_PALLAS", True)
+    assert ops.scan_path(48, 8, 6, 12, jnp.float32) == "xla"
