@@ -57,6 +57,7 @@ from shallowspeed_tpu.observability.slo import (
 from shallowspeed_tpu.optimizer import (
     is_stateless,
     join_state,
+    WithGradScratch,
     make_optimizer,
     split_state,
 )
@@ -609,6 +610,16 @@ class TrainingSession:
             )
             if self._metrics.enabled:
                 self._metrics.event("scan_path", **scan_plan)
+            # a model with routed layers: what the experts held here were
+            # routed comes with each epoch's loss (``_run_epoch_program``)
+            # and starts at nothing
+            if self.spec.routed_layers:
+                held = self.spec.experts_held
+                self._token_counts.update(
+                    moe_layers=self.spec.routed_layers,
+                    moe_experts_held=held[1] - held[0],
+                    moe_rows_held=0, moe_load_max=0,
+                )
             # an id outside the table raises nothing on the device (the
             # lookup clamps it, the scatter-add drops it)
             ids = self._train_ds.input_X
@@ -626,6 +637,10 @@ class TrainingSession:
         if clip_norm is not None and clip_norm <= 0:
             raise ValueError("clip_norm must be positive (or None to disable)")
         opt = self._opt = make_optimizer(optimizer, lr, momentum, weight_decay)
+        if self._token and trainer.token_step_is_scanned(mubatches):
+            # the step loops over its microbatches and keeps its gradient
+            # accumulator in the optimizer's state (a donated argument)
+            opt = self._opt = WithGradScratch(opt)
         self._opt_config = {
             "name": optimizer,
             "lr": lr,
@@ -1464,10 +1479,10 @@ class TrainingSession:
         the MPMD runtime's epoch is a host loop over stage programs, not one
         program, and is not registered."""
         if args[-1].shape != self._registered_shape and self.runtime != "mpmd":
-            name = scopes.register_program(self._epoch_fn, args)
+            self._program_name = scopes.register_program(self._epoch_fn, args)
             self._registered_shape = args[-1].shape
             if self._token:
-                scopes.record_counts(name, self._token_counts)
+                scopes.record_counts(self._program_name, self._token_counts)
         metrics = self._metrics if self._metrics.enabled else None
         with host_span("epoch/dispatch", metrics):
             out = self._epoch_fn(*args)
@@ -1477,6 +1492,13 @@ class TrainingSession:
             self._stacked, self._opt_state = out[0], out[1]
         with host_span("epoch/readback", metrics):
             loss = float(out[2])  # forces device completion
+            if self._token and self.spec.routed_layers:
+                # the program's last output, complete with the loss: the
+                # pairs this epoch routed to the experts held, and the most
+                # one of them took in a step
+                held, most = (int(n) for n in np.asarray(out[-1]))
+                self._token_counts.update(moe_rows_held=held, moe_load_max=most)
+                scopes.record_counts(self._program_name, self._token_counts)
         return out, loss
 
     def train_steps(self, n):

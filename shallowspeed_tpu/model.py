@@ -393,34 +393,69 @@ def model_backward(
 
 # ---------------------------------------------------------------------------
 # Token models: a function of token ids, built from the keys of a published
-# ``config.json`` (not from a tuple of ``sizes``). One family so far,
-# ``olmo_hybrid``: layers of Gated DeltaNet (``linear_attention``) and of
-# full attention in the pattern ``layer_types`` gives, an embedding, a final
-# RMSNorm, an untied head, mean cross-entropy over the (sliced) vocabulary.
-# The equations are written out in benchmarks/references/olmo_hybrid.py.
-# The sequential path only (trainer.py); the mesh executor's stage functions
-# know Linears and nothing else (ROADMAP R0a, D2).
+# ``config.json`` (not from a tuple of ``sizes``), each family from ITS keys.
+# Two families (``model_type``):
+#
+# ``olmo_hybrid``: layers of Gated DeltaNet (``linear_attention``) and of full
+# attention in the pattern ``layer_types`` gives, norms on the branches
+# (``h = x + norm(mix(x))``), a dense SwiGLU. Written out in
+# benchmarks/references/olmo_hybrid.py.
+#
+# ``solar_open2``: layers of Kimi-style delta attention with a decay per key
+# channel (``kda``) and of gated grouped-query attention without a positional
+# term (``gqa``, at the indices ``gqa_layers`` gives), pre-norm residuals
+# (``h = x + mix(norm(x))``), and in every layer a routed mixture of SwiGLU
+# experts with a shared expert. The router scores all ``n_routed_experts``;
+# this chip HOLDS the range ``routed_experts_held = [lo, hi)`` of them and
+# computes their part of the result (the others are other chips'; nothing
+# stands in for them). Written out in benchmarks/references/solar_open2.py.
+#
+# Both: an embedding, a final RMSNorm, an untied head, mean cross-entropy
+# over the (sliced) vocabulary. The sequential path only (trainer.py); the
+# mesh executor's stage functions know Linears and nothing else (ROADMAP
+# R0a, D2).
 #
 # Parameters: ONE stage whose layers are dictionaries of arrays: the
 # embedding ``{"E"}``, one dictionary per layer, the head ``{"norm", "W"}``.
-# Weights are (out, in), as every Linear's.
+# Weights are (out, in), as every Linear's; the held experts' are stacked,
+# (held, out, in).
 # ---------------------------------------------------------------------------
 
 # name -> the configuration file, relative to the checkout: the published
 # keys live in ONE place, the file the benchmark's configuration names too
-TOKEN_MODELS = {"olmo-hybrid-7b": "benchmarks/configs/olmo-hybrid-7b.json"}
-
-_TOKEN_KEYS = (
-    "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
-    "num_attention_heads", "num_key_value_heads", "rms_norm_eps", "layer_types",
-    "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
-    "linear_value_head_dim", "linear_conv_kernel_dim", "linear_allow_neg_eigval",
-)
-# what a config.json may say beside them, and the one value this code covers
-_TOKEN_FIXED = {
-    "model_type": "olmo_hybrid", "hidden_act": "silu", "attention_bias": False,
-    "tie_word_embeddings": False,
+TOKEN_MODELS = {
+    "olmo-hybrid-7b": "benchmarks/configs/olmo-hybrid-7b.json",
+    "solar-open2-250b": "benchmarks/configs/solar-open2-250b.json",
 }
+
+# per family: the keys it reads, and what a config.json may say beside them
+# with the one value this code covers
+_TOKEN_KEYS = {
+    "olmo_hybrid": (
+        "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
+        "num_attention_heads", "num_key_value_heads", "rms_norm_eps", "layer_types",
+        "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+        "linear_value_head_dim", "linear_conv_kernel_dim", "linear_allow_neg_eigval",
+    ),
+    "solar_open2": (
+        "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
+        "head_dim", "num_key_value_heads", "rms_norm_eps", "gqa_layers",
+        "linear_attn_config", "kda_allow_neg_eigval", "n_routed_experts",
+        "routed_experts_held", "n_shared_experts", "num_experts_per_tok",
+        "moe_intermediate_size", "norm_topk_prob", "routed_scaling_factor",
+    ),
+}
+_TOKEN_FIXED = {
+    "olmo_hybrid": {
+        "hidden_act": "silu", "attention_bias": False, "tie_word_embeddings": False,
+    },
+    "solar_open2": {
+        "tie_word_embeddings": False, "use_rope": False, "use_gqa_gate": True,
+        "kda_use_full_proj": False, "first_k_dense_replace": 0,
+    },
+}
+# which scan a layer kind runs, where it runs one
+_SCAN_KINDS = ("linear_attention", "kda")
 # residual bytes of one microbatch above which a layer's forward is run
 # again in the backward instead of kept
 _RECOMPUTE_ABOVE_BYTES = 1 << 30
@@ -442,11 +477,13 @@ def token_model_config(model):
 
 @dataclasses.dataclass(frozen=True)
 class TokenModelSpec:
-    """Static description of a token model and the job's shape."""
+    """Static description of a token model and the job's shape. The fields
+    after ``attn_block`` are the second family's; a family without experts
+    holds none (``experts_held == (0, 0)``)."""
 
     vocab_size: int
     hidden_size: int
-    intermediate_size: int
+    intermediate_size: int  # the dense SwiGLU's width; a routed expert's
     layer_types: tuple
     num_attention_heads: int
     rms_norm_eps: float
@@ -460,92 +497,185 @@ class TokenModelSpec:
     recompute: bool = True  # run a layer's forward again in its backward
     scan_chunk: int = ops.SCAN_CHUNK
     attn_block: int = ops.ATTN_BLOCK
+    family: str = "olmo_hybrid"
+    num_key_value_heads: int = 0  # 0: as many as query heads
+    head_dim: int = 0  # 0: hidden_size // num_attention_heads
+    gate_rank: int = 0  # the low-rank decay and output-gate projections' rank
+    n_routed_experts: int = 0  # the router's width, as published
+    experts_held: tuple = (0, 0)  # [lo, hi) of them are this chip's
+    num_experts_per_tok: int = 0
+    shared_size: int = 0  # the shared expert's width (all of them side by side)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    moe_tile: int = ops.MOE_TILE
     n_stages = 1
 
     @property
     def step_tokens(self):
         return self.global_batch_size * self.seq_len
 
+    @property
+    def attn_head_dim(self):
+        return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def kv_heads(self):
+        return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def routed_layers(self):
+        """How many layers route (every layer of a family with experts)."""
+        return len(self.layer_types) if self.n_routed_experts else 0
+
 
 def token_scan_plan(spec: "TokenModelSpec", mubatches):
-    """Which form the Gated DeltaNet layers' scan runs at this spec's shapes
-    (``ops.scan_path``) and the kernel launches one optimizer step makes:
-    layers x microbatches x passes (a forward, the forward again where the
-    layer is recomputed, a backward; none where the XLA form runs). -> the
-    ``scan_path`` event's fields."""
-    path = ops.scan_path(
-        spec.seq_len, spec.scan_chunk, spec.linear_key_head_dim,
-        spec.linear_value_head_dim, jnp.float32,
-    )
-    layers = sum(kind == "linear_attention" for kind in spec.layer_types)
+    """Which form the recurrent layers' scan runs at this spec's shapes and
+    the kernel launches one optimizer step makes: layers x microbatches x
+    passes (a forward, the forward again where the layer is recomputed, a
+    backward; none where the XLA form runs). The Gated DeltaNet's scan has a
+    kernel form where its shapes tile (``ops.scan_path``); the per-channel
+    rule (``ops.kda_scan``) has the XLA form only. -> the ``scan_path``
+    event's fields."""
+    chunk = spec.scan_chunk
+    if spec.family == "solar_open2":
+        path = "xla"
+    else:
+        path = ops.scan_path(
+            spec.seq_len, chunk, spec.linear_key_head_dim,
+            spec.linear_value_head_dim, jnp.float32,
+        )
+    layers = sum(kind in _SCAN_KINDS for kind in spec.layer_types)
     passes = (3 if spec.recompute else 2) if path == "pallas" else 0
     return {
         "path": path,
-        "chunk": ops._block_len(spec.seq_len, spec.scan_chunk),
+        "chunk": ops._block_len(spec.seq_len, chunk),
         "d_k": spec.linear_key_head_dim,
         "d_v": spec.linear_value_head_dim,
         "kernel_calls_per_step": layers * mubatches * passes,
     }
 
 
-def _layer_residual_bytes(cfg, tokens):
+def _layer_residual_bytes(widest, tokens):
     """Float32 bytes one layer keeps between its forward and its backward
     where nothing is recomputed, roughly: the widest of its intermediates,
     a handful of times."""
-    widest = max(cfg["intermediate_size"], 2 * cfg["hidden_size"])
     return 4 * 8 * widest * tokens
 
 
-def make_token_spec(
-    config, seq_len, global_batch_size, mubatch_rows=None, recompute=None
-) -> TokenModelSpec:
-    """``config``: the published keys (``token_model_config``). Refuses what
-    the equations here do not cover. ``recompute=None`` decides from the
-    microbatch: kept where one microbatch's residuals of all layers stay
-    under ``_RECOMPUTE_ABOVE_BYTES``."""
-    missing = [k for k in _TOKEN_KEYS if k not in config]
-    if missing:
-        raise ValueError(f"token model configuration lacks {missing}")
-    for key, value in _TOKEN_FIXED.items():
-        if config.get(key, value) != value:
-            raise ValueError(f"token models cover {key}={value!r} only, got {config[key]!r}")
-    layer_types = tuple(config["layer_types"])
-    if len(layer_types) != config["num_hidden_layers"]:
-        raise ValueError("layer_types does not list num_hidden_layers layers")
-    unknown = set(layer_types) - {"linear_attention", "full_attention"}
-    if unknown:
-        raise ValueError(f"unknown layer types {sorted(unknown)}")
+def _olmo_spec_fields(config):
     if config["num_key_value_heads"] != config["num_attention_heads"]:
-        raise ValueError("grouped key/value heads are not covered (ROADMAP R8)")
+        raise ValueError(
+            "olmo_hybrid is covered with num_key_value_heads == "
+            "num_attention_heads, its published shape (grouped key/value "
+            "heads run in the solar_open2 family's layers)"
+        )
     if config["linear_num_key_heads"] != config["linear_num_value_heads"]:
         raise ValueError("linear_num_key_heads != linear_num_value_heads is not covered")
     if (config.get("rope_parameters") or {}).get("rope_theta") is not None:
         raise ValueError("rotary embeddings are not covered (ROADMAP R8)")
     if config["hidden_size"] % config["num_attention_heads"]:
         raise ValueError("num_attention_heads must divide hidden_size")
-    if seq_len is None or seq_len < 1:
-        raise ValueError("a token model needs seq_len (train.py --seq-len)")
-    if recompute is None:
-        rows = mubatch_rows or global_batch_size
-        recompute = (
-            len(layer_types) * _layer_residual_bytes(config, rows * seq_len)
-            > _RECOMPUTE_ABOVE_BYTES
-        )
-    return TokenModelSpec(
-        vocab_size=config["vocab_size"],
-        hidden_size=config["hidden_size"],
+    layer_types = tuple(config["layer_types"])
+    unknown = set(layer_types) - {"linear_attention", "full_attention"}
+    if unknown:
+        raise ValueError(f"unknown layer types {sorted(unknown)}")
+    return dict(
         intermediate_size=config["intermediate_size"],
         layer_types=layer_types,
-        num_attention_heads=config["num_attention_heads"],
-        rms_norm_eps=config["rms_norm_eps"],
         linear_num_heads=config["linear_num_value_heads"],
         linear_key_head_dim=config["linear_key_head_dim"],
         linear_value_head_dim=config["linear_value_head_dim"],
         linear_conv_kernel_dim=config["linear_conv_kernel_dim"],
         linear_allow_neg_eigval=bool(config["linear_allow_neg_eigval"]),
+    ), max(config["intermediate_size"], 2 * config["hidden_size"])
+
+
+def _solar_spec_fields(config):
+    linear = config["linear_attn_config"]
+    heads, kv = config["num_attention_heads"], config["num_key_value_heads"]
+    if heads % kv:
+        raise ValueError("num_key_value_heads must divide num_attention_heads")
+    if linear.get("num_kv_heads") not in (None, linear["num_heads"]):
+        raise ValueError("linear_attn_config.num_kv_heads other than num_heads is not covered")
+    gqa = set(config["gqa_layers"])
+    if not gqa <= set(range(config["num_hidden_layers"])):
+        raise ValueError("gqa_layers names a layer past num_hidden_layers")
+    lo, hi = config["routed_experts_held"]
+    if not 0 <= lo < hi <= config["n_routed_experts"]:
+        raise ValueError(
+            f"routed_experts_held {[lo, hi]} is no range of the "
+            f"{config['n_routed_experts']} routed experts"
+        )
+    if config["num_experts_per_tok"] > config["n_routed_experts"]:
+        raise ValueError("num_experts_per_tok exceeds n_routed_experts")
+    head_dim = linear["head_dim"]
+    return dict(
+        family="solar_open2",
+        intermediate_size=config["moe_intermediate_size"],
+        layer_types=tuple(
+            "gqa" if i in gqa else "kda" for i in range(config["num_hidden_layers"])
+        ),
+        linear_num_heads=linear["num_heads"],
+        linear_key_head_dim=head_dim,
+        linear_value_head_dim=head_dim,
+        linear_conv_kernel_dim=linear["short_conv_kernel_size"],
+        linear_allow_neg_eigval=bool(config["kda_allow_neg_eigval"]),
+        scan_chunk=ops.KDA_CHUNK,
+        num_key_value_heads=kv,
+        head_dim=config["head_dim"],
+        # assumed: the low-rank projections' rank is one head's width
+        gate_rank=config.get("kda_gate_rank", head_dim),
+        n_routed_experts=config["n_routed_experts"],
+        experts_held=(lo, hi),
+        num_experts_per_tok=config["num_experts_per_tok"],
+        shared_size=config["moe_intermediate_size"] * config["n_shared_experts"],
+        norm_topk_prob=bool(config["norm_topk_prob"]),
+        routed_scaling_factor=float(config["routed_scaling_factor"]),
+    ), max(2 * config["hidden_size"], linear["num_heads"] * head_dim)
+
+
+_SPEC_FIELDS = {"olmo_hybrid": _olmo_spec_fields, "solar_open2": _solar_spec_fields}
+
+
+def make_token_spec(
+    config, seq_len, global_batch_size, mubatch_rows=None, recompute=None
+) -> TokenModelSpec:
+    """``config``: the published keys (``token_model_config``), read by the
+    family ``model_type`` names. Refuses what the equations here do not
+    cover. ``recompute=None`` decides from the microbatch: kept where one
+    microbatch's residuals of all layers stay under
+    ``_RECOMPUTE_ABOVE_BYTES``."""
+    family = config.get("model_type", "olmo_hybrid")
+    if family not in _TOKEN_KEYS:
+        raise ValueError(
+            f"token models cover model_type {sorted(_TOKEN_KEYS)}, got {family!r}"
+        )
+    missing = [k for k in _TOKEN_KEYS[family] if k not in config]
+    if missing:
+        raise ValueError(f"token model configuration lacks {missing}")
+    for key, value in _TOKEN_FIXED[family].items():
+        if config.get(key, value) != value:
+            raise ValueError(f"{family} is covered with {key}={value!r} only, got {config[key]!r}")
+    if seq_len is None or seq_len < 1:
+        raise ValueError("a token model needs seq_len (train.py --seq-len)")
+    fields, widest = _SPEC_FIELDS[family](config)
+    if len(fields["layer_types"]) != config["num_hidden_layers"]:
+        raise ValueError("layer_types does not list num_hidden_layers layers")
+    if recompute is None:
+        rows = mubatch_rows or global_batch_size
+        recompute = (
+            len(fields["layer_types"]) * _layer_residual_bytes(widest, rows * seq_len)
+            > _RECOMPUTE_ABOVE_BYTES
+        )
+    return TokenModelSpec(
+        vocab_size=config["vocab_size"],
+        hidden_size=config["hidden_size"],
+        num_attention_heads=config["num_attention_heads"],
+        rms_norm_eps=config["rms_norm_eps"],
         seq_len=int(seq_len),
         global_batch_size=int(global_batch_size),
         recompute=bool(recompute),
+        **fields,
     )
 
 
@@ -555,28 +685,61 @@ def token_layer_shapes(spec: TokenModelSpec):
     d, ff, v = spec.hidden_size, spec.intermediate_size, spec.vocab_size
     h, dk, dv = spec.linear_num_heads, spec.linear_key_head_dim, spec.linear_value_head_dim
     taps = spec.linear_conv_kernel_dim
-    mlp = {
-        "attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones"),
-        "W_gate": ((ff, d), "weight"), "W_up": ((ff, d), "weight"),
-        "W_down": ((d, ff), "weight"),
-    }
-    kinds = {
-        "linear_attention": {
-            "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
-            "Wv": ((h * dv, d), "weight"), "Wg": ((h * dv, d), "weight"),
-            "Wo": ((d, h * dv), "weight"),
-            "Wb": ((h, d), "weight"), "Wa": ((h, d), "weight"),
-            "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
-            "conv_v": ((h * dv, taps), "taps"),
-            "A_log": ((h,), "a_log"), "dt_bias": ((h,), "dt_bias"),
-            "o_norm": ((dv,), "ones"), **mlp,
-        },
-        "full_attention": {
-            "Wq": ((d, d), "weight"), "Wk": ((d, d), "weight"),
-            "Wv": ((d, d), "weight"), "Wo": ((d, d), "weight"),
-            "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"), **mlp,
-        },
-    }
+    norms = {"attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones")}
+    if spec.family == "solar_open2":
+        q_width, kv_width = (
+            spec.num_attention_heads * spec.attn_head_dim, spec.kv_heads * spec.attn_head_dim
+        )
+        held, rank, shared = spec.experts_held[1] - spec.experts_held[0], spec.gate_rank, spec.shared_size
+        moe = {
+            **norms,
+            "W_r": ((spec.n_routed_experts, d), "weight"),
+            "W1": ((held, ff, d), "weight"), "W3": ((held, ff, d), "weight"),
+            "W2": ((held, d, ff), "weight"),
+            "Ws1": ((shared, d), "weight"), "Ws3": ((shared, d), "weight"),
+            "Ws2": ((d, shared), "weight"),
+        }
+        kinds = {
+            "kda": {
+                "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
+                "Wv": ((h * dv, d), "weight"), "Wo": ((d, h * dv), "weight"),
+                "Wb": ((h, d), "weight"),
+                "W_fa": ((rank, d), "weight"), "W_fb": ((h * dk, rank), "weight"),
+                "W_ga": ((rank, d), "weight"), "W_gb": ((h * dv, rank), "weight"),
+                "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
+                "conv_v": ((h * dv, taps), "taps"),
+                "A_log": ((h,), "a_log"), "dt_bias": ((h * dk,), "dt_bias"),
+                "o_norm": ((dv,), "ones"), **moe,
+            },
+            "gqa": {
+                "Wq": ((q_width, d), "weight"), "Wk": ((kv_width, d), "weight"),
+                "Wv": ((kv_width, d), "weight"), "Wz": ((q_width, d), "weight"),
+                "Wo": ((d, q_width), "weight"), **moe,
+            },
+        }
+    else:
+        mlp = {
+            **norms,
+            "W_gate": ((ff, d), "weight"), "W_up": ((ff, d), "weight"),
+            "W_down": ((d, ff), "weight"),
+        }
+        kinds = {
+            "linear_attention": {
+                "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
+                "Wv": ((h * dv, d), "weight"), "Wg": ((h * dv, d), "weight"),
+                "Wo": ((d, h * dv), "weight"),
+                "Wb": ((h, d), "weight"), "Wa": ((h, d), "weight"),
+                "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
+                "conv_v": ((h * dv, taps), "taps"),
+                "A_log": ((h,), "a_log"), "dt_bias": ((h,), "dt_bias"),
+                "o_norm": ((dv,), "ones"), **mlp,
+            },
+            "full_attention": {
+                "Wq": ((d, d), "weight"), "Wk": ((d, d), "weight"),
+                "Wv": ((d, d), "weight"), "Wo": ((d, d), "weight"),
+                "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"), **mlp,
+            },
+        }
     return (
         [{"E": ((v, d), "weight")}]
         + [kinds[kind] for kind in spec.layer_types]
@@ -685,12 +848,166 @@ def _full_attention_mix(p, x, seg, spec, precision):
     return out, back
 
 
-def token_layer(p, x, seg, kind, spec, precision):
-    """One layer: ``h = x + norm(mix(x))``, ``y = h + norm(mlp(h))`` (the
-    family's norms sit on the branches). -> ``(y, back)``; ``back(dy) ->
-    (dx, grads)`` with ``grads`` shaped like ``p``."""
-    mix = _linear_attention_mix if kind == "linear_attention" else _full_attention_mix
-    mixed, mix_back = mix(p, x, seg, spec, precision)
+def _kda_mix(p, x, seg, spec, precision):
+    """Kimi-style delta attention: the Gated DeltaNet's projections,
+    convolutions and normalisation, a decay per key channel through a
+    low-rank projection (``W_fa``, ``W_fb``), the per-channel scan
+    (``ops.kda_scan``), the output RMS-normed per head and gated by the
+    sigmoid of a second low-rank projection. -> ``(out, back)``."""
+    h = spec.linear_num_heads
+    names = ("Wq", "Wk", "Wv", "Wb", "W_fa", "W_ga")
+    projected = {name: ops.dense(x, p[name], precision) for name in names}
+    decay, decay_back = ops.dense(projected["W_fa"][0], p["W_fb"], precision)
+    gate, gate_back = ops.dense(projected["W_ga"][0], p["W_gb"], precision)
+    q1, conv_q = ops.conv_silu(projected["Wq"][0], p["conv_q"], seg)
+    k1, conv_k = ops.conv_silu(projected["Wk"][0], p["conv_k"], seg)
+    v1, conv_v = ops.conv_silu(projected["Wv"][0], p["conv_v"], seg)
+    (q2, k2), unit = ops.qk_l2norm(_heads(q1, h), _heads(k1, h))
+    (beta, log_decay), gates = ops.channel_gates(
+        projected["Wb"][0], _heads(decay, h), p["A_log"],
+        p["dt_bias"].reshape(h, -1), spec.linear_allow_neg_eigval,
+    )
+    o, scan = ops.kda_scan(
+        q2, k2, _heads(v1, h), beta, log_decay, seg, chunk=spec.scan_chunk
+    )
+    gated, out_gate = ops.head_norm_sigmoid_gate(
+        o, _heads(gate, h), p["o_norm"], spec.rms_norm_eps
+    )
+    out, out_back = ops.dense(gated.reshape(*x.shape[:2], -1), p["Wo"], precision)
+
+    def back(dout):
+        grads = {}
+        dgated, grads["Wo"] = out_back(dout)
+        do, dgate, grads["o_norm"] = out_gate(dgated.reshape(gated.shape))
+        dq2, dk2, dv1, dbeta, dlog_decay = scan(do)
+        db, ddecay, grads["A_log"], d_dt_bias = gates((dbeta, dlog_decay))
+        grads["dt_bias"] = d_dt_bias.reshape(-1)
+        dq1, dk1 = unit((dq2, dk2))
+        dq0, grads["conv_q"] = conv_q(dq1.reshape(q1.shape))
+        dk0, grads["conv_k"] = conv_k(dk1.reshape(k1.shape))
+        dv0, grads["conv_v"] = conv_v(dv1.reshape(v1.shape))
+        d_fa, grads["W_fb"] = decay_back(ddecay.reshape(decay.shape))
+        d_ga, grads["W_gb"] = gate_back(dgate.reshape(gate.shape))
+        parts = []
+        for name, d in zip(names, (dq0, dk0, dv0, db, d_fa, d_ga)):
+            dx_part, grads[name] = projected[name][1](d)
+            parts.append(dx_part)
+        return ops.fan_in(*parts), grads
+
+    return out, back
+
+
+def _gqa_mix(p, x, seg, spec, precision):
+    """Grouped-query attention without a positional term, masked by
+    document, its output gated elementwise by ``sigmoid(x W_z)``. ->
+    ``(out, back)``."""
+    heads, kv = spec.num_attention_heads, spec.kv_heads
+    names = ("Wq", "Wk", "Wv", "Wz")
+    projected = {name: ops.dense(x, p[name], precision) for name in names}
+
+    def split(a, n):  # (rows, seq, n * d) -> (rows, n, seq, d)
+        with scope("attn/core"):
+            return _heads(a, n).transpose(0, 2, 1, 3)
+
+    def merge(a):
+        with scope("attn/core"):
+            return a.transpose(0, 2, 1, 3).reshape(*a.shape[::2], -1)
+
+    o, core = ops.attention(
+        split(projected["Wq"][0], heads), split(projected["Wk"][0], kv),
+        split(projected["Wv"][0], kv), seg, precision, spec.attn_block,
+    )
+    merged = merge(o)
+    gated, gate = ops.sigmoid_gate(merged, projected["Wz"][0])
+    out, out_back = ops.dense(gated, p["Wo"], precision)
+
+    def back(dout):
+        grads = {}
+        dgated, grads["Wo"] = out_back(dout)
+        dmerged, dz = gate(dgated)
+        dq, dk, dv = map(merge, core(split(dmerged, heads)))
+        parts = []
+        for name, d in zip(names, (dq, dk, dv, dz)):
+            dx_part, grads[name] = projected[name][1](d)
+            parts.append(dx_part)
+        return ops.fan_in(*parts), grads
+
+    return out, back
+
+
+def _routed_ffn(p, x, spec, precision, census=None):
+    """The routed mixture: the shared expert (a SwiGLU every token takes)
+    plus, of the ``num_experts_per_tok`` experts the router picks among all
+    ``n_routed_experts``, those this chip holds (``ops.route``,
+    ``ops.experts``). ``census`` (a list) gains the (held,) int32 count of
+    (token, slot) pairs routed to each held expert. -> ``(out, back)``."""
+    x2 = x.reshape(-1, x.shape[-1])
+    (weights, sel), route_back = ops.route(
+        x2, p["W_r"], spec.num_experts_per_tok, spec.norm_topk_prob,
+        spec.routed_scaling_factor,
+    )
+    routed, experts_back, rows = ops.experts(
+        x2, sel, weights, spec.experts_held, p["W1"], p["W3"], p["W2"], precision,
+        spec.moe_tile,
+    )
+    if census is not None:
+        census.append(rows)
+    gate, gate_back = ops.dense(x, p["Ws1"], precision)
+    up, up_back = ops.dense(x, p["Ws3"], precision)
+    act, act_back = ops.swiglu(gate, up)
+    shared, down_back = ops.dense(act, p["Ws2"], precision)
+    out = ops.fan_in(shared, routed.reshape(x.shape))
+
+    def back(dout):
+        grads = {}
+        dact, grads["Ws2"] = down_back(dout)
+        dgate, dup = act_back(dact)
+        dx_g, grads["Ws1"] = gate_back(dgate)
+        dx_u, grads["Ws3"] = up_back(dup)
+        dx_e, dweights, grads["W1"], grads["W3"], grads["W2"] = experts_back(
+            dout.reshape(x2.shape)
+        )
+        dx_r, grads["W_r"] = route_back(dweights)
+        return ops.fan_in(dx_g, dx_u, (dx_e + dx_r).reshape(x.shape)), grads
+
+    return out, back
+
+
+_MIXERS = {
+    "linear_attention": _linear_attention_mix, "full_attention": _full_attention_mix,
+    "kda": _kda_mix, "gqa": _gqa_mix,
+}
+
+
+def _pre_norm_layer(p, x, seg, kind, spec, precision, census):
+    """``h = x + mix(norm(x))``, ``y = h + moe(norm(h))``."""
+    normed, norm1 = ops.rms_norm(x, p["attn_norm"], spec.rms_norm_eps)
+    mixed, mix_back = _MIXERS[kind](p, normed, seg, spec, precision)
+    hid = ops.fan_in(x, mixed)
+    normed2, norm2 = ops.rms_norm(hid, p["mlp_norm"], spec.rms_norm_eps)
+    routed, ffn_back = _routed_ffn(p, normed2, spec, precision, census)
+    y = ops.fan_in(hid, routed)
+
+    def back(dy):
+        dnormed2, grads = ffn_back(dy)
+        dhid, grads["mlp_norm"] = norm2(dnormed2)
+        dhid = ops.fan_in(dy, dhid)
+        dnormed, mix_grads = mix_back(dhid)
+        dx, grads["attn_norm"] = norm1(dnormed)
+        return ops.fan_in(dhid, dx), {**grads, **mix_grads}
+
+    return y, back
+
+
+def token_layer(p, x, seg, kind, spec, precision, census=None):
+    """One layer, by the family's residual form: ``olmo_hybrid``'s ``h = x +
+    norm(mix(x))``, ``y = h + norm(mlp(h))`` (the norms sit on the branches),
+    ``solar_open2``'s pre-norm form with a routed feed-forward
+    (``_pre_norm_layer``; ``census``: see ``_routed_ffn``). -> ``(y,
+    back)``; ``back(dy) -> (dx, grads)`` with ``grads`` shaped like ``p``."""
+    if spec.family == "solar_open2":
+        return _pre_norm_layer(p, x, seg, kind, spec, precision, census)
+    mixed, mix_back = _MIXERS[kind](p, x, seg, spec, precision)
     hid, add1 = ops.residual_norm(x, mixed, p["attn_norm"], spec.rms_norm_eps)
     gate, gate_back = ops.dense(hid, p["W_gate"], precision)
     up, up_back = ops.dense(hid, p["W_up"], precision)
@@ -713,7 +1030,8 @@ def token_layer(p, x, seg, kind, spec, precision):
 
 
 def token_loss_and_grads(
-    params, spec: TokenModelSpec, tokens, segments, precision, acc=None
+    params, spec: TokenModelSpec, tokens, segments, precision, acc=None, census=None,
+    fresh_weights=False,
 ):
     """One microbatch: ``tokens``, ``segments``: (rows, seq_len + 1) int32;
     inputs are ``[:, :-1]``, targets ``[:, 1:]``, every position a target.
@@ -724,7 +1042,11 @@ def token_loss_and_grads(
     backward is due. ``acc`` (shaped like ``params``): returned instead of
     the gradient is ``acc + gradient``, each layer's added as soon as its
     backward has made it, so that no second tree of gradients exists beside
-    the accumulator (a model's worth of memory)."""
+    the accumulator (a model's worth of memory). ``fresh_weights``: a layer's
+    recomputed forward reads its weights behind the barrier too, so nothing
+    the first forward made of them alone is kept for it. ``census`` (a list): gains,
+    from the first forward only, one (held,) int32 count a routed layer
+    (``_routed_ffn``)."""
     embedding, *layers, head = params[0]
 
     def made(index, layer_grads):
@@ -738,7 +1060,7 @@ def token_loss_and_grads(
     x, embed_back = ops.embed(embedding["E"], inputs)
     kept = []
     for p, kind in zip(layers, spec.layer_types):
-        y, back = token_layer(p, x, seg, kind, spec, precision)
+        y, back = token_layer(p, x, seg, kind, spec, precision, census)
         kept.append(x if spec.recompute else back)
         x = y
     normed, norm_back = ops.rms_norm(x, head["norm"], spec.rms_norm_eps)
@@ -754,7 +1076,10 @@ def token_loss_and_grads(
         if spec.recompute:
             # the barrier keeps the compiler from merging this forward with
             # the first one, which would keep every layer's residuals alive
-            x_in, dx = lax.optimization_barrier((keep, dx))
+            if fresh_weights:
+                x_in, dx, p = lax.optimization_barrier((keep, dx, p))
+            else:
+                x_in, dx = lax.optimization_barrier((keep, dx))
             _, keep = token_layer(p, x_in, seg, kind, spec, precision)
         dx, layer_grads = keep(dx)
         grads.append(made(index + 1, layer_grads))

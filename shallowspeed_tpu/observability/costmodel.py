@@ -91,13 +91,34 @@ def token_train_flops_per_token(spec, pairs_per_token=None):
     h, dk, dv = spec.linear_num_heads, spec.linear_key_head_dim, spec.linear_value_head_dim
     if pairs_per_token is None:
         pairs_per_token = (spec.seq_len + 1) / 2
-    mlp = 3 * d * ff
-    per_layer = {
-        "full_attention": 6 * (4 * d * d + mlp) + 3 * 4 * d * pairs_per_token,
-        "linear_attention": 6 * (
-            d * (2 * h * dk + 2 * h * dv) + h * dv * d + 2 * d * h + mlp
-        ) + 3 * 9 * h * dk * dv,
-    }
+    if spec.family == "solar_open2":
+        # the second family: grouped-query attention with an output gate, the
+        # per-channel rule's low-rank gates, and in every layer the router
+        # (all published experts), the shared expert, and of the
+        # ``num_experts_per_tok`` routed products a token takes the share this
+        # chip holds under even routing (held / published)
+        heads, hd, rank = spec.num_attention_heads, spec.attn_head_dim, spec.gate_rank
+        held = spec.experts_held[1] - spec.experts_held[0]
+        ffn = (
+            d * spec.n_routed_experts + 3 * d * spec.shared_size
+            + 3 * d * ff * spec.num_experts_per_tok * held / spec.n_routed_experts
+        )
+        per_layer = {
+            "gqa": 6 * (d * hd * (3 * heads + 2 * spec.kv_heads) + ffn)
+            + 3 * 4 * heads * hd * pairs_per_token,
+            "kda": 6 * (
+                d * (2 * h * dk + h * dv) + h * dv * d + d * h
+                + rank * (2 * d + h * dk + h * dv) + ffn
+            ) + 3 * 7 * h * dk * dv,  # the per-channel rule: 7 d_k d_v forward
+        }
+    else:
+        mlp = 3 * d * ff
+        per_layer = {
+            "full_attention": 6 * (4 * d * d + mlp) + 3 * 4 * d * pairs_per_token,
+            "linear_attention": 6 * (
+                d * (2 * h * dk + 2 * h * dv) + h * dv * d + 2 * d * h + mlp
+            ) + 3 * 9 * h * dk * dv,
+        }
     return 6 * d * spec.vocab_size + sum(per_layer[kind] for kind in spec.layer_types)
 
 

@@ -26,6 +26,13 @@ scope belongs to a class, the unit the benchmark's class table
                                       ``fanin`` sums the cotangents that meet
                                       at one value)
     head       embed, head/xent      (ops.py: embedding, cross-entropy)
+    kda_scan   kda/scan              (ops.py: the chunked delta rule with a
+                                      decay per key channel)
+    moe_route  moe/route             (ops.py: the router's scores, the top-k,
+                                      the sort of the (token, slot) pairs, each
+                                      tile's gather and weighted scatter)
+    moe_experts  moe/experts         (ops.py: the grouped products of the
+                                      experts held, forward and backward)
 
 The token model's ops trace forward and backward under the op's scope; where
 a backward is ``jax.vjp``'s, the transform wraps what FOLLOWS the scope in
@@ -82,6 +89,9 @@ _CLASS_OF = {
     "fanin": "token_mix",
     "embed": "head",
     "head/xent": "head",
+    "kda/scan": "kda_scan",
+    "moe/route": "moe_route",
+    "moe/experts": "moe_experts",
 }
 SCOPES = tuple(_CLASS_OF)
 

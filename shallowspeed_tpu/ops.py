@@ -489,25 +489,42 @@ def _attn_mask(seg_q, seg_k, at_q, at_k):
 
 def attention(q, k, v, seg, precision=DEFAULT_PRECISION, block=ATTN_BLOCK):
     """``softmax(q k^T / sqrt(d) + M) v`` with ``M`` = causal and same
-    document, by blocks: ``q, k, v``: (rows, heads, seq, d), ``seg``: (rows,
-    seq). For each block of queries a loop over the blocks of keys from the
-    first that holds one of its documents up to its own (an online softmax),
-    so no score matrix wider than a block exists and blocks the mask empties
-    are not visited. The backward recomputes each visited block's scores from
-    the saved log-sum-exp. -> ``o, back``; ``back(do) -> (dq, dk, dv)``."""
+    document, by blocks: ``q``: (rows, heads, seq, d), ``k, v``: (rows,
+    kv_heads, seq, d) with ``kv_heads`` dividing ``heads`` (grouped-query
+    attention: query head ``h`` reads key/value head ``h // (heads //
+    kv_heads)``; the group is one more axis of the products, no key or value
+    is repeated), ``seg``: (rows, seq). For each block of queries a loop over
+    the blocks of keys from the first that holds one of its documents up to
+    its own (an online softmax), so no score matrix wider than a block
+    exists and blocks the mask empties are not visited. The backward
+    recomputes each visited block's scores from the saved log-sum-exp. ->
+    ``o, back``; ``back(do) -> (dq, dk, dv)``."""
     rows, heads, seq, d = q.shape
+    kv_heads = k.shape[1]
+    if heads % kv_heads:
+        raise ValueError(f"{kv_heads} key/value heads do not divide {heads} query heads")
+    group = heads // kv_heads
     c = _block_len(seq, block)
     n = seq // c
     scale = d**-0.5
+    # the axes of a block of queries: with one query head a key/value head
+    # the products are the ungrouped ones, letter for letter
+    Q = "bhq" if group == 1 else "bhgq"
+    lead = (rows, heads) if group == 1 else (rows, kv_heads, group)
 
-    def blocks(a):  # (rows, heads, seq, d) -> (n, rows, heads, c, d)
-        return jnp.moveaxis(a.reshape(rows, heads, n, c, d), 2, 0)
+    def blocks(a):  # (rows, heads, seq, d) -> (n, *lead, c, d)
+        shape = lead if a.shape[1] == heads else (rows, kv_heads)
+        return jnp.moveaxis(a.reshape(*shape, n, c, d), len(shape), 0)
 
-    def unblocks(a):
-        return jnp.moveaxis(a, 0, 2).reshape(rows, heads, seq, d)
+    def unblocks(a):  # (n, rows, ..., c, d) -> (rows, heads, seq, d)
+        return jnp.moveaxis(a, 0, a.ndim - 3).reshape(rows, -1, seq, d)
 
     def dot(spec, a, b):
         return jnp.einsum(spec, a, b, precision=precision)
+
+    def masked(seg_q, seg_k, at_q, at_k):
+        mask = _attn_mask(seg_q, seg_k, at_q, at_k)
+        return mask if group == 1 else mask[:, :, None]
 
     with scope("attn/core"):
         qb, kb, vb = blocks(q), blocks(k), blocks(v)
@@ -521,20 +538,20 @@ def attention(q, k, v, seg, precision=DEFAULT_PRECISION, block=ATTN_BLOCK):
 
             def key_block(j, carry):
                 m, l, acc = carry
-                s = dot("bhqd,bhkd->bhqk", q_i, kb[j]) * scale
-                mask = _attn_mask(seg_n[i], seg_n[j], at[i], at[j])
+                s = dot(f"{Q}d,bhkd->{Q}k", q_i, kb[j]) * scale
+                mask = masked(seg_n[i], seg_n[j], at[i], at[j])
                 m_new = jnp.maximum(m, jnp.max(jnp.where(mask, s, _NEG_MASK), -1))
                 p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
                 fix = jnp.exp(m - m_new)
-                acc = acc * fix[..., None] + dot("bhqk,bhkd->bhqd", p, vb[j])
+                acc = acc * fix[..., None] + dot(f"{Q}k,bhkd->{Q}d", p, vb[j])
                 return m_new, l * fix + p.sum(-1), acc
 
             m, l, acc = lax.fori_loop(
                 first_key[i], i + 1, key_block,
                 (
-                    jnp.full((rows, heads, c), _NEG_MASK, q.dtype),
-                    jnp.zeros((rows, heads, c), q.dtype),
-                    jnp.zeros((rows, heads, c, d), q.dtype),
+                    jnp.full((*lead, c), _NEG_MASK, q.dtype),
+                    jnp.zeros((*lead, c), q.dtype),
+                    jnp.zeros((*lead, c, d), q.dtype),
                 ),
             )
             return acc / l[..., None], m + jnp.log(l)
@@ -545,21 +562,21 @@ def attention(q, k, v, seg, precision=DEFAULT_PRECISION, block=ATTN_BLOCK):
     def back(do):
         with scope("attn/core"):
             dob = blocks(do)
-            delta = jnp.sum(dob * ob, axis=-1)  # (n, rows, heads, c)
+            delta = jnp.sum(dob * ob, axis=-1)  # (n, *lead, c)
 
             def query_block(i, grads):
                 q_i, do_i = qb[i], dob[i]
 
                 def key_block(j, carry):
                     dq_i, dk, dv = carry
-                    s = dot("bhqd,bhkd->bhqk", q_i, kb[j]) * scale
-                    mask = _attn_mask(seg_n[i], seg_n[j], at[i], at[j])
+                    s = dot(f"{Q}d,bhkd->{Q}k", q_i, kb[j]) * scale
+                    mask = masked(seg_n[i], seg_n[j], at[i], at[j])
                     p = jnp.where(mask, jnp.exp(s - lse[i][..., None]), 0.0)
-                    dp = dot("bhqd,bhkd->bhqk", do_i, vb[j])
+                    dp = dot(f"{Q}d,bhkd->{Q}k", do_i, vb[j])
                     ds = p * (dp - delta[i][..., None]) * scale
-                    dq_i = dq_i + dot("bhqk,bhkd->bhqd", ds, kb[j])
-                    dk = dk.at[j].add(dot("bhqk,bhqd->bhkd", ds, q_i))
-                    dv = dv.at[j].add(dot("bhqk,bhqd->bhkd", p, do_i))
+                    dq_i = dq_i + dot(f"{Q}k,bhkd->{Q}d", ds, kb[j])
+                    dk = dk.at[j].add(dot(f"{Q}k,{Q}d->bhkd", ds, q_i))
+                    dv = dv.at[j].add(dot(f"{Q}k,{Q}d->bhkd", p, do_i))
                     return dq_i, dk, dv
 
                 dq, dk, dv = grads
@@ -569,7 +586,8 @@ def attention(q, k, v, seg, precision=DEFAULT_PRECISION, block=ATTN_BLOCK):
                 return dq.at[i].set(dq_i), dk, dv
 
             zeros = jnp.zeros_like(qb)
-            dq, dk, dv = lax.fori_loop(0, n, query_block, (zeros, zeros, zeros))
+            zeros_kv = zeros if group == 1 else jnp.zeros_like(kb)
+            dq, dk, dv = lax.fori_loop(0, n, query_block, (zeros, zeros_kv, zeros_kv))
             return unblocks(dq), unblocks(dk), unblocks(dv)
 
     return o, back
@@ -835,3 +853,333 @@ def _gated_delta_scan_xla(q, k, v, beta, log_decay, seg, precision, chunk, block
             return pull(do)
 
     return o, back
+
+
+# ---------------------------------------------------------------------------
+# The second token family's ops (``solar_open2``): the delta rule with a
+# decay PER KEY CHANNEL (Kimi Delta Attention), an elementwise output gate,
+# and a routed expert layer that holds a range of the experts it routes over.
+# ---------------------------------------------------------------------------
+
+KDA_CHUNK = 64  # tokens per chunk of the per-channel rule
+KDA_SUB = 16  # tokens per sub-block of a chunk's pair matrices
+KDA_BLOCK = 4  # chunks whose matrices are made (and, backward, rebuilt) at once
+MOE_TILE = 256  # rows of one expert's tile in the grouped product
+ROUTER_PRECISION = lax.Precision.HIGHEST  # float32 passes: a rounded score flips selections
+
+
+def sigmoid_gate(o, gate):
+    """``o * sigmoid(gate)``, elementwise: the grouped-query layer's output
+    gate."""
+    return _pointwise("gdn/gate", lambda o, g: o * jax.nn.sigmoid(g), o, gate)
+
+
+def head_norm_sigmoid_gate(o, gate, w, eps):
+    """``rms_norm(o) * sigmoid(gate)`` per head (last axis)."""
+    return _pointwise(
+        "gdn/gate", lambda o, g, w: _rms(o, w, eps) * jax.nn.sigmoid(g), o, gate, w
+    )
+
+
+def channel_gates(b, f, a_log, dt_bias, neg_eigval):
+    """``beta = sigmoid(b)`` (doubled under ``neg_eigval``), one a head, and
+    the LOG of the decay, one a key channel: ``-exp(A_log_h) softplus(f +
+    dt_bias)``. ``b``: (rows, seq, heads), ``f``: (rows, seq, heads, d_k),
+    ``a_log``: (heads,), ``dt_bias``: (heads, d_k)."""
+
+    def fn(b, f, a_log, dt_bias):
+        beta = jax.nn.sigmoid(b) * (2.0 if neg_eigval else 1.0)
+        return beta, -jnp.exp(a_log)[:, None] * jax.nn.softplus(f + dt_bias)
+
+    return _pointwise("gdn/gate", fn, b, f, a_log, dt_bias)
+
+
+def _decayed_pairs(x, k, g, sub, precision):
+    """``P[i, j] = sum_d x_i[d] k_j[d] exp(g_i[d] - g_j[d])`` for ``j <= i``
+    (garbage, finite, above the diagonal): ``x, k, g``: (..., c, d), ``g`` the
+    running sum of a log decay that is never positive. The decay does not
+    factor out of the product over ``d`` as a scalar one does, and ``exp(g_i)
+    exp(-g_j)`` overflows where the decay is strong; so, in sub-blocks of
+    ``sub`` tokens: a pair in two different sub-blocks goes through the
+    running sum at the LATER sub-block's first token, ``(x_i exp(g_i - r))
+    . (k_j exp(r - g_j))`` with both exponents at most zero, one matrix
+    product a sub-block of queries; a pair inside one sub-block is summed
+    channel by channel with its own exponent."""
+    *lead, c, d = x.shape
+    n = c // sub
+
+    def subs(a):
+        return a.reshape(*lead, n, sub, d)
+
+    xs, ks, gs = subs(x), subs(k), subs(g)
+    ref = gs[..., 0, :]  # (..., n, d): the running sum entering sub-block I
+    x_in = xs * jnp.exp(gs - ref[..., None, :])
+    # (..., n, c, d): every key as sub-block I's queries see it; a key that is
+    # not before sub-block I has a positive exponent, clamped: it is masked
+    k_out = k[..., None, :, :] * jnp.exp(
+        jnp.minimum(ref[..., :, None, :] - g[..., None, :, :], 0.0)
+    )
+    across = jnp.einsum(
+        "...nid,...njd->...nij", x_in, k_out, precision=precision
+    ).reshape(*lead, n, sub, n, sub)
+    lower = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None]
+    exponent = jnp.where(lower, gs[..., :, None, :] - gs[..., None, :, :], 0.0)
+    within = jnp.sum(
+        xs[..., :, None, :] * ks[..., None, :, :] * jnp.exp(exponent), axis=-1
+    )  # (..., n, sub, sub)
+    same_sub = jnp.eye(n, dtype=bool)[:, None, :, None]
+    return jnp.where(same_sub, within[..., :, :, None, :], across).reshape(*lead, c, c)
+
+
+def kda_scan(
+    q, k, v, beta, log_decay, seg, precision=None, chunk=KDA_CHUNK,
+    block=KDA_BLOCK, sub=KDA_SUB,
+):
+    """The delta rule with a decay per key channel: ``S_t = (I - b_t k_t
+    k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T q_t``, ``S``
+    (d_k x d_v) zero at a document's first token; ``gated_delta_scan`` is the
+    case of ``g_t`` constant over the channels. ``q, k``: (rows, seq, heads,
+    d_k), ``v``: (rows, seq, heads, d_v), ``beta``: (rows, seq, heads),
+    ``log_decay``: (rows, seq, heads, d_k), never positive, ``seg``: (rows,
+    seq). The chunked (WY) form of ``_gated_delta_scan_xla``, in
+    ``jax.numpy``: the same inverse, state recurrence, document masks,
+    blocks of chunks and ``jax.vjp`` backward; what differs is that a pair's
+    decay sits INSIDE its product over the channels (``_decayed_pairs``) and
+    that the state's rows decay each at their own rate. -> ``o, back``;
+    ``back(do) -> (dq, dk, dv, dbeta, dlog_decay)``. No kernel form yet."""
+    if precision is None:
+        precision = SCAN_PRECISION
+    rows, seq, heads, dk = q.shape
+    dv = v.shape[-1]
+    c = _block_len(seq, chunk)
+    n = seq // c
+    per = _block_len(n, block)  # chunks per block
+    sub = _block_len(c, sub)
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=precision)
+
+    def chunks(a):  # (rows, seq, heads, ...) -> (blocks, per, rows, heads, c, ...)
+        a = a.reshape(rows, n, c, heads, *a.shape[3:])
+        a = jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+        return a.reshape(n // per, per, *a.shape[1:])
+
+    def blocks(a):  # (n, ...) -> (blocks, per, ...)
+        return a.reshape(n // per, per, *a.shape[1:])
+
+    with scope("kda/scan"):
+        first, segc, carried, to_last = _document_masks(seg, n, c)
+        same = blocks((segc[:, :, :, None] == segc[:, :, None, :])[:, :, None])
+        carried = blocks(carried[:, :, None, :, None])  # (.., rows, 1, c, 1)
+        to_last = blocks(to_last[:, :, None, :, None])
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        strict = jnp.tril(jnp.ones((c, c), bool), -1)
+
+    @jax.checkpoint
+    def block_of_chunks(state, xs):
+        qc, kc, vc, bc, gc, same, carried, to_last = xs  # (per, rows, heads, c, ..)
+        g = jnp.cumsum(gc, axis=-2)  # (.., c, d_k)
+        pair = same & lower
+        kk = _decayed_pairs(kc, kc, g, sub, precision)
+        a = jnp.where(same & strict, bc[..., None] * kk, 0.0)
+        g_in = jnp.where(carried, jnp.exp(g), 0.0)  # decay from the entering state
+        solved = dot(
+            "nbhij,nbhjd->nbhid", _unit_lower_inverse(a),
+            jnp.concatenate([bc[..., None] * vc, bc[..., None] * g_in * kc], axis=-1),
+        )
+        qk = jnp.where(pair, _decayed_pairs(qc, kc, g, sub, precision), 0.0)
+        g_out = jnp.where(to_last, jnp.exp(g[..., -1:, :] - g), 0.0)
+
+        def step(state, xs):  # state: (rows, heads, d_k, d_v)
+            u0, w, qk, q_in, k_out, keep = xs
+            u = u0 - dot("bhik,bhkv->bhiv", w, state)
+            o = dot("bhik,bhkv->bhiv", q_in, state) + dot("bhij,bhjv->bhiv", qk, u)
+            state = state * keep[..., None] + dot("bhik,bhiv->bhkv", k_out, u)
+            return state, o
+
+        return lax.scan(
+            step, state,
+            (solved[..., :dv], solved[..., dv:], qk, qc * g_in, kc * g_out, g_in[..., -1, :]),
+        )
+
+    def fn(q, k, v, beta, log_decay):
+        # a document's first token takes no decay: its state starts from zero
+        decays = jnp.where(first[..., None, None], 0.0, log_decay)
+        _, o = lax.scan(
+            block_of_chunks, jnp.zeros((rows, heads, dk, dv), q.dtype),
+            (
+                chunks(q), chunks(k), chunks(v), chunks(beta), chunks(decays),
+                same, carried, to_last,
+            ),
+        )
+        # (blocks, per, rows, heads, c, d_v) -> (rows, seq, heads, d_v)
+        o = o.reshape(n, rows, heads, c, dv)
+        return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(rows, seq, heads, dv)
+
+    with scope("kda/scan"):
+        o, pull = jax.vjp(fn, q, k, v, beta, log_decay)
+
+    def back(do):
+        with scope("kda/scan"):
+            return pull(do)
+
+    return o, back
+
+
+def _top_indices(values, top):
+    """The indices of the ``top`` largest along the last axis, largest first,
+    ties to the lower index, as ``top`` passes of ``argmax`` (the chip's
+    ``top_k`` sorts every row whole)."""
+    lanes = jnp.arange(values.shape[-1])
+    found = []
+    for _ in range(top):
+        best = jnp.argmax(values, axis=-1)
+        found.append(best)
+        values = jnp.where(lanes == best[..., None], -jnp.inf, values)
+    return jnp.stack(found, axis=-1).astype(jnp.int32)
+
+
+def route(x, w, top, normalise=True, scaling=1.0):
+    """The router: ``scores = sigmoid(x w^T)`` over EVERY published expert
+    (``w``: (experts, hidden); float32 passes whatever the session's
+    precision), the ``top`` largest selected (the family's selection bias is
+    zero and untrained: a constant, left out), the selected scores divided by
+    their sum over all ``top`` (``normalise``), held here or not, and
+    scaled. ``x``: (tokens, hidden). -> ``(weights, sel), back``: both
+    (tokens, top), ``sel`` the published indices; ``back(dweights) -> (dx,
+    dw)``. The selection carries no gradient."""
+
+    def scores_of(x, w):
+        return jax.nn.sigmoid(jnp.matmul(x, w.T, precision=ROUTER_PRECISION))
+
+    with scope("moe/route"):
+        sel = _top_indices(scores_of(x, w), top)
+        # the selected scores by comparison, not a gather along the last axis
+        chosen = jnp.arange(w.shape[0]) == sel[..., None]  # (tokens, top, experts)
+
+    def fn(x, w):
+        picked = jnp.sum(jnp.where(chosen, scores_of(x, w)[:, None, :], 0.0), axis=-1)
+        if normalise:
+            picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+        return picked * scaling
+
+    (weights, back) = _pointwise("moe/route", fn, x, w)
+    return (weights, sel), back
+
+
+def _swiglu_tile(xt, w_gate, w_up, precision):
+    gate = jnp.matmul(xt, w_gate.T, precision=precision)
+    up = jnp.matmul(xt, w_up.T, precision=precision)
+    return gate, up, _silu(gate) * up
+
+
+def experts(x, sel, weights, held, w_gate, w_up, w_down, precision=DEFAULT_PRECISION,
+            tile=MOE_TILE):
+    """The routed experts THIS chip holds: ``sum over a token's slots whose
+    expert is in held of weight * W_down,e(silu(W_gate,e x) * W_up,e x)``.
+    ``x``: (tokens, hidden); ``sel``, ``weights``: (tokens, top) (``route``);
+    ``held = (lo, hi)``: the published indices ``lo .. hi - 1`` are the
+    experts whose weights ``w_*`` (hi - lo, out, in) hold, in that order. A
+    slot routed elsewhere adds nothing here: it is another chip's.
+
+    Dropless, and no shape depends on the routing: the (token, slot) pairs
+    are sorted by expert (a stable sort, so by token within an expert); each
+    held expert then takes its pairs ``tile`` rows at a time in a loop whose
+    trip count is its own row count over ``tile``, rounded up: gather the
+    rows, the three products, scatter the weighted result back. The device
+    time follows the rows held through that loop alone. ``ops.dense``'s
+    rounding policy (operands to bfloat16 under ``Precision.DEFAULT``).
+    -> ``out, back, rows``: ``rows`` (hi - lo,) int32, the pairs routed to
+    each held expert; ``back(dout) -> (dx, dweights, dw_gate, dw_up,
+    dw_down)``, the forward's tiles run again."""
+    x, dtype = jnp.asarray(x), x.dtype
+    tokens, top = sel.shape
+    lo, hi = held
+    n_held = hi - lo
+    rounds = precision == lax.Precision.DEFAULT
+    rounded = _as_bfloat16 if rounds else (lambda a: a)
+
+    with scope("moe/route"):
+        local = jnp.where((sel >= lo) & (sel < hi), sel - lo, n_held).reshape(-1)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)  # row -> pair
+        rows = jnp.sum(
+            local[:, None] == jnp.arange(n_held, dtype=local.dtype), axis=0,
+            dtype=jnp.int32,
+        )
+        starts = jnp.cumsum(rows) - rows
+        flat_weights = weights.reshape(-1)
+        x_r = rounded(x)
+    with scope("moe/experts"):
+        w_gate_r, w_up_r, w_down_r = rounded(w_gate), rounded(w_up), rounded(w_down)
+
+    def tile_rows(e, t):
+        """The pairs, tokens and weights of tile ``t`` of expert ``e``, and
+        which of its rows are the expert's: the rows past its last weigh
+        nothing."""
+        with scope("moe/route"):
+            at = t * tile + jnp.arange(tile, dtype=jnp.int32)
+            valid = at < rows[e]
+            pair = order[jnp.minimum(starts[e] + at, tokens * top - 1)]
+            return pair, pair // top, jnp.where(valid, flat_weights[pair], 0.0), valid
+
+    def tiles_of(e):
+        return (rows[e] + tile - 1) // tile
+
+    def forward_tile(e, t, out):
+        _, token, weight, _ = tile_rows(e, t)
+        with scope("moe/route"):
+            xt = x_r[token]
+        with scope("moe/experts"):
+            _, _, act = _swiglu_tile(xt, w_gate_r[e], w_up_r[e], precision)
+            down = jnp.matmul(rounded(act), w_down_r[e].T, precision=precision)
+        with scope("moe/route"):
+            return out.at[token].add(weight[:, None] * down)
+
+    out = jnp.zeros_like(x)
+    for e in range(n_held):
+        out = lax.fori_loop(0, tiles_of(e), partial(forward_tile, e), out)
+
+    def back(dout):
+        dout = jnp.asarray(dout, dtype)
+
+        def backward_tile(e, t, carry):
+            dx, dweights, dw_gate, dw_up, dw_down = carry
+            pair, token, weight, valid = tile_rows(e, t)
+            with scope("moe/route"):
+                xt, dout_t = x_r[token], dout[token]
+            with scope("moe/experts"):
+                gate, up, act = _swiglu_tile(xt, w_gate_r[e], w_up_r[e], precision)
+                act_r = rounded(act)
+                down = jnp.matmul(act_r, w_down_r[e].T, precision=precision)
+                dweight = jnp.sum(dout_t * down, axis=-1)
+                ddown = rounded(weight[:, None] * dout_t)
+                dact = jnp.matmul(ddown, w_down_r[e], precision=precision)
+                dw_down = dw_down + jnp.matmul(ddown.T, act_r, precision=precision)
+                dgate, dup = jax.vjp(lambda g, u: _silu(g) * u, gate, up)[1](dact)
+                dgate, dup = rounded(dgate), rounded(dup)
+                dw_gate = dw_gate + jnp.matmul(dgate.T, xt, precision=precision)
+                dw_up = dw_up + jnp.matmul(dup.T, xt, precision=precision)
+                dxt = jnp.matmul(dgate, w_gate_r[e], precision=precision) + jnp.matmul(
+                    dup, w_up_r[e], precision=precision
+                )
+            with scope("moe/route"):
+                # a row past the expert's last carries weight zero, so its
+                # dxt is zero; its dweight is not, hence the where
+                dweights = dweights.at[pair].add(jnp.where(valid, dweight, 0.0))
+                return dx.at[token].add(dxt), dweights, dw_gate, dw_up, dw_down
+
+        with scope("moe/route"):
+            dx, dweights = jnp.zeros_like(x), jnp.zeros_like(flat_weights)
+        grads = []
+        for e in range(n_held):
+            with scope("moe/experts"):
+                zeros = tuple(jnp.zeros_like(w[e]) for w in (w_gate, w_up, w_down))
+            dx, dweights, *dw = lax.fori_loop(
+                0, tiles_of(e), partial(backward_tile, e), (dx, dweights, *zeros)
+            )
+            grads.append(dw)
+        with scope("moe/experts"):
+            dw_gate, dw_up, dw_down = (jnp.stack(g) for g in zip(*grads))
+        return dx, dweights.reshape(tokens, top), dw_gate, dw_up, dw_down
+
+    return out, back, rows

@@ -141,6 +141,42 @@ class Adam:
         return new, {"m": m, "v": v, "t": t}
 
 
+class WithGradScratch:
+    """An optimizer whose state also keeps the step's accumulated gradient,
+    ``{"opt": the wrapped optimizer's state, "grads": a tree like params}``:
+    ``apply`` hands the gradient it was given back as ``"grads"``. The point
+    is where the accumulator LIVES: a step that loops over its microbatches
+    (``trainer._token_step_scanned``) starts its accumulator from the
+    ``"grads"`` it was handed (a donated argument) and returns it there (the
+    output that aliases it), so the compiler places it with the arguments;
+    as a temporary inside the program it lay between the working set's
+    buffers and the 4-layer expert model's step did not fit the chip
+    (PERF.md section 6, PR 35). What ``"grads"`` holds between steps is never
+    read: the first microbatch overwrites it."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def __getattr__(self, name):  # lr, momentum, weight_decay: the wrapped one's
+        return getattr(self.inner, name)
+
+    def init(self, params):
+        import jax.numpy as jnp
+
+        return {
+            "opt": self.inner.init(params),
+            "grads": jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype), params),
+        }
+
+    def state_layout(self):
+        return {**{f"opt/{k}": v for k, v in self.inner.state_layout().items()},
+                "grads": "params"}
+
+    def apply(self, params, grads, state):
+        new, inner_state = self.inner.apply(params, grads, state["opt"])
+        return new, {"opt": inner_state, "grads": grads}
+
+
 def is_stateless(opt) -> bool:
     """True iff the optimizer carries no state (e.g. SGD). Answered by the
     state_layout() protocol — the single source of truth every call site

@@ -117,6 +117,83 @@ def feature_major(X):
     return X.swapaxes(2, 3)
 
 
+# A token model's step takes at most this many microbatches as one
+# straight-line program; more go through a loop (``_token_step_scanned``)
+_UNROLLED_MUBATCHES = 2
+
+
+def token_step_is_scanned(mubatches):
+    """Whether a token model's step loops over its microbatches
+    (``_token_step_scanned``: the session then wraps its optimizer in
+    ``optimizer.WithGradScratch``) or takes them as one straight-line
+    program (``_token_step_unrolled``)."""
+    return mubatches > _UNROLLED_MUBATCHES
+
+
+def _token_step_unrolled(params, opt_state, spec, xb, yb, precision):
+    """A token model's microbatches one after another in ONE straight-line
+    program: the first microbatch's gradient IS the accumulator, unzeroed,
+    and each later layer's gradient is added as it is made. The program, and
+    the time to compile it, grow with the microbatches. -> ``(grads, loss,
+    census)``; ``census``: the (routed layers, experts held) int32 count of
+    (token, slot) pairs routed, ``None`` for a model that routes nothing."""
+    acc, loss, census = None, jnp.zeros(()), None
+    for m in range(xb.shape[0]):
+        with scope("batch"):
+            tokens, segments = xb[m], yb[m]
+        routed = []  # one (experts held,) int32 count a routed layer
+        mb_loss, acc = token_loss_and_grads(
+            params, spec, tokens, segments, precision, acc=acc, census=routed
+        )
+        with scope("loss"):
+            loss = loss + mb_loss
+        if routed:
+            with scope("moe/route"):
+                rows = jnp.stack(routed)
+                census = rows if census is None else census + rows
+    return acc, loss, census
+
+
+def _token_step_scanned(params, opt_state, spec, xb, yb, precision):
+    """The same step as a loop over the microbatches: one copy of the
+    microbatch's program whatever their number (four unrolled microbatches
+    of the 4-layer expert model took the chip's compiler 4.6 minutes here,
+    the loop 1.4). The accumulator is the loop's carry and starts from
+    ``opt_state["grads"]`` (``optimizer.WithGradScratch``: what it holds is
+    overwritten by the first microbatch, where it lives is the point). The
+    weights pass an optimization barrier together with the microbatch's rows,
+    and again before a layer's recomputed forward (``fresh_weights``): what a
+    layer makes of its weights alone (``ops.dense``'s rounded copies) is then
+    made where it is used, not once before the loop and kept through it, half
+    a model's worth of memory. The first microbatch's add reads the
+    accumulator once more than the unrolled form does."""
+
+    def microbatch(carry, rows):
+        acc, m = carry
+        with scope("batch"):
+            weights, (tokens, segments) = lax.optimization_barrier((params, rows))
+        with scope("acc"):
+            acc = jax.tree.map(lambda a: jnp.where(m > 0, a, 0.0), acc)
+        routed = []
+        loss, acc = token_loss_and_grads(
+            weights, spec, tokens, segments, precision, acc=acc, census=routed,
+            fresh_weights=True,
+        )
+        with scope("moe/route"):
+            census = jnp.stack(routed) if routed else None
+        return (acc, m + 1), (loss, census)
+
+    (acc, _), (losses, census) = lax.scan(
+        microbatch, (opt_state["grads"], jnp.zeros((), jnp.int32)), (xb, yb)
+    )
+    with scope("loss"):
+        loss = jnp.sum(losses)
+    if census is not None:
+        with scope("moe/route"):
+            census = jnp.sum(census, axis=0)
+    return acc, loss, census
+
+
 def _make_batch_step(
     spec: ModelSpec, opt, precision, fuse_mubatches=False, clip_norm=None,
     megakernel=False, with_grad_norm=False, with_digests=False,
@@ -224,21 +301,14 @@ def _make_batch_step(
         ``with_grad_norm`` a fourth output carries the pre-clip global
         gradient norm."""
         if token:
-            # the microbatches one after another in ONE straight-line
-            # program, not a scan: as a while loop's carry the accumulator
-            # is held twice (measured with the chip's compiler: a model's
-            # worth of memory, which this model's cell does not have), and
-            # the first microbatch's gradient IS the accumulator, unzeroed
-            acc, loss = None, jnp.zeros(())
-            for m in range(xb.shape[0]):
-                with scope("batch"):
-                    tokens, segments = xb[m], yb[m]
-                mb_loss, acc = token_loss_and_grads(
-                    params, spec, tokens, segments, precision, acc=acc
-                )
-                with scope("loss"):
-                    loss = loss + mb_loss
-            return finish(params, opt_state, acc, loss)
+            step = (
+                _token_step_scanned if token_step_is_scanned(xb.shape[0])
+                else _token_step_unrolled
+            )
+            grads, loss, census = step(params, opt_state, spec, xb, yb, precision)
+            outs = finish(params, opt_state, grads, loss)
+            # a model with routed layers: the step's routing census rides LAST
+            return outs if census is None else outs + (census,)
         if fuse_mubatches:
             rows = xb.shape[1]
             with scope("batch"):
@@ -476,15 +546,19 @@ def make_train_epoch(
             with_grad_norm or with_step_stats, with_digests,
             x_layout=x_layout,
         )
+        routed = getattr(spec, "routed_layers", 0)
         epoch_core = _make_epoch_core(
-            batch_step, unroll, with_grad_norm, with_step_stats, with_digests
+            batch_step, unroll, with_grad_norm, with_step_stats, with_digests,
+            census_shape=(
+                (routed, spec.experts_held[1] - spec.experts_held[0]) if routed else None
+            ),
         )
     return jax.jit(epoch_core, donate_argnums=(0, 1))
 
 
 def _make_epoch_core(
     batch_step, unroll, with_grad_norm=False, with_step_stats=False,
-    with_digests=False,
+    with_digests=False, census_shape=None,
 ):
     """The one epoch-scan body shared by make_train_epoch and make_train_run:
     ``core(params, opt_state, X, Y) -> (params, opt_state, mean_loss)`` —
@@ -494,16 +568,32 @@ def _make_epoch_core(
     (ordinary scan ys — data flow, never host callbacks, so the epoch stays
     one fused XLA program). One scan body serves every arity: the grad-norm
     slot always rides the carry (zero when the aux is off) and XLA
-    dead-code-eliminates it from the uninstrumented program."""
+    dead-code-eliminates it from the uninstrumented program.
+    ``census_shape`` (a token model with routed layers: ``(routed layers,
+    experts held)``): ``batch_step``'s last output is the step's routing
+    census, int32 of that shape. The epoch's two counters ride the carry and
+    are the program's LAST output, after the aux dict where there is one, a
+    (2,) int32: the (token, slot) pairs routed to the experts held, summed
+    over the epoch, and the most any one held expert of any layer took in
+    one step (the session reads them back with the loss,
+    ``api._run_epoch_program``)."""
+    if census_shape and with_digests:
+        raise ValueError("the digest aux and a routing census both ride last")
     track_gn = with_grad_norm or with_step_stats
 
     def epoch_core(params, opt_state, X, Y):
         def body(carry, xy):
-            params, opt_state, loss_sum, gn_sum = carry
+            params, opt_state, loss_sum, gn_sum, *routed = carry
             out = batch_step(params, opt_state, *xy)
             params, opt_state, loss = out[0], out[1], out[2]
             gn = out[3] if track_gn else jnp.zeros(())
             carry = (params, opt_state, loss_sum + loss, gn_sum + gn)
+            if routed:
+                with scope("moe/route"):
+                    held, most = routed[0][0], routed[0][1]
+                    carry += (jnp.stack(
+                        [held + jnp.sum(out[-1]), jnp.maximum(most, jnp.max(out[-1]))]
+                    ),)
             ys = ()
             if with_step_stats:
                 from shallowspeed_tpu.optimizer import global_norm
@@ -515,15 +605,15 @@ def _make_epoch_core(
                 ys += (out[-1],)  # the digest dict rides last (see finish)
             return carry, (ys if ys else None)
 
-        (params, opt_state, loss_sum, gn_sum), ys = lax.scan(
-            body,
-            (params, opt_state, jnp.zeros(()), jnp.zeros(())),
-            (X, Y),
-            unroll=unroll,
+        start = (params, opt_state, jnp.zeros(()), jnp.zeros(()))
+        if census_shape:
+            start += (jnp.zeros((2,), jnp.int32),)
+        (params, opt_state, loss_sum, gn_sum, *routed), ys = lax.scan(
+            body, start, (X, Y), unroll=unroll,
         )
         nb = X.shape[0]
         if not (with_grad_norm or with_step_stats or with_digests):
-            return params, opt_state, loss_sum / nb
+            return (params, opt_state, loss_sum / nb, *routed)
         aux = {}
         if with_grad_norm:
             aux["grad_norm"] = gn_sum / nb
@@ -533,7 +623,7 @@ def _make_epoch_core(
             )
         if with_digests:
             aux["digests"] = ys[-1]
-        return params, opt_state, loss_sum / nb, aux
+        return (params, opt_state, loss_sum / nb, aux, *routed)
 
     return epoch_core
 

@@ -1,0 +1,315 @@
+"""The second token family's ops (``ops.kda_scan``, grouped-query
+``ops.attention``, ``ops.route`` and ``ops.experts``) against what they state
+they compute, written plainly: the per-channel delta rule token by token,
+attention with the key/value heads repeated, the routed mixture as a dense
+loop over experts. Tiny sizes, a CPU, float32 passes."""
+
+import importlib.util
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from shallowspeed_tpu import ops
+
+ROOT = Path(__file__).resolve().parents[1]
+HIGHEST = lax.Precision.HIGHEST
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _load(ROOT / "benchmarks" / "references" / "solar_open2.py", "ref_solar_open2")
+
+
+def _close(got, want, rtol=2e-5):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape
+    scale = np.linalg.norm(want) + 1e-30
+    assert np.linalg.norm(got - want) <= rtol * scale, np.linalg.norm(got - want) / scale
+
+
+def _segments(rows, seq, seed=0, rate=0.1):
+    starts = np.random.default_rng(seed).random((rows, seq)) < rate
+    starts[:, 0] = False
+    return np.cumsum(starts, axis=1).astype(np.int32)
+
+
+# -- the per-channel delta rule -----------------------------------------------
+
+ROWS, SEQ, H, DK, DV = 2, 48, 3, 8, 12
+
+
+@pytest.fixture(scope="module")
+def rule():
+    rng = np.random.default_rng(0)
+    f32 = lambda a: a.astype(np.float32)  # noqa: E731
+    k = rng.standard_normal((ROWS, SEQ, H, DK))
+    return dict(
+        q=f32(rng.standard_normal((ROWS, SEQ, H, DK))),
+        k=f32(k / np.linalg.norm(k, axis=-1, keepdims=True)),
+        v=f32(rng.standard_normal((ROWS, SEQ, H, DV))),
+        # beta up to 2: eigenvalues of I - beta k k^T down to -1
+        beta=f32(2 * rng.random((ROWS, SEQ, H))),
+        # strong decays too: exp(-3 x 48) underflows a product of exponentials
+        g=f32(-3 * rng.random((ROWS, SEQ, H, DK))),
+        seg=_segments(ROWS, SEQ),
+        do=f32(rng.standard_normal((ROWS, SEQ, H, DV))),
+    )
+
+
+def _recurrence(q, k, v, beta, g, seg):
+    def row(q, k, v, beta, g, seg):
+        first = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
+        return ref.channel_delta_rule(q, k, v, beta, g, first)
+
+    return jax.vmap(row)(q, k, v, beta, g, seg)
+
+
+@pytest.mark.parametrize("chunk,sub", [(8, 4), (16, 4), (8, 8), (48, 16), (12, 3)])
+def test_kda_scan_is_the_recurrence(rule, chunk, sub):
+    r = rule
+    assert r["beta"].max() > 1.5 and r["seg"].max() >= 2
+    want = _recurrence(r["q"], r["k"], r["v"], r["beta"], r["g"], r["seg"])
+    got, _ = ops.kda_scan(
+        r["q"], r["k"], r["v"], r["beta"], r["g"], r["seg"], chunk=chunk, block=2, sub=sub
+    )
+    _close(got, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("chunk,sub,block", [(8, 4, 2), (16, 8, 1), (24, 8, 2)])
+def test_kda_scan_gradients_are_the_recurrences(rule, chunk, sub, block):
+    r = rule
+    want = jax.grad(
+        lambda *a: jnp.sum(_recurrence(*a, r["seg"]) * r["do"]), argnums=(0, 1, 2, 3, 4)
+    )(r["q"], r["k"], r["v"], r["beta"], r["g"])
+    _, back = ops.kda_scan(
+        r["q"], r["k"], r["v"], r["beta"], r["g"], r["seg"], chunk=chunk, block=block, sub=sub
+    )
+    for got, w in zip(back(r["do"]), want):
+        _close(got, w, rtol=2e-5)
+
+
+def test_a_decay_constant_over_the_channels_is_the_gated_delta_rule(rule):
+    """``ops.gated_delta_scan`` is the special case: forward and gradients."""
+    r = rule
+    g_head = r["g"][..., 0]
+    g_all = np.broadcast_to(g_head[..., None], r["g"].shape)
+    got, got_back = ops.kda_scan(
+        r["q"], r["k"], r["v"], r["beta"], g_all, r["seg"], chunk=8, block=2, sub=4
+    )
+    want, want_back = ops.gated_delta_scan(
+        r["q"], r["k"], r["v"], r["beta"], g_head, r["seg"], chunk=8, block=2
+    )
+    _close(got, want, rtol=1e-5)
+    dq, dk, dv, dbeta, dg = got_back(r["do"])
+    for a, b in zip((dq, dk, dv, dbeta, dg.sum(-1)), want_back(r["do"])):
+        _close(a, b, rtol=2e-5)
+
+
+def test_kda_scan_state_stops_at_a_document_boundary(rule):
+    """Tokens before a boundary do not reach the outputs after it."""
+    r = rule
+    seg = np.zeros((ROWS, SEQ), np.int32)
+    seg[:, 20:] = 1
+    base, _ = ops.kda_scan(r["q"], r["k"], r["v"], r["beta"], r["g"], seg, chunk=8, sub=4)
+    v2 = r["v"].copy()
+    v2[:, :20] += 1.0
+    moved, _ = ops.kda_scan(r["q"], r["k"], v2, r["beta"], r["g"], seg, chunk=8, sub=4)
+    assert np.array_equal(np.asarray(base[:, 20:]), np.asarray(moved[:, 20:]))
+    assert not np.allclose(np.asarray(base[:, :20]), np.asarray(moved[:, :20]))
+
+
+def test_decayed_pairs_do_not_overflow_where_the_decay_is_strong():
+    """exp(g_i) exp(-g_j) overflows float32 past 88; the sub-blocks' reference
+    points keep every exponent at or below zero."""
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal((1, 32, 4)).astype(np.float32)
+    g = np.cumsum(-20 * rng.random((1, 32, 4)), axis=-2).astype(np.float32)
+    assert g.min() < -200
+    got = ops._decayed_pairs(x, x, g, 8, HIGHEST)
+    assert np.all(np.isfinite(np.asarray(got)))
+    want = np.einsum(
+        "id,jd,ijd->ij", x[0].astype(np.float64), x[0].astype(np.float64),
+        np.exp(np.minimum(g[0][:, None, :].astype(np.float64) - g[0][None, :, :], 0.0)),
+    )
+    lower = np.tril(np.ones((32, 32), bool))
+    _close(np.where(lower, np.asarray(got[0]), 0.0), np.where(lower, want, 0.0), rtol=1e-5)
+
+
+# -- grouped-query attention --------------------------------------------------
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1), (6, 3), (4, 4)])
+def test_grouped_attention_is_attention_with_the_kv_heads_repeated(heads, kv_heads):
+    rng = np.random.default_rng(3)
+    rows, seq, d = 2, 48, 8
+    q = rng.standard_normal((rows, heads, seq, d)).astype(np.float32)
+    k = rng.standard_normal((rows, kv_heads, seq, d)).astype(np.float32)
+    v = rng.standard_normal((rows, kv_heads, seq, d)).astype(np.float32)
+    do = rng.standard_normal((rows, heads, seq, d)).astype(np.float32)
+    seg = _segments(rows, seq, seed=4)
+    group = heads // kv_heads
+    got, got_back = ops.attention(q, k, v, seg, HIGHEST, block=16)
+    want, want_back = ops.attention(
+        q, np.repeat(k, group, axis=1), np.repeat(v, group, axis=1), seg, HIGHEST, block=16
+    )
+    _close(got, want, rtol=1e-6)
+    dq, dk, dv = got_back(do)
+    wq, wk, wv = want_back(do)
+    _close(dq, wq, rtol=1e-5)
+    # a repeated head's gradient is the sum over the query heads that read it
+    fold = lambda a: np.asarray(a).reshape(rows, kv_heads, group, seq, d).sum(2)  # noqa: E731
+    _close(dk, fold(wk), rtol=1e-5)
+    _close(dv, fold(wv), rtol=1e-5)
+
+
+def test_attention_refuses_key_value_heads_that_do_not_divide():
+    a = np.zeros((1, 4, 16, 8), np.float32)
+    with pytest.raises(ValueError, match="do not divide"):
+        ops.attention(a, a[:, :3], a[:, :3], np.zeros((1, 16), np.int32))
+
+
+# -- the routed experts -------------------------------------------------------
+
+TOKENS, D, FF, E, TOP = 96, 16, 12, 16, 4
+M_MOE = dict(
+    num_experts_per_tok=TOP, norm_topk_prob=True, routed_scaling_factor=1.0,
+)
+
+
+@pytest.fixture(scope="module")
+def mixture():
+    rng = np.random.default_rng(5)
+    f32 = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    return dict(
+        x=f32(TOKENS, D), W_r=f32(E, D), W1=0.3 * f32(E, FF, D), W3=0.3 * f32(E, FF, D),
+        W2=0.3 * f32(E, D, FF), Ws1=0.3 * f32(FF, D), Ws3=0.3 * f32(FF, D),
+        Ws2=0.3 * f32(D, FF), dout=f32(TOKENS, D),
+    )
+
+
+def _held(p, lo, hi):
+    return {**p, "W1": p["W1"][lo:hi], "W3": p["W3"][lo:hi], "W2": p["W2"][lo:hi]}
+
+
+def _reference_routed(p, x, lo, hi, policy):
+    """The reference's layer less its shared expert: the routed part alone."""
+    m = dict(M_MOE, routed_experts_held=[lo, hi])
+    shared = {k: jnp.zeros_like(p[k]) for k in ("Ws1", "Ws3", "Ws2")}
+    return ref.moe({**_held(p, lo, hi), **shared}, x, m, ref._matmul(policy))
+
+
+def _system_routed(p, x, lo, hi, precision, tile=8):
+    (weights, sel), route_back = ops.route(x, p["W_r"], TOP)
+    out, back, rows = ops.experts(
+        x, sel, weights, (lo, hi), p["W1"][lo:hi], p["W3"][lo:hi], p["W2"][lo:hi],
+        precision, tile,
+    )
+    return out, back, route_back, rows, sel
+
+
+@pytest.mark.parametrize("held", [(0, 4), (5, 13), (0, 16), (15, 16)])
+def test_routed_experts_are_the_references_dense_loop(mixture, held):
+    p, (lo, hi) = mixture, held
+    out, back, route_back, rows, sel = _system_routed(p, p["x"], lo, hi, HIGHEST)
+    _close(out, _reference_routed(p, p["x"], lo, hi, "highest"), rtol=1e-5)
+    counted = np.bincount(np.asarray(sel).reshape(-1), minlength=E)[lo:hi]
+    assert np.array_equal(np.asarray(rows), counted)
+    want = jax.grad(
+        lambda x, w_r, w1, w3, w2: jnp.sum(
+            ref.moe(
+                dict(W_r=w_r, W1=w1, W3=w3, W2=w2,
+                     **{k: jnp.zeros_like(p[k]) for k in ("Ws1", "Ws3", "Ws2")}),
+                x, dict(M_MOE, routed_experts_held=[lo, hi]), ref._matmul("highest"),
+            ) * p["dout"]
+        ),
+        argnums=(0, 1, 2, 3, 4),
+    )(p["x"], p["W_r"], p["W1"][lo:hi], p["W3"][lo:hi], p["W2"][lo:hi])
+    dx_e, dweights, dw1, dw3, dw2 = back(p["dout"])
+    dx_r, dw_r = route_back(dweights)
+    for got, w in zip((dx_e + dx_r, dw_r, dw1, dw3, dw2), want):
+        _close(got, w, rtol=2e-5)
+
+
+def test_routed_experts_round_as_the_reference_states(mixture):
+    """Under ``Precision.DEFAULT`` the operands are rounded to bfloat16 outright,
+    on a CPU as on the chip: identical selections (the router is float32), so
+    the system and the reference's ``default`` policy agree to summation order,
+    and the ``highest`` policy is a rounding away."""
+    p = mixture
+    out, back, _, _, _ = _system_routed(p, p["x"], 0, 8, lax.Precision.DEFAULT)
+    stated = _reference_routed(p, p["x"], 0, 8, "default")
+    _close(out, stated, rtol=1e-5)
+    exact = _reference_routed(p, p["x"], 0, 8, "highest")
+    assert np.linalg.norm(np.asarray(out) - np.asarray(exact)) > 1e-3 * np.linalg.norm(exact)
+    want = jax.grad(
+        lambda w1: jnp.sum(
+            _reference_routed({**p, "W1": jnp.asarray(p["W1"]).at[0:8].set(w1)}, p["x"], 0, 8, "default")
+            * p["dout"]
+        )
+    )(p["W1"][0:8])
+    _close(back(p["dout"])[2], want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("tile", [1, 8, 64, 512])
+def test_the_tile_changes_no_result(mixture, tile):
+    p = mixture
+    base, base_back, *_ = _system_routed(p, p["x"], 2, 9, HIGHEST, tile=8)
+    out, back, *_ = _system_routed(p, p["x"], 2, 9, HIGHEST, tile=tile)
+    _close(out, base, rtol=1e-6)
+    for a, b in zip(back(p["dout"]), base_back(p["dout"])):
+        _close(a, b, rtol=1e-5)
+
+
+def test_no_token_is_dropped_when_every_token_picks_one_expert(mixture):
+    """All 96 tokens on expert 3 and three more: 12 full tiles of 8, no
+    capacity to exceed."""
+    p = mixture
+    w_r = p["W_r"].copy()
+    w_r[:] = 0.0
+    x = np.abs(p["x"])  # every score of a positive row is above one half
+    w_r[[3, 7, 8, 9]] = 1.0
+    out, _, _, rows, sel = _system_routed({**p, "W_r": w_r}, x, 0, 8, HIGHEST)
+    assert np.array_equal(np.asarray(rows), [0, 0, 0, TOKENS, 0, 0, 0, TOKENS])
+    _close(out, _reference_routed({**p, "W_r": w_r}, x, 0, 8, "highest"), rtol=1e-5)
+
+
+@pytest.mark.parametrize("shares", [4, 2, 16])
+def test_the_shares_add_up_to_the_uncut_layer(mixture, shares):
+    """The share test: every share of the experts computes its own part of
+    the routed result (routing over all of them, weights normalised over all
+    the selected), and the parts, with the shared expert counted once, add up
+    to what the uncut reference gives for the whole layer."""
+    p, per = mixture, E // shares
+    whole = ref.moe(p, p["x"], dict(M_MOE, routed_experts_held=[0, E]), ref._matmul("highest"))
+    shared = ref.moe(
+        {**_held(p, 0, 1), "W2": jnp.zeros_like(p["W2"][:1])}, p["x"],
+        dict(M_MOE, routed_experts_held=[0, 1]), ref._matmul("highest"),
+    )
+    total, counted = shared, 0
+    for share in range(shares):
+        part, _, _, rows, _ = _system_routed(p, p["x"], share * per, (share + 1) * per, HIGHEST)
+        total, counted = total + part, counted + int(np.asarray(rows).sum())
+    assert counted == TOKENS * TOP  # every (token, slot) pair is some share's
+    _close(total, whole, rtol=1e-5)
+
+
+def test_route_selects_the_largest_scores_and_normalises_over_all_selected(mixture):
+    p = mixture
+    (weights, sel), back = ops.route(p["x"], p["W_r"], TOP)
+    scores = jax.nn.sigmoid(jnp.matmul(p["x"], p["W_r"].T, precision=HIGHEST))
+    want_sel = np.argsort(-np.asarray(scores), axis=-1, kind="stable")[:, :TOP]
+    assert np.array_equal(np.asarray(sel), want_sel)
+    picked = np.take_along_axis(np.asarray(scores), want_sel, axis=-1)
+    _close(weights, picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    _close(np.asarray(weights).sum(-1), np.ones(TOKENS), rtol=1e-6)
+    dx, dw = back(jnp.ones_like(weights))  # the weights sum to one: no gradient
+    assert float(jnp.abs(dx).max()) < 1e-6 and float(jnp.abs(dw).max()) < 1e-5

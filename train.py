@@ -123,7 +123,10 @@ def main(argv=None):
     )
     ap.add_argument(
         "--model",
-        choices=["mnist-mlp", "mlp-wide", "mlp-deep", "transformer", "olmo-hybrid-7b"],
+        choices=[
+            "mnist-mlp", "mlp-wide", "mlp-deep", "transformer", "olmo-hybrid-7b",
+            "solar-open2-250b",
+        ],
         default=None,
         help="model-zoo configuration (model.MODEL_ZOO): a named (sizes, "
         "activation family) pair. 'mnist-mlp' is the reference 8-layer "
@@ -139,7 +142,10 @@ def main(argv=None):
         "benchmarks/configs/olmo-hybrid-7b.json): it takes --seq-len, trains "
         "on a packed token set (tokens_train.npy, segments_train.npy) on "
         "one chip (dp = pp = tp = 1), and has no validation split, "
-        "checkpoint or fused run yet (pass --no-eval)",
+        "checkpoint or fused run yet (pass --no-eval). 'solar-open2-250b' is "
+        "the second token family (per-channel delta-rule and gated "
+        "grouped-query layers, a routed mixture of experts of which this "
+        "chip holds 8 of 320; benchmarks/configs/solar-open2-250b.json)",
     )
     ap.add_argument(
         "--seq-len",
