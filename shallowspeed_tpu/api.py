@@ -600,9 +600,10 @@ class TrainingSession:
             self.spec = Mo.make_token_spec(
                 token_config, seq_len, self.B, mubatch_rows=mubatch_rows
             )
-            # which form of the gated delta rule's scan the epoch program
-            # holds (ops.scan_path, from the shapes alone): provenance like
-            # ``data_layout``'s, an event and a count beside the program's
+            # which form of the recurrent layers' scan the epoch program
+            # holds (ops.scan_path or ops.kda_scan_path, from the shapes
+            # alone): provenance like ``data_layout``'s, an event and a
+            # count beside the program's
             scan_plan = Mo.token_scan_plan(self.spec, mubatches)
             self._scan_path = scan_plan["path"]
             self._token_counts["scan_kernel_calls"] = (
@@ -2557,8 +2558,9 @@ class TrainingSession:
     @property
     def scan_path(self):
         """``"pallas"`` or ``"xla"``: the form in which a token model's
-        Gated DeltaNet layers run their chunked scan (``ops.scan_path``; the
-        ``scan_path`` metrics event carries the same with the chunk, the
+        recurrent layers run their chunked scan (``ops.scan_path`` for the
+        Gated DeltaNet's, ``ops.kda_scan_path`` for the per-channel rule's;
+        the ``scan_path`` metrics event carries the same with the chunk, the
         head sizes and the kernel launches a step). ``None`` for an MLP."""
         return self._scan_path
 

@@ -532,23 +532,24 @@ def token_scan_plan(spec: "TokenModelSpec", mubatches):
     """Which form the recurrent layers' scan runs at this spec's shapes and
     the kernel launches one optimizer step makes: layers x microbatches x
     passes (a forward, the forward again where the layer is recomputed, a
-    backward; none where the XLA form runs). The Gated DeltaNet's scan has a
-    kernel form where its shapes tile (``ops.scan_path``); the per-channel
-    rule (``ops.kda_scan``) has the XLA form only. -> the ``scan_path``
-    event's fields."""
-    chunk = spec.scan_chunk
-    if spec.family == "solar_open2":
-        path = "xla"
+    backward; none where the XLA form runs). Each rule has a kernel form
+    where its shapes tile, and the op's own rule says where: the Gated
+    DeltaNet's ``ops.scan_path``, the per-channel rule's
+    ``ops.kda_scan_path`` (whose kernels choose their chunk). -> the
+    ``scan_path`` event's fields."""
+    chunk = ops._block_len(spec.seq_len, spec.scan_chunk)
+    shapes = (spec.linear_key_head_dim, spec.linear_value_head_dim, jnp.float32)
+    if "kda" in spec.layer_types:
+        path = ops.kda_scan_path(spec.seq_len, spec.linear_num_heads, *shapes)
+        if path == "pallas":
+            chunk = ops.KDA_KERNEL_CHUNK
     else:
-        path = ops.scan_path(
-            spec.seq_len, chunk, spec.linear_key_head_dim,
-            spec.linear_value_head_dim, jnp.float32,
-        )
+        path = ops.scan_path(spec.seq_len, spec.scan_chunk, *shapes)
     layers = sum(kind in _SCAN_KINDS for kind in spec.layer_types)
     passes = (3 if spec.recompute else 2) if path == "pallas" else 0
     return {
         "path": path,
-        "chunk": ops._block_len(spec.seq_len, chunk),
+        "chunk": chunk,
         "d_k": spec.linear_key_head_dim,
         "d_v": spec.linear_value_head_dim,
         "kernel_calls_per_step": layers * mubatches * passes,

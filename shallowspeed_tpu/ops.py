@@ -641,6 +641,14 @@ def _unit_lower_inverse(a):
     return inverse[..., 0, :, :]
 
 
+def _kernels_lower(dtype, precision):
+    """What both pairs of scan kernels take: float32, and a precision Mosaic
+    lowers (``HIGHEST`` and ``DEFAULT``; it refuses ``HIGH``)."""
+    return jnp.dtype(dtype) == jnp.float32 and precision in (
+        lax.Precision.HIGHEST, lax.Precision.DEFAULT
+    )
+
+
 def scan_path(seq, chunk, d_k, d_v, dtype, precision=SCAN_PRECISION):
     """Which form ``gated_delta_scan`` runs for these shapes: ``"pallas"``
     (the kernels of ``pallas_ops.gdn_scan_fwd`` / ``_bwd``) where they tile,
@@ -655,8 +663,29 @@ def scan_path(seq, chunk, d_k, d_v, dtype, precision=SCAN_PRECISION):
     tiles = (
         _block_len(seq, chunk) == 128
         and all(d % 8 == 0 and 8 <= d <= 256 for d in (d_k, d_v))
-        and jnp.dtype(dtype) == jnp.float32
-        and precision in (lax.Precision.HIGHEST, lax.Precision.DEFAULT)
+        and _kernels_lower(dtype, precision)
+    )
+    return "pallas" if tiles else "xla"
+
+
+def kda_scan_path(seq, heads, d_k, d_v, dtype, precision=SCAN_PRECISION):
+    """``scan_path`` for ``kda_scan``: the kernels of
+    ``pallas_ops.kda_scan_fwd`` / ``_bwd`` where the shapes tile. They choose
+    their own chunk, ``KDA_KERNEL_CHUNK`` tokens whatever chunk the XLA form
+    would take, so a row is whole chunks of that; they read ``q, k, v`` and
+    the log decay where the model keeps them, (rows, seq, heads, d), a block
+    8 heads of a chunk (a head is one sublane of a token's tile, a channel a
+    lane), so the heads come in eights, a key head is the 128 lanes and a
+    value head 128 or 256 (compiled for the chip at both, run on it at the
+    cell's 128; with 256 key channels Mosaic took 166 s over the pair:
+    PERF.md section 6, PR 36); float32 and the precisions Mosaic lowers, as
+    the scalar rule's."""
+    tiles = (
+        seq % KDA_KERNEL_CHUNK == 0
+        and heads % 8 == 0
+        and d_k == 128
+        and d_v in (128, 256)
+        and _kernels_lower(dtype, precision)
     )
     return "pallas" if tiles else "xla"
 
@@ -864,6 +893,7 @@ def _gated_delta_scan_xla(q, k, v, beta, log_decay, seg, precision, chunk, block
 KDA_CHUNK = 64  # tokens per chunk of the per-channel rule
 KDA_SUB = 16  # tokens per sub-block of a chunk's pair matrices
 KDA_BLOCK = 4  # chunks whose matrices are made (and, backward, rebuilt) at once
+KDA_KERNEL_CHUNK = 64  # tokens per chunk of the kernel form (``kda_scan_path``)
 MOE_TILE = 256  # rows of one expert's tile in the grouped product
 ROUTER_PRECISION = lax.Precision.HIGHEST  # float32 passes: a rounded score flips selections
 
@@ -941,14 +971,103 @@ def kda_scan(
     case of ``g_t`` constant over the channels. ``q, k``: (rows, seq, heads,
     d_k), ``v``: (rows, seq, heads, d_v), ``beta``: (rows, seq, heads),
     ``log_decay``: (rows, seq, heads, d_k), never positive, ``seg``: (rows,
-    seq). The chunked (WY) form of ``_gated_delta_scan_xla``, in
-    ``jax.numpy``: the same inverse, state recurrence, document masks,
-    blocks of chunks and ``jax.vjp`` backward; what differs is that a pair's
-    decay sits INSIDE its product over the channels (``_decayed_pairs``) and
-    that the state's rows decay each at their own rate. -> ``o, back``;
-    ``back(do) -> (dq, dk, dv, dbeta, dlog_decay)``. No kernel form yet."""
+    seq). The chunked (WY) form of ``gated_delta_scan``: the same inverse,
+    state recurrence and document masks; what differs is that a pair's decay
+    sits INSIDE its product over the channels and that the state's rows
+    decay each at their own rate. -> ``o, back``; ``back(do) -> (dq, dk, dv,
+    dbeta, dlog_decay)``. Every product is ``precision`` (float32 passes by
+    default, ``SCAN_PRECISION``) in either form.
+
+    Two forms, and ``kda_scan_path`` between them from the shapes alone (no
+    flag, no environment variable): the kernels of ``_kda_scan_pallas``
+    where the shapes tile, the ``jax.numpy`` form of ``_kda_scan_xla``
+    (which ``chunk``, ``block`` and ``sub`` belong to) everywhere else. The
+    second is the first's oracle (tests/test_solar_ops.py)."""
     if precision is None:
         precision = SCAN_PRECISION
+    shapes = (q.shape[1], q.shape[2], q.shape[-1], v.shape[-1], q.dtype)
+    if kda_scan_path(*shapes, precision) == "pallas":
+        return _kda_scan_pallas(q, k, v, beta, log_decay, seg, precision)
+    return _kda_scan_xla(q, k, v, beta, log_decay, seg, precision, chunk, block, sub)
+
+
+# A model calls the kernels once a layer and pass (9 times in the cell's
+# step), and tracing a kernel's body is seconds of the host's time (2 s a
+# call on the chip's host: 19 s of set-up, PERF.md section 6, PR 36). Under
+# ``jax.jit`` the calls of one shape share one trace and one lowering; what
+# decides the program besides the shapes is static, the backend's choice of
+# Mosaic or the interpreter among it. The scope is opened inside: a callee's
+# instructions carry their own path, not the caller's.
+@partial(jax.jit, static_argnames=("leaf", "precision", "interpret"))
+def _kda_kernel_fwd(*operands, **static):
+    from shallowspeed_tpu import pallas_ops as K
+
+    with scope("kda/scan"):
+        return K.kda_scan_fwd(*operands, **static)
+
+
+@partial(jax.jit, static_argnames=("precision", "interpret"))
+def _kda_kernel_bwd(*operands, **static):
+    from shallowspeed_tpu import pallas_ops as K
+
+    with scope("kda/scan"):
+        return K.kda_scan_bwd(*operands, **static)
+
+
+def _kda_scan_pallas(q, k, v, beta, log_decay, seg, precision, chunk=KDA_KERNEL_CHUNK):
+    """``kda_scan`` through the kernels ``pallas_ops.kda_scan_fwd`` /
+    ``_bwd``, which read ``q, k, v`` and the log decay where they lie (a
+    block is a chunk of 8 heads, a head a sublane of each token's tile) and
+    make the running sum of the log decay, and its pull-back, themselves.
+    Left in XLA, under the same scope: the document masks, 8 rows a chunk,
+    and each head's ``beta`` as a row a chunk, and ``dbeta`` taken out of
+    the same."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    rows, seq, heads, _ = q.shape
+    c = chunk
+    n = seq // c
+    per = K.kda_heads_per_step(heads)
+    groups = heads // per
+    static = dict(precision=precision, interpret=K._interpret())
+
+    with scope("kda/scan"):
+        first, segc, carried, to_last = _document_masks(seg, n, c)
+        by_row = {
+            K.KDA_SEG: segc, K.KDA_CARRIED: carried, K.KDA_TO_LAST: to_last,
+            K.KDA_FIRST: jnp.moveaxis(first.reshape(rows, n, c), 1, 0),
+        }
+        zero = jnp.zeros_like(segc)
+        p = jnp.moveaxis(
+            jnp.stack([by_row.get(r, zero) for r in range(K.KDA_ROWS)], axis=-2), 0, 1
+        ).astype(q.dtype)  # (rows, n, 8, c)
+
+        def by_group(beta):  # (rows, seq, heads) -> (rows * groups, n, 8, c)
+            betas = jnp.transpose(beta.reshape(rows, n, c, groups, per), (0, 3, 1, 4, 2))
+            betas = jnp.pad(betas, ((0, 0),) * 3 + ((0, K.KDA_ROWS - per), (0, 0)))
+            return betas.reshape(rows * groups, n, K.KDA_ROWS, c)
+
+        betas, pull_beta = jax.vjp(by_group, beta)
+        o, states, inverses = _kda_kernel_fwd(
+            q, k, v, log_decay, p, betas, leaf=min(INVERSE_LEAF, c), **static
+        )
+
+    def back(do):
+        with scope("kda/scan"):
+            dq, dk, dv, dg, dbetas = _kda_kernel_bwd(
+                q, k, v, log_decay, p, betas, states, inverses, do, **static
+            )
+            (dbeta,) = pull_beta(dbetas)
+            return dq, dk, dv, dbeta, dg
+
+    return o, back
+
+
+def _kda_scan_xla(q, k, v, beta, log_decay, seg, precision, chunk, block, sub):
+    """``kda_scan`` in ``jax.numpy``, for any chunk and head size:
+    ``_gated_delta_scan_xla``'s blocks of chunks and ``jax.vjp`` backward,
+    the pair matrices by ``_decayed_pairs`` in sub-blocks of ``sub``
+    tokens."""
     rows, seq, heads, dk = q.shape
     dv = v.shape[-1]
     c = _block_len(seq, chunk)

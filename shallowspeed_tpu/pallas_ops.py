@@ -1,7 +1,9 @@
 """Pallas TPU kernels: the MLP's fused linear + ReLU, forward & backward
-(opt-in, below), and the gated delta rule's chunked scan (``gdn_scan_fwd`` /
-``gdn_scan_bwd``, at the end of the file: what ``ops.gated_delta_scan`` runs
-wherever ``ops.scan_path`` says the shapes tile, with no switch of its own).
+(opt-in, below), and the two delta rules' chunked scans (``gdn_scan_fwd`` /
+``gdn_scan_bwd`` and, for the rule with a decay per key channel,
+``kda_scan_fwd`` / ``kda_scan_bwd``, at the end of the file: what
+``ops.gated_delta_scan`` and ``ops.kda_scan`` run wherever ``ops.scan_path``
+and ``ops.kda_scan_path`` say the shapes tile, with no switch of their own).
 
 The framework's compute path is XLA-compiled jax.numpy (ops.py) — for this
 model class XLA already fuses bias-add and ReLU into the matmul. These Pallas
@@ -56,7 +58,12 @@ axis runs in order with the (d_k x d_v) state in a VMEM scratch, and a
 chunk's (128 x 128) matrices never leave VMEM. On the chip one layer-row of
 ``olmo-hybrid-7b`` (8,192 tokens, 30 heads, 96 / 192) takes 8.8 ms forward
 and 10.8 ms backward, the XLA around the kernels included, where the XLA
-form took 10.9 and 31.6 (PERF.md section 6, PR 33). A token model still refuses ``kernel_backend="pallas"``: that
+form took 10.9 and 31.6 (PERF.md section 6, PR 33). The per-channel rule's
+layers (``solar_open2``) run theirs by shape too (rows of whole 64-token
+chunks, heads in eights, 128 key channels): one layer-row of
+``solar-open2-250b`` (2,048 tokens, 64 heads of 128) takes 4.8 ms forward
+and 8.9 ms backward where the XLA form took 8.3 and 29.7 (PERF.md section
+6, PR 36). A token model still refuses ``kernel_backend="pallas"``: that
 names the MLP flag kernels.
 """
 
@@ -964,7 +971,18 @@ def _gdn_inverse(a, i, j, leaf, dot):
     same blocks as one block-diagonal right operand, and a merge multiplies
     only the rows of each pair's second block. ``leaf`` and ``c`` are
     powers of two, so ``i ^ j < b`` says "same block of b rows"."""
-    c = a.shape[0]
+    return _gdn_inverses([a], i, j, leaf, dot)[0]
+
+
+def _gdn_inverses(mats, i, j, leaf, dot):
+    """``_gdn_inverse`` of each of ``mats``, step by step side by side: every
+    product waits for the one before it, and the products of different
+    matrices, issued in turn, fill each other's waits (on the chip the
+    per-channel forward of a layer-row took 7.30 ms a head at a time, 5.93
+    with two heads side by side and 5.23 with four: PERF.md section 6, PR
+    36)."""
+    c = mats[0].shape[0]
+    each = range(len(mats))
     same_leaf = (i ^ j) < leaf
 
     def side_by_side(m):  # block-diagonal (c, c) -> (leaf, c)
@@ -976,32 +994,36 @@ def _gdn_inverse(a, i, j, leaf, dot):
     def diagonal(m):  # (leaf, c) -> block-diagonal (c, c)
         return jnp.where(same_leaf, jnp.concatenate([m] * (c // leaf), axis=0), 0.0)
 
-    power_d = jnp.where(same_leaf, -a, 0.0)
-    power = side_by_side(power_d)
+    power_d = [jnp.where(same_leaf, -a, 0.0) for a in mats]
+    power = [side_by_side(m) for m in power_d]
     at = jax.lax.broadcasted_iota(jnp.int32, (leaf, c), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (leaf, c), 1)
-    inverse = ((lane & (leaf - 1)) == at).astype(a.dtype) + power
+    inverse = [((lane & (leaf - 1)) == at).astype(m.dtype) + m for m in power]
     done = 2  # powers 0 .. done - 1 are in ``inverse``
     while done < leaf:
-        power = dot(power, power_d)
-        power_d = diagonal(power)
-        inverse = inverse + dot(inverse, power_d)
+        power = [dot(power[x], power_d[x]) for x in each]
+        power_d = [diagonal(m) for m in power]
+        inverse = [inverse[x] + dot(inverse[x], power_d[x]) for x in each]
         done *= 2
-    t = diagonal(inverse)
+    t = [diagonal(m) for m in inverse]
     b = leaf
     while b < c:
-        a21 = jnp.where(((i ^ j) < 2 * b) & ((i ^ j) >= b), a, 0.0)
+        a21 = [jnp.where(((i ^ j) < 2 * b) & ((i ^ j) >= b), a, 0.0) for a in mats]
         pairs = range(0, c, 2 * b)
-        t22 = jnp.concatenate([t[s + b : s + 2 * b] for s in pairs], axis=0)
-        t2 = t22 - dot(dot(t22, a21), t)  # the rows [t21 | t22] of every pair
-        t = jnp.concatenate(
-            [
-                part
-                for n, s in enumerate(pairs)
-                for part in (t[s : s + b], t2[n * b : (n + 1) * b])
-            ],
-            axis=0,
-        )
+        t22 = [jnp.concatenate([m[s + b : s + 2 * b] for s in pairs], axis=0) for m in t]
+        t2a = [dot(t22[x], a21[x]) for x in each]
+        t2 = [t22[x] - dot(t2a[x], t[x]) for x in each]  # the rows [t21 | t22] of every pair
+        t = [
+            jnp.concatenate(
+                [
+                    part
+                    for n, s in enumerate(pairs)
+                    for part in (t[x][s : s + b], t2[x][n * b : (n + 1) * b])
+                ],
+                axis=0,
+            )
+            for x in each
+        ]
         b *= 2
     return t
 
@@ -1168,3 +1190,390 @@ def gdn_scan_bwd(q, k, v, p, states, inverses, do, *, precision):
         interpret=_interpret(),
         name="gdn_scan_bwd",
     )(q, k, v, p, states, inverses, do)
+
+
+# ---------------------------------------------------------------------------
+# The per-channel delta rule's chunked scan (``ops.kda_scan``'s kernel form):
+# the scalar rule's kernels above with the decay INSIDE each pair's product
+# over the key channels. What that changes: ``g`` (the log decay) is an
+# operand shaped like ``k``, and its running sum over the chunk is made in
+# the kernel (a product with a triangle of ones; backward, the transposed
+# triangle pulls the cotangent back); a chunk's pair matrices ``sum_d x_i[d]
+# k_j[d] exp(g_i[d] - g_j[d])`` are built in VMEM by sub-blocks that are
+# halved until one token is left (``_kda_levels``), every exponent at most
+# zero; the state is kept transposed, (d_v x d_k), so that the decay of a
+# key channel is a broadcast along the lanes. The arrays per token are read
+# and written where the model keeps them, (rows, seq, heads, d): a block is
+# one chunk of 8 heads, a head one sublane of each token's (8, 128) tile, so
+# nothing is transposed in XLA. The heads of a block are worked a few side
+# by side (``KDA_SIDE_BY_SIDE``): a chunk is a chain of small products that
+# each wait for the one before, and another head's fill the waits.
+# ---------------------------------------------------------------------------
+
+# rows of the packed block (8, chunk) of one chunk of one row: the document
+# and three masks a token. Each head's beta comes in a block of its own, (8,
+# chunk) a chunk and group of at most 8 heads.
+KDA_SEG, KDA_FIRST, KDA_CARRIED, KDA_TO_LAST = range(4)
+KDA_ROWS = 8
+KDA_SIDE_BY_SIDE = 4
+# of the chip's 128 MiB: the backward's blocks, twice, and its values take 18
+# MiB at the cell's shapes and 25 at 256 value channels; Mosaic's own limit is 16
+KDA_VMEM_BYTES = 64 * 2**20
+
+
+def _kda_reference_rows(g, h):
+    """Row ``t`` of the result is row ``(t & ~(h - 1)) | h`` of ``g`` (c, d):
+    the first token of the second half of ``t``'s block of ``2 h`` tokens.
+    Whole sublane tiles where ``h >= 8``; inside a tile, a broadcast of one
+    sublane selected by the token's place in it."""
+    c, d = g.shape
+    if h >= 8:
+        parts = [
+            jnp.broadcast_to(g[s + h : s + h + 1], (2 * h, d)) for s in range(0, c, 2 * h)
+        ]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    tiles = g.reshape(c // 8, 8, d)
+    at = jax.lax.broadcasted_iota(jnp.int32, tiles.shape, 1)
+    out = jnp.broadcast_to(tiles[:, h : h + 1], tiles.shape)
+    for s in range(2 * h, 8, 2 * h):
+        out = jnp.where(at >= s, jnp.broadcast_to(tiles[:, s + h : s + h + 1], tiles.shape), out)
+    return out.reshape(c, d)
+
+
+def _kda_halves(h, c):
+    """-> ``rows(x, second)``, the rows of the second (or first) halves of
+    the blocks of ``2 h`` tokens, (c, n) -> (c / 2, n), and ``back(y,
+    second)``, which puts them back with zeros between: a product costs the
+    MXU its left operand's rows, and at a level only second halves are an
+    ``i`` and only first halves a ``j``. Where a half is whole sublane tiles
+    (``h >= 8``); below that every row goes in, and both are the identity."""
+    if h < 8:
+        return (lambda x, second: x), (lambda y, second: y)
+
+    def rows(x, second):
+        parts = [x[s + h * second : s + h * (second + 1)] for s in range(0, c, 2 * h)]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+    def back(y, second):
+        zero = jnp.zeros((h, y.shape[1]), y.dtype)
+        parts = []
+        for n in range(c // (2 * h)):
+            part = y[n * h : (n + 1) * h]
+            parts += [zero, part] if second else [part, zero]
+        return jnp.concatenate(parts, axis=0)
+
+    return rows, back
+
+
+def _kda_levels(g, i, j):
+    """The pairs ``j < i`` of a chunk by the highest bit in which ``i`` and
+    ``j`` differ, ``h`` = 1 ... c / 2: such a pair lies in one block of ``2
+    h`` tokens, ``i`` in its second half and ``j`` in its first, and goes
+    through the running sum ``r`` at the second half's first token, ``(x_i
+    exp(g_i - r)) . (k_j exp(r - g_j))``: ``g`` never rises, so both
+    exponents are at most zero whatever the decay. -> ``level_of`` (c, c):
+    1 + the place of that bit, 0 on the diagonal; and for each level, the
+    finest first, ``(e, upper)``: the factor ``e`` (c, d_k) of every token
+    in its role at this level (a second half's as ``i``, a first half's as
+    ``j``) and (c, 1) whether a token is an ``i``. ``ops._decayed_pairs``'
+    sub-blocks, halved until a token is left: each level is one product in
+    place of a sum over the channels pair by pair."""
+    c = g.shape[0]
+    differ = i ^ j
+    level_of = jnp.zeros_like(differ)
+    levels = []
+    h = 1
+    while h < c:
+        ref = _kda_reference_rows(g, h)
+        upper = (i[:, :1] & h) != 0
+        levels.append((jnp.exp(jnp.where(upper, g - ref, ref - g)), upper))
+        level_of = level_of + (differ >= h).astype(level_of.dtype)
+        h *= 2
+    return level_of, levels
+
+
+def _kda_chunk(q, k, gl, p, beta, dot, top):
+    """What both passes build of one chunk of one head before the inverse.
+    ``gl``: (c, d_k) the log decay, ``p``: (8, c) the packed rows, ``beta``:
+    (c, 1). ``top`` is the float32-pass product: a running sum stands for
+    exact additions."""
+    c = k.shape[0]
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    cols = p.T  # (c, 8): the rows as columns
+    col = lambda r: cols[:, r : r + 1]  # noqa: E731
+    first = col(KDA_FIRST) > 0
+    lower = (col(KDA_SEG) == p[KDA_SEG : KDA_SEG + 1]) & (i > j)
+    # a document's first token takes no decay: its state starts from zero
+    g = top((i >= j).astype(gl.dtype), jnp.where(first, 0.0, gl))
+    level_of, levels = _kda_levels(g, i, j)
+    # [k k^T; q k^T] with the decay inside, a level at a time: a coarser
+    # level overwrites what a finer one left of its pairs
+    level_of2 = jnp.concatenate([level_of, level_of], axis=0)
+    pairs = jnp.zeros((2 * c, c), k.dtype)
+    for level, (e, _) in enumerate(levels, 1):
+        k_e, (rows, back) = k * e, _kda_halves(2 ** (level - 1), c)
+        both = dot(jnp.concatenate([rows(k_e, 1), rows(q * e, 1)], axis=0), k_e, _NT)
+        half = both.shape[0] // 2
+        both = jnp.concatenate([back(both[:half], 1), back(both[half:], 1)], axis=0)
+        pairs = jnp.where(level_of2 >= level, both, pairs)
+    kk = jnp.where(lower, pairs[:c], 0.0)
+    qk = jnp.where(lower, pairs[c:], 0.0)
+    qk = qk + jnp.where(i == j, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    g_in = jnp.where(col(KDA_CARRIED) > 0, jnp.exp(g), 0.0)
+    g_out = jnp.where(col(KDA_TO_LAST) > 0, jnp.exp(g[c - 1 :] - g), 0.0)
+    return dict(
+        i=i, j=j, first=first, lower=lower, level_of=level_of, levels=levels,
+        kk=kk, a=beta * kk, m=qk, g_in=g_in, g_out=g_out, keep=g_in[c - 1 :],
+    )
+
+
+def _kda_side_by_side(ref, body):
+    """``body(heads)`` over the heads of a block (``ref``: heads first), ``KDA_
+    SIDE_BY_SIDE`` at a time where they come in so many."""
+    heads = ref.shape[0]
+    width = next(w for w in (KDA_SIDE_BY_SIDE, 2, 1) if heads % w == 0)
+    jax.lax.fori_loop(
+        0, heads // width,
+        lambda step, _: body([step * width + n for n in range(width)]), None,
+    )
+
+
+def _kda_operands(refs, p_ref, b_ref, heads, dot, top):
+    """Of each of ``heads``: -> ``at`` (its index in a block (1, c, heads, d)
+    of an array per token), ``beta`` (c, 1), ``q, k, v`` and ``_kda_chunk``'s
+    values, a list each."""
+    p, betas = p_ref[0, 0], b_ref[0, 0].T  # (8, c), (c, 8)
+    head_of = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+    at = [(0, slice(None), h, slice(None)) for h in heads]
+    beta = [jnp.sum(jnp.where(head_of == h, betas, 0.0), axis=1, keepdims=True) for h in heads]
+    q, k, v, gl = ([ref[a] for a in at] for ref in refs)
+    x = [_kda_chunk(q[n], k[n], gl[n], p, beta[n], dot, top) for n in range(len(heads))]
+    return at, beta, q, k, v, x
+
+
+def _kda_corrected(t, beta, k, v, g_in, s, dot):
+    """``U0 = T (beta v)``, ``W = T (beta g_in k)`` and the corrected values
+    ``U = U0 - W S`` (``S`` as (d_v, d_k)), of several heads side by side."""
+    each = range(len(t))
+    u0 = [dot(t[n], beta[n] * v[n]) for n in each]
+    w = [dot(t[n], (beta[n] * g_in[n]) * k[n]) for n in each]
+    return u0, w, [u0[n] - dot(w[n], s[n], _NT) for n in each]
+
+
+def _kda_fwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, o_ref, s_ref, t_ref, state, *,
+    leaf, precision,
+):
+    dot, top = _gdn_dot(precision), _gdn_dot(jax.lax.Precision.HIGHEST)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    def several(heads):
+        at, beta, q, k, v, x = _kda_operands(
+            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads, dot, top
+        )
+        each = range(len(heads))
+        g_in = [c["g_in"] for c in x]
+        # float32 passes whatever ``precision``, as ``ops._unit_lower_inverse``
+        t = _gdn_inverses([c["a"] for c in x], x[0]["i"], x[0]["j"], leaf, top)
+        s = [state[h] for h in heads]  # (d_v, d_k)
+        _, _, u = _kda_corrected(t, beta, k, v, g_in, s, dot)
+        o_in = [dot(q[n] * g_in[n], s[n], _NT) for n in each]
+        o = [o_in[n] + dot(x[n]["m"], u[n]) for n in each]
+        left = [dot(u[n], k[n] * x[n]["g_out"], _TN) for n in each]
+        for n, h in enumerate(heads):
+            s_ref[h, 0] = s[n]
+            t_ref[h] = t[n]
+            o_ref[at[n]] = o[n]
+            state[h] = s[n] * x[n]["keep"] + left[n]
+
+    _kda_side_by_side(state, several)
+
+
+def _kda_bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, s_ref, t_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, precision,
+):
+    dot, top = _gdn_dot(precision), _gdn_dot(jax.lax.Precision.HIGHEST)
+
+    @pl.when(pl.program_id(1) == 0)  # the LAST chunk: the index maps run reversed
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    def lanes(x):  # (c, d) -> (c, 1)
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    db_ref[...] = jnp.zeros_like(db_ref)
+
+    def several(heads):
+        at, beta, q, k, v, x = _kda_operands(
+            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads, dot, top
+        )
+        each = range(len(heads))
+        i, j, c = x[0]["i"], x[0]["j"], k[0].shape[0]
+        g_in, g_out, keep = ([x[n][name] for n in each] for name in ("g_in", "g_out", "keep"))
+        s, t = [s_ref[h, 0] for h in heads], [t_ref[h] for h in heads]
+        do, ds_out = [do_ref[a] for a in at], [dstate[h] for h in heads]
+        u0, w, u = _kda_corrected(t, beta, k, v, g_in, s, dot)  # the forward's again
+        # O = (q g_in) S + M U;  S' = S keep + U^T (k g_out), S as (d_v, d_k)
+        du = [dot(x[n]["m"], do[n], _TN) for n in each]
+        du = [du[n] + dot(k[n] * g_out[n], ds_out[n], _NT) for n in each]
+        dm = [dot(do[n], u[n], _NT) for n in each]
+        dq_in = [dot(do[n], s[n]) for n in each]
+        dk_out = [dot(u[n], ds_out[n]) for n in each]
+        dw = [-dot(du[n], s[n]) for n in each]  # U = U0 - W S
+        ds_in = [dot(do[n], q[n] * g_in[n], _TN) for n in each]
+        ds_in = [ds_in[n] - dot(du[n], w[n], _TN) for n in each]
+        # [U0 | W] = T [beta v | beta g_in k];  dA = -T^T dT T^T, strictly lower
+        dr_v = [dot(t[n], du[n], _TN) for n in each]
+        dr_k = [dot(t[n], dw[n], _TN) for n in each]
+        da = [dot(dr_v[n], u0[n], _NT) for n in each]
+        da = [jnp.where(x[n]["lower"], -(da[n] + dot(dr_k[n], w[n], _NT)), 0.0) for n in each]
+        dq, dk, dg, sym, dqk, dqk_t = [], [], [], [], [], []
+        for n, h in enumerate(heads):
+            dstate[h] = ds_out[n] * keep[n] + ds_in[n]
+            dkeep = jnp.sum(ds_out[n] * s[n], axis=0, keepdims=True)  # (1, d_k)
+            on_diagonal = lanes(jnp.where(i == j, dm[n], 0.0))  # of sum_d q_i k_i
+            dq.append(dq_in[n] * g_in[n] + on_diagonal * k[n])
+            dk.append(
+                dk_out[n] * g_out[n] + (beta[n] * g_in[n]) * dr_k[n] + on_diagonal * q[n]
+            )
+            # of g: through g_in (and ``keep``, its last row), g_out (whose
+            # exponent holds the last row too), and each level's exponents
+            leaving = (dk_out[n] * k[n]) * g_out[n]
+            last = jnp.sum(leaving, axis=0, keepdims=True) + dkeep * keep[n]
+            through = (dq_in[n] * q[n] + beta[n] * dr_k[n] * k[n]) * g_in[n] - leaving
+            dg.append(through + jnp.where(i[:, :1] == c - 1, last, 0.0))
+            dkk = da[n] * beta[n]  # A = beta KK
+            sym.append(dkk + dkk.T)  # a level's mask is symmetric
+            dqk.append(jnp.where(x[n]["lower"], dm[n], 0.0))
+            dqk_t.append(dqk[n].T)
+            dv_ref[at[n]] = beta[n] * dr_v[n]
+            dbeta = lanes(da[n] * x[n]["kk"]) + lanes(dr_v[n] * v[n])
+            dbeta = dbeta + lanes(dr_k[n] * k[n] * g_in[n])
+            # the column, as row ``h`` of the block
+            db_ref[0, 0] += jnp.where(j == h, dbeta, 0.0).T[:KDA_ROWS]
+        for level in range(1, len(x[0]["levels"]) + 1):
+            here = x[0]["level_of"] == level
+            e = [x[n]["levels"][level - 1][0] for n in each]
+            k_e = [k[n] * e[n] for n in each]
+            q_e = [q[n] * e[n] for n in each]
+            # an ``i`` of this level reads ``dqk`` and ``sym``'s lower half, a
+            # ``j`` the transposes
+            rows, back = _kda_halves(2 ** (level - 1), c)
+            both = [
+                dot(
+                    jnp.concatenate(
+                        [rows(jnp.where(here, dqk[n], 0.0), 1), jnp.where(here, sym[n], 0.0)],
+                        axis=0,
+                    ),
+                    k_e[n],
+                )
+                for n in each
+            ]
+            as_i, of_kk = [back(m[:-c], 1) for m in both], [m[-c:] for m in both]
+            as_j = [
+                back(dot(rows(jnp.where(here, dqk_t[n], 0.0), 0), q_e[n]), 0) for n in each
+            ]
+            for n in each:
+                upper = x[n]["levels"][level - 1][1]
+                dx = as_i[n] * e[n]
+                dk_level = (of_kk[n] + as_j[n]) * e[n]
+                dq[n] = dq[n] + dx
+                dk[n] = dk[n] + dk_level
+                signed = jnp.where(upper, k[n] * dk_level, -(k[n] * dk_level))
+                dg[n] = dg[n] + q[n] * dx + signed
+        pulled = [top((i <= j).astype(dg[n].dtype), dg[n]) for n in each]
+        for n in each:
+            dq_ref[at[n]] = dq[n]
+            dk_ref[at[n]] = dk[n]
+            dg_ref[at[n]] = jnp.where(x[n]["first"], 0.0, pulled[n])
+
+    _kda_side_by_side(dstate, several)
+
+
+def kda_heads_per_step(heads):
+    """Heads a grid step: a sublane tile of them, or all of fewer than eight
+    (a block then spans the array's whole axis; a block of ``betas`` has a
+    row a head and 8 rows)."""
+    return 8 if heads % 8 == 0 else heads
+
+
+def _kda_operand_specs(q, v, p, chunk_of, interpret):
+    rows, seq, heads, dk = q.shape
+    dv, n, c = v.shape[-1], p.shape[1], p.shape[-1]
+    per = kda_heads_per_step(heads)
+    groups = heads // per
+    tokens, per_chunk = _gdn_specs(per, c, chunk_of)
+    _, of_group = _gdn_specs(1, c, chunk_of)
+
+    def in_place(d):
+        return pl.BlockSpec(
+            (1, c, per, d), lambda i, j: (i // groups, chunk_of(j), i % groups, 0)
+        )
+
+    return dict(
+        qk=in_place(dk), v=in_place(dv), betas=of_group(KDA_ROWS, c),
+        p=pl.BlockSpec((1, 1, KDA_ROWS, c), lambda i, j: (i // groups, chunk_of(j), 0, 0)),
+        states=per_chunk(dv, dk), inverses=tokens(c),
+        states_shape=jax.ShapeDtypeStruct((rows * heads, n, dv, dk), q.dtype),
+        inverses_shape=jax.ShapeDtypeStruct((rows * heads, seq, c), q.dtype),
+        call=dict(
+            grid=(rows * groups, n),
+            scratch_shapes=[pltpu.VMEM((per, dv, dk), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=KDA_VMEM_BYTES,
+            ),
+            interpret=interpret,
+        ),
+    )
+
+
+def kda_scan_fwd(q, k, v, g, p, betas, *, leaf, precision, interpret):
+    """``q, k, g``: (rows, seq, heads, d_k) (``g`` the log decay a token and
+    channel), ``v``: (rows, seq, heads, d_v), ``p``: (rows, n, 8, c) the
+    packed rows ``KDA_*``, ``betas``: (rows * groups, n, 8, c), a row a head
+    of each group of ``kda_heads_per_step`` heads; ``interpret``: what
+    ``_interpret()`` says (the caller's, which keys a trace by it). -> ``o``
+    shaped like ``v``, the state entering each chunk (rows * heads, n, d_v,
+    d_k) and each chunk's inverse (rows * heads, seq, c)."""
+    x = _kda_operand_specs(q, v, p, lambda j: j, interpret)
+    return pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, leaf=leaf, precision=precision),
+        in_specs=[x["qk"], x["qk"], x["v"], x["qk"], x["p"], x["betas"]],
+        out_specs=[x["v"], x["states"], x["inverses"]],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, q.dtype), x["states_shape"], x["inverses_shape"],
+        ],
+        name="kda_scan_fwd",
+        **x["call"],
+    )(q, k, v, g, p, betas)
+
+
+def kda_scan_bwd(q, k, v, g, p, betas, states, inverses, do, *, precision, interpret):
+    """The pull-back of ``kda_scan_fwd``'s ``o``: -> ``dq, dk, dv, dg``
+    shaped like ``q, k, v, g`` (``dg`` of the log decay itself: the running
+    sum is pulled back in the kernel) and ``dbetas`` shaped like ``betas``."""
+    n = p.shape[1]
+    x = _kda_operand_specs(q, v, p, lambda j: n - 1 - j, interpret)
+    return pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, precision=precision),
+        in_specs=[
+            x["qk"], x["qk"], x["v"], x["qk"], x["p"], x["betas"], x["states"],
+            x["inverses"], x["v"],
+        ],
+        out_specs=[x["qk"], x["qk"], x["v"], x["qk"], x["betas"]],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
+            jax.ShapeDtypeStruct(g.shape, q.dtype),
+            jax.ShapeDtypeStruct(betas.shape, q.dtype),
+        ],
+        name="kda_scan_bwd",
+        **x["call"],
+    )(q, k, v, g, p, betas, states, inverses, do)

@@ -579,3 +579,51 @@ def test_the_scan_kernels_trace_under_the_scans_scope():
     for kernel in ("gdn_scan_fwd", "gdn_scan_bwd"):
         (path,) = [p for p in paths if p.endswith(f"{kernel}/pallas_call")]
         assert scopes.scope_of(path) == ("gdn/scan", "gdn_scan"), path
+
+
+def _kda_both(q, k, v, beta, log_decay, seg, do):
+    from shallowspeed_tpu import ops
+
+    o, back = ops.kda_scan(q, k, v, beta, log_decay, seg)
+    return o, back(do)
+
+
+def _kda_shapes(seq, sharding=None):
+    import jax.numpy as jnp
+
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)  # noqa: E731
+    tokens = shape(1, seq, 8, 128)
+    seg = jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=sharding)
+    return tokens, tokens, tokens, shape(1, seq, 8), tokens, seg, tokens
+
+
+def test_the_kda_kernels_trace_under_the_scans_scope():
+    """As the scalar rule's: ``kda/scan`` before the kernel's own name."""
+    text = jax.jit(_kda_both).lower(*_kda_shapes(128)).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]+)"', text))
+    for kernel in ("kda_scan_fwd", "kda_scan_bwd"):
+        (path,) = [p for p in paths if p.endswith(f"{kernel}/pallas_call")]
+        assert scopes.scope_of(path) == ("kda/scan", "kda_scan"), path
+
+
+def test_the_kda_kernels_compile_for_the_chip_and_copy_no_operand(v5e_mesh, monkeypatch):
+    """Mosaic takes both kernels at the cell's blocks (a chunk of 64 tokens
+    of 8 heads of 128 channels, a head a sublane of each token's tile) with
+    Mosaic in place of the interpreter a CPU would get, and XLA hands them
+    ``q, k, v``, the log decay and the cotangents as they lie: no copy or
+    transposition of a (1, seq, 8, 128) array is left in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from shallowspeed_tpu import pallas_ops
+
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e_mesh.devices.flat[0])
+    lowered = jax.jit(_kda_both).lower(*_kda_shapes(256, one_chip))
+    text = _compile_off_cache(lowered).as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == 2 and "kda_scan_fwd" in text and "kda_scan_bwd" in text
+    moved = [
+        line for line in text.splitlines()
+        if re.search(r"= f32\[1,256,8,128\]\S* (copy|transpose)\(", line)
+    ]
+    assert not moved, moved

@@ -19,7 +19,7 @@ from jax import lax
 from shallowspeed_tpu import model as Mo
 from shallowspeed_tpu import trainer
 from shallowspeed_tpu.api import TrainingSession
-from shallowspeed_tpu.observability import costmodel, scopes
+from shallowspeed_tpu.observability import JsonlMetrics, costmodel, read_jsonl, scopes
 from shallowspeed_tpu.optimizer import SGD, WithGradScratch
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -287,6 +287,72 @@ def test_the_session_leaves_the_routing_counters_with_the_program(trained):
     assert mean <= counts["moe_load_max"] <= counts["moe_rows_held"]
     assert trained["session"].scan_path == "xla"
     assert isinstance(trained["session"]._opt, WithGradScratch)
+
+
+# heads of 128 channels in eights and rows of whole 64-token chunks: the
+# shapes ``ops.kda_scan_path`` gives the kernels (interpreted on a CPU)
+TILING = dict(
+    TINY, num_hidden_layers=2,
+    linear_attn_config=dict(short_conv_kernel_size=4, head_dim=128, num_heads=8, num_kv_heads=None),
+)
+
+
+@pytest.fixture(scope="module")
+def on_kernels(tmp_path_factory):
+    where = tmp_path_factory.mktemp("strata_kernels")
+    tokens, segments = strata.make_dataset(
+        12, 2, {"seq_len": 64}, {"vocab_size": TINY["vocab_size"]}, where
+    )
+    with JsonlMetrics(where / "run.jsonl") as metrics:
+        session = TrainingSession(
+            data_dir=str(where), model=TILING, seq_len=64, global_batch_size=2, mubatches=2,
+            optimizer="sgd", lr=0.5, precision="highest", metrics=metrics,
+        )
+        start = check.layers(session.params())
+        loss = session.train_epoch()
+    return dict(session=session, start=start, after=check.layers(session.params()),
+                loss=loss, counts=dict(scopes.program_counts("jit_epoch_core")),
+                tokens=np.array(tokens), segments=np.array(segments),
+                events=[r for r in read_jsonl(where / "run.jsonl") if r.get("name") == "scan_path"])
+
+
+def test_shapes_that_tile_run_the_kernels_and_the_session_says_so(on_kernels):
+    session = on_kernels["session"]
+    assert session.scan_path == "pallas"
+    (event,) = on_kernels["events"]
+    passes = 3 if session.spec.recompute else 2
+    calls = 1 * 2 * passes  # kda layers x microbatches x passes
+    fields = {k: event[k] for k in ("path", "chunk", "d_k", "d_v", "kernel_calls_per_step")}
+    assert fields == dict(path="pallas", chunk=64, d_k=128, d_v=128, kernel_calls_per_step=calls)
+    assert on_kernels["counts"]["scan_kernel_calls"] == calls  # an epoch of one step
+
+
+def test_one_step_on_the_kernels_is_the_references(on_kernels):
+    config = {"session": {"model": TILING, "optimizer": "sgd", "lr": 0.5,
+                          "precision": "highest", "seq_len": 64}}
+    prefix = check.prefix((on_kernels["tokens"], on_kernels["segments"]), 1, 2, 2)
+    want, losses = ref.make_reference(config)(on_kernels["start"], *prefix)
+    report = check.compare(
+        on_kernels["after"], want, on_kernels["start"],
+        {"update_rtol": 2e-3, "weight_ulps": 8, "loss_rtol": 1e-5},
+        loss=on_kernels["loss"], ref_loss=losses[0],
+    )
+    assert report["ok"], report
+
+
+@pytest.mark.parametrize(
+    "seq,batch,mubatches,want",
+    [
+        (2048, 8, 8, dict(path="pallas", chunk=64, kernel_calls_per_step=3 * 8 * 3)),  # the cell
+        (2048, 8, 4, dict(path="pallas", chunk=64, kernel_calls_per_step=3 * 4 * 3)),
+        (2080, 8, 8, dict(path="xla", chunk=52, kernel_calls_per_step=0)),  # no whole chunks of 64
+    ],
+)
+def test_the_named_models_plan_by_shape(seq, batch, mubatches, want):
+    config = Mo.token_model_config("solar-open2-250b")
+    spec = Mo.make_token_spec(config, seq, batch, mubatch_rows=batch // mubatches)
+    plan = Mo.token_scan_plan(spec, mubatches)
+    assert {k: plan[k] for k in want} == want and plan["d_k"] == plan["d_v"] == 128
 
 
 def test_scopes_classes_of_the_new_work():

@@ -4,6 +4,7 @@ they compute, written plainly: the per-channel delta rule token by token,
 attention with the key/value heads repeated, the routed mixture as a dense
 loop over experts. Tiny sizes, a CPU, float32 passes."""
 
+import functools
 import importlib.util
 from pathlib import Path
 
@@ -142,6 +143,165 @@ def test_decayed_pairs_do_not_overflow_where_the_decay_is_strong():
     )
     lower = np.tril(np.ones((32, 32), bool))
     _close(np.where(lower, np.asarray(got[0]), 0.0), np.where(lower, want, 0.0), rtol=1e-5)
+
+
+# -- the per-channel rule's kernels (interpreted on a CPU) ---------------------
+
+KERNEL_OUTPUTS = ("o", "dq", "dk", "dv", "dbeta", "dlog_decay")
+# name: (rows, seq, chunk, heads, d_k, d_v, decay scale, a document's starts a row)
+KERNEL_CASES = {
+    # a document starts inside a chunk, and a row without a start carries its
+    # state across the chunk boundaries
+    "inside": (2, 64, 16, 2, 8, 16, 3.0, ((21,), ())),
+    # at a chunk's first token and at a chunk's last
+    "edges": (2, 64, 16, 2, 8, 16, 3.0, ((32,), (15, 47))),
+    "one_chunk": (1, 32, 32, 2, 8, 16, 3.0, ((11,),)),  # the row is one chunk
+    "wide_keys": (1, 32, 8, 3, 16, 8, 3.0, ((9, 24),)),  # d_k > d_v, heads side by side 1
+    "four_heads": (1, 64, 64, 4, 8, 24, 1.0, ((40,),)),  # the cell's chunk, 4 heads side by side
+    # test_decayed_pairs_do_not_overflow_where_the_decay_is_strong's decays:
+    # exp(g_i) exp(-g_j) overflows float32 inside one chunk
+    "strong": (1, 64, 32, 2, 4, 8, 20.0, ((21,),)),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_run(case):
+    """``{form: {output: array}}`` for the kernels, the XLA form and the
+    token-by-token rule on one case's inputs. One run per case."""
+    rows, seq, chunk, heads, dk, dv, decay, starts = KERNEL_CASES[case]
+    rng = np.random.default_rng(7)
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    q, k, v, do = (f32(rows, seq, heads, d) for d in (dk, dk, dv, dv))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = (2 * rng.random((rows, seq, heads))).astype(np.float32)
+    log_decay = (-decay * rng.random((rows, seq, heads, dk))).astype(np.float32)
+    first = np.zeros((rows, seq), bool)
+    for r, at in enumerate(starts):
+        first[r, list(at)] = True
+    seg = jnp.asarray(np.cumsum(first, axis=1).astype(np.int32))
+    if decay > 10:
+        assert np.cumsum(log_decay, axis=1)[:, chunk - 1].min() < -200
+    args = (q, k, v, beta, log_decay)
+    runs = {}
+    for form, fn in (
+        ("kernels", lambda *a: ops._kda_scan_pallas(*a, seg, HIGHEST, chunk=chunk)),
+        ("xla", lambda *a: ops._kda_scan_xla(*a, seg, HIGHEST, chunk, 2, 4)),
+    ):
+        o, back = fn(*args)
+        runs[form] = dict(zip(KERNEL_OUTPUTS, (o, *back(do))))
+    grads = jax.grad(lambda *a: jnp.sum(_recurrence(*a, seg) * do), (0, 1, 2, 3, 4))(*args)
+    runs["rule"] = dict(zip(KERNEL_OUTPUTS, (_recurrence(*args, seg), *grads)))
+    return runs
+
+
+@pytest.mark.parametrize("output", KERNEL_OUTPUTS)
+@pytest.mark.parametrize("oracle", ["rule", "xla"])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_kda_kernels_are_the_rule_and_the_xla_form(case, oracle, output):
+    runs = _kernel_run(case)
+    got = np.asarray(runs["kernels"][output])
+    assert np.all(np.isfinite(got))
+    _close(got, runs[oracle][output], rtol=2e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _constant_decay_run():
+    """The per-channel kernels fed a decay constant over the channels, and
+    the scalar rule's kernels on the same inputs (chunks of 128 both)."""
+    rows, seq, heads, dk, dv = 1, 256, 2, 8, 16
+    rng = np.random.default_rng(9)
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    q, k, v, do = (f32(rows, seq, heads, d) for d in (dk, dk, dv, dv))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = (2 * rng.random((rows, seq, heads))).astype(np.float32)
+    g_head = (-rng.random((rows, seq, heads))).astype(np.float32)
+    seg = jnp.asarray(np.cumsum(np.arange(seq) == 150)[None].astype(np.int32))
+    g_all = np.broadcast_to(g_head[..., None], (rows, seq, heads, dk))
+    got, got_back = ops._kda_scan_pallas(q, k, v, beta, g_all, seg, HIGHEST, chunk=128)
+    want, want_back = ops._gated_delta_scan_pallas(q, k, v, beta, g_head, seg, HIGHEST)
+    dq, dk_, dv_, dbeta, dg = got_back(do)
+    return (
+        dict(zip(KERNEL_OUTPUTS, (got, dq, dk_, dv_, dbeta, dg.sum(-1)))),
+        dict(zip(KERNEL_OUTPUTS, (want, *want_back(do)))),
+    )
+
+
+@pytest.mark.parametrize("output", KERNEL_OUTPUTS)
+def test_a_constant_decay_through_the_kernels_is_the_scalar_rules_kernels(output):
+    got, want = _constant_decay_run()
+    _close(got[output], want[output], rtol=2e-5)
+
+
+def test_the_kernels_pair_matrices_do_not_overflow_where_the_decay_is_strong():
+    """``test_decayed_pairs_do_not_overflow_where_the_decay_is_strong`` at the
+    same decays, against what the kernels build of a chunk: every level's
+    exponents are at most zero."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    rng = np.random.default_rng(1)
+    c, d = 32, 4
+    x = rng.standard_normal((c, d)).astype(np.float32)
+    log_decay = (-20 * rng.random((c, d))).astype(np.float32)
+    log_decay[0] = 0.0  # the chunk's first token starts the document
+    g = np.cumsum(log_decay, axis=0)
+    assert g.min() < -200
+    p = np.zeros((K.KDA_ROWS, c), np.float32)
+    p[K.KDA_FIRST, 0] = 1.0
+    dot = K._gdn_dot(HIGHEST)
+    built = K._kda_chunk(x, x, log_decay, jnp.asarray(p), jnp.ones((c, 1)), dot, dot)
+    for e, _ in built["levels"]:
+        assert float(e.max()) <= 1.0 and np.all(np.isfinite(np.asarray(e)))
+    assert np.all(np.isfinite(np.asarray(built["kk"])))
+    want = np.einsum(
+        "id,jd,ijd->ij", x.astype(np.float64), x.astype(np.float64),
+        np.exp(np.minimum(g[:, None, :].astype(np.float64) - g[None, :, :], 0.0)),
+    )
+    _close(built["kk"], np.tril(want, -1), rtol=1e-5)
+    _close(built["m"], np.tril(want), rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "seq,heads,dk,dv,dtype,want",
+    [
+        (2048, 64, 128, 128, jnp.float32, "pallas"),  # the cell
+        (64, 8, 128, 128, jnp.float32, "pallas"),  # one chunk; tests/test_solar_model.py's
+        (4096, 16, 128, 256, jnp.float32, "pallas"),
+        (64, 4, 8, 8, jnp.float32, "xla"),  # the rehearsal: heads of 8 channels
+        (48, 3, 8, 12, jnp.float32, "xla"),  # this file's
+        (2048, 64, 96, 128, jnp.float32, "xla"),  # d_k off the lanes
+        (2048, 64, 256, 256, jnp.float32, "xla"),  # two tiles of key channels
+        (2048, 64, 128, 384, jnp.float32, "xla"),  # d_v beyond what was compiled
+        (2048, 60, 128, 128, jnp.float32, "xla"),  # the heads do not come in eights
+        (2080, 64, 128, 128, jnp.float32, "xla"),  # 2080 = 32.5 chunks of 64
+        (2048, 64, 128, 128, jnp.bfloat16, "xla"),
+    ],
+)
+def test_the_kda_kernels_engage_by_shape_alone(seq, heads, dk, dv, dtype, want):
+    assert ops.kda_scan_path(seq, heads, dk, dv, dtype) == want
+
+
+@pytest.mark.parametrize(
+    "precision,want",
+    [(lax.Precision.HIGHEST, "pallas"), (lax.Precision.DEFAULT, "pallas"),
+     (lax.Precision.HIGH, "xla")],  # Mosaic lowers no three-pass product
+)
+def test_the_kda_kernels_take_the_precisions_mosaic_lowers(precision, want):
+    assert ops.kda_scan_path(2048, 64, 128, 128, jnp.float32, precision) == want
+
+
+def test_nothing_but_the_shapes_selects_the_kda_kernels(monkeypatch):
+    """No environment variable and no flag: the MLP kernels' switch leaves
+    the rule alone, and ``kda_scan`` asks the rule and nothing else."""
+    monkeypatch.setenv("SHALLOWSPEED_PALLAS", "0")
+    monkeypatch.setattr(ops, "_PALLAS", False)
+    assert ops.kda_scan_path(2048, 64, 128, 128, jnp.float32) == "pallas"
+    monkeypatch.setattr(ops, "_PALLAS", True)
+    assert ops.kda_scan_path(48, 4, 8, 8, jnp.float32) == "xla"
+    asked = []
+    monkeypatch.setattr(ops, "kda_scan_path", lambda *a: asked.append(a) or "xla")
+    r = np.zeros((1, 64, 8, 128), np.float32)
+    ops.kda_scan(r, r, r, r[..., 0], r, np.zeros((1, 64), np.int32))
+    assert asked == [(64, 8, 128, 128, np.dtype("float32"), ops.SCAN_PRECISION)]
 
 
 # -- grouped-query attention --------------------------------------------------
