@@ -116,51 +116,35 @@ def require(ok, message):
 # -- what JAX itself says about compiling -------------------------------------
 
 
-class CompileClock:
-    """Listens to JAX's compile events for the life of one smoke: wall time
-    spent tracing, lowering and compiling (or loading from the persistent
-    cache), and persistent-cache hits and misses."""
+def compile_mark():
+    """Where the program's record of its compiles stands now: the span log of
+    ``observability.spans``, fed by the package's one ``jax.monitoring``
+    listener (``enable_compile_cache`` registers it). Before the import leg
+    has loaded the package nothing has compiled under the listener."""
+    spans = sys.modules.get("shallowspeed_tpu.observability.spans")
+    cache = spans.log().cache if spans else {"hits": 0, "misses": 0}
+    return time.perf_counter_ns(), cache["hits"], cache["misses"]
 
-    def __init__(self):
-        import jax.monitoring
 
-        self._spans = []
-        self.hits = 0
-        self.misses = 0
-        self._open = True
-        jax.monitoring.register_event_time_span_listener(self._on_span)
-        jax.monitoring.register_event_listener(self._on_event)
+def compile_since(mark):
+    """``(compile_seconds, cache_hits, cache_misses)`` since ``mark``: wall
+    time spent tracing, lowering and compiling (or loading from the persistent
+    cache), as the union of the log's compile events, since nested traces
+    report nested events; and the persistent cache's hits and misses."""
+    from shallowspeed_tpu.observability import spans
 
-    def _on_span(self, event, start, end, **_):
-        if self._open and event.startswith("/jax/core/compile/"):
-            self._spans.append((start, end))
-
-    def _on_event(self, event, **_):
-        if not self._open:
-            return
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def mark(self):
-        return len(self._spans), self.hits, self.misses
-
-    def since(self, mark):
-        """``(compile_seconds, cache_hits, cache_misses)`` since ``mark``.
-        Nested traces report nested spans, so the seconds are the length of
-        the spans' union, not their sum."""
-        n, hits, misses = mark
-        total, reach = 0.0, -math.inf
-        for start, end in sorted(self._spans[n:]):
-            if end > reach:
-                total += end - max(start, reach)
-                reach = end
-        return total, self.hits - hits, self.misses - misses
-
-    def close(self):
-        """JAX offers no public way to unregister one listener: go quiet."""
-        self._open = False
+    since, hits, misses = mark
+    log = spans.log()
+    events = [
+        e for e in log.entries()
+        if e.start >= since
+        and e.name in ("compile/trace", "compile/lower", "compile/backend")
+    ]
+    return (
+        spans.covered_ns(events) / 1e9,
+        log.cache["hits"] - hits,
+        log.cache["misses"] - misses,
+    )
 
 
 # -- running one leg under a limit ---------------------------------------------
@@ -175,14 +159,14 @@ def _expire(leg, limit_s):
     os._exit(3)
 
 
-def run_leg(leg, fn, clock, *args, **kwargs):
+def run_leg(leg, fn, *args, **kwargs):
     """Run ``fn`` as the phase named ``leg`` under its wall-clock limit.
     Returns the leg's facts plus its timing; any failure (``train.main``
     exits through ``SystemExit``) becomes ``LegFailed(leg)``."""
     limit_s = LEG_LIMIT_S[leg]
     watchdog = threading.Timer(limit_s, _expire, (leg, limit_s))
     watchdog.daemon = True
-    mark = clock.mark()
+    mark = compile_mark()
     t0 = time.perf_counter()
     watchdog.start()
     try:
@@ -192,7 +176,7 @@ def run_leg(leg, fn, clock, *args, **kwargs):
     finally:
         watchdog.cancel()
     wall = time.perf_counter() - t0
-    compile_s, hits, misses = clock.since(mark)
+    compile_s, hits, misses = compile_since(mark)
     facts.update(
         wall_s=round(wall, 2),
         compile_s=round(compile_s, 2),
@@ -545,43 +529,38 @@ def _cache_entries(cache_dir):
 def run_smoke(n_devices, work_dir, out_dir):
     """Every phase, in order, each under its limit. Returns the summary;
     raises ``LegFailed`` at the first failure."""
-    clock = CompileClock()
-    try:
-        summary = {"import": run_leg("import", _load_program, clock)}
-        cache_dir = summary["import"]["cache_dir"]
-        entries_before = _cache_entries(cache_dir)
-        work_dir.mkdir(parents=True, exist_ok=True)
-        out_dir.mkdir(parents=True, exist_ok=True)
+    summary = {"import": run_leg("import", _load_program)}
+    cache_dir = summary["import"]["cache_dir"]
+    entries_before = _cache_entries(cache_dir)
+    work_dir.mkdir(parents=True, exist_ok=True)
+    out_dir.mkdir(parents=True, exist_ok=True)
 
-        dirs = {}
-        summary["data"] = run_leg(
-            "data",
-            lambda: dirs.update(make_data(work_dir, ORACLE_STEPS, DEEP_STEPS)),
-            clock,
+    dirs = {}
+    summary["data"] = run_leg(
+        "data",
+        lambda: dirs.update(make_data(work_dir, ORACLE_STEPS, DEEP_STEPS)),
+    )
+    summary["leg A"] = run_leg(
+        "leg A", leg_reference,
+        dirs["full"], dirs["oracle"], work_dir, out_dir,
+    )
+    summary["leg B"] = run_leg(
+        "leg B", leg_deep_one_chip, dirs["deep"], work_dir, out_dir
+    )
+    if n_devices >= 4:
+        summary["leg C"] = run_leg(
+            "leg C", leg_deep_mesh, dirs["deep"], work_dir, out_dir,
+            summary["leg B"]["checkpoint"],
         )
-        summary["leg A"] = run_leg(
-            "leg A", leg_reference, clock,
-            dirs["full"], dirs["oracle"], work_dir, out_dir,
-        )
-        summary["leg B"] = run_leg(
-            "leg B", leg_deep_one_chip, clock, dirs["deep"], work_dir, out_dir
-        )
-        if n_devices >= 4:
-            summary["leg C"] = run_leg(
-                "leg C", leg_deep_mesh, clock, dirs["deep"], work_dir, out_dir,
-                summary["leg B"]["checkpoint"],
-            )
-        else:
-            summary["leg C"] = f"not run, {n_devices} device(s)"
-            print(f"chip_smoke: leg C: {summary['leg C']}", flush=True)
-        summary["compile_cache"] = {
-            "dir": cache_dir,
-            "entries_before": entries_before,
-            "entries_after": _cache_entries(cache_dir),
-        }
-        return summary
-    finally:
-        clock.close()
+    else:
+        summary["leg C"] = f"not run, {n_devices} device(s)"
+        print(f"chip_smoke: leg C: {summary['leg C']}", flush=True)
+    summary["compile_cache"] = {
+        "dir": cache_dir,
+        "entries_before": entries_before,
+        "entries_after": _cache_entries(cache_dir),
+    }
+    return summary
 
 
 def _next_run_dir(out_dir):

@@ -47,13 +47,13 @@ from shallowspeed_tpu.checkpoint import (
 from shallowspeed_tpu.data import Dataset, default_data_dir, packed_counts
 from shallowspeed_tpu.observability import NullMetrics, costmodel, program_audit
 from shallowspeed_tpu.observability import scopes
-from shallowspeed_tpu.observability import span as host_span
 from shallowspeed_tpu.observability.flight import FlightRecorder
 from shallowspeed_tpu.observability.health import HealthError, make_monitor
 from shallowspeed_tpu.observability.slo import (
     LiveTelemetry,
     default_training_rules,
 )
+from shallowspeed_tpu.observability.spans import listen_to_compiles
 from shallowspeed_tpu.optimizer import (
     is_stateless,
     join_state,
@@ -131,1003 +131,1035 @@ class TrainingSession:
         predict_slot_ladder=None,
         runtime="lockstep",
     ):
-        # telemetry hook (observability package): None -> the zero-overhead
-        # null backend. Everything the session emits — construction spans,
-        # jit-compile spans, per-epoch training records, per-step flight
-        # records, MFU gauges, pipeline program stats — flows through this
-        # one recorder (docs/observability.md).
+        # telemetry hook (observability package): None -> the null backend,
+        # which drops everything but spans. Everything the session emits
+        # (per-epoch training records, per-step flight records, MFU gauges,
+        # pipeline program stats) flows through this one recorder; its spans
+        # are ALWAYS written to the process's span log and the profiler's
+        # trace (observability/spans.py), recorder or none, and the compile
+        # listener puts each trace, lowering and backend compile beside them
+        # (docs/observability.md).
         self._metrics = metrics if metrics is not None else NullMetrics()
-        # live telemetry (schema v11, docs/observability.md § Live
-        # telemetry & alerting): per-step loss/throughput/MFU rollup
-        # windows plus the trainer rule set (health-event alerts, the
-        # checkpoint-overhead fraction vs its budget). Fed only inside
-        # metrics-enabled blocks — a NullMetrics session pays nothing.
-        self._telemetry = LiveTelemetry(
-            "train", metrics=self._metrics, rules=default_training_rules()
-        )
-        # compiled-program audit (observability/program_audit.py): with a
-        # metrics recorder attached, the jit-time collective census +
-        # memory analysis is ALWAYS recorded (schema-v3 xla_audit record).
-        # ``audit=True`` additionally ENFORCES the layout's comms contract:
-        # the epoch/run program is compiled (even without metrics) and an
-        # AuditMismatchError is raised when its collective census violates
-        # the layout's analytical contract.
-        self._audit_strict = bool(audit)
-        self._audit_done = set()  # program names already audited
-        # numerics health monitor: None, a policy string ("record" / "warn"
-        # / "halt"), or a HealthMonitor instance (observability/health.py).
-        # Checks run on host against the fused per-step aux after each
-        # epoch's readback; under "halt" a finding raises HealthError AFTER
-        # the epoch's update has been applied (the monitor observes the
-        # fused program's outputs, it cannot unwind them).
-        self._health = make_monitor(health)
-        # model zoo (model.MODEL_ZOO / train.py --model): a named
-        # compute-bound configuration — (sizes, activation family) — that
-        # overrides ``sizes``. The family is STATIC program structure
-        # (relu traces the historical expressions byte-identically; the
-        # gelu family adds residual slots and f32 grad-multiplier masks),
-        # and every zoo model keeps the 784-wide MNIST input so the data
-        # pipeline, checkpoints and serving slots compose unchanged.
-        # A TOKEN model (model.TOKEN_MODELS, or the keys of a published
-        # config.json given as a dictionary) is a function of token ids of
-        # ``seq_len`` a row: it has no ``sizes``, trains on a packed token
-        # set, and runs the sequential path only (checked below, once the
-        # layout is known).
-        self.model_name = model
-        token_config = (
-            Mo.token_model_config(model) if Mo.is_token_model(model) else None
-        )
-        self._token = token_config is not None
-        if self._token:
-            if isinstance(model, dict):
-                self.model_name = model.get("model_type", "token-model")
-            sizes, act = (), "relu"
-        elif seq_len is not None:
-            raise ValueError(
-                "seq_len is a token model's sequence length; "
-                f"model={model!r} takes rows of features"
+        listen_to_compiles()
+        with self._metrics.span("session/init"):
+            # live telemetry (schema v11, docs/observability.md § Live
+            # telemetry & alerting): per-step loss/throughput/MFU rollup
+            # windows plus the trainer rule set (health-event alerts, the
+            # checkpoint-overhead fraction vs its budget). Fed only inside
+            # metrics-enabled blocks — a NullMetrics session pays nothing.
+            self._telemetry = LiveTelemetry(
+                "train", metrics=self._metrics, rules=default_training_rules()
             )
-        elif model is not None:
-            sizes, act = Mo.resolve_model(model)
-        else:
-            act = "relu"
-        self._act = act
-        if global_batch_size % dp != 0:
-            raise ValueError("global batch size must be divisible by dp")
-        local_batch = global_batch_size // dp
-        if local_batch % mubatches != 0:
-            raise ValueError("mubatches must divide the local batch")
-        if tp < 1:
-            raise ValueError(f"tp must be >= 1, got {tp}")
-        self.dp, self.pp, self.tp = dp, pp, int(tp)
-        self.B, self.M = global_batch_size, mubatches
-        self.schedule = schedule
-        if precision not in PRECISIONS:
-            raise ValueError(
-                f"precision must be one of {sorted(PRECISIONS)}, got {precision!r}"
+            # compiled-program audit (observability/program_audit.py): with a
+            # metrics recorder attached, the jit-time collective census +
+            # memory analysis is ALWAYS recorded (schema-v3 xla_audit record).
+            # ``audit=True`` additionally ENFORCES the layout's comms contract:
+            # the epoch/run program is compiled (even without metrics) and an
+            # AuditMismatchError is raised when its collective census violates
+            # the layout's analytical contract.
+            self._audit_strict = bool(audit)
+            self._audit_done = set()  # program names already audited
+            # numerics health monitor: None, a policy string ("record" / "warn"
+            # / "halt"), or a HealthMonitor instance (observability/health.py).
+            # Checks run on host against the fused per-step aux after each
+            # epoch's readback; under "halt" a finding raises HealthError AFTER
+            # the epoch's update has been applied (the monitor observes the
+            # fused program's outputs, it cannot unwind them).
+            self._health = make_monitor(health)
+            # model zoo (model.MODEL_ZOO / train.py --model): a named
+            # compute-bound configuration — (sizes, activation family) — that
+            # overrides ``sizes``. The family is STATIC program structure
+            # (relu traces the historical expressions byte-identically; the
+            # gelu family adds residual slots and f32 grad-multiplier masks),
+            # and every zoo model keeps the 784-wide MNIST input so the data
+            # pipeline, checkpoints and serving slots compose unchanged.
+            # A TOKEN model (model.TOKEN_MODELS, or the keys of a published
+            # config.json given as a dictionary) is a function of token ids of
+            # ``seq_len`` a row: it has no ``sizes``, trains on a packed token
+            # set, and runs the sequential path only (checked below, once the
+            # layout is known).
+            self.model_name = model
+            token_config = (
+                Mo.token_model_config(model) if Mo.is_token_model(model) else None
             )
-        self._precision_name = precision  # the MFU peak is precision-classed
-        if schedule not in S.SCHEDULES:
-            raise ValueError(
-                f"schedule must be one of {sorted(S.SCHEDULES)}, got {schedule!r}"
-            )
-        self.precision = PRECISIONS[precision]
-        if fuse_mubatches and not (
-            dp == 1 and pp == 1 and virtual_stages == 1 and tp == 1
-        ):
-            raise ValueError(
-                "fuse_mubatches applies to the sequential path only; in the "
-                "pipeline executor microbatches are semantic (they ARE the "
-                "pipeline's unit of work)"
-            )
-        if megakernel and not fuse_mubatches:
-            raise ValueError(
-                "megakernel runs the whole fused batch as one Pallas kernel; "
-                "it requires fuse_mubatches=True (sequential path)"
-            )
-        if epoch_kernel and not fuse_mubatches:
-            raise ValueError(
-                "epoch_kernel runs the whole epoch as one Pallas kernel; "
-                "it requires fuse_mubatches=True (sequential path)"
-            )
-        if run_kernel and not fuse_mubatches:
-            raise ValueError(
-                "run_kernel runs the whole multi-epoch run as one Pallas "
-                "kernel; it requires fuse_mubatches=True (sequential path)"
-            )
-        if run_kernel and (megakernel or epoch_kernel):
-            raise ValueError(
-                "run_kernel subsumes the mega/epoch kernels; pass only "
-                "run_kernel=True"
-            )
-        self._run_kernel = bool(run_kernel)
-        if kernel_backend not in ("xla", "pallas"):
-            raise ValueError(
-                f"kernel_backend must be 'xla' or 'pallas', got {kernel_backend!r}"
-            )
-        if virtual_stages < 1:
-            raise ValueError("virtual_stages must be >= 1")
-        if virtual_stages > 1 and schedule != "interleaved":
-            raise ValueError(
-                "virtual_stages > 1 requires schedule='interleaved' (the flat "
-                "schedules place exactly one stage per device)"
-            )
-        if scan_unroll < 1 or tick_unroll < 1:
-            raise ValueError("scan_unroll/tick_unroll must be >= 1")
-        self.V = virtual_stages
-        self._sequential = dp == 1 and pp == 1 and virtual_stages == 1 and tp == 1
-        self._kernel_backend = kernel_backend
-        if self._token:
-            wanted = {
-                "a mesh layout (dp, pp, tp or virtual_stages > 1)": not self._sequential,
-                "zero": bool(zero) or zero1,
-                "fuse_mubatches": fuse_mubatches,
-                "the pallas kernels (megakernel, epoch_kernel, run_kernel, "
-                "kernel_backend='pallas')": megakernel or epoch_kernel
-                or run_kernel or kernel_backend == "pallas",
-                "runtime='mpmd'": runtime != "lockstep",
-                "digests": digests,
-                "checkpoints (resume, checkpoint_dir)": resume is not None
-                or checkpoint_dir is not None,
-            }
-            refused = [what for what, asked in wanted.items() if asked]
-            if refused:
+            self._token = token_config is not None
+            if self._token:
+                if isinstance(model, dict):
+                    self.model_name = model.get("model_type", "token-model")
+                sizes, act = (), "relu"
+            elif seq_len is not None:
                 raise ValueError(
-                    f"token model {self.model_name!r} runs the sequential "
-                    f"one-chip path (dp = pp = tp = 1) only; asked for: "
-                    f"{'; '.join(refused)}. The mesh executor's stage "
-                    "functions, its kernels and the checkpoint format are "
-                    "written for stacks of {W, b} Linears (ROADMAP R0a, D2)"
+                    "seq_len is a token model's sequence length; "
+                    f"model={model!r} takes rows of features"
                 )
-        if kernel_backend == "pallas" and act != "relu":
-            raise ValueError(
-                "kernel_backend='pallas' hard-codes the relu/identity slot "
-                "expressions; the gelu-family models (f32 grad-multiplier "
-                "masks, residual adds) run the XLA backend only"
-            )
-        if kernel_backend == "pallas" and tp > 1:
-            raise ValueError(
-                "tensor parallelism (tp > 1) shards each slot's W across "
-                "the tp axis; the fused pallas flag kernels compute whole "
-                "slots — use kernel_backend='xla'"
-            )
-        if kernel_backend == "pallas" and self._sequential:
-            raise ValueError(
-                "kernel_backend='pallas' selects the pipeline executor's "
-                "flag-operand kernels and needs a mesh layout (dp/pp > 1 or "
-                "virtual_stages > 1); on the sequential path use "
-                "megakernel=True or SHALLOWSPEED_PALLAS=1 instead"
-            )
-        if tick_unroll > 1 and self._sequential:
-            raise ValueError(
-                "tick_unroll unrolls the pipeline tick loop; the sequential "
-                "path has no ticks — use scan_unroll"
-            )
-        # the dp-axis ZeRO stage (arXiv 2004.13336): ``zero`` in {0,1,2,3}
-        # supersedes the historical ``zero1`` boolean — ``zero=1`` IS the
-        # zero1 path, verbatim. Stage 2 shards gradients + optimizer state
-        # (block-cyclic per-slot layout, bitwise-equal weights to stage 1
-        # on clip-free runs); stage 3 additionally shards the params at
-        # rest with just-in-time per-tick gathers.
-        if zero is None:
-            zero = 1 if zero1 else 0
-        else:
-            zero = int(zero)
-            if zero not in (0, 1, 2, 3):
-                raise ValueError(f"zero must be one of 0/1/2/3, got {zero}")
-            if zero1 and zero != 1:
+            elif model is not None:
+                sizes, act = Mo.resolve_model(model)
+            else:
+                act = "relu"
+            self._act = act
+            if global_batch_size % dp != 0:
+                raise ValueError("global batch size must be divisible by dp")
+            local_batch = global_batch_size // dp
+            if local_batch % mubatches != 0:
+                raise ValueError("mubatches must divide the local batch")
+            if tp < 1:
+                raise ValueError(f"tp must be >= 1, got {tp}")
+            self.dp, self.pp, self.tp = dp, pp, int(tp)
+            self.B, self.M = global_batch_size, mubatches
+            self.schedule = schedule
+            if precision not in PRECISIONS:
                 raise ValueError(
-                    f"conflicting dp-stage selectors: zero1=True but "
-                    f"zero={zero} — pass only --zero"
+                    f"precision must be one of {sorted(PRECISIONS)}, got {precision!r}"
                 )
-        self._zero = zero
-        self._zero1 = zero == 1
-        # ZeRO-3 eval view: the {W, b} stacked layout rebuilt from the
-        # at-rest shards for inference programs, cached by identity
-        self._eval_stacked_cache = None
-        if self._zero and self._sequential:
-            if self._zero1:
+            self._precision_name = precision  # the MFU peak is precision-classed
+            if schedule not in S.SCHEDULES:
                 raise ValueError(
-                    "zero1 shards the optimizer update over the dp mesh "
-                    "axis; the sequential path has no mesh — use dp/pp > 1"
+                    f"schedule must be one of {sorted(S.SCHEDULES)}, got {schedule!r}"
                 )
-            raise ValueError(
-                f"zero={zero} shards the update over the dp mesh axis; "
-                "the sequential path has no mesh — use dp/pp > 1"
-            )
-        if self._zero >= 2 and digests:
-            raise ValueError(
-                "digests read the zero1 flat-chunk segment map; the "
-                "block-cyclic shard layout of zero>=2 has no flat chunk — "
-                "use --zero 1 or below with --digests"
-            )
-        if self._zero == 3 and kernel_backend == "pallas":
-            raise ValueError(
-                "zero=3 all-gathers parameter segments inside every tick "
-                "branch; the fused pallas flag kernels take whole resident "
-                "slots — use kernel_backend='xla' with --zero 3"
-            )
-        self._backward_split = bool(backward_split)
-        if self._backward_split:
-            if self._sequential:
+            self.precision = PRECISIONS[precision]
+            if fuse_mubatches and not (
+                dp == 1 and pp == 1 and virtual_stages == 1 and tp == 1
+            ):
                 raise ValueError(
-                    "backward_split is a pipeline-schedule property (B-input "
-                    "at the relay tick, B-weight deferred into bubbles); the "
-                    "sequential path has no schedule — use dp/pp > 1"
+                    "fuse_mubatches applies to the sequential path only; in the "
+                    "pipeline executor microbatches are semantic (they ARE the "
+                    "pipeline's unit of work)"
                 )
-            if virtual_stages > 1:
+            if megakernel and not fuse_mubatches:
                 raise ValueError(
-                    "backward_split is not supported with interleaved "
-                    "virtual stages (the chunked steady state interleaves "
-                    "its own bubbles; splitting its backward is future work)"
+                    "megakernel runs the whole fused batch as one Pallas kernel; "
+                    "it requires fuse_mubatches=True (sequential path)"
                 )
-            if kernel_backend == "pallas":
+            if epoch_kernel and not fuse_mubatches:
                 raise ValueError(
-                    "backward_split needs the XLA per-slot backward; the "
-                    "fused pallas flag kernel has no split halves"
+                    "epoch_kernel runs the whole epoch as one Pallas kernel; "
+                    "it requires fuse_mubatches=True (sequential path)"
                 )
-        # activation recompute (docs/lowering.md "Recompute ticks"): drop
-        # the forward's activation stashes, keep only the stage INPUT, and
-        # re-run the stage forward inside the backward tick (OP_RECOMPUTE)
-        # — a memory-for-FLOPs trade that shortens the stash lifetime from
-        # fwd->bwd to recompute->bwd (arXiv 2004.09910's checkpointing,
-        # tick-table form). Bitwise-identical training: the recompute
-        # re-traces the character-identical forward expressions.
-        self._recompute = bool(recompute)
-        if self._recompute:
-            if self._sequential:
+            if run_kernel and not fuse_mubatches:
                 raise ValueError(
-                    "recompute drops pipeline activation stashes and "
-                    "re-runs the stage forward at the backward tick; the "
-                    "sequential path holds no cross-tick stash — use "
-                    "dp/pp > 1"
+                    "run_kernel runs the whole multi-epoch run as one Pallas "
+                    "kernel; it requires fuse_mubatches=True (sequential path)"
                 )
-            if virtual_stages > 1:
+            if run_kernel and (megakernel or epoch_kernel):
                 raise ValueError(
-                    "recompute is not supported with interleaved virtual "
-                    "stages (the chunked stash rotation is its own "
-                    "lifetime discipline; recomputing it is future work)"
+                    "run_kernel subsumes the mega/epoch kernels; pass only "
+                    "run_kernel=True"
                 )
-            if kernel_backend == "pallas":
+            self._run_kernel = bool(run_kernel)
+            if kernel_backend not in ("xla", "pallas"):
                 raise ValueError(
-                    "recompute re-runs the XLA per-slot forward inside "
-                    "the backward tick; the fused pallas flag kernel has "
-                    "no recompute branch"
+                    f"kernel_backend must be 'xla' or 'pallas', got {kernel_backend!r}"
                 )
-        # pipeline runtime (docs/performance.md "The MPMD runtime"):
-        # "lockstep" is the historical ONE-SPMD-program executor (the
-        # correctness oracle); "mpmd" dispatches one compiled program per
-        # stage role asynchronously from the host with device-to-device
-        # relays (parallel/mpmd.py) — bitwise-identical weights, measured
-        # lower op-issue overhead. The MPMD feature envelope is enforced
-        # here: the knobs whose lockstep implementations live in the fused
-        # program's tail (zero1, the cross-stage clip norm,
-        # the pallas tick backend, the per-step flight aux) stay
-        # lockstep-only until the per-stage update learns their math.
-        if runtime not in ("lockstep", "mpmd"):
-            raise ValueError(
-                f"runtime must be 'lockstep' or 'mpmd', got {runtime!r}"
-            )
-        self.runtime = runtime
-        self._mpmd = None  # the train runner, built with the tick program
-        self._mpmd_infer = None  # the streaming inference runner (lazy)
-        if runtime == "mpmd":
-            if self._sequential:
+            if virtual_stages < 1:
+                raise ValueError("virtual_stages must be >= 1")
+            if virtual_stages > 1 and schedule != "interleaved":
                 raise ValueError(
-                    "runtime='mpmd' dispatches one program per pipeline "
-                    "stage; the sequential path has no stages — use a mesh "
-                    "layout (dp/pp/tp > 1)"
+                    "virtual_stages > 1 requires schedule='interleaved' (the flat "
+                    "schedules place exactly one stage per device)"
                 )
-            if self._zero:
-                raise ValueError(
-                    f"runtime='mpmd' does not support zero (stage "
-                    f"{self._zero}) yet: the ZeRO reduce-scatter/all-gather "
-                    "update spans the whole sharded param layout, not one "
-                    "stage — use runtime='lockstep'"
-                )
-            if clip_norm is not None:
-                raise ValueError(
-                    "runtime='mpmd' does not support clip_norm yet: the "
-                    "global norm spans every stage's gradient, which the "
-                    "per-stage update programs cannot see — use "
-                    "runtime='lockstep'"
-                )
-            if kernel_backend != "xla":
-                raise ValueError(
-                    "runtime='mpmd' uses the XLA per-slot stage functions; "
-                    "kernel_backend='pallas' is lockstep-only"
-                )
-            if record_steps:
-                raise ValueError(
-                    "runtime='mpmd' does not thread the per-step flight aux "
-                    "(loss/grad-norm/param-norm vectors ride the lockstep "
-                    "epoch scan); pass record_steps=False or use "
-                    "runtime='lockstep'"
-                )
-            record_steps = False
-            if digests:
-                raise ValueError(
-                    "runtime='mpmd' does not thread the per-step digest aux "
-                    "(the per-layer checksum grids ride the lockstep epoch "
-                    "scan); pass digests=False or use runtime='lockstep'"
-                )
-
-        self.epoch = 0
-        # step cursor within the current epoch: 0 except after a mid-epoch
-        # resume / between train_steps() chunks. global_step (property) is
-        # the run-lifetime optimizer-step count — the unit the step
-        # checkpoints, fault injections and flight records all share.
-        self.step_in_epoch = 0
-        # fault-tolerance wiring (docs/robustness.md): the step-checkpoint
-        # directory + retention, the fault-injection plan (explicit arg, or
-        # the SHALLOWSPEED_FAULTS env spec), and what resume discovered
-        if checkpoint_keep < 1:
-            raise ValueError("checkpoint_keep must be >= 1")
-        self._ckpt_dir = checkpoint_dir
-        self._ckpt_keep = int(checkpoint_keep)
-        # paths THIS session wrote with all_finite=True: rotation trusts
-        # them without re-reading (their checksums were computed in-process)
-        self._trusted_snapshots = set()
-        self._faults = F.make_plan(faults)
-        # async checkpointing (docs/robustness.md "The async writer"):
-        # save_step_checkpoint(async_=True) — or async_checkpoint=True as
-        # the session default — keeps only the device->host snapshot on
-        # the step path and hands verify/write/fsync/rename/rotate to a
-        # single background writer behind a bounded queue. The writer is
-        # created lazily on the first async save; save_seq is the
-        # @save=N fault anchor, counted over EVERY save this process
-        # attempts (sync, async, halt flush) so a spec replays
-        # deterministically whichever mode is active.
-        if checkpoint_queue < 1:
-            raise ValueError("checkpoint_queue must be >= 1")
-        self._async_ckpt_default = bool(async_checkpoint)
-        self._ckpt_queue = int(checkpoint_queue)
-        self._ckpt_writer = None
-        self._save_seq = 0
-        self.resumed_from = None  # path of the restored snapshot, if any
-        self._recovery = None  # the recovery record's fields, if resume ran
-        # per-epoch aggregation across train_steps() chunks. steps_counted
-        # tracks how many steps THIS process dispatched: after a mid-epoch
-        # resume it is smaller than batches_per_epoch (the head of the
-        # epoch ran in the dead process), and the completing epoch's
-        # loss/throughput are reported over the counted steps only
-        self._epoch_loss_sum = 0.0
-        self._epoch_wall = 0.0
-        self._epoch_steps_counted = 0
-        self._epoch_first_dispatch = False
-
-        data_dir = data_dir or default_data_dir()
-        self._data_dir = data_dir
-        self._train_ds = Dataset(
-            data_dir, self.B, mubatch_size=local_batch // mubatches,
-            tokens=self._token,
-        )
-        self._train_ds.load(0, 1)
-        # validation split is loaded lazily on the first accuracy() call, so
-        # eval-free runs (train.py --no-eval, benchmarks) pay neither the host
-        # load nor the device transfer
-        self._vx = self._vy = None
-        # inference slot geometry (serving/slots.py): predict(), mesh eval
-        # and the serving engine all dispatch whole microbatch SLOTS of
-        # ``slot_rows`` global rows, with per-dispatch slot counts rounded
-        # up a fixed ladder — so the predict cache holds at most
-        # len(ladder) compiled programs (one per rung) instead of one per
-        # distinct row count, and a request slot computes bitwise-
-        # identically in every rung program (docs/serving.md)
-        if predict_slot_rows is None:
-            self._slot_rows = serving_slots.default_slot_rows(dp)
-        else:
-            self._slot_rows = int(predict_slot_rows)
-            if self._slot_rows < 1 or self._slot_rows % dp:
-                raise ValueError(
-                    f"predict_slot_rows must be a positive multiple of dp="
-                    f"{dp}, got {predict_slot_rows}"
-                )
-        self._slot_ladder = serving_slots.validate_ladder(
-            predict_slot_ladder
-            if predict_slot_ladder is not None
-            else serving_slots.DEFAULT_SLOT_LADDER
-        )
-        self._predict_cache = {}  # inference programs, keyed by ladder rung
-        self._run_fns = {}  # fused multi-epoch programs, keyed by with_eval
-        self._compiled_runs = {}  # AOT warm_run executables, keyed by (with_eval, epochs)
-
-        nb = self._train_ds.get_num_batches()
-        if nb == 0:
-            raise ValueError(
-                f"training split has {self._train_ds.raw_len} samples — fewer "
-                f"than one global batch of {self.B}"
-            )
-        # the orientation in which the training set is resident: the
-        # sequential microbatch scan's choice, from the microbatch's shape
-        # against the chip's tile, the first Linear's and the precision
-        # (trainer.data_layout); a mesh places and slices its own batches
-        # (executor.py) and stays row-major.
-        # Provenance like the mesh's layout: a run's record has to say
-        # which of the two epoch programs it timed
-        mubatch_rows = local_batch // mubatches
-        self._scan_path = None
-        self._data_layout = (
-            trainer.data_layout(
-                mubatch_rows, sizes, self.precision,
-                scanned=not (
-                    fuse_mubatches or megakernel or epoch_kernel or run_kernel
-                ),
-            )
-            if self._sequential and not self._token
-            else "row_major"
-        )
-        Xb, Yb = self._train_ds.epoch_arrays()
-        if self._metrics.enabled:
-            self._metrics.event(
-                "data_layout",
-                layout=self._data_layout, mb=mubatch_rows, F=int(Xb.shape[-1]),
-            )
-        if self._token:
-            if seq_len is None or Xb.shape[-1] != seq_len + 1:
-                raise ValueError(
-                    f"the token set's rows hold {Xb.shape[-1]} ids; a token "
-                    f"model needs seq_len (got {seq_len}) and rows of "
-                    f"seq_len + 1"
-                )
-            # what the resident set holds, for the readers of a trace: plain
-            # numbers, handed to observability.scopes with the epoch
-            # program (``_run_epoch_program``)
-            self._token_counts = packed_counts(
-                self._train_ds.target_y[: nb * self.B]
-            )
-        if self.runtime == "mpmd":
-            # the MPMD host scheduler feeds per-microbatch device_puts to
-            # the endpoint stages' sub-meshes itself; the epoch arrays
-            # stay host-side (numpy slices are the step-chunk unit)
-            self._X = Xb.reshape(nb, self.B, Xb.shape[-1])
-            self._Y = Yb.reshape(nb, self.B, Yb.shape[-1])
-        else:
-            with self._metrics.span("device_put"):
-                if self._data_layout != "feature_major":
-                    Xb = Xb.reshape(nb, self.B, Xb.shape[-1])
-                # else placed by microbatch, as the dataset hands it over:
-                # the chip stores THAT shape features-major, so
-                # trainer.feature_major below is one straight copy; from
-                # (nb, B, F) it compiles to two passes and a third
-                # set-sized buffer
-                self._X = jnp.asarray(Xb)
-                self._Y = jnp.asarray(Yb.reshape(nb, self.B, Yb.shape[-1]))
-        self.batches_per_epoch = nb
-
-        n_model_stages = pp * virtual_stages
-        if self._token:
-            self.spec = Mo.make_token_spec(
-                token_config, seq_len, self.B, mubatch_rows=mubatch_rows
-            )
-            # which form of the recurrent layers' scan the epoch program
-            # holds (ops.scan_path or ops.kda_scan_path, from the shapes
-            # alone): provenance like ``data_layout``'s, an event and a
-            # count beside the program's
-            scan_plan = Mo.token_scan_plan(self.spec, mubatches)
-            self._scan_path = scan_plan["path"]
-            self._token_counts["scan_kernel_calls"] = (
-                scan_plan["kernel_calls_per_step"] * nb
-            )
-            if self._metrics.enabled:
-                self._metrics.event("scan_path", **scan_plan)
-            # a model with routed layers: what the experts held here were
-            # routed comes with each epoch's loss (``_run_epoch_program``)
-            # and starts at nothing
-            if self.spec.routed_layers:
-                held = self.spec.experts_held
-                self._token_counts.update(
-                    moe_layers=self.spec.routed_layers,
-                    moe_experts_held=held[1] - held[0],
-                    moe_rows_held=0, moe_load_max=0,
-                )
-            # an id outside the table raises nothing on the device (the
-            # lookup clamps it, the scatter-add drops it)
-            ids = self._train_ds.input_X
-            if ids.min() < 0 or ids.max() >= self.spec.vocab_size:
-                raise ValueError(
-                    f"token ids span {ids.min()}..{ids.max()}; the model "
-                    f"holds a vocabulary of {self.spec.vocab_size}"
-                )
-        else:
-            self.spec = Mo.make_model_spec(sizes, n_model_stages, self.B, act=act)
-        # device-major stage placement for virtual chunks (identity otherwise)
-        self._order = (
-            E.interleave_order(n_model_stages, pp) if virtual_stages > 1 else None
-        )
-        if clip_norm is not None and clip_norm <= 0:
-            raise ValueError("clip_norm must be positive (or None to disable)")
-        opt = self._opt = make_optimizer(optimizer, lr, momentum, weight_decay)
-        if self._token and trainer.token_step_is_scanned(mubatches):
-            # the step loops over its microbatches and keeps its gradient
-            # accumulator in the optimizer's state (a donated argument)
-            opt = self._opt = WithGradScratch(opt)
-        self._opt_config = {
-            "name": optimizer,
-            "lr": lr,
-            "momentum": momentum,
-            "weight_decay": weight_decay,
-        }
-
-        host_opt_state = None  # logical (per-stage ragged) saved state, if any
-        verified = None  # (meta, arrays) of the snapshot discovery verified
-        if resume == "auto":
-            # crash-recovery discovery: newest VERIFYING snapshot in the
-            # checkpoint dir (corrupt/torn/non-finite ones are skipped with
-            # their causes recorded); an empty/missing dir is a fresh start,
-            # a dir with snapshots where NONE verifies is unrecoverable.
-            # with_arrays: discovery's verified read IS the load's read —
-            # one read, one checksum pass, and the discovery->load TOCTOU
-            # window (the snapshot rotting or rotating away between the
-            # verify and a re-read) is closed by construction instead of
-            # by the re-verification `load` used to repeat
-            if self._ckpt_dir is None:
-                raise ValueError(
-                    "resume='auto' discovers snapshots in the step-checkpoint "
-                    "directory — pass checkpoint_dir"
-                )
-            path, vmeta, varrays, skipped = find_latest_good(
-                self._ckpt_dir, with_arrays=True
-            )
-            if path is not None:
-                verified = (vmeta, varrays)
-            skipped_fields = [
-                {"path": str(p), "cause": cause} for p, cause in skipped
-            ]
-            if path is None and skipped:
-                # every candidate failed: corrupt/torn files, or non-finite
-                # blow-up snapshots that discovery skips BY DESIGN — name
-                # each cause so the operator can tell which they have
-                raise CheckpointError(
-                    self._ckpt_dir,
-                    "no snapshot verifies: "
-                    + "; ".join(f"{p.name}: {c}" for p, c in skipped)
-                    + " (non-finite snapshots are skipped by design — "
-                    "delete the directory to start fresh)",
-                )
-            if path is None:
-                resume = None
-                self._recovery = {
-                    "verdict": "fresh_start",
-                    "resumed_from": None,
-                    "skipped": skipped_fields,
+            if scan_unroll < 1 or tick_unroll < 1:
+                raise ValueError("scan_unroll/tick_unroll must be >= 1")
+            self.V = virtual_stages
+            self._sequential = dp == 1 and pp == 1 and virtual_stages == 1 and tp == 1
+            self._kernel_backend = kernel_backend
+            if self._token:
+                wanted = {
+                    "a mesh layout (dp, pp, tp or virtual_stages > 1)": (
+                        not self._sequential
+                    ),
+                    "zero": bool(zero) or zero1,
+                    "fuse_mubatches": fuse_mubatches,
+                    "the pallas kernels (megakernel, epoch_kernel, run_kernel, "
+                    "kernel_backend='pallas')": megakernel or epoch_kernel
+                    or run_kernel or kernel_backend == "pallas",
+                    "runtime='mpmd'": runtime != "lockstep",
+                    "digests": digests,
+                    "checkpoints (resume, checkpoint_dir)": resume is not None
+                    or checkpoint_dir is not None,
                 }
-            else:
-                resume = path
-                self._recovery = {
-                    "verdict": "resumed",
-                    "resumed_from": str(path),
-                    "skipped": skipped_fields,
-                }
-        if resume is not None:
-            if verified is not None:
-                # resume-auto: assemble from the arrays discovery already
-                # read and checksummed — `load` does not touch the file
-                host_params, loaded_spec, meta, host_opt_state = (
-                    assemble_checkpoint(
-                        resume, verified[0], verified[1], n_model_stages,
-                        self.B, with_opt_state=True,
+                refused = [what for what, asked in wanted.items() if asked]
+                if refused:
+                    raise ValueError(
+                        f"token model {self.model_name!r} runs the sequential "
+                        f"one-chip path (dp = pp = tp = 1) only; asked for: "
+                        f"{'; '.join(refused)}. The mesh executor's stage "
+                        "functions, its kernels and the checkpoint format are "
+                        "written for stacks of {W, b} Linears (ROADMAP R0a, D2)"
                     )
-                )
-            else:  # explicit path: one read+verify via the loader
-                host_params, loaded_spec, meta, host_opt_state = (
-                    load_checkpoint(
-                        resume, n_model_stages, self.B, with_opt_state=True
-                    )
-                )
-            self.resumed_from = str(resume)
-            if tuple(loaded_spec.sizes) != tuple(self.spec.sizes):
+            if kernel_backend == "pallas" and act != "relu":
                 raise ValueError(
-                    f"checkpoint sizes {loaded_spec.sizes} do not match the "
-                    f"requested model sizes {self.spec.sizes}"
+                    "kernel_backend='pallas' hard-codes the relu/identity slot "
+                    "expressions; the gelu-family models (f32 grad-multiplier "
+                    "masks, residual adds) run the XLA backend only"
                 )
-            if getattr(loaded_spec, "act", "relu") != self.spec.act:
+            if kernel_backend == "pallas" and tp > 1:
                 raise ValueError(
-                    f"checkpoint activation family "
-                    f"{getattr(loaded_spec, 'act', 'relu')!r} does not match "
-                    f"the requested model's {self.spec.act!r} — the family "
-                    f"is program structure, not a runtime knob"
+                    "tensor parallelism (tp > 1) shards each slot's W across "
+                    "the tp axis; the fused pallas flag kernels compute whole "
+                    "slots — use kernel_backend='xla'"
                 )
-            saved_opt = meta.get("extra", {}).get("optimizer")
-            if saved_opt is not None:
-                # name must match, and for stateful optimizers so must the
-                # coefficient the saved state was accumulated under — a
-                # mismatch would silently reinterpret the velocity. lr is
-                # deliberately free (changing it on resume is a schedule, not
-                # a reinterpretation of saved state).
-                if saved_opt["name"] != optimizer:
-                    raise ValueError(
-                        f"checkpoint was trained with optimizer "
-                        f"{saved_opt['name']!r}; resuming with {optimizer!r} "
-                        f"would silently change the trajectory — pass "
-                        f"optimizer={saved_opt['name']!r} to continue it, or "
-                        f"start a fresh run without resume"
-                    )
-                if optimizer == "momentum" and saved_opt.get("momentum") != momentum:
-                    raise ValueError(
-                        f"checkpoint velocity was accumulated with "
-                        f"momentum={saved_opt.get('momentum')}; resuming with "
-                        f"momentum={momentum} would reinterpret it — pass the "
-                        f"saved coefficient"
-                    )
-                saved_wd = saved_opt.get("weight_decay", 0.0)
-                if saved_wd != weight_decay:
-                    raise ValueError(
-                        f"checkpoint was trained with weight_decay={saved_wd}; "
-                        f"resuming with weight_decay={weight_decay} would "
-                        f"silently change the trajectory — pass the saved value"
-                    )
-            self.spec = loaded_spec
-            if meta.get("step_in_epoch") is not None:
-                # v2 step snapshot: ``epoch`` is the epoch IN PROGRESS and
-                # the cursor restarts mid-epoch. The bit-identity contract
-                # needs the identical deterministic data order, so the
-                # global batch size must match the saved run exactly.
-                if meta["global_batch_size"] != self.B:
-                    raise ValueError(
-                        f"mid-epoch resume needs the saved data order: "
-                        f"checkpoint was taken at global_batch_size="
-                        f"{meta['global_batch_size']}, this run uses {self.B}"
-                    )
-                if not 0 <= meta["step_in_epoch"] < max(nb, 1):
-                    raise ValueError(
-                        f"checkpoint step_in_epoch {meta['step_in_epoch']} "
-                        f"out of range for {nb} batches/epoch — different "
-                        f"dataset?"
-                    )
-                self.epoch = int(meta["epoch"])
-                self.step_in_epoch = int(meta["step_in_epoch"])
+            if kernel_backend == "pallas" and self._sequential:
+                raise ValueError(
+                    "kernel_backend='pallas' selects the pipeline executor's "
+                    "flag-operand kernels and needs a mesh layout (dp/pp > 1 or "
+                    "virtual_stages > 1); on the sequential path use "
+                    "megakernel=True or SHALLOWSPEED_PALLAS=1 instead"
+                )
+            if tick_unroll > 1 and self._sequential:
+                raise ValueError(
+                    "tick_unroll unrolls the pipeline tick loop; the sequential "
+                    "path has no ticks — use scan_unroll"
+                )
+            # the dp-axis ZeRO stage (arXiv 2004.13336): ``zero`` in {0,1,2,3}
+            # supersedes the historical ``zero1`` boolean — ``zero=1`` IS the
+            # zero1 path, verbatim. Stage 2 shards gradients + optimizer state
+            # (block-cyclic per-slot layout, bitwise-equal weights to stage 1
+            # on clip-free runs); stage 3 additionally shards the params at
+            # rest with just-in-time per-tick gathers.
+            if zero is None:
+                zero = 1 if zero1 else 0
             else:
-                # legacy epoch-boundary snapshot: ``epoch`` is the last
-                # COMPLETED epoch
-                self.epoch = meta["epoch"] + 1
-        elif self._token:
-            host_params = Mo.init_token_model(self.spec)
-        else:
-            host_params = Mo.init_model(self.spec)
+                zero = int(zero)
+                if zero not in (0, 1, 2, 3):
+                    raise ValueError(f"zero must be one of 0/1/2/3, got {zero}")
+                if zero1 and zero != 1:
+                    raise ValueError(
+                        f"conflicting dp-stage selectors: zero1=True but "
+                        f"zero={zero} — pass only --zero"
+                    )
+            self._zero = zero
+            self._zero1 = zero == 1
+            # ZeRO-3 eval view: the {W, b} stacked layout rebuilt from the
+            # at-rest shards for inference programs, cached by identity
+            self._eval_stacked_cache = None
+            if self._zero and self._sequential:
+                if self._zero1:
+                    raise ValueError(
+                        "zero1 shards the optimizer update over the dp mesh "
+                        "axis; the sequential path has no mesh — use dp/pp > 1"
+                    )
+                raise ValueError(
+                    f"zero={zero} shards the update over the dp mesh axis; "
+                    "the sequential path has no mesh — use dp/pp > 1"
+                )
+            if self._zero >= 2 and digests:
+                raise ValueError(
+                    "digests read the zero1 flat-chunk segment map; the "
+                    "block-cyclic shard layout of zero>=2 has no flat chunk — "
+                    "use --zero 1 or below with --digests"
+                )
+            if self._zero == 3 and kernel_backend == "pallas":
+                raise ValueError(
+                    "zero=3 all-gathers parameter segments inside every tick "
+                    "branch; the fused pallas flag kernels take whole resident "
+                    "slots — use kernel_backend='xla' with --zero 3"
+                )
+            self._backward_split = bool(backward_split)
+            if self._backward_split:
+                if self._sequential:
+                    raise ValueError(
+                        "backward_split is a pipeline-schedule property (B-input "
+                        "at the relay tick, B-weight deferred into bubbles); the "
+                        "sequential path has no schedule — use dp/pp > 1"
+                    )
+                if virtual_stages > 1:
+                    raise ValueError(
+                        "backward_split is not supported with interleaved "
+                        "virtual stages (the chunked steady state interleaves "
+                        "its own bubbles; splitting its backward is future work)"
+                    )
+                if kernel_backend == "pallas":
+                    raise ValueError(
+                        "backward_split needs the XLA per-slot backward; the "
+                        "fused pallas flag kernel has no split halves"
+                    )
+            # activation recompute (docs/lowering.md "Recompute ticks"): drop
+            # the forward's activation stashes, keep only the stage INPUT, and
+            # re-run the stage forward inside the backward tick (OP_RECOMPUTE)
+            # — a memory-for-FLOPs trade that shortens the stash lifetime from
+            # fwd->bwd to recompute->bwd (arXiv 2004.09910's checkpointing,
+            # tick-table form). Bitwise-identical training: the recompute
+            # re-traces the character-identical forward expressions.
+            self._recompute = bool(recompute)
+            if self._recompute:
+                if self._sequential:
+                    raise ValueError(
+                        "recompute drops pipeline activation stashes and "
+                        "re-runs the stage forward at the backward tick; the "
+                        "sequential path holds no cross-tick stash — use "
+                        "dp/pp > 1"
+                    )
+                if virtual_stages > 1:
+                    raise ValueError(
+                        "recompute is not supported with interleaved virtual "
+                        "stages (the chunked stash rotation is its own "
+                        "lifetime discipline; recomputing it is future work)"
+                    )
+                if kernel_backend == "pallas":
+                    raise ValueError(
+                        "recompute re-runs the XLA per-slot forward inside "
+                        "the backward tick; the fused pallas flag kernel has "
+                        "no recompute branch"
+                    )
+            # pipeline runtime (docs/performance.md "The MPMD runtime"):
+            # "lockstep" is the historical ONE-SPMD-program executor (the
+            # correctness oracle); "mpmd" dispatches one compiled program per
+            # stage role asynchronously from the host with device-to-device
+            # relays (parallel/mpmd.py) — bitwise-identical weights, measured
+            # lower op-issue overhead. The MPMD feature envelope is enforced
+            # here: the knobs whose lockstep implementations live in the fused
+            # program's tail (zero1, the cross-stage clip norm,
+            # the pallas tick backend, the per-step flight aux) stay
+            # lockstep-only until the per-stage update learns their math.
+            if runtime not in ("lockstep", "mpmd"):
+                raise ValueError(
+                    f"runtime must be 'lockstep' or 'mpmd', got {runtime!r}"
+                )
+            self.runtime = runtime
+            self._mpmd = None  # the train runner, built with the tick program
+            self._mpmd_infer = None  # the streaming inference runner (lazy)
+            if runtime == "mpmd":
+                if self._sequential:
+                    raise ValueError(
+                        "runtime='mpmd' dispatches one program per pipeline "
+                        "stage; the sequential path has no stages — use a mesh "
+                        "layout (dp/pp/tp > 1)"
+                    )
+                if self._zero:
+                    raise ValueError(
+                        f"runtime='mpmd' does not support zero (stage "
+                        f"{self._zero}) yet: the ZeRO reduce-scatter/all-gather "
+                        "update spans the whole sharded param layout, not one "
+                        "stage — use runtime='lockstep'"
+                    )
+                if clip_norm is not None:
+                    raise ValueError(
+                        "runtime='mpmd' does not support clip_norm yet: the "
+                        "global norm spans every stage's gradient, which the "
+                        "per-stage update programs cannot see — use "
+                        "runtime='lockstep'"
+                    )
+                if kernel_backend != "xla":
+                    raise ValueError(
+                        "runtime='mpmd' uses the XLA per-slot stage functions; "
+                        "kernel_backend='pallas' is lockstep-only"
+                    )
+                if record_steps:
+                    raise ValueError(
+                        "runtime='mpmd' does not thread the per-step flight aux "
+                        "(loss/grad-norm/param-norm vectors ride the lockstep "
+                        "epoch scan); pass record_steps=False or use "
+                        "runtime='lockstep'"
+                    )
+                record_steps = False
+                if digests:
+                    raise ValueError(
+                        "runtime='mpmd' does not thread the per-step digest aux "
+                        "(the per-layer checksum grids ride the lockstep epoch "
+                        "scan); pass digests=False or use runtime='lockstep'"
+                    )
 
-        # telemetry aux: when recording AND clipping, the epoch/run programs
-        # also return the pre-clip global gradient norm (ordinary fused
-        # outputs — never host callbacks inside the scan). The kernel paths
-        # keep gradients in VMEM, so the aux is unavailable there; both
-        # layouts' fused runs thread it (trainer.make_train_run and
-        # executor.make_pipeline_run).
-        kernel_path = megakernel or epoch_kernel or run_kernel
-        aux_gnorm = self._metrics.enabled and clip_norm is not None and not kernel_path
-        self._epoch_aux = aux_gnorm
-        self._run_aux = aux_gnorm
-        # flight-recorder aux: per-step (per-batch) loss / pre-clip grad
-        # norm / post-update param norm vectors out of the SAME fused epoch
-        # program. ``record_steps=None`` (default) auto-enables whenever
-        # anything will consume them (a metrics recorder or a health
-        # monitor); ``False`` opts a metrics session back out (epoch-level
-        # telemetry only — the PR1 cost profile: no per-step param-norm in
-        # the program, no per-step JSONL lines; health falls back to
-        # epoch-granular checks); ``True`` forces the flight ring on even
-        # without a recorder. The NullMetrics default without a monitor
-        # keeps the uninstrumented program, so recording disabled stays
-        # zero-overhead on the hot path.
-        if record_steps is None:
-            record_steps = self._metrics.enabled or self._health is not None
-        elif record_steps and kernel_path:
-            raise ValueError(
-                "record_steps is unavailable on the kernel paths: the "
-                "gradient never leaves the Pallas kernel's VMEM"
-            )
-        # numerics-provenance aux (docs/numerics.md "Divergence
-        # debugging"): per-step per-layer digest grids (uint32 bitcast
-        # checksums + block norms) out of the SAME fused epoch program,
-        # emitted as schema-v12 ``digest`` records. Opt-in only — the
-        # default keeps today's programs byte-identical.
-        if digests and kernel_path:
-            raise ValueError(
-                "digests is unavailable on the kernel paths: params/grads "
-                "never leave the Pallas kernel's VMEM, so the per-layer "
-                "digest aux cannot be threaded out"
-            )
-        self._digests = bool(digests)
-        if self._digests and self._metrics.enabled:
-            # replay provenance for the bisect CLI (observability/
-            # divergence.py --bisect): everything needed to reconstruct a
-            # numerically identical session and re-arm its injections —
-            # ``die`` faults are stripped at replay time, step faults
-            # (nan/flip) must fire again or the divergence won't reproduce
-            self._metrics.event(
-                "digest_config",
-                sizes=list(sizes), model=model, dp=dp, pp=pp, tp=self.tp,
-                schedule=schedule, global_batch_size=global_batch_size,
-                mubatches=mubatches, lr=lr, precision=precision,
-                optimizer=optimizer, momentum=momentum,
-                virtual_stages=virtual_stages, zero1=zero1,
-                zero=self._zero,
-                backward_split=backward_split, recompute=recompute,
-                scan_unroll=scan_unroll,
-                tick_unroll=tick_unroll, weight_decay=weight_decay,
-                clip_norm=clip_norm, fuse_mubatches=fuse_mubatches,
-                data_dir=None if data_dir is None else str(data_dir),
-                faults=",".join(repr(f) for f in self._faults.faults),
-            )
-        self._step_aux = bool(record_steps) and not kernel_path
-        self.flight = FlightRecorder() if self._step_aux else None
-        if self.flight is not None:
-            # the metrics cursor: resumed step records continue the global
-            # numbering instead of restarting at 0
-            self.flight.total_steps = self.global_step
-        self._epoch_compiled = False  # compile-span already recorded?
-        self._epoch_dispatched = False  # first train_epoch includes compile
-        self._registered_shape = None  # Y of the program scopes.py knows
-        self._cost_recorded = False  # cost_model event already emitted?
-        self._cost_xla_recorded = False  # ... with the XLA cross-check leg?
+            self.epoch = 0
+            # step cursor within the current epoch: 0 except after a mid-epoch
+            # resume / between train_steps() chunks. global_step (property) is
+            # the run-lifetime optimizer-step count — the unit the step
+            # checkpoints, fault injections and flight records all share.
+            self.step_in_epoch = 0
+            # fault-tolerance wiring (docs/robustness.md): the step-checkpoint
+            # directory + retention, the fault-injection plan (explicit arg, or
+            # the SHALLOWSPEED_FAULTS env spec), and what resume discovered
+            if checkpoint_keep < 1:
+                raise ValueError("checkpoint_keep must be >= 1")
+            self._ckpt_dir = checkpoint_dir
+            self._ckpt_keep = int(checkpoint_keep)
+            # paths THIS session wrote with all_finite=True: rotation trusts
+            # them without re-reading (their checksums were computed in-process)
+            self._trusted_snapshots = set()
+            self._faults = F.make_plan(faults)
+            # async checkpointing (docs/robustness.md "The async writer"):
+            # save_step_checkpoint(async_=True) — or async_checkpoint=True as
+            # the session default — keeps only the device->host snapshot on
+            # the step path and hands verify/write/fsync/rename/rotate to a
+            # single background writer behind a bounded queue. The writer is
+            # created lazily on the first async save; save_seq is the
+            # @save=N fault anchor, counted over EVERY save this process
+            # attempts (sync, async, halt flush) so a spec replays
+            # deterministically whichever mode is active.
+            if checkpoint_queue < 1:
+                raise ValueError("checkpoint_queue must be >= 1")
+            self._async_ckpt_default = bool(async_checkpoint)
+            self._ckpt_queue = int(checkpoint_queue)
+            self._ckpt_writer = None
+            self._save_seq = 0
+            self.resumed_from = None  # path of the restored snapshot, if any
+            self._recovery = None  # the recovery record's fields, if resume ran
+            # per-epoch aggregation across train_steps() chunks. steps_counted
+            # tracks how many steps THIS process dispatched: after a mid-epoch
+            # resume it is smaller than batches_per_epoch (the head of the
+            # epoch ran in the dead process), and the completing epoch's
+            # loss/throughput are reported over the counted steps only
+            self._epoch_loss_sum = 0.0
+            self._epoch_wall = 0.0
+            self._epoch_steps_counted = 0
+            self._epoch_first_dispatch = False
 
-        if self._sequential:
-            with self._metrics.span("device_put"):
-                self._params = jax.tree.map(jnp.asarray, host_params)
-            if host_opt_state is not None and not is_stateless(opt):
-                self._opt_state = join_state(
-                    opt,
-                    {
-                        k: jax.tree.map(jnp.asarray, v)
-                        for k, v in host_opt_state["parts"].items()
-                    },
-                    {
-                        k: jnp.asarray(v, jnp.float32)
-                        for k, v in host_opt_state["scalars"].items()
-                    },
+            with self._metrics.span("session/data"):
+                data_dir = data_dir or default_data_dir()
+                self._data_dir = data_dir
+                self._train_ds = Dataset(
+                    data_dir, self.B, mubatch_size=local_batch // mubatches,
+                    tokens=self._token,
                 )
+                self._train_ds.load(0, 1)
+            # validation split is loaded lazily on the first accuracy() call, so
+            # eval-free runs (train.py --no-eval, benchmarks) pay neither the host
+            # load nor the device transfer
+            self._vx = self._vy = None
+            # inference slot geometry (serving/slots.py): predict(), mesh eval
+            # and the serving engine all dispatch whole microbatch SLOTS of
+            # ``slot_rows`` global rows, with per-dispatch slot counts rounded
+            # up a fixed ladder — so the predict cache holds at most
+            # len(ladder) compiled programs (one per rung) instead of one per
+            # distinct row count, and a request slot computes bitwise-
+            # identically in every rung program (docs/serving.md)
+            if predict_slot_rows is None:
+                self._slot_rows = serving_slots.default_slot_rows(dp)
             else:
-                self._opt_state = opt.init(self._params)
-            self._epoch_fn = trainer.make_train_epoch(
-                self.spec, opt, precision=self.precision,
-                fuse_mubatches=fuse_mubatches, unroll=scan_unroll,
-                clip_norm=clip_norm, megakernel=megakernel,
-                epoch_kernel=epoch_kernel or run_kernel,
-                with_grad_norm=self._epoch_aux,
-                with_step_stats=self._step_aux,
-                with_digests=self._digests,
-                x_layout=self._data_layout,
+                self._slot_rows = int(predict_slot_rows)
+                if self._slot_rows < 1 or self._slot_rows % dp:
+                    raise ValueError(
+                        f"predict_slot_rows must be a positive multiple of dp="
+                        f"{dp}, got {predict_slot_rows}"
+                    )
+            self._slot_ladder = serving_slots.validate_ladder(
+                predict_slot_ladder
+                if predict_slot_ladder is not None
+                else serving_slots.DEFAULT_SLOT_LADDER
             )
-            self._predict = (
-                None if self._token
-                else trainer.make_predict(self.spec, precision=self.precision)
-            )
-            self._run_kwargs = dict(
-                precision=self.precision, fuse_mubatches=fuse_mubatches,
-                unroll=scan_unroll, clip_norm=clip_norm, megakernel=megakernel,
-                epoch_kernel=epoch_kernel or run_kernel,
-                x_layout=self._data_layout,
-            )
-            # one device program either way, the set in and the set out:
-            # the peak of two sets is this transient (PERF.md §4)
-            if self._data_layout == "feature_major":
-                self._Xe = trainer.feature_major(self._X)
-            else:
-                self._Xe = self._X.reshape(nb, self.M, self.B // self.M, -1)
-            self._Ye = self._Y.reshape(nb, self.M, self.B // self.M, -1)
-            self._X = self._Y = None  # the microbatched views are the only users
-        else:
-            self.mesh, self._mesh_layout = make_mesh_with_layout(
-                dp, pp, devices, tp
-            )
-            if self._metrics.enabled:
-                # placement provenance (topology-aware vs order-preserving):
-                # a bench record measured on one placement must say so —
-                # the two differ materially on a real slice
-                self._metrics.event(
-                    "mesh_layout",
-                    dp=dp, pp=pp, tp=self.tp, layout=self._mesh_layout,
-                    n_devices=dp * pp * self.tp,
-                )
-            with self._metrics.span("schedule_lower"):
-                prog = lower_schedule(
-                    S.SCHEDULES[schedule], mubatches, pp, virtual=self.V,
-                    backward_split=self._backward_split,
-                    recompute=self._recompute,
-                )
-            if self._metrics.enabled or self._audit_strict:
-                # program-level static analysis at lowering time, BEFORE
-                # anything compiles or dispatches: send/recv match, MPMD
-                # deadlock-freedom, stash lifetimes (analysis/;
-                # docs/static-analysis.md) — the machine-checked form of
-                # the invariants the lowering simulator constructs by
-                # simulation (the simulator is the spec, this is the proof)
-                self._record_static_analysis(prog, "epoch_program")
-            if self._metrics.enabled:
-                # per-tick program stats, recorded once at lowering time:
-                # the executor's runtime tick behaviour is fully determined
-                # by these static tables (ticks, sends, occupancy, bubble)
-                stats = program_stats(
-                    prog, spec=self.spec,
-                    mubatch_size=local_batch // mubatches, tp=self.tp,
-                )
-                if self._recompute:
-                    # the stashed twin's footprint, lowered alongside (pure
-                    # Python, no compile): the report CLI's Memory section
-                    # renders the two peaks side by side from ONE stream —
-                    # the saving is an artifact of both real tick tables,
-                    # not a formula
-                    twin = program_stats(
-                        lower_schedule(
-                            S.SCHEDULES[schedule], mubatches, pp,
-                            virtual=self.V,
-                            backward_split=self._backward_split,
-                            recompute=False,
+            self._predict_cache = {}  # inference programs, keyed by ladder rung
+            self._run_fns = {}  # fused multi-epoch programs, keyed by with_eval
+            # AOT warm_run executables, keyed by (with_eval, epochs)
+            self._compiled_runs = {}
+
+            with self._metrics.span("session/data"):
+                nb = self._train_ds.get_num_batches()
+                if nb == 0:
+                    raise ValueError(
+                        f"training split has {self._train_ds.raw_len} samples — "
+                        f"fewer than one global batch of {self.B}"
+                    )
+                # the orientation in which the training set is resident: the
+                # sequential microbatch scan's choice, from the microbatch's shape
+                # against the chip's tile, the first Linear's and the precision
+                # (trainer.data_layout); a mesh places and slices its own batches
+                # (executor.py) and stays row-major.
+                # Provenance like the mesh's layout: a run's record has to say
+                # which of the two epoch programs it timed
+                mubatch_rows = local_batch // mubatches
+                self._scan_path = None
+                self._data_layout = (
+                    trainer.data_layout(
+                        mubatch_rows, sizes, self.precision,
+                        scanned=not (
+                            fuse_mubatches or megakernel or epoch_kernel or run_kernel
                         ),
-                        spec=self.spec,
-                        mubatch_size=local_batch // mubatches, tp=self.tp,
                     )
-                    stats["stash_bytes_peak_stashed_twin"] = twin[
-                        "stash_bytes_peak"
-                    ]
-                    stats["stash_slots_stashed_twin"] = twin["stash_slots"]
-                self._metrics.event(
-                    "pipeline_program",
-                    schedule=schedule, dp=dp, pp=pp, tp=self.tp,
-                    virtual=self.V, model=self.model_name, **stats,
+                    if self._sequential and not self._token
+                    else "row_major"
                 )
-                self._metrics.gauge(
-                    "pipeline.bubble_fraction", stats["bubble_fraction"]
-                )
-            with self._metrics.span("device_put"):
-                stacked_np, flags_np = E.stack_params(
-                    host_params, self.spec, order=self._order, tp=self.tp
-                )
-                if self._zero == 3:
-                    # ZeRO-3 params at rest: one (pp*tp, dp*csz3)
-                    # block-cyclic array, each device holding only its own
-                    # 1/dp shard — the {W,b} stacked layout never lands on
-                    # device (predict/save rebuild it on demand)
-                    self._stacked = {
-                        "P": jax.device_put(
-                            E.zero_block_flatten_rows(
-                                stacked_np, self.spec, self.mesh
-                            ),
-                            E.zero1_part_sharding(self.mesh),
+                Xb, Yb = self._train_ds.epoch_arrays()
+                if self._metrics.enabled:
+                    self._metrics.event(
+                        "data_layout",
+                        layout=self._data_layout, mb=mubatch_rows, F=int(Xb.shape[-1]),
+                    )
+                if self._token:
+                    if seq_len is None or Xb.shape[-1] != seq_len + 1:
+                        raise ValueError(
+                            f"the token set's rows hold {Xb.shape[-1]} ids; a token "
+                            f"model needs seq_len (got {seq_len}) and rows of "
+                            f"seq_len + 1"
                         )
-                    }
-                    self._flags = E.put_pp(flags_np, self.mesh)
+                    # what the resident set holds, for the readers of a trace: plain
+                    # numbers, handed to observability.scopes with the epoch
+                    # program (``_run_epoch_program``)
+                    self._token_counts = packed_counts(
+                        self._train_ds.target_y[: nb * self.B]
+                    )
+                if self.runtime == "mpmd":
+                    # the MPMD host scheduler feeds per-microbatch device_puts to
+                    # the endpoint stages' sub-meshes itself; the epoch arrays
+                    # stay host-side (numpy slices are the step-chunk unit)
+                    self._X = Xb.reshape(nb, self.B, Xb.shape[-1])
+                    self._Y = Yb.reshape(nb, self.B, Yb.shape[-1])
                 else:
-                    self._stacked, self._flags = E.put_stacked(
-                        stacked_np, flags_np, self.mesh
+                    with self._metrics.span("device_put"):
+                        if self._data_layout != "feature_major":
+                            Xb = Xb.reshape(nb, self.B, Xb.shape[-1])
+                        # else placed by microbatch, as the dataset hands it over:
+                        # the chip stores THAT shape features-major, so
+                        # trainer.feature_major below is one straight copy; from
+                        # (nb, B, F) it compiles to two passes and a third
+                        # set-sized buffer
+                        self._X = jnp.asarray(Xb)
+                        self._Y = jnp.asarray(Yb.reshape(nb, self.B, Yb.shape[-1]))
+                self.batches_per_epoch = nb
+
+            n_model_stages = pp * virtual_stages
+            if self._token:
+                self.spec = Mo.make_token_spec(
+                    token_config, seq_len, self.B, mubatch_rows=mubatch_rows
+                )
+                # which form of the recurrent layers' scan the epoch program
+                # holds (ops.scan_path or ops.kda_scan_path, from the shapes
+                # alone): provenance like ``data_layout``'s, an event and a
+                # count beside the program's
+                scan_plan = Mo.token_scan_plan(self.spec, mubatches)
+                self._scan_path = scan_plan["path"]
+                self._token_counts["scan_kernel_calls"] = (
+                    scan_plan["kernel_calls_per_step"] * nb
+                )
+                if self._metrics.enabled:
+                    self._metrics.event("scan_path", **scan_plan)
+                # a model with routed layers: what the experts held here were
+                # routed comes with each epoch's loss (``_run_epoch_program``)
+                # and starts at nothing
+                if self.spec.routed_layers:
+                    held = self.spec.experts_held
+                    self._token_counts.update(
+                        moe_layers=self.spec.routed_layers,
+                        moe_experts_held=held[1] - held[0],
+                        moe_rows_held=0, moe_load_max=0,
                     )
-            if self._zero >= 2:
-                self._opt_state = E.zero_block_state_from_logical(
-                    host_opt_state, opt, self.spec, self.mesh, order=self._order
-                )
-            elif self._zero1:
-                self._opt_state = E.zero1_state_from_logical(
-                    host_opt_state, opt, self.spec, self.mesh, order=self._order
-                )
-            elif host_opt_state is not None and not is_stateless(opt):
-                # stack + place each state part exactly like the params it
-                # mirrors (zero padding is consistent: padded grads are
-                # exactly zero, so padded state stays zero); scalars replicate
-                rep = NamedSharding(self.mesh, PartitionSpec())
-                self._opt_state = join_state(
-                    opt,
-                    {
-                        k: E.put_stacked_tree(
-                            E.stack_params(
-                                v, self.spec, order=self._order, tp=self.tp
-                            )[0],
-                            self.mesh,
+                # an id outside the table raises nothing on the device (the
+                # lookup clamps it, the scatter-add drops it)
+                ids = self._train_ds.input_X
+                if ids.min() < 0 or ids.max() >= self.spec.vocab_size:
+                    raise ValueError(
+                        f"token ids span {ids.min()}..{ids.max()}; the model "
+                        f"holds a vocabulary of {self.spec.vocab_size}"
+                    )
+            else:
+                self.spec = Mo.make_model_spec(sizes, n_model_stages, self.B, act=act)
+            # device-major stage placement for virtual chunks (identity otherwise)
+            self._order = (
+                E.interleave_order(n_model_stages, pp) if virtual_stages > 1 else None
+            )
+            if clip_norm is not None and clip_norm <= 0:
+                raise ValueError("clip_norm must be positive (or None to disable)")
+            opt = self._opt = make_optimizer(optimizer, lr, momentum, weight_decay)
+            if self._token and trainer.token_step_is_scanned(mubatches):
+                # the step loops over its microbatches and keeps its gradient
+                # accumulator in the optimizer's state (a donated argument)
+                opt = self._opt = WithGradScratch(opt)
+            self._opt_config = {
+                "name": optimizer,
+                "lr": lr,
+                "momentum": momentum,
+                "weight_decay": weight_decay,
+            }
+
+            host_opt_state = None  # logical (per-stage ragged) saved state, if any
+            verified = None  # (meta, arrays) of the snapshot discovery verified
+            with self._metrics.span("session/resume"):
+                if resume == "auto":
+                    # crash-recovery discovery: newest VERIFYING snapshot in the
+                    # checkpoint dir (corrupt/torn/non-finite ones are skipped with
+                    # their causes recorded); an empty/missing dir is a fresh start,
+                    # a dir with snapshots where NONE verifies is unrecoverable.
+                    # with_arrays: discovery's verified read IS the load's read —
+                    # one read, one checksum pass, and the discovery->load TOCTOU
+                    # window (the snapshot rotting or rotating away between the
+                    # verify and a re-read) is closed by construction instead of
+                    # by the re-verification `load` used to repeat
+                    if self._ckpt_dir is None:
+                        raise ValueError(
+                            "resume='auto' discovers snapshots in the step-checkpoint "
+                            "directory — pass checkpoint_dir"
                         )
-                        for k, v in host_opt_state["parts"].items()
-                    },
-                    {
-                        k: jax.device_put(np.float32(v), rep)
-                        for k, v in host_opt_state["scalars"].items()
-                    },
-                )
-            else:
-                self._opt_state = opt.init(self._stacked)
-            if self.runtime == "mpmd":
-                from shallowspeed_tpu.observability.tracing import Tracer
-                from shallowspeed_tpu.parallel import mpmd
-
-                # the MPMD runner's constructor IS the admission gate:
-                # analyze_program must prove the tick tables deadlock-free
-                # before any stage program can be built or dispatched
-                self._mpmd = mpmd.MpmdTrainRunner(
-                    self.mesh, self.spec, prog, local_batch // mubatches,
-                    opt, precision=self.precision,
-                    tracer=Tracer(self._metrics, process="m"),
-                )
-
-                def _mpmd_epoch(stacked, flags, opt_state, X, Y):
-                    return self._mpmd.run(
-                        stacked, flags, opt_state, X, Y,
-                        trace_id=f"mpmd-{self.global_step}",
+                    path, vmeta, varrays, skipped = find_latest_good(
+                        self._ckpt_dir, with_arrays=True
+                    )
+                    if path is not None:
+                        verified = (vmeta, varrays)
+                    skipped_fields = [
+                        {"path": str(p), "cause": cause} for p, cause in skipped
+                    ]
+                    if path is None and skipped:
+                        # every candidate failed: corrupt/torn files, or non-finite
+                        # blow-up snapshots that discovery skips BY DESIGN — name
+                        # each cause so the operator can tell which they have
+                        raise CheckpointError(
+                            self._ckpt_dir,
+                            "no snapshot verifies: "
+                            + "; ".join(f"{p.name}: {c}" for p, c in skipped)
+                            + " (non-finite snapshots are skipped by design — "
+                            "delete the directory to start fresh)",
+                        )
+                    if path is None:
+                        resume = None
+                        self._recovery = {
+                            "verdict": "fresh_start",
+                            "resumed_from": None,
+                            "skipped": skipped_fields,
+                        }
+                    else:
+                        resume = path
+                        self._recovery = {
+                            "verdict": "resumed",
+                            "resumed_from": str(path),
+                            "skipped": skipped_fields,
+                        }
+                if resume is not None:
+                    if verified is not None:
+                        # resume-auto: assemble from the arrays discovery already
+                        # read and checksummed — `load` does not touch the file
+                        host_params, loaded_spec, meta, host_opt_state = (
+                            assemble_checkpoint(
+                                resume, verified[0], verified[1], n_model_stages,
+                                self.B, with_opt_state=True,
+                            )
+                        )
+                    else:  # explicit path: one read+verify via the loader
+                        host_params, loaded_spec, meta, host_opt_state = (
+                            load_checkpoint(
+                                resume, n_model_stages, self.B, with_opt_state=True
+                            )
+                        )
+                    self.resumed_from = str(resume)
+                    if tuple(loaded_spec.sizes) != tuple(self.spec.sizes):
+                        raise ValueError(
+                            f"checkpoint sizes {loaded_spec.sizes} do not match the "
+                            f"requested model sizes {self.spec.sizes}"
+                        )
+                    if getattr(loaded_spec, "act", "relu") != self.spec.act:
+                        raise ValueError(
+                            f"checkpoint activation family "
+                            f"{getattr(loaded_spec, 'act', 'relu')!r} does not match "
+                            f"the requested model's {self.spec.act!r} — the family "
+                            f"is program structure, not a runtime knob"
+                        )
+                    saved_opt = meta.get("extra", {}).get("optimizer")
+                    if saved_opt is not None:
+                        # name must match, and for stateful optimizers so must the
+                        # coefficient the saved state was accumulated under — a
+                        # mismatch would silently reinterpret the velocity. lr is
+                        # deliberately free (changing it on resume is a schedule, not
+                        # a reinterpretation of saved state).
+                        if saved_opt["name"] != optimizer:
+                            raise ValueError(
+                                f"checkpoint was trained with optimizer "
+                                f"{saved_opt['name']!r}; resuming with {optimizer!r} "
+                                f"would silently change the trajectory — pass "
+                                f"optimizer={saved_opt['name']!r} to continue it, or "
+                                f"start a fresh run without resume"
+                            )
+                        if (
+                            optimizer == "momentum"
+                            and saved_opt.get("momentum") != momentum
+                        ):
+                            raise ValueError(
+                                f"checkpoint velocity was accumulated with "
+                                f"momentum={saved_opt.get('momentum')}; resuming with "
+                                f"momentum={momentum} would reinterpret it — pass "
+                                f"the saved coefficient"
+                            )
+                        saved_wd = saved_opt.get("weight_decay", 0.0)
+                        if saved_wd != weight_decay:
+                            raise ValueError(
+                                f"checkpoint was trained with weight_decay={saved_wd}; "
+                                f"resuming with weight_decay={weight_decay} would "
+                                f"silently change the trajectory — pass the saved "
+                                f"value"
+                            )
+                    self.spec = loaded_spec
+                    if meta.get("step_in_epoch") is not None:
+                        # v2 step snapshot: ``epoch`` is the epoch IN PROGRESS and
+                        # the cursor restarts mid-epoch. The bit-identity contract
+                        # needs the identical deterministic data order, so the
+                        # global batch size must match the saved run exactly.
+                        if meta["global_batch_size"] != self.B:
+                            raise ValueError(
+                                f"mid-epoch resume needs the saved data order: "
+                                f"checkpoint was taken at global_batch_size="
+                                f"{meta['global_batch_size']}, this run uses {self.B}"
+                            )
+                        if not 0 <= meta["step_in_epoch"] < max(nb, 1):
+                            raise ValueError(
+                                f"checkpoint step_in_epoch {meta['step_in_epoch']} "
+                                f"out of range for {nb} batches/epoch — different "
+                                f"dataset?"
+                            )
+                        self.epoch = int(meta["epoch"])
+                        self.step_in_epoch = int(meta["step_in_epoch"])
+                    else:
+                        # legacy epoch-boundary snapshot: ``epoch`` is the last
+                        # COMPLETED epoch
+                        self.epoch = meta["epoch"] + 1
+            if resume is None:
+                with self._metrics.span("session/weights"):
+                    host_params = (
+                        Mo.init_token_model(self.spec)
+                        if self._token
+                        else Mo.init_model(self.spec)
                     )
 
-                self._epoch_fn = _mpmd_epoch
-            else:
-                self._epoch_fn = E.make_pipeline_epoch(
-                    self.mesh, self.spec, prog, local_batch // mubatches, opt,
-                    precision=self.precision, zero=self._zero,
-                    unroll=scan_unroll, tick_unroll=tick_unroll,
-                    clip_norm=clip_norm, kernel_backend=kernel_backend,
-                    with_grad_norm=self._epoch_aux,
-                    with_step_stats=self._step_aux,
-                    with_digests=self._digests,
-                )
-            self._prog = prog
-            self._mubatch_local = local_batch // mubatches
-            self._run_kwargs = dict(
-                precision=self.precision, unroll=scan_unroll,
-                tick_unroll=tick_unroll, zero=self._zero,
-                clip_norm=clip_norm, kernel_backend=kernel_backend,
+            # telemetry aux: when recording AND clipping, the epoch/run programs
+            # also return the pre-clip global gradient norm (ordinary fused
+            # outputs — never host callbacks inside the scan). The kernel paths
+            # keep gradients in VMEM, so the aux is unavailable there; both
+            # layouts' fused runs thread it (trainer.make_train_run and
+            # executor.make_pipeline_run).
+            kernel_path = megakernel or epoch_kernel or run_kernel
+            aux_gnorm = (
+                self._metrics.enabled and clip_norm is not None and not kernel_path
             )
+            self._epoch_aux = aux_gnorm
+            self._run_aux = aux_gnorm
+            # flight-recorder aux: per-step (per-batch) loss / pre-clip grad
+            # norm / post-update param norm vectors out of the SAME fused epoch
+            # program. ``record_steps=None`` (default) auto-enables whenever
+            # anything will consume them (a metrics recorder or a health
+            # monitor); ``False`` opts a metrics session back out (epoch-level
+            # telemetry only — the PR1 cost profile: no per-step param-norm in
+            # the program, no per-step JSONL lines; health falls back to
+            # epoch-granular checks); ``True`` forces the flight ring on even
+            # without a recorder. The NullMetrics default without a monitor
+            # keeps the uninstrumented program, so recording disabled stays
+            # zero-overhead on the hot path.
+            if record_steps is None:
+                record_steps = self._metrics.enabled or self._health is not None
+            elif record_steps and kernel_path:
+                raise ValueError(
+                    "record_steps is unavailable on the kernel paths: the "
+                    "gradient never leaves the Pallas kernel's VMEM"
+                )
+            # numerics-provenance aux (docs/numerics.md "Divergence
+            # debugging"): per-step per-layer digest grids (uint32 bitcast
+            # checksums + block norms) out of the SAME fused epoch program,
+            # emitted as schema-v12 ``digest`` records. Opt-in only — the
+            # default keeps today's programs byte-identical.
+            if digests and kernel_path:
+                raise ValueError(
+                    "digests is unavailable on the kernel paths: params/grads "
+                    "never leave the Pallas kernel's VMEM, so the per-layer "
+                    "digest aux cannot be threaded out"
+                )
+            self._digests = bool(digests)
+            if self._digests and self._metrics.enabled:
+                # replay provenance for the bisect CLI (observability/
+                # divergence.py --bisect): everything needed to reconstruct a
+                # numerically identical session and re-arm its injections —
+                # ``die`` faults are stripped at replay time, step faults
+                # (nan/flip) must fire again or the divergence won't reproduce
+                self._metrics.event(
+                    "digest_config",
+                    sizes=list(sizes), model=model, dp=dp, pp=pp, tp=self.tp,
+                    schedule=schedule, global_batch_size=global_batch_size,
+                    mubatches=mubatches, lr=lr, precision=precision,
+                    optimizer=optimizer, momentum=momentum,
+                    virtual_stages=virtual_stages, zero1=zero1,
+                    zero=self._zero,
+                    backward_split=backward_split, recompute=recompute,
+                    scan_unroll=scan_unroll,
+                    tick_unroll=tick_unroll, weight_decay=weight_decay,
+                    clip_norm=clip_norm, fuse_mubatches=fuse_mubatches,
+                    data_dir=None if data_dir is None else str(data_dir),
+                    faults=",".join(repr(f) for f in self._faults.faults),
+                )
+            self._step_aux = bool(record_steps) and not kernel_path
+            self.flight = FlightRecorder() if self._step_aux else None
+            if self.flight is not None:
+                # the metrics cursor: resumed step records continue the global
+                # numbering instead of restarting at 0
+                self.flight.total_steps = self.global_step
+            self._epoch_compiled = False  # compile-span already recorded?
+            self._epoch_dispatched = False  # first train_epoch includes compile
+            self._registered_shape = None  # Y of the program scopes.py knows
+            self._cost_recorded = False  # cost_model event already emitted?
+            self._cost_xla_recorded = False  # ... with the XLA cross-check leg?
 
-        # analytical cost model + MFU accounting (observability/costmodel):
-        # the model-FLOP numerator is known at construction; the XLA
-        # cost_analysis cross-check attaches at jit time
-        # (_ensure_epoch_compiled / warm_run). On mesh layouts the padded
-        # hardware FLOPs come from the lowered tick tables
-        # (lowering.program_flops), so the padding tax is recorded per
-        # layout, not guessed.
-        if self._sequential:
-            device = jax.devices()[0]
-            padded = None
-            self._mesh_layout = None
-        else:
-            device = self.mesh.devices.flat[0]
-            padded = (
-                program_flops(
-                    self._prog, self.spec, self._mubatch_local, tp=self.tp
+            if self._sequential:
+                with self._metrics.span("session/weights"):
+                    with self._metrics.span("device_put"):
+                        self._params = jax.tree.map(jnp.asarray, host_params)
+                    # the host's copy goes here, in the phase that made it: at
+                    # 0.9 to 1.3B parameters its release takes a third to half
+                    # a second, which would otherwise fall after the root span
+                    del host_params
+                    if host_opt_state is not None and not is_stateless(opt):
+                        self._opt_state = join_state(
+                            opt,
+                            {
+                                k: jax.tree.map(jnp.asarray, v)
+                                for k, v in host_opt_state["parts"].items()
+                            },
+                            {
+                                k: jnp.asarray(v, jnp.float32)
+                                for k, v in host_opt_state["scalars"].items()
+                            },
+                        )
+                    else:
+                        self._opt_state = opt.init(self._params)
+                with self._metrics.span("session/program"):
+                    self._epoch_fn = trainer.make_train_epoch(
+                        self.spec, opt, precision=self.precision,
+                        fuse_mubatches=fuse_mubatches, unroll=scan_unroll,
+                        clip_norm=clip_norm, megakernel=megakernel,
+                        epoch_kernel=epoch_kernel or run_kernel,
+                        with_grad_norm=self._epoch_aux,
+                        with_step_stats=self._step_aux,
+                        with_digests=self._digests,
+                        x_layout=self._data_layout,
+                    )
+                    self._predict = (
+                        None if self._token
+                        else trainer.make_predict(self.spec, precision=self.precision)
+                    )
+                    self._run_kwargs = dict(
+                        precision=self.precision, fuse_mubatches=fuse_mubatches,
+                        unroll=scan_unroll, clip_norm=clip_norm, megakernel=megakernel,
+                        epoch_kernel=epoch_kernel or run_kernel,
+                        x_layout=self._data_layout,
+                    )
+                with self._metrics.span("session/data"):
+                    # one device program either way, the set in and the set out:
+                    # the peak of two sets is this transient (PERF.md §4)
+                    if self._data_layout == "feature_major":
+                        self._Xe = trainer.feature_major(self._X)
+                    else:
+                        self._Xe = self._X.reshape(nb, self.M, self.B // self.M, -1)
+                    self._Ye = self._Y.reshape(nb, self.M, self.B // self.M, -1)
+                    # the microbatched views are the only users
+                    self._X = self._Y = None
+            else:
+                with self._metrics.span("session/lower"):
+                    self.mesh, self._mesh_layout = make_mesh_with_layout(
+                        dp, pp, devices, tp
+                    )
+                    if self._metrics.enabled:
+                        # placement provenance (topology-aware vs order-preserving):
+                        # a bench record measured on one placement must say so —
+                        # the two differ materially on a real slice
+                        self._metrics.event(
+                            "mesh_layout",
+                            dp=dp, pp=pp, tp=self.tp, layout=self._mesh_layout,
+                            n_devices=dp * pp * self.tp,
+                        )
+                    prog = lower_schedule(
+                        S.SCHEDULES[schedule], mubatches, pp, virtual=self.V,
+                        backward_split=self._backward_split,
+                        recompute=self._recompute,
+                    )
+                    if self._metrics.enabled or self._audit_strict:
+                        # program-level static analysis at lowering time, BEFORE
+                        # anything compiles or dispatches: send/recv match, MPMD
+                        # deadlock-freedom, stash lifetimes (analysis/;
+                        # docs/static-analysis.md) — the machine-checked form of
+                        # the invariants the lowering simulator constructs by
+                        # simulation (the simulator is the spec, this is the proof)
+                        self._record_static_analysis(prog, "epoch_program")
+                    if self._metrics.enabled:
+                        # per-tick program stats, recorded once at lowering time:
+                        # the executor's runtime tick behaviour is fully determined
+                        # by these static tables (ticks, sends, occupancy, bubble)
+                        stats = program_stats(
+                            prog, spec=self.spec,
+                            mubatch_size=local_batch // mubatches, tp=self.tp,
+                        )
+                        if self._recompute:
+                            # the stashed twin's footprint, lowered alongside (pure
+                            # Python, no compile): the report CLI's Memory section
+                            # renders the two peaks side by side from ONE stream —
+                            # the saving is an artifact of both real tick tables,
+                            # not a formula
+                            twin = program_stats(
+                                lower_schedule(
+                                    S.SCHEDULES[schedule], mubatches, pp,
+                                    virtual=self.V,
+                                    backward_split=self._backward_split,
+                                    recompute=False,
+                                ),
+                                spec=self.spec,
+                                mubatch_size=local_batch // mubatches, tp=self.tp,
+                            )
+                            stats["stash_bytes_peak_stashed_twin"] = twin[
+                                "stash_bytes_peak"
+                            ]
+                            stats["stash_slots_stashed_twin"] = twin["stash_slots"]
+                        self._metrics.event(
+                            "pipeline_program",
+                            schedule=schedule, dp=dp, pp=pp, tp=self.tp,
+                            virtual=self.V, model=self.model_name, **stats,
+                        )
+                        self._metrics.gauge(
+                            "pipeline.bubble_fraction", stats["bubble_fraction"]
+                        )
+                with self._metrics.span("session/weights"):
+                    with self._metrics.span("device_put"):
+                        stacked_np, flags_np = E.stack_params(
+                            host_params, self.spec, order=self._order, tp=self.tp
+                        )
+                        if self._zero == 3:
+                            # ZeRO-3 params at rest: one (pp*tp, dp*csz3)
+                            # block-cyclic array, each device holding only its own
+                            # 1/dp shard — the {W,b} stacked layout never lands on
+                            # device (predict/save rebuild it on demand)
+                            self._stacked = {
+                                "P": jax.device_put(
+                                    E.zero_block_flatten_rows(
+                                        stacked_np, self.spec, self.mesh
+                                    ),
+                                    E.zero1_part_sharding(self.mesh),
+                                )
+                            }
+                            self._flags = E.put_pp(flags_np, self.mesh)
+                        else:
+                            self._stacked, self._flags = E.put_stacked(
+                                stacked_np, flags_np, self.mesh
+                            )
+                        del host_params, stacked_np, flags_np  # as above
+                    if self._zero >= 2:
+                        self._opt_state = E.zero_block_state_from_logical(
+                            host_opt_state, opt, self.spec, self.mesh, order=self._order
+                        )
+                    elif self._zero1:
+                        self._opt_state = E.zero1_state_from_logical(
+                            host_opt_state, opt, self.spec, self.mesh, order=self._order
+                        )
+                    elif host_opt_state is not None and not is_stateless(opt):
+                        # stack + place each state part exactly like the params it
+                        # mirrors (zero padding is consistent: padded grads are
+                        # exactly zero, so padded state stays zero); scalars replicate
+                        rep = NamedSharding(self.mesh, PartitionSpec())
+                        self._opt_state = join_state(
+                            opt,
+                            {
+                                k: E.put_stacked_tree(
+                                    E.stack_params(
+                                        v, self.spec, order=self._order, tp=self.tp
+                                    )[0],
+                                    self.mesh,
+                                )
+                                for k, v in host_opt_state["parts"].items()
+                            },
+                            {
+                                k: jax.device_put(np.float32(v), rep)
+                                for k, v in host_opt_state["scalars"].items()
+                            },
+                        )
+                    else:
+                        self._opt_state = opt.init(self._stacked)
+                with self._metrics.span("session/program"):
+                    if self.runtime == "mpmd":
+                        from shallowspeed_tpu.observability.tracing import Tracer
+                        from shallowspeed_tpu.parallel import mpmd
+
+                        # the MPMD runner's constructor IS the admission gate:
+                        # analyze_program must prove the tick tables deadlock-free
+                        # before any stage program can be built or dispatched
+                        self._mpmd = mpmd.MpmdTrainRunner(
+                            self.mesh, self.spec, prog, local_batch // mubatches,
+                            opt, precision=self.precision,
+                            tracer=Tracer(self._metrics, process="m"),
+                        )
+
+                        def _mpmd_epoch(stacked, flags, opt_state, X, Y):
+                            return self._mpmd.run(
+                                stacked, flags, opt_state, X, Y,
+                                trace_id=f"mpmd-{self.global_step}",
+                            )
+
+                        self._epoch_fn = _mpmd_epoch
+                    else:
+                        self._epoch_fn = E.make_pipeline_epoch(
+                            self.mesh, self.spec, prog, local_batch // mubatches, opt,
+                            precision=self.precision, zero=self._zero,
+                            unroll=scan_unroll, tick_unroll=tick_unroll,
+                            clip_norm=clip_norm, kernel_backend=kernel_backend,
+                            with_grad_norm=self._epoch_aux,
+                            with_step_stats=self._step_aux,
+                            with_digests=self._digests,
+                        )
+                    self._prog = prog
+                    self._mubatch_local = local_batch // mubatches
+                    self._run_kwargs = dict(
+                        precision=self.precision, unroll=scan_unroll,
+                        tick_unroll=tick_unroll, zero=self._zero,
+                        clip_norm=clip_norm, kernel_backend=kernel_backend,
+                    )
+
+            with self._metrics.span("session/program"):
+                # analytical cost model + MFU accounting (observability/costmodel):
+                # the model-FLOP numerator is known at construction; the XLA
+                # cost_analysis cross-check attaches at jit time
+                # (_ensure_epoch_compiled / warm_run). On mesh layouts the padded
+                # hardware FLOPs come from the lowered tick tables
+                # (lowering.program_flops), so the padding tax is recorded per
+                # layout, not guessed.
+                if self._sequential:
+                    device = jax.devices()[0]
+                    padded = None
+                    self._mesh_layout = None
+                else:
+                    device = self.mesh.devices.flat[0]
+                    padded = (
+                        program_flops(
+                            self._prog, self.spec, self._mubatch_local, tp=self.tp
+                        )
+                        * dp
+                    )
+                self._cost_model = costmodel.CostModel(
+                    sizes=sizes if self._token else self.spec.sizes,
+                    flops_per_sample=(
+                        # attention over the pairs this set's packing admits
+                        costmodel.token_train_flops_per_sample(
+                            self.spec,
+                            self._token_counts["pairs"] / self._token_counts["tokens"],
+                        )
+                        if self._token else None
+                    ),
+                    global_batch=self.B,
+                    batches_per_epoch=self.batches_per_epoch,
+                    n_devices=1 if self._sequential else dp * pp * self.tp,
+                    platform=device.platform,
+                    device_kind=device.device_kind,
+                    precision=self._precision_name,
+                    padded_flops_per_batch=padded,
                 )
-                * dp
-            )
-        self._cost_model = costmodel.CostModel(
-            sizes=sizes if self._token else self.spec.sizes,
-            flops_per_sample=(
-                # attention over the pairs this set's packing admits
-                costmodel.token_train_flops_per_sample(
+                # the layout's analytical comms contract (required/forbidden
+                # collective kinds + bytes/step per mesh axis, derived from the
+                # lowered tick tables) — what the compiled program's collective
+                # census is audited against at jit time.
+                self._expected_comms = program_audit.expected_comms(
                     self.spec,
-                    self._token_counts["pairs"] / self._token_counts["tokens"],
+                    dp,
+                    pp,
+                    prog=None if self._sequential else self._prog,
+                    zero=self._zero,
+                    mubatch_size=None if self._sequential else self._mubatch_local,
+                    platform=device.platform,
+                    device_kind=device.device_kind,
+                    precision=self._precision_name,
+                    tp=self.tp,
+                    # only params-mirroring parts occupy per-layer bytes (Adam's
+                    # "t" is a scalar) — the forecast prices what actually shards
+                    opt_state_parts=sum(
+                        1 for v in opt.state_layout().values() if v == "params"
+                    ),
                 )
-                if self._token else None
-            ),
-            global_batch=self.B,
-            batches_per_epoch=self.batches_per_epoch,
-            n_devices=1 if self._sequential else dp * pp * self.tp,
-            platform=device.platform,
-            device_kind=device.device_kind,
-            precision=self._precision_name,
-            padded_flops_per_batch=padded,
-        )
-        # the layout's analytical comms contract (required/forbidden
-        # collective kinds + bytes/step per mesh axis, derived from the
-        # lowered tick tables) — what the compiled program's collective
-        # census is audited against at jit time.
-        self._expected_comms = program_audit.expected_comms(
-            self.spec,
-            dp,
-            pp,
-            prog=None if self._sequential else self._prog,
-            zero=self._zero,
-            mubatch_size=None if self._sequential else self._mubatch_local,
-            platform=device.platform,
-            device_kind=device.device_kind,
-            precision=self._precision_name,
-            tp=self.tp,
-            # only params-mirroring parts occupy per-layer bytes (Adam's
-            # "t" is a scalar) — the forecast prices what actually shards
-            opt_state_parts=sum(
-                1 for v in opt.state_layout().values() if v == "params"
-            ),
-        )
-        if self._recovery is not None and self._metrics.enabled:
-            # one schema-v4 recovery record per resume decision: what was
-            # restored (or that nothing was), where training restarts, and
-            # every corrupt snapshot skipped on the way
-            self._metrics.recovery(
-                self._recovery["verdict"],
-                resumed_from=self._recovery["resumed_from"],
-                epoch=self.epoch,
-                step_in_epoch=self.step_in_epoch,
-                global_step=self.global_step,
-                skipped=self._recovery["skipped"],
-            )
+            if self._recovery is not None and self._metrics.enabled:
+                # one schema-v4 recovery record per resume decision: what was
+                # restored (or that nothing was), where training restarts, and
+                # every corrupt snapshot skipped on the way
+                self._metrics.recovery(
+                    self._recovery["verdict"],
+                    resumed_from=self._recovery["resumed_from"],
+                    epoch=self.epoch,
+                    step_in_epoch=self.step_in_epoch,
+                    global_step=self.global_step,
+                    skipped=self._recovery["skipped"],
+                )
 
     # -- training -----------------------------------------------------------
 
@@ -1337,7 +1369,7 @@ class TrainingSession:
         self._audit_done.add(dedup)
 
     def _record_cost_model(self):
-        """Emit the cost_model event + model_flops gauge. Emitted once per
+        """Emit the cost_model event. Emitted once per
         session — except that a record written BEFORE the XLA cross-check
         attached (a warm_run-first session) is re-emitted once the compiled
         epoch program's cost_analysis exists, so the flops_ratio signal is
@@ -1348,7 +1380,6 @@ class TrainingSession:
         if self._cost_recorded and (self._cost_xla_recorded or not has_xla):
             return
         self._metrics.event("cost_model", **self._cost_model.as_record())
-        self._metrics.gauge("model_flops", self._cost_model.flops_per_epoch)
         self._cost_recorded = True
         self._cost_xla_recorded = has_xla
 
@@ -1469,10 +1500,11 @@ class TrainingSession:
     def _run_epoch_program(self, args):
         """Dispatch the epoch (or chunk) program on ``args``, keep the new
         state, read the mean loss back: ``(outputs, loss)``. The two halves
-        are always spans on the profiler's clock (``epoch/dispatch``,
-        ``epoch/readback``; ``jax.profiler.TraceAnnotation``, so a capture
-        shows them beside the device's operations) and also records of the
-        metrics stream where a recorder is attached. The first dispatch of
+        are the spans ``epoch/dispatch`` and ``epoch/readback``: on the
+        profiler's clock (a capture shows them beside the device's
+        operations) and in the span log. What the host does between one
+        epoch's readback and the next one's dispatch needs no span of its
+        own: it is the stretch between those two. The first dispatch of
         each batch-axis length (a chunk and a whole epoch are two programs
         under one name) registers the program with ``observability.scopes``
         (the jitted callable and the arguments' shapes, no array), so that a
@@ -1484,14 +1516,13 @@ class TrainingSession:
             self._registered_shape = args[-1].shape
             if self._token:
                 scopes.record_counts(self._program_name, self._token_counts)
-        metrics = self._metrics if self._metrics.enabled else None
-        with host_span("epoch/dispatch", metrics):
+        with self._metrics.span("epoch/dispatch"):
             out = self._epoch_fn(*args)
         if self._sequential:
             self._params, self._opt_state = out[0], out[1]
         else:
             self._stacked, self._opt_state = out[0], out[1]
-        with host_span("epoch/readback", metrics):
+        with self._metrics.span("epoch/readback"):
             loss = float(out[2])  # forces device completion
             if self._token and self.spec.routed_layers:
                 # the program's last output, complete with the loss: the
@@ -1573,8 +1604,6 @@ class TrainingSession:
         self._epoch_wall += wall
         self._epoch_steps_counted += steps
         self._epoch_first_dispatch = self._epoch_first_dispatch or first_dispatch
-        if self._metrics.enabled:
-            self._metrics.counter("samples_trained", steps * self.B)
         epoch_loss = None
         if k1 == nb:
             # loss/throughput over the steps THIS process dispatched: after
@@ -1603,7 +1632,6 @@ class TrainingSession:
                 if mfu is not None:
                     record["mfu"] = mfu
                 self._metrics.event("epoch", **record)
-                self._metrics.counter("epochs_trained")
                 self._telemetry.note_step(
                     time.perf_counter(), loss=epoch_loss, step_s=ew,
                     throughput=sps, mfu=mfu,
@@ -1897,8 +1925,6 @@ class TrainingSession:
             self._metrics.event("epoch", **record)
             if not first_dispatch:  # steady-state only, per the histogram's use
                 self._metrics.observe("epoch.seconds", wall)
-            self._metrics.counter("epochs_trained")
-            self._metrics.counter("samples_trained", samples)
             self._telemetry.note_step(
                 time.perf_counter(), loss=loss, step_s=wall,
                 throughput=sps, mfu=mfu,
@@ -2019,9 +2045,6 @@ class TrainingSession:
                     time.perf_counter(), loss=loss, step_s=wall / epochs,
                     throughput=sps, mfu=mfu,
                 )
-            self._metrics.observe("run.seconds", wall)
-            self._metrics.counter("epochs_trained", epochs)
-            self._metrics.counter("samples_trained", epochs * samples)
         if self._health is not None:
             # the fused run returns in one dispatch: epoch-granular checks
             # (per-epoch mean loss + mean grad norm when threaded)

@@ -23,6 +23,11 @@ not the cure: it keys on file paths and line numbers, so every checkout and
 every edited line would compile cold.)
 
 This is JAX's own store, and the only executable store the repository has.
+
+The same call registers the package's one set of compile listeners
+(``observability.spans.listen_to_compiles``): every entry point makes it
+before its first compile, so the span log holds each trace, lowering, backend
+compile and cache load of the process, by phase and by function.
 """
 
 import os
@@ -31,6 +36,7 @@ from pathlib import Path
 import jax
 
 from shallowspeed_tpu.observability.scopes import CACHE_TAG
+from shallowspeed_tpu.observability.spans import listen_to_compiles
 
 CHECKOUT_CACHE_DIR = Path(__file__).resolve().parent.parent / ".jax_cache"
 
@@ -43,6 +49,7 @@ def enable_compile_cache() -> str:
     the one big epoch program; at the default thresholds (1 s, and a
     minimum entry size) they would be rebuilt by every process.
     """
+    listen_to_compiles()
     base = os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
     jax.config.update("jax_compilation_cache_dir", str(Path(base) / CACHE_TAG))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -8,14 +8,18 @@ evidence trail instead of prose:
 - ``metrics``      the recording surface: ``MetricsRecorder`` (in-memory
                    counters / gauges / timers / per-step histograms),
                    ``JsonlMetrics`` (the versioned JSONL sink) and
-                   ``NullMetrics`` (the zero-overhead default — recording
-                   disabled costs nothing on the hot path);
-- ``spans``        profiling spans: wall-clock + ``jax.profiler``
-                   TraceAnnotation context managers (so host-side phases —
-                   schedule lowering, jit compile, device put, epoch
-                   execution — are labeled inside profiler captures AND
-                   timed into the metrics stream), plus ``capture`` wrapping
-                   ``jax.profiler.trace``;
+                   ``NullMetrics`` (the default: recording disabled costs
+                   nothing on the hot path but its spans, which are real);
+- ``spans``        host spans, the one source of host-side timing, always
+                   on: ``HOST_SPANS`` (the vocabulary), the process's bounded
+                   span log (``log()``: every closed span and every compile
+                   event, recorder or none), ``jax.profiler``
+                   TraceAnnotations (so the phases of a session's
+                   construction, device puts, an epoch's dispatch and
+                   readback are labeled inside profiler captures) and
+                   ``span`` records where a recorder is bound; the package's
+                   one compile listener (``listen_to_compiles``); plus
+                   ``capture`` wrapping ``jax.profiler.trace``;
 - ``trace_stats``  the chrome-trace analyzer behind docs/performance.md's
                    roofline numbers (promoted from scripts/ to an importable,
                    tested module; the script remains as a thin shim);
@@ -63,8 +67,8 @@ evidence trail instead of prose:
                    finished runs (``--once``), rendering current-window
                    throughput / p50 / p99 / queue depth / alert state;
 - ``costmodel``    analytical MLP FLOPs + ``Compiled.cost_analysis()``
-                   cross-check + MFU accounting (``model_flops``,
-                   ``achieved_flops_per_sec``, ``mfu`` gauges per layout);
+                   cross-check + MFU accounting (the ``cost_model`` event,
+                   ``achieved_flops_per_sec`` and ``mfu`` gauges per layout);
 - ``program_audit`` the XLA program audit: collective census parsed from
                    ``Compiled.as_text()``, ``memory_analysis()`` through
                    one shared helper, the analytical comms model derived

@@ -356,7 +356,10 @@ _NULL_CONTEXT = _NullContext()
 class NullMetrics:
     """The no-op backend: the hot-path methods take fixed positional
     arguments (no ``**kwargs`` — an empty kwargs dict is still a dict
-    allocation per call) and return module-level singletons."""
+    allocation per call) and return module-level singletons. The one thing
+    it does not drop is a span: ``span(name)`` is a real ``spans.Span``
+    without a recorder, so the process's span log and the profiler's trace
+    hold the same spans whether or not a recorder is attached."""
 
     __slots__ = ()
     enabled = False
@@ -374,7 +377,7 @@ class NullMetrics:
         return _NULL_CONTEXT
 
     def span(self, name):
-        return _NULL_CONTEXT
+        return Span(name)
 
     def event(self, name, **fields):
         pass

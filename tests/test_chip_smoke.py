@@ -43,22 +43,18 @@ def tiny_data(smoke, tmp_path_factory):
 def test_legs_pass_small_on_the_cpu_mesh(smoke, tiny_data, tmp_path):
     """Every leg, through train.main, at the reference model's full width on
     two batches — leg C on a 2x2 corner of the 8 emulated devices."""
-    clock = smoke.CompileClock()
-    try:
-        a = smoke.run_leg(
-            "leg A", smoke.leg_reference, clock,
-            tiny_data, tiny_data, tmp_path, tmp_path, epochs=2,
-        )
-        b = smoke.run_leg(
-            "leg B", smoke.leg_deep_one_chip, clock,
-            tiny_data, tmp_path, tmp_path, model="mnist-mlp",
-        )
-        c = smoke.run_leg(
-            "leg C", smoke.leg_deep_mesh, clock,
-            tiny_data, tmp_path, tmp_path, b["checkpoint"], model="mnist-mlp",
-        )
-    finally:
-        clock.close()
+    a = smoke.run_leg(
+        "leg A", smoke.leg_reference,
+        tiny_data, tiny_data, tmp_path, tmp_path, epochs=2,
+    )
+    b = smoke.run_leg(
+        "leg B", smoke.leg_deep_one_chip,
+        tiny_data, tmp_path, tmp_path, model="mnist-mlp",
+    )
+    c = smoke.run_leg(
+        "leg C", smoke.leg_deep_mesh,
+        tiny_data, tmp_path, tmp_path, b["checkpoint"], model="mnist-mlp",
+    )
     assert a["init_hash"] == smoke.INIT_HASH["mnist-mlp"]
     assert a["oracle_gap"]["steps"] == 2 and a["oracle_gap"]["of_tolerance"] <= 1
     assert a["losses"][1] < a["losses"][0]
@@ -66,6 +62,10 @@ def test_legs_pass_small_on_the_cpu_mesh(smoke, tiny_data, tmp_path):
     assert c["cross_layout_gap"]["of_tolerance"] <= 1
     for facts in (a, b, c):
         assert facts["wall_s"] >= facts["compile_s"] >= 0
+    # the compile seconds come from the package's own listener (the span
+    # log), and every leg compiles or loads something
+    assert a["compile_s"] > 0 and c["compile_s"] > 0
+    assert a["cache_hits"] + a["cache_misses"] > 0
     assert (tmp_path / "legC.log").read_text().count("DP replicas in sync") == 1
 
 

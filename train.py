@@ -583,10 +583,9 @@ def main(argv=None):
 
     def profiled(i):
         # trace one post-compile epoch when asked (observability.capture =
-        # jax.profiler.trace + a profiler_capture record in the metrics
-        # stream naming the trace artifact)
+        # jax.profiler.trace, or nothing without a directory)
         if args.profile_dir and i == min(1, args.epochs - 1):
-            return capture(args.profile_dir, metrics)
+            return capture(args.profile_dir)
         return contextlib.nullcontext()
 
     t0 = time.time()
@@ -605,7 +604,7 @@ def main(argv=None):
                 # AOT-compile first so the trace holds steady-state execution,
                 # not compilation (mirrors the loop mode's post-compile trace)
                 run.warm_run(args.epochs, with_eval=not args.no_eval)
-            with capture(args.profile_dir, metrics):
+            with capture(args.profile_dir):
                 losses, accs = run.train_run(args.epochs, with_eval=not args.no_eval)
             for e, loss in enumerate(losses):
                 print(f"Epoch: {start + e}, mean train loss: {loss:.5f}")
@@ -655,7 +654,7 @@ def main(argv=None):
                 else:
                     n = nb - run.step_in_epoch
                 with (
-                    capture(args.profile_dir, metrics)
+                    capture(args.profile_dir)
                     if run.epoch == prof_epoch
                     else contextlib.nullcontext()
                 ):
