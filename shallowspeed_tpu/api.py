@@ -799,10 +799,8 @@ class TrainingSession:
             if resume is None:
                 with self._metrics.span("session/weights"):
                     host_params = (
-                        Mo.init_token_model(self.spec)
-                        if self._token
-                        else Mo.init_model(self.spec)
-                    )
+                        Mo.init_token_model if self._token else Mo.init_model
+                    )(self.spec, self._metrics)
 
             # telemetry aux: when recording AND clipping, the epoch/run programs
             # also return the pre-clip global gradient norm (ordinary fused

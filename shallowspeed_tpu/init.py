@@ -7,11 +7,30 @@ MT19937 stream per Linear layer from its (in, out) dims
 bit-for-bit on host NumPy, then device_put — it is what makes "TPU run reaches
 the NumPy reference's loss" a checkable statement, and what makes the
 layout-independent model hash (utils.py) meaningful.
+
+No leaf's values depend on any other leaf's (each has a stream of its own), so
+a model's leaves are drawn concurrently (``draw_leaves``): the same functions,
+the same streams, the same bits, on as many threads as the process may use.
 """
 
+import concurrent.futures
+import os
 import zlib
+from typing import Callable, NamedTuple
 
 import numpy as np
+
+from shallowspeed_tpu.observability.metrics import NullMetrics
+
+# A list of draws with fewer elements than this is drawn by the calling
+# thread. Measured once (PERF.md §6, PR 38: 16 equal draws through
+# ``draw_leaves`` with the pool on and off, on the 13 cores of the chip's
+# host): handing 16 draws to 13 threads costs 5 to 8 ms however small they
+# are, which 2**20 elements (11 ms of ``token_leaf_init``, 26 ms of
+# ``linear_init``) are the first to pay back. ``mnist-mlp`` (181,105) and the
+# tests' trees lie under it, the benchmark's other models 85 times and more
+# above.
+POOL_MIN_ELEMENTS = 1 << 20
 
 
 def linear_init(in_dim: int, out_dim: int):
@@ -58,3 +77,52 @@ def token_leaf_init(layer_index: int, name: str, shape, kind: str):
     else:
         raise ValueError(f"unknown leaf kind {kind!r}")
     return np.asarray(leaf, dtype=np.float32)
+
+
+class Draw(NamedTuple):
+    """One independent draw: ``fn(*args)`` gives a leaf, or a tuple of
+    leaves, of ``sizes`` elements each."""
+
+    fn: Callable
+    args: tuple
+    sizes: tuple
+
+
+def draw_leaves(draws, metrics=None):
+    """``[d.fn(*d.args) for d in draws]``, drawn concurrently: one thread per
+    core the process may use and never more than there are draws, the largest
+    draws started first (a model's largest leaf is the floor of the whole
+    draw and must not start last), the results in the order given. With one
+    worker, or under ``POOL_MIN_ELEMENTS``, it is that serial loop itself.
+
+    The pool runs under the host span ``draw``, opened and closed by the
+    calling thread (no span opens in a worker: its path would not lie under
+    the caller's); with a recorder (``metrics``) the span is a record of the
+    stream too and one event ``weights_init`` says how far the pool engaged."""
+    sizes = [n for d in draws for n in d.sizes]
+    workers = 1
+    if sum(sizes) >= POOL_MIN_ELEMENTS:
+        workers = min(len(os.sched_getaffinity(0)), len(draws))
+    if metrics is None:
+        metrics = NullMetrics()
+    with metrics.span("draw") as drawn:
+        if workers == 1:
+            leaves = [d.fn(*d.args) for d in draws]
+        else:
+            pool = concurrent.futures.ThreadPoolExecutor(workers, "draw")
+            try:
+                largest_first = sorted(
+                    range(len(draws)), key=lambda i: -sum(draws[i].sizes)
+                )
+                futures = [None] * len(draws)
+                for i in largest_first:
+                    futures[i] = pool.submit(draws[i].fn, *draws[i].args)
+                leaves = [future.result() for future in futures]
+            finally:
+                # after an error the draws not yet started are dropped
+                pool.shutdown(cancel_futures=True)
+    metrics.event(
+        "weights_init", workers=workers, leaves=len(sizes), elements=sum(sizes),
+        largest_leaf_elements=max(sizes, default=0), draw_s=drawn.seconds,
+    )
+    return leaves

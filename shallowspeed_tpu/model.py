@@ -30,6 +30,7 @@ holds whenever the last stage has at least one Linear.
 
 import dataclasses
 import json
+import math
 from pathlib import Path
 from typing import Sequence
 
@@ -37,7 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from shallowspeed_tpu import ops
-from shallowspeed_tpu.init import linear_init, token_leaf_init
+from shallowspeed_tpu.init import Draw, draw_leaves, linear_init, token_leaf_init
 from shallowspeed_tpu.observability.scopes import scope
 
 
@@ -225,17 +226,33 @@ def resolve_model(name):
     return tuple(entry["sizes"]), entry["act"]
 
 
-def init_stage_params(spec: StageSpec):
-    """Host-side deterministic init for one stage; list of {"W","b"} numpy."""
+def _init_stages(stages, metrics):
+    """Every Linear of ``stages`` in one ``init.draw_leaves`` (one draw a
+    Linear: its ``(W, b)``), reassembled stage by stage."""
+    drawn = iter(
+        draw_leaves(
+            [
+                Draw(linear_init, (fan_in, fan_out), (fan_out * fan_in, fan_out))
+                for s in stages
+                for fan_in, fan_out in zip(s.local_sizes, s.local_sizes[1:])
+            ],
+            metrics,
+        )
+    )
     return [
-        dict(zip(("W", "b"), linear_init(spec.local_sizes[l], spec.local_sizes[l + 1])))
-        for l in range(spec.n_linears)
+        [dict(zip(("W", "b"), next(drawn))) for _ in range(s.n_linears)]
+        for s in stages
     ]
 
 
-def init_model(spec: ModelSpec):
+def init_stage_params(spec: StageSpec, metrics=None):
+    """Host-side deterministic init for one stage; list of {"W","b"} numpy."""
+    return _init_stages([spec], metrics)[0]
+
+
+def init_model(spec: ModelSpec, metrics=None):
     """Per-stage parameter pytrees (host numpy; caller device_puts/shards)."""
-    return [init_stage_params(s) for s in spec.stages]
+    return _init_stages(spec.stages, metrics)
 
 
 # ---------------------------------------------------------------------------
@@ -748,17 +765,22 @@ def token_layer_shapes(spec: TokenModelSpec):
     )
 
 
-def init_token_model(spec: TokenModelSpec):
+def init_token_model(spec: TokenModelSpec, metrics=None):
     """Host-side deterministic init: one stage, a list of layers, each a
     dictionary of float32 arrays seeded by the layer's index and the leaf's
-    name."""
-    return [[
-        {
-            name: token_leaf_init(index, name, shape, kind)
-            for name, (shape, kind) in layer.items()
-        }
-        for index, layer in enumerate(token_layer_shapes(spec))
-    ]]
+    name, drawn in one ``init.draw_leaves``."""
+    layers = token_layer_shapes(spec)
+    drawn = iter(
+        draw_leaves(
+            [
+                Draw(token_leaf_init, (index, name, shape, kind), (math.prod(shape),))
+                for index, layer in enumerate(layers)
+                for name, (shape, kind) in layer.items()
+            ],
+            metrics,
+        )
+    )
+    return [[{name: next(drawn) for name in layer} for layer in layers]]
 
 
 def _heads(x, heads):

@@ -46,6 +46,9 @@ HOST_SPANS = (
     "session/lower",
     "session/program",
     "session/resume",
+    # under session/weights: the host drawing the model's initial leaves
+    # (init.draw_leaves, the pool and its wait; placement is device_put's)
+    "draw",
     # a leaf of whichever phase or call places something on the devices
     "device_put",
     # the audit's ahead-of-time probe compile (not the compile a run pays)
