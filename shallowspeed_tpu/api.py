@@ -612,12 +612,16 @@ class TrainingSession:
                 )
                 # which form of the recurrent layers' scan the epoch program
                 # holds (ops.scan_path or ops.kda_scan_path, from the shapes
-                # alone): provenance like ``data_layout``'s, an event and a
-                # count beside the program's
+                # alone) and how many layers' forwards run twice: provenance
+                # like ``data_layout``'s, an event and counts beside the
+                # program's
                 scan_plan = Mo.token_scan_plan(self.spec, mubatches)
                 self._scan_path = scan_plan["path"]
-                self._token_counts["scan_kernel_calls"] = (
-                    scan_plan["kernel_calls_per_step"] * nb
+                self._token_counts.update(
+                    scan_kernel_calls=scan_plan["kernel_calls_per_step"] * nb,
+                    recomputed_layer_passes=(
+                        scan_plan["recomputed_layers"] * mubatches * nb
+                    ),
                 )
                 if self._metrics.enabled:
                     self._metrics.event("scan_path", **scan_plan)

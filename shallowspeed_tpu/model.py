@@ -511,7 +511,7 @@ class TokenModelSpec:
     linear_allow_neg_eigval: bool
     seq_len: int
     global_batch_size: int
-    recompute: bool = True  # run a layer's forward again in its backward
+    recompute: bool = True  # run forwards again in the backward (``recomputed``)
     scan_chunk: int = ops.SCAN_CHUNK
     attn_block: int = ops.ATTN_BLOCK
     family: str = "olmo_hybrid"
@@ -544,14 +544,23 @@ class TokenModelSpec:
         """How many layers route (every layer of a family with experts)."""
         return len(self.layer_types) if self.n_routed_experts else 0
 
+    @property
+    def recomputed(self):
+        """Per layer, whether its forward runs again when its backward is
+        due: under ``recompute`` every layer's but the last's, whose
+        backward is the first one due, right after the head's."""
+        last = len(self.layer_types) - 1
+        return tuple(self.recompute and i < last for i in range(last + 1))
+
 
 def token_scan_plan(spec: "TokenModelSpec", mubatches):
     """Which form the recurrent layers' scan runs at this spec's shapes and
-    the kernel launches one optimizer step makes: layers x microbatches x
-    passes (a forward, the forward again where the layer is recomputed, a
-    backward; none where the XLA form runs). Each rule has a kernel form
-    where its shapes tile, and the op's own rule says where: the Gated
-    DeltaNet's ``ops.scan_path``, the per-channel rule's
+    the kernel launches one optimizer step makes: microbatches x the scan
+    layers' passes (a forward, the forward again where the layer is
+    recomputed, a backward; none where the XLA form runs), and how many
+    layers of any kind are recomputed (``spec.recomputed``). Each rule has
+    a kernel form where its shapes tile, and the op's own rule says where:
+    the Gated DeltaNet's ``ops.scan_path``, the per-channel rule's
     ``ops.kda_scan_path`` (whose kernels choose their chunk). -> the
     ``scan_path`` event's fields."""
     chunk = ops._block_len(spec.seq_len, spec.scan_chunk)
@@ -562,14 +571,18 @@ def token_scan_plan(spec: "TokenModelSpec", mubatches):
             chunk = ops.KDA_KERNEL_CHUNK
     else:
         path = ops.scan_path(spec.seq_len, spec.scan_chunk, *shapes)
-    layers = sum(kind in _SCAN_KINDS for kind in spec.layer_types)
-    passes = (3 if spec.recompute else 2) if path == "pallas" else 0
+    passes = sum(
+        3 if again else 2
+        for kind, again in zip(spec.layer_types, spec.recomputed)
+        if kind in _SCAN_KINDS
+    )
     return {
         "path": path,
         "chunk": chunk,
         "d_k": spec.linear_key_head_dim,
         "d_v": spec.linear_value_head_dim,
-        "kernel_calls_per_step": layers * mubatches * passes,
+        "kernel_calls_per_step": mubatches * passes if path == "pallas" else 0,
+        "recomputed_layers": sum(spec.recomputed),
     }
 
 
@@ -1062,7 +1075,9 @@ def token_loss_and_grads(
     cross-entropy and its gradient, ``grads`` shaped like ``params``. With
     ``spec.recompute`` the forward keeps each layer's input only and a
     layer's forward runs again, behind an optimization barrier, when its
-    backward is due. ``acc`` (shaped like ``params``): returned instead of
+    backward is due; the last layer keeps its forward's residuals
+    (``spec.recomputed``), since between that forward and its backward lies
+    only the head. ``acc`` (shaped like ``params``): returned instead of
     the gradient is ``acc + gradient``, each layer's added as soon as its
     backward has made it, so that no second tree of gradients exists beside
     the accumulator (a model's worth of memory). ``fresh_weights``: a layer's
@@ -1082,9 +1097,9 @@ def token_loss_and_grads(
         inputs, targets, seg = tokens[:, :-1], tokens[:, 1:], segments[:, :-1]
     x, embed_back = ops.embed(embedding["E"], inputs)
     kept = []
-    for p, kind in zip(layers, spec.layer_types):
+    for p, kind, again in zip(layers, spec.layer_types, spec.recomputed):
         y, back = token_layer(p, x, seg, kind, spec, precision, census)
-        kept.append(x if spec.recompute else back)
+        kept.append(x if again else back)
         x = y
     normed, norm_back = ops.rms_norm(x, head["norm"], spec.rms_norm_eps)
     logits, logits_back = ops.dense(normed, head["W"], precision)
@@ -1096,7 +1111,7 @@ def token_loss_and_grads(
     grads = [made(len(layers) + 1, {"norm": d_head_norm, "W": d_head_w})]
     for index in reversed(range(len(layers))):
         p, kind, keep = layers[index], spec.layer_types[index], kept[index]
-        if spec.recompute:
+        if spec.recomputed[index]:
             # the barrier keeps the compiler from merging this forward with
             # the first one, which would keep every layer's residuals alive
             if fresh_weights:

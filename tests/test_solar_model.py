@@ -118,6 +118,47 @@ def test_model_gradients_are_the_references(microbatch, index):
         _close(got[name], want[name], rtol=5e-4)
 
 
+@pytest.mark.parametrize(
+    "recompute,order", [(True, [0, 1, 2, 3, 2, 1, 0]), (False, [0, 1, 2, 3])],
+    ids=["recompute", "kept"],
+)
+def test_every_layer_but_the_last_is_recomputed(microbatch, monkeypatch, recompute, order):
+    """``token_layer`` traced once a layer forward, then again for each
+    layer but the last, a delta-rule layer here, as its backward comes due."""
+    spec = dataclasses.replace(microbatch["spec"], recompute=recompute)
+    real, calls = Mo.token_layer, []
+
+    def spy(p, *args, **kw):
+        calls.append(id(p))
+        return real(p, *args, **kw)
+
+    monkeypatch.setattr(Mo, "token_layer", spy)
+    jax.eval_shape(
+        lambda p: Mo.token_loss_and_grads(
+            p, spec, microbatch["tokens"], microbatch["segments"], HIGHEST
+        ),
+        microbatch["params"],
+    )
+    layer_of = {key: index for index, key in enumerate(calls[:4])}
+    assert [layer_of[key] for key in calls] == order
+    assert spec.recomputed == tuple(i in order[4:] for i in range(4))
+
+
+def test_recomputation_on_against_off(microbatch):
+    spec = dataclasses.replace(microbatch["spec"], recompute=False)
+    census = []
+    loss, grads = Mo.token_loss_and_grads(
+        microbatch["params"], spec, microbatch["tokens"], microbatch["segments"], HIGHEST,
+        census=census,
+    )
+    _close(loss, microbatch["loss"], rtol=1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(microbatch["grads"])):
+        _close(got, want, rtol=2e-3)
+    # the census is the first forward's either way
+    for got, want in zip(census, microbatch["census"], strict=True):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_the_census_counts_the_pairs_routed_to_the_experts_held(microbatch):
     census, spec = microbatch["census"], microbatch["spec"]
     assert len(census) == spec.routed_layers == 4
@@ -140,6 +181,7 @@ def test_layer_kinds_shapes_and_the_family_switch():
         assert layer["W1"][0] == (4, 24, 32) and layer["W2"][0] == (4, 32, 24)
     plan = Mo.token_scan_plan(spec, 2)
     assert plan["path"] == "xla" and plan["kernel_calls_per_step"] == 0
+    assert plan["recomputed_layers"] == 3 and spec.recomputed == (True, True, True, False)
 
 
 def test_the_named_model_is_the_configuration_file_at_the_issues_count():
@@ -320,11 +362,17 @@ def test_shapes_that_tile_run_the_kernels_and_the_session_says_so(on_kernels):
     session = on_kernels["session"]
     assert session.scan_path == "pallas"
     (event,) = on_kernels["events"]
-    passes = 3 if session.spec.recompute else 2
-    calls = 1 * 2 * passes  # kda layers x microbatches x passes
-    fields = {k: event[k] for k in ("path", "chunk", "d_k", "d_v", "kernel_calls_per_step")}
-    assert fields == dict(path="pallas", chunk=64, d_k=128, d_v=128, kernel_calls_per_step=calls)
-    assert on_kernels["counts"]["scan_kernel_calls"] == calls  # an epoch of one step
+    # the one kda layer is the last, whose forward runs once either way: 2
+    # microbatches x (forward + backward)
+    calls = 2 * 2
+    recomputed = 1 if session.spec.recompute else 0  # the gqa layer
+    fields = {k: event[k] for k in ("path", "chunk", "d_k", "d_v", "kernel_calls_per_step",
+                                    "recomputed_layers")}
+    assert fields == dict(path="pallas", chunk=64, d_k=128, d_v=128, kernel_calls_per_step=calls,
+                          recomputed_layers=recomputed)
+    # an epoch of one step
+    assert on_kernels["counts"]["scan_kernel_calls"] == calls
+    assert on_kernels["counts"]["recomputed_layer_passes"] == recomputed * 2
 
 
 def test_one_step_on_the_kernels_is_the_references(on_kernels):
@@ -343,9 +391,13 @@ def test_one_step_on_the_kernels_is_the_references(on_kernels):
 @pytest.mark.parametrize(
     "seq,batch,mubatches,want",
     [
-        (2048, 8, 8, dict(path="pallas", chunk=64, kernel_calls_per_step=3 * 8 * 3)),  # the cell
-        (2048, 8, 4, dict(path="pallas", chunk=64, kernel_calls_per_step=3 * 4 * 3)),
-        (2080, 8, 8, dict(path="xla", chunk=52, kernel_calls_per_step=0)),  # no whole chunks of 64
+        # the cell: 8 microbatches x (2 recomputed kda layers x 3 passes + the
+        # last x 2); the gqa layer is recomputed too
+        (2048, 8, 8, dict(path="pallas", chunk=64, kernel_calls_per_step=64, recomputed_layers=3)),
+        (2048, 8, 4, dict(path="pallas", chunk=64, kernel_calls_per_step=4 * 8,
+                          recomputed_layers=3)),
+        # no whole chunks of 64
+        (2080, 8, 8, dict(path="xla", chunk=52, kernel_calls_per_step=0, recomputed_layers=3)),
     ],
 )
 def test_the_named_models_plan_by_shape(seq, batch, mubatches, want):

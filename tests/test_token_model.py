@@ -143,6 +143,33 @@ def test_recomputation_on_against_off(microbatch):
         _close(got, want, rtol=2e-3)
 
 
+@pytest.mark.parametrize(
+    "recompute,order", [(True, [0, 1, 2, 3, 2, 1, 0]), (False, [0, 1, 2, 3])],
+    ids=["recompute", "kept"],
+)
+def test_every_layer_but_the_last_is_recomputed(microbatch, monkeypatch, recompute, order):
+    """``token_layer`` traced once a layer forward, then again for each
+    layer but the last as its backward comes due (the last one's backward
+    runs on its first forward's residuals, right after the head)."""
+    spec = dataclasses.replace(microbatch["spec"], recompute=recompute)
+    real, calls = Mo.token_layer, []
+
+    def spy(p, *args, **kw):
+        calls.append(id(p))
+        return real(p, *args, **kw)
+
+    monkeypatch.setattr(Mo, "token_layer", spy)
+    jax.eval_shape(
+        lambda p: Mo.token_loss_and_grads(
+            p, spec, microbatch["tokens"], microbatch["segments"], HIGHEST
+        ),
+        microbatch["params"],
+    )
+    layer_of = {key: index for index, key in enumerate(calls[:4])}
+    assert [layer_of[key] for key in calls] == order
+    assert spec.recomputed == tuple(i in order[4:] for i in range(4))
+
+
 def test_recomputation_is_chosen_by_what_a_microbatch_keeps():
     assert Mo.make_token_spec(TINY, SEQ, 2).recompute is False
     assert Mo.make_token_spec(
@@ -245,6 +272,7 @@ def test_one_microbatch_against_two(token_set, trained):
 def test_session_leaves_the_sets_counts_with_the_program(token_set, trained):
     counts = dict(scopes.program_counts("jit_epoch_core"))
     assert counts.pop("scan_kernel_calls") == 0  # 6 and 12 wide: the XLA form
+    assert counts.pop("recomputed_layer_passes") == 0  # 96 tokens keep every layer
     assert counts == packed_counts(token_set[2]) == ref.packed_counts(token_set[2])
     assert counts["tokens"] == 8 * SEQ
     assert counts["documents"] <= counts["tokens"] <= counts["pairs"]
@@ -308,25 +336,39 @@ def test_session_says_which_form_the_scan_runs(on_kernels, trained):
 
 def test_scan_path_event_and_the_programs_count(on_kernels):
     spec = on_kernels["session"].spec
-    # 3 Gated DeltaNet layers x 2 microbatches x (forward [+ forward again] + backward)
-    calls = 3 * 2 * (3 if spec.recompute else 2)
+    # 3 Gated DeltaNet layers, none of them the last, x 2 microbatches x
+    # (forward [+ forward again] + backward)
+    recomputed = 3 if spec.recompute else 0
+    calls = 2 * (3 * 2 + recomputed)
     (event,) = on_kernels["events"]
-    fields = {k: event[k] for k in ("path", "chunk", "d_k", "d_v", "kernel_calls_per_step")}
-    assert fields == dict(path="pallas", chunk=128, d_k=8, d_v=16, kernel_calls_per_step=calls)
-    assert on_kernels["counts"]["scan_kernel_calls"] == calls  # an epoch of one step
+    fields = {k: event[k] for k in ("path", "chunk", "d_k", "d_v", "kernel_calls_per_step",
+                                    "recomputed_layers")}
+    assert fields == dict(path="pallas", chunk=128, d_k=8, d_v=16, kernel_calls_per_step=calls,
+                          recomputed_layers=recomputed)
+    # an epoch of one step
+    assert on_kernels["counts"]["scan_kernel_calls"] == calls
+    assert on_kernels["counts"]["recomputed_layer_passes"] == recomputed * 2
     assert on_kernels["counts"]["tokens"] == 2 * SCAN_SEQ
 
 
 @pytest.mark.parametrize(
     "changes,seq,recompute,want",
     [
-        ({}, SEQ, True, dict(path="xla", chunk=48, kernel_calls_per_step=0)),
-        ({}, 64, False, dict(path="xla", chunk=64, kernel_calls_per_step=0)),  # the rehearsal's
-        (TILING, 128, False, dict(path="pallas", chunk=128, kernel_calls_per_step=12)),
-        (TILING, 256, True, dict(path="pallas", chunk=128, kernel_calls_per_step=18)),
-        # the cell's: 3 layers x 2 microbatches x (2 forwards + 1 backward)
+        ({}, SEQ, True, dict(path="xla", chunk=48, kernel_calls_per_step=0, recomputed_layers=3)),
+        # the rehearsal's
+        ({}, 64, False, dict(path="xla", chunk=64, kernel_calls_per_step=0, recomputed_layers=0)),
+        (TILING, 128, False,
+         dict(path="pallas", chunk=128, kernel_calls_per_step=12, recomputed_layers=0)),
+        (TILING, 256, True,
+         dict(path="pallas", chunk=128, kernel_calls_per_step=18, recomputed_layers=3)),
+        # a scan layer last: its forward runs once, 2 x (3 + 3 + 2)
+        ({**TILING, "layer_types": ["full_attention"] + 3 * ["linear_attention"]}, 256, True,
+         dict(path="pallas", chunk=128, kernel_calls_per_step=16, recomputed_layers=3)),
+        # the cell's: 3 scan layers x 2 microbatches x (2 forwards + 1
+        # backward); the last layer, full attention, is the one kept
         (dict(linear_key_head_dim=96, linear_value_head_dim=192), 8192, True,
-         dict(path="pallas", chunk=128, d_k=96, d_v=192, kernel_calls_per_step=18)),
+         dict(path="pallas", chunk=128, d_k=96, d_v=192, kernel_calls_per_step=18,
+              recomputed_layers=3)),
     ],
 )
 def test_scan_plan_counts_layers_microbatches_and_passes(changes, seq, recompute, want):
