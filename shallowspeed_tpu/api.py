@@ -622,6 +622,9 @@ class TrainingSession:
                     recomputed_layer_passes=(
                         scan_plan["recomputed_layers"] * mubatches * nb
                     ),
+                    acc_inplace_leaf_passes=(
+                        trainer.accumulated_expert_leaves(self.spec, mubatches) * nb
+                    ),
                 )
                 if self._metrics.enabled:
                     self._metrics.event("scan_path", **scan_plan)

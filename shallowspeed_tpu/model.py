@@ -971,12 +971,30 @@ def _gqa_mix(p, x, seg, spec, precision):
     return out, back
 
 
+def _summed(acc, grads, fresh=False):
+    """``grads`` added leaf by leaf to ``acc`` (a dict holding at least
+    their names; read as zero where ``fresh``, a bool, traced or not), or
+    ``grads`` itself where ``acc`` is None."""
+    if acc is None:
+        return grads
+    with scope("acc"):
+        return {
+            k: (acc[k] if fresh is False else jnp.where(fresh, 0.0, acc[k])) + g
+            for k, g in grads.items()
+        }
+
+
 def _routed_ffn(p, x, spec, precision, census=None):
     """The routed mixture: the shared expert (a SwiGLU every token takes)
     plus, of the ``num_experts_per_tok`` experts the router picks among all
     ``n_routed_experts``, those this chip holds (``ops.route``,
     ``ops.experts``). ``census`` (a list) gains the (held,) int32 count of
-    (token, slot) pairs routed to each held expert. -> ``(out, back)``."""
+    (token, slot) pairs routed to each held expert. -> ``(out, back)``;
+    ``back(dout, acc=None, fresh=False) -> (dx, grads, made)``: ``made``
+    holds the held experts' leaves ``W1``, ``W3``, ``W2``, made in ``acc``
+    (the layer's accumulator, read as zero where ``fresh``) by
+    ``ops.experts`` and so holding ``acc + gradient``; ``grads`` holds every
+    other leaf's gradient alone."""
     x2 = x.reshape(-1, x.shape[-1])
     (weights, sel), route_back = ops.route(
         x2, p["W_r"], spec.num_experts_per_tok, spec.norm_topk_prob,
@@ -994,17 +1012,19 @@ def _routed_ffn(p, x, spec, precision, census=None):
     shared, down_back = ops.dense(act, p["Ws2"], precision)
     out = ops.fan_in(shared, routed.reshape(x.shape))
 
-    def back(dout):
+    def back(dout, acc=None, fresh=False):
         grads = {}
         dact, grads["Ws2"] = down_back(dout)
         dgate, dup = act_back(dact)
         dx_g, grads["Ws1"] = gate_back(dgate)
         dx_u, grads["Ws3"] = up_back(dup)
-        dx_e, dweights, grads["W1"], grads["W3"], grads["W2"] = experts_back(
-            dout.reshape(x2.shape)
+        made = {}
+        held_acc = None if acc is None else (acc["W1"], acc["W3"], acc["W2"])
+        dx_e, dweights, made["W1"], made["W3"], made["W2"] = experts_back(
+            dout.reshape(x2.shape), held_acc, fresh
         )
         dx_r, grads["W_r"] = route_back(dweights)
-        return ops.fan_in(dx_g, dx_u, (dx_e + dx_r).reshape(x.shape)), grads
+        return ops.fan_in(dx_g, dx_u, (dx_e + dx_r).reshape(x.shape)), grads, made
 
     return out, back
 
@@ -1024,13 +1044,14 @@ def _pre_norm_layer(p, x, seg, kind, spec, precision, census):
     routed, ffn_back = _routed_ffn(p, normed2, spec, precision, census)
     y = ops.fan_in(hid, routed)
 
-    def back(dy):
-        dnormed2, grads = ffn_back(dy)
+    def back(dy, acc=None, fresh=False):
+        dnormed2, grads, made = ffn_back(dy, acc, fresh)
         dhid, grads["mlp_norm"] = norm2(dnormed2)
         dhid = ops.fan_in(dy, dhid)
         dnormed, mix_grads = mix_back(dhid)
         dx, grads["attn_norm"] = norm1(dnormed)
-        return ops.fan_in(dhid, dx), {**grads, **mix_grads}
+        grads = _summed(acc, {**grads, **mix_grads}, fresh)
+        return ops.fan_in(dhid, dx), {**grads, **made}
 
     return y, back
 
@@ -1040,7 +1061,11 @@ def token_layer(p, x, seg, kind, spec, precision, census=None):
     norm(mix(x))``, ``y = h + norm(mlp(h))`` (the norms sit on the branches),
     ``solar_open2``'s pre-norm form with a routed feed-forward
     (``_pre_norm_layer``; ``census``: see ``_routed_ffn``). -> ``(y,
-    back)``; ``back(dy) -> (dx, grads)`` with ``grads`` shaped like ``p``."""
+    back)``; ``back(dy, acc=None, fresh=False) -> (dx, grads)`` with
+    ``grads`` shaped like ``p``: with ``acc``, the layer's accumulator
+    (shaped like ``p``; read as zero where ``fresh``), every leaf holds
+    ``acc + gradient``, the held experts' made in it in place
+    (``_routed_ffn``)."""
     if spec.family == "solar_open2":
         return _pre_norm_layer(p, x, seg, kind, spec, precision, census)
     mixed, mix_back = _MIXERS[kind](p, x, seg, spec, precision)
@@ -1051,7 +1076,7 @@ def token_layer(p, x, seg, kind, spec, precision, census=None):
     down, down_back = ops.dense(act, p["W_down"], precision)
     y, add2 = ops.residual_norm(hid, down, p["mlp_norm"], spec.rms_norm_eps)
 
-    def back(dy):
+    def back(dy, acc=None, fresh=False):
         grads = {}
         dhid, ddown, grads["mlp_norm"] = add2(dy)
         dact, grads["W_down"] = down_back(ddown)
@@ -1060,14 +1085,14 @@ def token_layer(p, x, seg, kind, spec, precision, census=None):
         dhid_u, grads["W_up"] = up_back(dup)
         dx, dmixed, grads["attn_norm"] = add1(ops.fan_in(dhid, dhid_g, dhid_u))
         dx_mix, mix_grads = mix_back(dmixed)
-        return ops.fan_in(dx, dx_mix), {**grads, **mix_grads}
+        return ops.fan_in(dx, dx_mix), _summed(acc, {**grads, **mix_grads}, fresh)
 
     return y, back
 
 
 def token_loss_and_grads(
     params, spec: TokenModelSpec, tokens, segments, precision, acc=None, census=None,
-    fresh_weights=False,
+    fresh_weights=False, fresh=False,
 ):
     """One microbatch: ``tokens``, ``segments``: (rows, seq_len + 1) int32;
     inputs are ``[:, :-1]``, targets ``[:, 1:]``, every position a target.
@@ -1080,18 +1105,18 @@ def token_loss_and_grads(
     only the head. ``acc`` (shaped like ``params``): returned instead of
     the gradient is ``acc + gradient``, each layer's added as soon as its
     backward has made it, so that no second tree of gradients exists beside
-    the accumulator (a model's worth of memory). ``fresh_weights``: a layer's
-    recomputed forward reads its weights behind the barrier too, so nothing
-    the first forward made of them alone is kept for it. ``census`` (a list): gains,
-    from the first forward only, one (held,) int32 count a routed layer
-    (``_routed_ffn``)."""
+    the accumulator (a model's worth of memory); the held experts'
+    gradients are made in the accumulator itself (``_routed_ffn``).
+    ``fresh`` (a bool, traced or not): ``acc`` is taken as zero, what it
+    holds never read, and the gradient alone returned in it.
+    ``fresh_weights``: a layer's recomputed forward reads its weights
+    behind the barrier too, so nothing the first forward made of them alone
+    is kept for it. ``census`` (a list): gains, from the first forward only,
+    one (held,) int32 count a routed layer (``_routed_ffn``)."""
     embedding, *layers, head = params[0]
 
     def made(index, layer_grads):
-        if acc is None:
-            return layer_grads
-        with scope("acc"):
-            return {k: acc[0][index][k] + g for k, g in layer_grads.items()}
+        return _summed(None if acc is None else acc[0][index], layer_grads, fresh)
 
     with scope("batch"):
         inputs, targets, seg = tokens[:, :-1], tokens[:, 1:], segments[:, :-1]
@@ -1119,7 +1144,7 @@ def token_loss_and_grads(
             else:
                 x_in, dx = lax.optimization_barrier((keep, dx))
             _, keep = token_layer(p, x_in, seg, kind, spec, precision)
-        dx, layer_grads = keep(dx)
-        grads.append(made(index + 1, layer_grads))
+        dx, layer_grads = keep(dx, None if acc is None else acc[0][index + 1], fresh)
+        grads.append(layer_grads)
     grads.append(made(0, {"E": embed_back(dx)}))
     return loss, [grads[::-1]]

@@ -1186,6 +1186,15 @@ def route(x, w, top, normalise=True, scaling=1.0):
     return (weights, sel), back
 
 
+def _add_into(stacked, e, product, reset=False):
+    """``stacked`` with ``product`` added into its slice ``e`` along the
+    first axis (put there in place of what it held where ``reset``, a bool,
+    traced or not), as an update of that slice: in a loop that carries
+    ``stacked`` the compiler writes it in place."""
+    start = stacked[e] if reset is False else jnp.where(reset, 0.0, stacked[e])
+    return lax.dynamic_update_index_in_dim(stacked, start + product, e, 0)
+
+
 def _swiglu_tile(xt, w_gate, w_up, precision):
     gate = jnp.matmul(xt, w_gate.T, precision=precision)
     up = jnp.matmul(xt, w_up.T, precision=precision)
@@ -1209,8 +1218,16 @@ def experts(x, sel, weights, held, w_gate, w_up, w_down, precision=DEFAULT_PRECI
     time follows the rows held through that loop alone. ``ops.dense``'s
     rounding policy (operands to bfloat16 under ``Precision.DEFAULT``).
     -> ``out, back, rows``: ``rows`` (hi - lo,) int32, the pairs routed to
-    each held expert; ``back(dout) -> (dx, dweights, dw_gate, dw_up,
-    dw_down)``, the forward's tiles run again."""
+    each held expert; ``back(dout, acc=None, fresh=False) -> (dx, dweights,
+    dw_gate, dw_up, dw_down)``, the forward's tiles run again. ``acc``: a
+    gradient accumulator's three stacked leaves ``(dw_gate, dw_up,
+    dw_down)``; each tile's products are added into expert ``e``'s slice of
+    them in place, and they come back holding ``acc + gradient``, or the
+    gradient alone where ``fresh`` (a bool, traced or not: what ``acc``
+    holds is then never read; an expert no row reaches runs one empty tile
+    to clear its slice). Without ``acc`` the stacked gradients start from
+    zeros. Either way no expert's gradient is made apart and stacked or
+    added afterwards: a copy of every weight a microbatch."""
     x, dtype = jnp.asarray(x), x.dtype
     tokens, top = sel.shape
     lo, hi = held
@@ -1258,12 +1275,18 @@ def experts(x, sel, weights, held, w_gate, w_up, w_down, precision=DEFAULT_PRECI
     for e in range(n_held):
         out = lax.fori_loop(0, tiles_of(e), partial(forward_tile, e), out)
 
-    def back(dout):
+    def back(dout, acc=None, fresh=False):
         dout = jnp.asarray(dout, dtype)
 
         def backward_tile(e, t, carry):
             dx, dweights, dw_gate, dw_up, dw_down = carry
             pair, token, weight, valid = tile_rows(e, t)
+            # the tile's counter passes a barrier, as the looped token step's
+            # microbatch counter does: the chip's compiler has dropped a
+            # first-trip select on a loop's counter (PERF.md section 6)
+            reset = fresh if fresh is False else jnp.logical_and(
+                fresh, lax.optimization_barrier(t) == 0
+            )
             with scope("moe/route"):
                 xt, dout_t = x_r[token], dout[token]
             with scope("moe/experts"):
@@ -1273,11 +1296,17 @@ def experts(x, sel, weights, held, w_gate, w_up, w_down, precision=DEFAULT_PRECI
                 dweight = jnp.sum(dout_t * down, axis=-1)
                 ddown = rounded(weight[:, None] * dout_t)
                 dact = jnp.matmul(ddown, w_down_r[e], precision=precision)
-                dw_down = dw_down + jnp.matmul(ddown.T, act_r, precision=precision)
+                dw_down = _add_into(
+                    dw_down, e, jnp.matmul(ddown.T, act_r, precision=precision), reset
+                )
                 dgate, dup = jax.vjp(lambda g, u: _silu(g) * u, gate, up)[1](dact)
                 dgate, dup = rounded(dgate), rounded(dup)
-                dw_gate = dw_gate + jnp.matmul(dgate.T, xt, precision=precision)
-                dw_up = dw_up + jnp.matmul(dup.T, xt, precision=precision)
+                dw_gate = _add_into(
+                    dw_gate, e, jnp.matmul(dgate.T, xt, precision=precision), reset
+                )
+                dw_up = _add_into(
+                    dw_up, e, jnp.matmul(dup.T, xt, precision=precision), reset
+                )
                 dxt = jnp.matmul(dgate, w_gate_r[e], precision=precision) + jnp.matmul(
                     dup, w_up_r[e], precision=precision
                 )
@@ -1289,16 +1318,15 @@ def experts(x, sel, weights, held, w_gate, w_up, w_down, precision=DEFAULT_PRECI
 
         with scope("moe/route"):
             dx, dweights = jnp.zeros_like(x), jnp.zeros_like(flat_weights)
-        grads = []
-        for e in range(n_held):
+        dw = acc
+        if dw is None:
             with scope("moe/experts"):
-                zeros = tuple(jnp.zeros_like(w[e]) for w in (w_gate, w_up, w_down))
+                dw = tuple(jnp.zeros_like(w) for w in (w_gate, w_up, w_down))
+        for e in range(n_held):
+            trips = jnp.maximum(tiles_of(e), jnp.asarray(fresh, jnp.int32))
             dx, dweights, *dw = lax.fori_loop(
-                0, tiles_of(e), partial(backward_tile, e), (dx, dweights, *zeros)
+                0, trips, partial(backward_tile, e), (dx, dweights, *dw)
             )
-            grads.append(dw)
-        with scope("moe/experts"):
-            dw_gate, dw_up, dw_down = (jnp.stack(g) for g in zip(*grads))
-        return dx, dweights.reshape(tokens, top), dw_gate, dw_up, dw_down
+        return (dx, dweights.reshape(tokens, top), *dw)
 
     return out, back, rows

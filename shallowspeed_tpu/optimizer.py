@@ -152,7 +152,7 @@ class WithGradScratch:
     as a temporary inside the program it lay between the working set's
     buffers and the 4-layer expert model's step did not fit the chip
     (PERF.md section 6, PR 35). What ``"grads"`` holds between steps is never
-    read: the first microbatch overwrites it."""
+    read: the first microbatch takes it as zero."""
 
     def __init__(self, inner):
         self.inner = inner

@@ -130,6 +130,15 @@ def token_step_is_scanned(mubatches):
     return mubatches > _UNROLLED_MUBATCHES
 
 
+def accumulated_expert_leaves(spec, mubatches):
+    """How many of the held experts' weight-gradient leaves (``W1``, ``W3``,
+    ``W2`` of each routed layer) one step makes in its accumulator itself
+    (``ops.experts``): those of every microbatch that has an accumulator,
+    which the straight-line step's first has not."""
+    with_acc = mubatches if token_step_is_scanned(mubatches) else mubatches - 1
+    return spec.routed_layers * 3 * with_acc
+
+
 def _token_step_unrolled(params, opt_state, spec, xb, yb, precision):
     """A token model's microbatches one after another in ONE straight-line
     program: the first microbatch's gradient IS the accumulator, unzeroed,
@@ -160,24 +169,28 @@ def _token_step_scanned(params, opt_state, spec, xb, yb, precision):
     of the 4-layer expert model took the chip's compiler 4.6 minutes here,
     the loop 1.4). The accumulator is the loop's carry and starts from
     ``opt_state["grads"]`` (``optimizer.WithGradScratch``: what it holds is
-    overwritten by the first microbatch, where it lives is the point). The
-    weights pass an optimization barrier together with the microbatch's rows,
-    and again before a layer's recomputed forward (``fresh_weights``): what a
-    layer makes of its weights alone (``ops.dense``'s rounded copies) is then
-    made where it is used, not once before the loop and kept through it, half
-    a model's worth of memory. The first microbatch's add reads the
-    accumulator once more than the unrolled form does."""
+    never read, where it lives is the point): the first microbatch takes it
+    as zero (``fresh``), its dense leaves' adds and the held experts' first
+    tiles selecting zero in place of what it holds. The flag passes an
+    optimization barrier: written on the loop's own counter as ``where(m >
+    0, acc, 0)``, the select is dropped by the TPU compiler, which then adds
+    the first microbatch onto the scratch's old value (PERF.md section 6). The
+    weights pass an optimization barrier together with the microbatch's
+    rows, and again before a layer's recomputed forward (``fresh_weights``):
+    what a layer makes of its weights alone (``ops.dense``'s rounded copies)
+    is then made where it is used, not once before the loop and kept
+    through it, half a model's worth of memory."""
 
     def microbatch(carry, rows):
         acc, m = carry
         with scope("batch"):
             weights, (tokens, segments) = lax.optimization_barrier((params, rows))
         with scope("acc"):
-            acc = jax.tree.map(lambda a: jnp.where(m > 0, a, 0.0), acc)
+            fresh = lax.optimization_barrier(m) == 0
         routed = []
         loss, acc = token_loss_and_grads(
             weights, spec, tokens, segments, precision, acc=acc, census=routed,
-            fresh_weights=True,
+            fresh_weights=True, fresh=fresh,
         )
         with scope("moe/route"):
             census = jnp.stack(routed) if routed else None

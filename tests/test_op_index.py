@@ -627,3 +627,67 @@ def test_the_kda_kernels_compile_for_the_chip_and_copy_no_operand(v5e_mesh, monk
         if re.search(r"= f32\[1,256,8,128\]\S* (copy|transpose)\(", line)
     ]
     assert not moved, moved
+
+
+# -- the looped token step's first-microbatch flag (trainer._token_step_scanned)
+
+
+def test_a_first_microbatch_select_survives_the_chips_compiler_behind_a_barrier(v5e_mesh):
+    """A scan whose body selects zero for its carry on the first step, the
+    step flag read through an optimization barrier as the looped token step
+    reads it: the select is in the program the chip's compiler makes.
+    Written on the counter itself as ``where(m > 0, a, 0)``, that compiler
+    drops it and adds the first step onto the carry's start (PERF.md
+    section 6). A CPU computes either form right, so only the compiled text
+    is read here."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import SingleDeviceSharding
+
+    def f(init, xs):
+        def body(carry, x):
+            a, m = carry
+            first = lax.optimization_barrier(m) == 0
+            return (jnp.where(first, 0.0, a) + 2.0 * x, m + 1), None
+
+        return lax.scan(body, (init, jnp.zeros((), jnp.int32)), xs)[0][0]
+
+    one_chip = SingleDeviceSharding(v5e_mesh.devices.flat[0])
+    init = jax.ShapeDtypeStruct((1024, 1024), np.float32, sharding=one_chip)
+    xs = jax.ShapeDtypeStruct((8, 1024, 1024), np.float32, sharding=one_chip)
+    text = _compile_off_cache(jax.jit(f).lower(init, xs)).as_text()
+    assert [line for line in text.splitlines() if " select(" in line]
+
+
+def test_the_held_experts_first_tile_keeps_its_select_for_the_chip(v5e_mesh):
+    """``ops.experts``' backward handed an accumulator and a traced
+    ``fresh``, compiled for the chip: each held expert's first tile still
+    selects zero in place of its three accumulator slices, one select a
+    leaf and expert under ``moe/experts``, the tile's counter read through
+    a barrier as the looped step reads its microbatch's."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from shallowspeed_tpu import ops
+
+    held, tokens, top, d, ff = 4, 64, 2, 128, 256
+    one_chip = SingleDeviceSharding(v5e_mesh.devices.flat[0])
+    shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip
+    )
+    leaves = (shape(held, ff, d), shape(held, ff, d), shape(held, d, ff))
+
+    def f(x, sel, weights, w, dout, acc, fresh):
+        _, back, _ = ops.experts(x, sel, weights, (0, held), *w, tile=16)
+        return back(dout, acc, fresh)[2:]
+
+    text = _compile_off_cache(jax.jit(f).lower(
+        shape(tokens, d), shape(tokens, top, dtype=jnp.int32), shape(tokens, top),
+        leaves, shape(tokens, d), leaves, shape(dtype=jnp.bool_),
+    )).as_text()
+    resets = [
+        line for line in text.splitlines()
+        if " select(" in line
+        and re.search(r'op_name="[^"]*moe/experts/[^"]*select', line)
+    ]
+    assert len(resets) == 3 * held, resets

@@ -8,6 +8,7 @@ mixture as a dense loop, and differentiates with ``jax.grad``."""
 import dataclasses
 import importlib.util
 import json
+import re
 from pathlib import Path
 
 import jax
@@ -280,6 +281,72 @@ def test_with_grad_scratch_wraps_any_optimizer():
     assert np.allclose(new[0][0]["W"], 0.8) and state["grads"] is grads
 
 
+def test_the_looped_step_reads_nothing_of_the_scratch_it_is_handed():
+    """The first microbatch takes the accumulator as zero, whatever the
+    scratch held: a stale one and a zero one give the same weights, loss
+    and census, to the bit."""
+    spec = _spec(batch=3)
+    tokens, segments = _tokens(3, seed=3)[:, None], _segments(3, seed=4)[:, None]
+    opt = WithGradScratch(SGD(0.05))
+    zero = opt.init(_params(spec))
+    rng = np.random.default_rng(9)
+    stale = {**zero, "grads": jax.tree.map(
+        lambda g: g + rng.standard_normal(g.shape).astype(np.float32), zero["grads"]
+    )}
+    fresh = _step(spec, _params(spec), opt, zero, tokens, segments)
+    after_stale = _step(spec, _params(spec), opt, stale, tokens, segments)
+    for a, b in zip(jax.tree.leaves(fresh[0]), jax.tree.leaves(after_stale[0])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert float(fresh[2]) == float(after_stale[2])
+    assert np.array_equal(np.asarray(fresh[-1]), np.asarray(after_stale[-1]))
+
+
+def test_two_looped_steps_are_two_unrolled_steps(monkeypatch):
+    """Two steps in one epoch program, the scratch carried from the first to
+    the second: the second step's accumulator starts from zero again, so the
+    weights are those of two steps of the straight-line program, to
+    summation order."""
+    spec = _spec(batch=3)
+    tokens = _tokens(6, seed=5).reshape(2, 3, 1, SEQ + 1)
+    segments = _segments(6, seed=6).reshape(2, 3, 1, SEQ + 1)
+    opt = WithGradScratch(SGD(0.05))
+    epoch = trainer.make_train_epoch(spec, opt, precision=HIGHEST)
+    looped = epoch(_params(spec), opt.init(_params(spec)), tokens, segments)
+    monkeypatch.setattr(trainer, "_UNROLLED_MUBATCHES", 3)
+    unrolled = trainer.make_train_epoch(spec, SGD(0.05), precision=HIGHEST)(
+        _params(spec), (), tokens, segments
+    )
+    for a, b in zip(jax.tree.leaves(looped[0]), jax.tree.leaves(unrolled[0])):
+        _close(a, b, rtol=1e-6)
+    _close(looped[2], unrolled[2], rtol=1e-6)
+    assert np.array_equal(np.asarray(looped[-1]), np.asarray(unrolled[-1]))
+
+
+def test_the_held_experts_gradients_are_made_in_the_accumulator():
+    """The looped step as compiled: no held expert's weight gradient is
+    stacked (``jnp.stack`` under ``moe/experts``) or added to the
+    accumulator afterwards (``acc/add`` of a ``(held, out, in)`` leaf); the
+    tile loops add into the accumulator's slices themselves."""
+    spec = _spec(batch=3)
+    params = _params(spec)
+    opt = WithGradScratch(SGD(0.05))
+    rows = jax.ShapeDtypeStruct((1, 3, 1, SEQ + 1), np.int32)
+    epoch = trainer.make_train_epoch(spec, opt, precision=lax.Precision.DEFAULT)
+    text = epoch.lower(params, opt.init(params), rows, rows).compile().as_text()
+    held, ff, d = params[0][1]["W1"].shape
+    stacked = {f"f32[{held},{ff},{d}]", f"f32[{held},{d},{ff}]"}
+    made = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        shape = re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])", line)
+        if name and shape:
+            made.append((name.group(1), shape.group(1)))
+    assert not [n for n, _ in made if n.endswith("moe/experts/concatenate")]
+    assert not [(n, t) for n, t in made if n.endswith("/acc/add") and t in stacked]
+    updates = [t for n, t in made if n.endswith("experts/dynamic_update_slice")]
+    assert any(t in stacked for t in updates)
+
+
 # -- through TrainingSession --------------------------------------------------
 
 
@@ -329,6 +396,21 @@ def test_the_session_leaves_the_routing_counters_with_the_program(trained):
     assert mean <= counts["moe_load_max"] <= counts["moe_rows_held"]
     assert trained["session"].scan_path == "xla"
     assert isinstance(trained["session"]._opt, WithGradScratch)
+
+
+def test_the_session_counts_the_leaves_made_in_the_accumulator(trained):
+    """4 routed layers x (W1, W3, W2) x 4 microbatches x 1 step."""
+    assert trained["counts"]["acc_inplace_leaf_passes"] == 4 * 3 * 4
+
+
+@pytest.mark.parametrize("mubatches,with_acc", [(8, 8), (3, 3), (2, 1), (1, 0)])
+def test_the_count_follows_the_step_form(mubatches, with_acc):
+    """The looped step makes the held experts' leaves in its accumulator in
+    every microbatch; the straight-line step's first microbatch has no
+    accumulator and makes them from zeros."""
+    spec = _spec()
+    assert spec.routed_layers == 4
+    assert trainer.accumulated_expert_leaves(spec, mubatches) == 4 * 3 * with_acc
 
 
 # heads of 128 channels in eights and rows of whole 64-token chunks: the

@@ -442,6 +442,80 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert(mixture):
     _close(out, _reference_routed({**p, "W_r": w_r}, x, 0, 8, "highest"), rtol=1e-5)
 
 
+def _stacked_accumulator(lo, hi, seed=11):
+    """Stale sums of earlier microbatches, shaped like the held ``W1``,
+    ``W3``, ``W2``."""
+    rng = np.random.default_rng(seed)
+    return tuple(
+        rng.standard_normal((hi - lo, *shape)).astype(np.float32)
+        for shape in ((FF, D), (FF, D), (D, FF))
+    )
+
+
+@pytest.mark.parametrize("tile", [512, 4])
+def test_the_backward_adds_into_the_accumulator_it_is_handed(mixture, tile):
+    """Handed the accumulator's stacked leaves, the backward returns ``acc +
+    gradient`` with ``dx`` and ``dweights`` untouched. With one tile an
+    expert (512 rows) that is the old form's sum to the bit, ``acc + (0 +
+    p)``; with tiles of 4 rows (three or more an expert) the products are
+    added one by one, ``(acc + p1) + p2``: summation order alone."""
+    p, (lo, hi) = mixture, (2, 9)
+    _, back, _, rows, _ = _system_routed(p, p["x"], lo, hi, lax.Precision.DEFAULT, tile)
+    tiles = -(-np.asarray(rows) // tile)
+    assert tiles.max() == 1 if tile == 512 else tiles.min() >= 3
+    acc = _stacked_accumulator(lo, hi)
+    alone = back(p["dout"])
+    into = back(p["dout"], tuple(map(jnp.asarray, acc)))
+    for a, b in zip(into[:2], alone[:2]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    for got, a, g in zip(into[2:], acc, alone[2:]):
+        want = a + np.asarray(g)
+        if tile == 512:
+            assert np.array_equal(np.asarray(got), want)
+        else:
+            _close(got, want, rtol=1e-6)
+
+
+def _idle_experts_case(mixture):
+    """All 96 tokens on experts 3, 7, 8 and 9: of the held 0 to 7, experts 0,
+    1, 2, 4, 5 and 6 take no row."""
+    p = mixture
+    w_r = np.zeros_like(p["W_r"])
+    w_r[[3, 7, 8, 9]] = 1.0
+    x = np.abs(p["x"])  # every score of a positive row is above one half
+    _, back, _, rows, _ = _system_routed({**p, "W_r": w_r}, x, 0, 8, HIGHEST)
+    idle = np.flatnonzero(np.asarray(rows) == 0)
+    assert idle.tolist() == [0, 1, 2, 4, 5, 6]
+    return back, idle
+
+
+def test_an_expert_no_row_reaches_leaves_its_accumulator_slice_untouched(mixture):
+    """The idle experts' slices come back as handed in, to the bit; the two
+    that work add their gradient."""
+    back, idle = _idle_experts_case(mixture)
+    acc = _stacked_accumulator(0, 8)
+    alone = back(mixture["dout"])
+    into = back(mixture["dout"], tuple(map(jnp.asarray, acc)))
+    for got, a, g in zip(into[2:], acc, alone[2:]):
+        got = np.asarray(got)
+        assert np.array_equal(got[idle], a[idle])
+        _close(got[[3, 7]], a[[3, 7]] + np.asarray(g)[[3, 7]], rtol=1e-6)
+        assert not np.array_equal(got[[3, 7]], a[[3, 7]])
+
+
+@pytest.mark.parametrize("fresh", [True, jnp.asarray(True)])
+def test_a_fresh_accumulator_is_read_as_zero(mixture, fresh):
+    """``fresh`` (a Python or a traced bool): what the accumulator holds is
+    never read, the idle experts' slices among it, and every output is the
+    backward's without an accumulator, to the bit."""
+    back, _ = _idle_experts_case(mixture)
+    acc = _stacked_accumulator(0, 8)
+    alone = back(mixture["dout"])
+    into = back(mixture["dout"], tuple(map(jnp.asarray, acc)), fresh)
+    for got, want in zip(into, alone):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("shares", [4, 2, 16])
 def test_the_shares_add_up_to_the_uncut_layer(mixture, shares):
     """The share test: every share of the experts computes its own part of

@@ -273,6 +273,7 @@ def test_session_leaves_the_sets_counts_with_the_program(token_set, trained):
     counts = dict(scopes.program_counts("jit_epoch_core"))
     assert counts.pop("scan_kernel_calls") == 0  # 6 and 12 wide: the XLA form
     assert counts.pop("recomputed_layer_passes") == 0  # 96 tokens keep every layer
+    assert counts.pop("acc_inplace_leaf_passes") == 0  # no routed layer
     assert counts == packed_counts(token_set[2]) == ref.packed_counts(token_set[2])
     assert counts["tokens"] == 8 * SEQ
     assert counts["documents"] <= counts["tokens"] <= counts["pairs"]
