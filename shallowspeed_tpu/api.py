@@ -619,6 +619,7 @@ class TrainingSession:
                 self._scan_path = scan_plan["path"]
                 self._token_counts.update(
                     scan_kernel_calls=scan_plan["kernel_calls_per_step"] * nb,
+                    scan_pairs_read=scan_plan["pairs_read_per_step"] * nb,
                     recomputed_layer_passes=(
                         scan_plan["recomputed_layers"] * mubatches * nb
                     ),

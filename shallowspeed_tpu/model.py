@@ -558,9 +558,11 @@ def token_scan_plan(spec: "TokenModelSpec", mubatches):
     the kernel launches one optimizer step makes: microbatches x the scan
     layers' passes (a forward, the forward again where the layer is
     recomputed, a backward; none where the XLA form runs), and how many
-    layers of any kind are recomputed (``spec.recomputed``). Each rule has
-    a kernel form where its shapes tile, and the op's own rule says where:
-    the Gated DeltaNet's ``ops.scan_path``, the per-channel rule's
+    layers of any kind are recomputed (``spec.recomputed``), and the
+    backward launches that read the pair matrices their forward kept (the
+    per-channel rule's kernels: one a scan layer and microbatch). Each rule
+    has a kernel form where its shapes tile, and the op's own rule says
+    where: the Gated DeltaNet's ``ops.scan_path``, the per-channel rule's
     ``ops.kda_scan_path`` (whose kernels choose their chunk). -> the
     ``scan_path`` event's fields."""
     chunk = ops._block_len(spec.seq_len, spec.scan_chunk)
@@ -583,6 +585,9 @@ def token_scan_plan(spec: "TokenModelSpec", mubatches):
         "d_v": spec.linear_value_head_dim,
         "kernel_calls_per_step": mubatches * passes if path == "pallas" else 0,
         "recomputed_layers": sum(spec.recomputed),
+        "pairs_read_per_step": (
+            mubatches * spec.layer_types.count("kda") if path == "pallas" else 0
+        ),
     }
 
 

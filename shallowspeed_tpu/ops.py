@@ -1018,8 +1018,10 @@ def _kda_scan_pallas(q, k, v, beta, log_decay, seg, precision, chunk=KDA_KERNEL_
     """``kda_scan`` through the kernels ``pallas_ops.kda_scan_fwd`` /
     ``_bwd``, which read ``q, k, v`` and the log decay where they lie (a
     block is a chunk of 8 heads, a head a sublane of each token's tile) and
-    make the running sum of the log decay, and its pull-back, themselves.
-    Left in XLA, under the same scope: the document masks, 8 rows a chunk,
+    make the running sum of the log decay, and its pull-back, themselves;
+    the backward reads the state entering each chunk, each chunk's inverse
+    and its two pair matrices as the forward kept them. Left in XLA, under
+    the same scope: the document masks, 8 rows a chunk,
     and each head's ``beta`` as a row a chunk, and ``dbeta`` taken out of
     the same."""
     from shallowspeed_tpu import pallas_ops as K
@@ -1048,14 +1050,14 @@ def _kda_scan_pallas(q, k, v, beta, log_decay, seg, precision, chunk=KDA_KERNEL_
             return betas.reshape(rows * groups, n, K.KDA_ROWS, c)
 
         betas, pull_beta = jax.vjp(by_group, beta)
-        o, states, inverses = _kda_kernel_fwd(
+        o, states, inverses, pairs = _kda_kernel_fwd(
             q, k, v, log_decay, p, betas, leaf=min(INVERSE_LEAF, c), **static
         )
 
     def back(do):
         with scope("kda/scan"):
             dq, dk, dv, dg, dbetas = _kda_kernel_bwd(
-                q, k, v, log_decay, p, betas, states, inverses, do, **static
+                q, k, v, log_decay, p, betas, states, inverses, pairs, do, **static
             )
             (dbeta,) = pull_beta(dbetas)
             return dq, dk, dv, dbeta, dg

@@ -1201,13 +1201,14 @@ def gdn_scan_bwd(q, k, v, p, states, inverses, do, *, precision):
 # triangle pulls the cotangent back); a chunk's pair matrices ``sum_d x_i[d]
 # k_j[d] exp(g_i[d] - g_j[d])`` are built in VMEM by sub-blocks that are
 # halved until one token is left (``_kda_levels``), every exponent at most
-# zero; the state is kept transposed, (d_v x d_k), so that the decay of a
-# key channel is a broadcast along the lanes. The arrays per token are read
-# and written where the model keeps them, (rows, seq, heads, d): a block is
-# one chunk of 8 heads, a head one sublane of each token's (8, 128) tile, so
-# nothing is transposed in XLA. The heads of a block are worked a few side
-# by side (``KDA_SIDE_BY_SIDE``): a chunk is a chain of small products that
-# each wait for the one before, and another head's fill the waits.
+# zero, and handed to the backward, which builds none; the state is kept
+# transposed, (d_v x d_k), so that the decay of a key channel is a
+# broadcast along the lanes. The arrays per token are read and written
+# where the model keeps them, (rows, seq, heads, d): a block is one chunk of
+# 8 heads, a head one sublane of each token's (8, 128) tile, so nothing is
+# transposed in XLA. The heads of a block are worked a few side by side
+# (``KDA_SIDE_BY_SIDE``): a chunk is a chain of small products that each
+# wait for the one before, and another head's fill the waits.
 # ---------------------------------------------------------------------------
 
 # rows of the packed block (8, chunk) of one chunk of one row: the document
@@ -1292,12 +1293,14 @@ def _kda_levels(g, i, j):
     return level_of, levels
 
 
-def _kda_chunk(q, k, gl, p, beta, dot, top):
-    """What both passes build of one chunk of one head before the inverse.
-    ``gl``: (c, d_k) the log decay, ``p``: (8, c) the packed rows, ``beta``:
-    (c, 1). ``top`` is the float32-pass product: a running sum stands for
-    exact additions."""
-    c = k.shape[0]
+def _kda_decays(gl, p, top):
+    """What both passes build of one chunk of one head from its log decay
+    ``gl`` (c, d_k) and the packed rows ``p`` (8, c) alone: the masks, the
+    running sum of the decay (one product, ``top``, the float32-pass one: it
+    stands for exact additions) and the factors made from it, each level's
+    among them. The backward builds this and reads the pair matrices the
+    forward kept."""
+    c = gl.shape[0]
     i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     cols = p.T  # (c, 8): the rows as columns
@@ -1307,25 +1310,35 @@ def _kda_chunk(q, k, gl, p, beta, dot, top):
     # a document's first token takes no decay: its state starts from zero
     g = top((i >= j).astype(gl.dtype), jnp.where(first, 0.0, gl))
     level_of, levels = _kda_levels(g, i, j)
+    g_in = jnp.where(col(KDA_CARRIED) > 0, jnp.exp(g), 0.0)
+    g_out = jnp.where(col(KDA_TO_LAST) > 0, jnp.exp(g[c - 1 :] - g), 0.0)
+    return dict(
+        i=i, j=j, first=first, lower=lower, level_of=level_of, levels=levels,
+        g_in=g_in, g_out=g_out, keep=g_in[c - 1 :],
+    )
+
+
+def _kda_chunk(q, k, gl, p, beta, dot, top):
+    """``_kda_decays`` and the chunk's two pair matrices, which the forward
+    builds and hands on: ``kk``, the strictly lower decayed ``k k^T``, and
+    ``m``, ``q k^T`` with its diagonal. ``beta``: (c, 1)."""
+    x = _kda_decays(gl, p, top)
+    c = k.shape[0]
     # [k k^T; q k^T] with the decay inside, a level at a time: a coarser
     # level overwrites what a finer one left of its pairs
-    level_of2 = jnp.concatenate([level_of, level_of], axis=0)
+    level_of2 = jnp.concatenate([x["level_of"], x["level_of"]], axis=0)
     pairs = jnp.zeros((2 * c, c), k.dtype)
-    for level, (e, _) in enumerate(levels, 1):
+    for level, (e, _) in enumerate(x["levels"], 1):
         k_e, (rows, back) = k * e, _kda_halves(2 ** (level - 1), c)
         both = dot(jnp.concatenate([rows(k_e, 1), rows(q * e, 1)], axis=0), k_e, _NT)
         half = both.shape[0] // 2
         both = jnp.concatenate([back(both[:half], 1), back(both[half:], 1)], axis=0)
         pairs = jnp.where(level_of2 >= level, both, pairs)
+    i, j, lower = x["i"], x["j"], x["lower"]
     kk = jnp.where(lower, pairs[:c], 0.0)
     qk = jnp.where(lower, pairs[c:], 0.0)
     qk = qk + jnp.where(i == j, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-    g_in = jnp.where(col(KDA_CARRIED) > 0, jnp.exp(g), 0.0)
-    g_out = jnp.where(col(KDA_TO_LAST) > 0, jnp.exp(g[c - 1 :] - g), 0.0)
-    return dict(
-        i=i, j=j, first=first, lower=lower, level_of=level_of, levels=levels,
-        kk=kk, a=beta * kk, m=qk, g_in=g_in, g_out=g_out, keep=g_in[c - 1 :],
-    )
+    return dict(x, kk=kk, a=beta * kk, m=qk)
 
 
 def _kda_side_by_side(ref, body):
@@ -1339,17 +1352,16 @@ def _kda_side_by_side(ref, body):
     )
 
 
-def _kda_operands(refs, p_ref, b_ref, heads, dot, top):
+def _kda_operands(refs, p_ref, b_ref, heads):
     """Of each of ``heads``: -> ``at`` (its index in a block (1, c, heads, d)
-    of an array per token), ``beta`` (c, 1), ``q, k, v`` and ``_kda_chunk``'s
-    values, a list each."""
+    of an array per token), ``beta`` (c, 1) and ``q, k, v`` and the log
+    decay, a list each; and the packed rows (8, c)."""
     p, betas = p_ref[0, 0], b_ref[0, 0].T  # (8, c), (c, 8)
     head_of = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
     at = [(0, slice(None), h, slice(None)) for h in heads]
     beta = [jnp.sum(jnp.where(head_of == h, betas, 0.0), axis=1, keepdims=True) for h in heads]
     q, k, v, gl = ([ref[a] for a in at] for ref in refs)
-    x = [_kda_chunk(q[n], k[n], gl[n], p, beta[n], dot, top) for n in range(len(heads))]
-    return at, beta, q, k, v, x
+    return at, beta, q, k, v, gl, p
 
 
 def _kda_corrected(t, beta, k, v, g_in, s, dot):
@@ -1362,7 +1374,7 @@ def _kda_corrected(t, beta, k, v, g_in, s, dot):
 
 
 def _kda_fwd_kernel(
-    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, o_ref, s_ref, t_ref, state, *,
+    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, o_ref, s_ref, t_ref, r_ref, state, *,
     leaf, precision,
 ):
     dot, top = _gdn_dot(precision), _gdn_dot(jax.lax.Precision.HIGHEST)
@@ -1372,10 +1384,11 @@ def _kda_fwd_kernel(
         state[...] = jnp.zeros_like(state)
 
     def several(heads):
-        at, beta, q, k, v, x = _kda_operands(
-            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads, dot, top
+        at, beta, q, k, v, gl, p = _kda_operands(
+            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads
         )
         each = range(len(heads))
+        x = [_kda_chunk(q[n], k[n], gl[n], p, beta[n], dot, top) for n in each]
         g_in = [c["g_in"] for c in x]
         # float32 passes whatever ``precision``, as ``ops._unit_lower_inverse``
         t = _gdn_inverses([c["a"] for c in x], x[0]["i"], x[0]["j"], leaf, top)
@@ -1387,6 +1400,7 @@ def _kda_fwd_kernel(
         for n, h in enumerate(heads):
             s_ref[h, 0] = s[n]
             t_ref[h] = t[n]
+            r_ref[h] = jnp.concatenate([x[n]["kk"], x[n]["m"]], axis=1)
             o_ref[at[n]] = o[n]
             state[h] = s[n] * x[n]["keep"] + left[n]
 
@@ -1394,7 +1408,7 @@ def _kda_fwd_kernel(
 
 
 def _kda_bwd_kernel(
-    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, s_ref, t_ref, do_ref,
+    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, s_ref, t_ref, r_ref, do_ref,
     dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, precision,
 ):
     dot, top = _gdn_dot(precision), _gdn_dot(jax.lax.Precision.HIGHEST)
@@ -1409,17 +1423,21 @@ def _kda_bwd_kernel(
     db_ref[...] = jnp.zeros_like(db_ref)
 
     def several(heads):
-        at, beta, q, k, v, x = _kda_operands(
-            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads, dot, top
+        at, beta, q, k, v, gl, p = _kda_operands(
+            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads
         )
         each = range(len(heads))
+        x = [_kda_decays(gl[n], p, top) for n in each]
         i, j, c = x[0]["i"], x[0]["j"], k[0].shape[0]
         g_in, g_out, keep = ([x[n][name] for n in each] for name in ("g_in", "g_out", "keep"))
+        # the pair matrices the forward built, [kk | m] side by side
+        pairs = [r_ref[h] for h in heads]
+        kk, m = [r[:, :c] for r in pairs], [r[:, c:] for r in pairs]
         s, t = [s_ref[h, 0] for h in heads], [t_ref[h] for h in heads]
         do, ds_out = [do_ref[a] for a in at], [dstate[h] for h in heads]
         u0, w, u = _kda_corrected(t, beta, k, v, g_in, s, dot)  # the forward's again
         # O = (q g_in) S + M U;  S' = S keep + U^T (k g_out), S as (d_v, d_k)
-        du = [dot(x[n]["m"], do[n], _TN) for n in each]
+        du = [dot(m[n], do[n], _TN) for n in each]
         du = [du[n] + dot(k[n] * g_out[n], ds_out[n], _NT) for n in each]
         dm = [dot(do[n], u[n], _NT) for n in each]
         dq_in = [dot(do[n], s[n]) for n in each]
@@ -1452,7 +1470,7 @@ def _kda_bwd_kernel(
             dqk.append(jnp.where(x[n]["lower"], dm[n], 0.0))
             dqk_t.append(dqk[n].T)
             dv_ref[at[n]] = beta[n] * dr_v[n]
-            dbeta = lanes(da[n] * x[n]["kk"]) + lanes(dr_v[n] * v[n])
+            dbeta = lanes(da[n] * kk[n]) + lanes(dr_v[n] * v[n])
             dbeta = dbeta + lanes(dr_k[n] * k[n] * g_in[n])
             # the column, as row ``h`` of the block
             db_ref[0, 0] += jnp.where(j == h, dbeta, 0.0).T[:KDA_ROWS]
@@ -1518,9 +1536,10 @@ def _kda_operand_specs(q, v, p, chunk_of, interpret):
     return dict(
         qk=in_place(dk), v=in_place(dv), betas=of_group(KDA_ROWS, c),
         p=pl.BlockSpec((1, 1, KDA_ROWS, c), lambda i, j: (i // groups, chunk_of(j), 0, 0)),
-        states=per_chunk(dv, dk), inverses=tokens(c),
+        states=per_chunk(dv, dk), inverses=tokens(c), pairs=tokens(2 * c),
         states_shape=jax.ShapeDtypeStruct((rows * heads, n, dv, dk), q.dtype),
         inverses_shape=jax.ShapeDtypeStruct((rows * heads, seq, c), q.dtype),
+        pairs_shape=jax.ShapeDtypeStruct((rows * heads, seq, 2 * c), q.dtype),
         call=dict(
             grid=(rows * groups, n),
             scratch_shapes=[pltpu.VMEM((per, dv, dk), jnp.float32)],
@@ -1540,31 +1559,36 @@ def kda_scan_fwd(q, k, v, g, p, betas, *, leaf, precision, interpret):
     of each group of ``kda_heads_per_step`` heads; ``interpret``: what
     ``_interpret()`` says (the caller's, which keys a trace by it). -> ``o``
     shaped like ``v``, the state entering each chunk (rows * heads, n, d_v,
-    d_k) and each chunk's inverse (rows * heads, seq, c)."""
+    d_k), each chunk's inverse (rows * heads, seq, c) and its two pair
+    matrices side by side, ``[kk | m]`` (rows * heads, seq, 2 c): at the
+    kernels' chunk of 64 one lane-dense tile a chunk and head, where the two
+    apart would each be padded to 128 lanes."""
     x = _kda_operand_specs(q, v, p, lambda j: j, interpret)
     return pl.pallas_call(
         functools.partial(_kda_fwd_kernel, leaf=leaf, precision=precision),
         in_specs=[x["qk"], x["qk"], x["v"], x["qk"], x["p"], x["betas"]],
-        out_specs=[x["v"], x["states"], x["inverses"]],
+        out_specs=[x["v"], x["states"], x["inverses"], x["pairs"]],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, q.dtype), x["states_shape"], x["inverses_shape"],
+            x["pairs_shape"],
         ],
         name="kda_scan_fwd",
         **x["call"],
     )(q, k, v, g, p, betas)
 
 
-def kda_scan_bwd(q, k, v, g, p, betas, states, inverses, do, *, precision, interpret):
-    """The pull-back of ``kda_scan_fwd``'s ``o``: -> ``dq, dk, dv, dg``
-    shaped like ``q, k, v, g`` (``dg`` of the log decay itself: the running
-    sum is pulled back in the kernel) and ``dbetas`` shaped like ``betas``."""
+def kda_scan_bwd(q, k, v, g, p, betas, states, inverses, pairs, do, *, precision, interpret):
+    """The pull-back of ``kda_scan_fwd``'s ``o``, from the states, inverses
+    and pair matrices it kept: -> ``dq, dk, dv, dg`` shaped like ``q, k, v,
+    g`` (``dg`` of the log decay itself: the running sum is pulled back in
+    the kernel) and ``dbetas`` shaped like ``betas``."""
     n = p.shape[1]
     x = _kda_operand_specs(q, v, p, lambda j: n - 1 - j, interpret)
     return pl.pallas_call(
         functools.partial(_kda_bwd_kernel, precision=precision),
         in_specs=[
             x["qk"], x["qk"], x["v"], x["qk"], x["p"], x["betas"], x["states"],
-            x["inverses"], x["v"],
+            x["inverses"], x["pairs"], x["v"],
         ],
         out_specs=[x["qk"], x["qk"], x["v"], x["qk"], x["betas"]],
         out_shape=[
@@ -1576,4 +1600,4 @@ def kda_scan_bwd(q, k, v, g, p, betas, states, inverses, do, *, precision, inter
         ],
         name="kda_scan_bwd",
         **x["call"],
-    )(q, k, v, g, p, betas, states, inverses, do)
+    )(q, k, v, g, p, betas, states, inverses, pairs, do)

@@ -182,6 +182,7 @@ def test_layer_kinds_shapes_and_the_family_switch():
         assert layer["W1"][0] == (4, 24, 32) and layer["W2"][0] == (4, 32, 24)
     plan = Mo.token_scan_plan(spec, 2)
     assert plan["path"] == "xla" and plan["kernel_calls_per_step"] == 0
+    assert plan["pairs_read_per_step"] == 0
     assert plan["recomputed_layers"] == 3 and spec.recomputed == (True, True, True, False)
 
 
@@ -395,6 +396,7 @@ def test_the_session_leaves_the_routing_counters_with_the_program(trained):
     mean = counts["moe_rows_held"] / (4 * 4)
     assert mean <= counts["moe_load_max"] <= counts["moe_rows_held"]
     assert trained["session"].scan_path == "xla"
+    assert counts["scan_pairs_read"] == 0  # the XLA form keeps no pairs
     assert isinstance(trained["session"]._opt, WithGradScratch)
 
 
@@ -455,6 +457,8 @@ def test_shapes_that_tile_run_the_kernels_and_the_session_says_so(on_kernels):
     # an epoch of one step
     assert on_kernels["counts"]["scan_kernel_calls"] == calls
     assert on_kernels["counts"]["recomputed_layer_passes"] == recomputed * 2
+    # the kda layer's backward reads its forward's pairs, once a microbatch
+    assert event["pairs_read_per_step"] == on_kernels["counts"]["scan_pairs_read"] == 2
 
 
 def test_one_step_on_the_kernels_is_the_references(on_kernels):
@@ -487,6 +491,24 @@ def test_the_named_models_plan_by_shape(seq, batch, mubatches, want):
     spec = Mo.make_token_spec(config, seq, batch, mubatch_rows=batch // mubatches)
     plan = Mo.token_scan_plan(spec, mubatches)
     assert {k: plan[k] for k in want} == want and plan["d_k"] == plan["d_v"] == 128
+
+
+@pytest.mark.parametrize(
+    "model,seq,batch,mubatches,want",
+    [
+        # the cell: 3 kda layers x 8 microbatches, each backward reading the
+        # pair matrices its forward kept
+        ("solar-open2-250b", 2048, 8, 8, 24),
+        # the scalar rule's kernels keep no pair matrix
+        ("olmo-hybrid-7b", 8192, 2, 2, 0),
+    ],
+)
+def test_the_backwards_that_read_the_kept_pairs(model, seq, batch, mubatches, want):
+    spec = Mo.make_token_spec(
+        Mo.token_model_config(model), seq, batch, mubatch_rows=batch // mubatches
+    )
+    plan = Mo.token_scan_plan(spec, mubatches)
+    assert plan["path"] == "pallas" and plan["pairs_read_per_step"] == want
 
 
 def test_scopes_classes_of_the_new_work():

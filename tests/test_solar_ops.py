@@ -6,13 +6,16 @@ loop over experts. Tiny sizes, a CPU, float32 passes."""
 
 import functools
 import importlib.util
+import itertools
 from pathlib import Path
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
+from jax.experimental import pallas as pl
 
 from shallowspeed_tpu import ops
 
@@ -167,7 +170,9 @@ KERNEL_CASES = {
 @functools.lru_cache(maxsize=None)
 def _kernel_run(case):
     """``{form: {output: array}}`` for the kernels, the XLA form and the
-    token-by-token rule on one case's inputs. One run per case."""
+    token-by-token rule on one case's inputs, and under ``"calls"`` what
+    each kernel was handed and gave back, ``{"fwd" | "bwd": (operands,
+    static, outputs)}``. One run per case."""
     rows, seq, chunk, heads, dk, dv, decay, starts = KERNEL_CASES[case]
     rng = np.random.default_rng(7)
     f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
@@ -182,13 +187,26 @@ def _kernel_run(case):
     if decay > 10:
         assert np.cumsum(log_decay, axis=1)[:, chunk - 1].min() < -200
     args = (q, k, v, beta, log_decay)
-    runs = {}
-    for form, fn in (
-        ("kernels", lambda *a: ops._kda_scan_pallas(*a, seg, HIGHEST, chunk=chunk)),
-        ("xla", lambda *a: ops._kda_scan_xla(*a, seg, HIGHEST, chunk, 2, 4)),
+    calls = {}
+
+    def recorded(name, kernel):
+        def call(*operands, **static):
+            calls[name] = (operands, static, kernel(*operands, **static))
+            return calls[name][2]
+
+        return call
+
+    runs = {"calls": calls}
+    with (
+        mock.patch.object(ops, "_kda_kernel_fwd", recorded("fwd", ops._kda_kernel_fwd)),
+        mock.patch.object(ops, "_kda_kernel_bwd", recorded("bwd", ops._kda_kernel_bwd)),
     ):
-        o, back = fn(*args)
-        runs[form] = dict(zip(KERNEL_OUTPUTS, (o, *back(do))))
+        for form, fn in (
+            ("kernels", lambda *a: ops._kda_scan_pallas(*a, seg, HIGHEST, chunk=chunk)),
+            ("xla", lambda *a: ops._kda_scan_xla(*a, seg, HIGHEST, chunk, 2, 4)),
+        ):
+            o, back = fn(*args)
+            runs[form] = dict(zip(KERNEL_OUTPUTS, (o, *back(do))))
     grads = jax.grad(lambda *a: jnp.sum(_recurrence(*a, seg) * do), (0, 1, 2, 3, 4))(*args)
     runs["rule"] = dict(zip(KERNEL_OUTPUTS, (_recurrence(*args, seg), *grads)))
     return runs
@@ -202,6 +220,198 @@ def test_kda_kernels_are_the_rule_and_the_xla_form(case, oracle, output):
     got = np.asarray(runs["kernels"][output])
     assert np.all(np.isfinite(got))
     _close(got, runs[oracle][output], rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_forward_keeps_each_chunks_pair_matrices(case):
+    """The forward's fourth output is ``[kk | m]`` of every head and chunk,
+    each as ``_kda_chunk`` builds it, masks included: to the bit but for
+    ``m``'s diagonal, a sum over the channels that XLA's CPU backend orders
+    by the program around it (one rounding apart, here or in a kernel)."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    (q, k, _, g, p, _), _, (*_, pairs) = _kernel_run(case)["calls"]["fwd"]
+    rows, seq, heads, _ = q.shape
+    n, c = p.shape[1], p.shape[-1]
+    assert pairs.shape == (rows * heads, seq, 2 * c)
+    dot = K._gdn_dot(HIGHEST)
+    built = jax.jit(lambda *a: K._kda_chunk(*a, jnp.ones((c, 1)), dot, dot))
+    off = ~np.eye(c, dtype=bool)
+    for r, h, j in itertools.product(range(rows), range(heads), range(n)):
+        at = slice(j * c, (j + 1) * c)
+        x = built(q[r, at, h], k[r, at, h], g[r, at, h], p[r, j])
+        kk, m = np.split(np.asarray(pairs[r * heads + h, at]), 2, axis=1)
+        assert np.array_equal(kk, x["kk"]), (r, h, j)
+        assert np.array_equal(m[off], np.asarray(x["m"])[off]), (r, h, j)
+        _close(np.diag(m), np.diag(x["m"]), rtol=1e-6)
+
+
+def _rebuilding_bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, p_ref, b_ref, s_ref, t_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, precision,
+):
+    """``pallas_ops._kda_bwd_kernel`` as it was before the forward kept the
+    pair matrices: it builds them again, every level's product, with
+    ``_kda_chunk``. The reference the kernel that reads them is held to."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    dot, top = K._gdn_dot(precision), K._gdn_dot(HIGHEST)
+    _NT, _TN = K._NT, K._TN
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    def lanes(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    db_ref[...] = jnp.zeros_like(db_ref)
+
+    def several(heads):
+        at, beta, q, k, v, gl, p = K._kda_operands(
+            (q_ref, k_ref, v_ref, g_ref), p_ref, b_ref, heads
+        )
+        each = range(len(heads))
+        x = [K._kda_chunk(q[n], k[n], gl[n], p, beta[n], dot, top) for n in each]
+        i, j, c = x[0]["i"], x[0]["j"], k[0].shape[0]
+        g_in, g_out, keep = ([x[n][name] for n in each] for name in ("g_in", "g_out", "keep"))
+        s, t = [s_ref[h, 0] for h in heads], [t_ref[h] for h in heads]
+        do, ds_out = [do_ref[a] for a in at], [dstate[h] for h in heads]
+        u0, w, u = K._kda_corrected(t, beta, k, v, g_in, s, dot)
+        du = [dot(x[n]["m"], do[n], _TN) for n in each]
+        du = [du[n] + dot(k[n] * g_out[n], ds_out[n], _NT) for n in each]
+        dm = [dot(do[n], u[n], _NT) for n in each]
+        dq_in = [dot(do[n], s[n]) for n in each]
+        dk_out = [dot(u[n], ds_out[n]) for n in each]
+        dw = [-dot(du[n], s[n]) for n in each]
+        ds_in = [dot(do[n], q[n] * g_in[n], _TN) for n in each]
+        ds_in = [ds_in[n] - dot(du[n], w[n], _TN) for n in each]
+        dr_v = [dot(t[n], du[n], _TN) for n in each]
+        dr_k = [dot(t[n], dw[n], _TN) for n in each]
+        da = [dot(dr_v[n], u0[n], _NT) for n in each]
+        da = [jnp.where(x[n]["lower"], -(da[n] + dot(dr_k[n], w[n], _NT)), 0.0) for n in each]
+        dq, dk, dg, sym, dqk, dqk_t = [], [], [], [], [], []
+        for n, h in enumerate(heads):
+            dstate[h] = ds_out[n] * keep[n] + ds_in[n]
+            dkeep = jnp.sum(ds_out[n] * s[n], axis=0, keepdims=True)
+            on_diagonal = lanes(jnp.where(i == j, dm[n], 0.0))
+            dq.append(dq_in[n] * g_in[n] + on_diagonal * k[n])
+            dk.append(
+                dk_out[n] * g_out[n] + (beta[n] * g_in[n]) * dr_k[n] + on_diagonal * q[n]
+            )
+            leaving = (dk_out[n] * k[n]) * g_out[n]
+            last = jnp.sum(leaving, axis=0, keepdims=True) + dkeep * keep[n]
+            through = (dq_in[n] * q[n] + beta[n] * dr_k[n] * k[n]) * g_in[n] - leaving
+            dg.append(through + jnp.where(i[:, :1] == c - 1, last, 0.0))
+            dkk = da[n] * beta[n]
+            sym.append(dkk + dkk.T)
+            dqk.append(jnp.where(x[n]["lower"], dm[n], 0.0))
+            dqk_t.append(dqk[n].T)
+            dv_ref[at[n]] = beta[n] * dr_v[n]
+            dbeta = lanes(da[n] * x[n]["kk"]) + lanes(dr_v[n] * v[n])
+            dbeta = dbeta + lanes(dr_k[n] * k[n] * g_in[n])
+            db_ref[0, 0] += jnp.where(j == h, dbeta, 0.0).T[: K.KDA_ROWS]
+        for level in range(1, len(x[0]["levels"]) + 1):
+            here = x[0]["level_of"] == level
+            e = [x[n]["levels"][level - 1][0] for n in each]
+            k_e = [k[n] * e[n] for n in each]
+            q_e = [q[n] * e[n] for n in each]
+            rows, back = K._kda_halves(2 ** (level - 1), c)
+            both = [
+                dot(
+                    jnp.concatenate(
+                        [rows(jnp.where(here, dqk[n], 0.0), 1), jnp.where(here, sym[n], 0.0)],
+                        axis=0,
+                    ),
+                    k_e[n],
+                )
+                for n in each
+            ]
+            as_i, of_kk = [back(m[:-c], 1) for m in both], [m[-c:] for m in both]
+            as_j = [
+                back(dot(rows(jnp.where(here, dqk_t[n], 0.0), 0), q_e[n]), 0) for n in each
+            ]
+            for n in each:
+                upper = x[n]["levels"][level - 1][1]
+                dx = as_i[n] * e[n]
+                dk_level = (of_kk[n] + as_j[n]) * e[n]
+                dq[n] = dq[n] + dx
+                dk[n] = dk[n] + dk_level
+                signed = jnp.where(upper, k[n] * dk_level, -(k[n] * dk_level))
+                dg[n] = dg[n] + q[n] * dx + signed
+        pulled = [top((i <= j).astype(dg[n].dtype), dg[n]) for n in each]
+        for n in each:
+            dq_ref[at[n]] = dq[n]
+            dk_ref[at[n]] = dk[n]
+            dg_ref[at[n]] = jnp.where(x[n]["first"], 0.0, pulled[n])
+
+    K._kda_side_by_side(dstate, several)
+
+
+@functools.partial(jax.jit, static_argnames=("precision", "interpret"))
+def _rebuilding_scan_bwd(q, k, v, g, p, betas, states, inverses, do, *, precision, interpret):
+    """``pallas_ops.kda_scan_bwd`` on ``_rebuilding_bwd_kernel``: no pairs in."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    n = p.shape[1]
+    x = K._kda_operand_specs(q, v, p, lambda j: n - 1 - j, interpret)
+    return pl.pallas_call(
+        functools.partial(_rebuilding_bwd_kernel, precision=precision),
+        in_specs=[
+            x["qk"], x["qk"], x["v"], x["qk"], x["p"], x["betas"], x["states"],
+            x["inverses"], x["v"],
+        ],
+        out_specs=[x["qk"], x["qk"], x["v"], x["qk"], x["betas"]],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, q.dtype) for a in (q, k, v, g, betas)],
+        **x["call"],
+    )(q, k, v, g, p, betas, states, inverses, do)
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_backward_on_the_kept_pairs_is_the_rebuilding_backward_to_the_bit(case):
+    """``dq, dk, dv, dg`` of the backward that reads the forward's pair
+    matrices equal, bit for bit, those of the backward that built them
+    again from the same operands. ``dbetas`` sums ``da kk`` over a chunk's
+    tokens, a sum XLA's CPU backend orders by the program around it: there
+    the two kernels differ by a rounding in a few of its entries."""
+    (*operands, pairs, do), static, got = _kernel_run(case)["calls"]["bwd"]
+    want = _rebuilding_scan_bwd(*operands, do, **static)
+    for name, a, b in zip(("dq", "dk", "dv", "dg"), got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    _close(got[4], want[4], rtol=1e-6)
+
+
+def test_the_backward_builds_the_running_sum_and_no_pair_matrix(monkeypatch):
+    """Counted on the traced jaxpr at the kernels' chunk of 64 and 128 key
+    channels: the helper the backward calls for a chunk issues one product,
+    the running sum's triangle, where ``_kda_chunk`` issues that and one a
+    level, seven; and the backward kernel traces without ``_kda_chunk``."""
+    from shallowspeed_tpu import pallas_ops as K
+
+    c, d = 64, 128
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    dot = K._gdn_dot(HIGHEST)
+
+    def products(fn, *shapes):
+        return sum(e.primitive.name == "dot_general" for e in jax.make_jaxpr(fn)(*shapes).eqns)
+
+    assert products(lambda gl, p: K._kda_decays(gl, p, dot), shape(c, d), shape(8, c)) == 1
+    chunk = lambda q, k, gl, p: K._kda_chunk(q, k, gl, p, jnp.ones((c, 1)), dot, dot)  # noqa: E731
+    assert products(chunk, shape(c, d), shape(c, d), shape(c, d), shape(8, c)) == 1 + 6
+
+    def refused(*_):
+        raise AssertionError("the backward built the pair matrices again")
+
+    monkeypatch.setattr(K, "_kda_chunk", refused)
+    tokens = shape(1, c, 8, d)
+    jax.make_jaxpr(functools.partial(K.kda_scan_bwd, precision=HIGHEST, interpret=True))(
+        tokens, tokens, tokens, tokens, shape(1, 1, 8, c), shape(1, 1, 8, c),
+        shape(8, 1, d, d), shape(8, c, c), shape(8, c, 2 * c), tokens,
+    )
+    with pytest.raises(AssertionError, match="built the pair matrices"):
+        jax.make_jaxpr(
+            functools.partial(K.kda_scan_fwd, leaf=16, precision=HIGHEST, interpret=True)
+        )(tokens, tokens, tokens, tokens, shape(1, 1, 8, c), shape(1, 1, 8, c))
 
 
 @functools.lru_cache(maxsize=None)

@@ -272,6 +272,7 @@ def test_one_microbatch_against_two(token_set, trained):
 def test_session_leaves_the_sets_counts_with_the_program(token_set, trained):
     counts = dict(scopes.program_counts("jit_epoch_core"))
     assert counts.pop("scan_kernel_calls") == 0  # 6 and 12 wide: the XLA form
+    assert counts.pop("scan_pairs_read") == 0
     assert counts.pop("recomputed_layer_passes") == 0  # 96 tokens keep every layer
     assert counts.pop("acc_inplace_leaf_passes") == 0  # no routed layer
     assert counts == packed_counts(token_set[2]) == ref.packed_counts(token_set[2])
@@ -350,6 +351,8 @@ def test_scan_path_event_and_the_programs_count(on_kernels):
     assert on_kernels["counts"]["scan_kernel_calls"] == calls
     assert on_kernels["counts"]["recomputed_layer_passes"] == recomputed * 2
     assert on_kernels["counts"]["tokens"] == 2 * SCAN_SEQ
+    # the scalar rule's backward rebuilds what it needs: no pairs are kept
+    assert event["pairs_read_per_step"] == on_kernels["counts"]["scan_pairs_read"] == 0
 
 
 @pytest.mark.parametrize(
