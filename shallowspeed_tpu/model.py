@@ -32,7 +32,7 @@ import dataclasses
 import json
 import math
 from pathlib import Path
-from typing import Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax.numpy as jnp
 from jax import lax
@@ -93,10 +93,6 @@ class ModelSpec:
     @property
     def out_dim(self):
         return self.sizes[-1]
-
-    @property
-    def has_residual(self):
-        return any(any(s.res_flags) for s in self.stages)
 
 
 def partition_sizes(sizes: Sequence[int], n_stages: int):
@@ -243,11 +239,6 @@ def _init_stages(stages, metrics):
         [dict(zip(("W", "b"), next(drawn))) for _ in range(s.n_linears)]
         for s in stages
     ]
-
-
-def init_stage_params(spec: StageSpec, metrics=None):
-    """Host-side deterministic init for one stage; list of {"W","b"} numpy."""
-    return _init_stages([spec], metrics)[0]
 
 
 def init_model(spec: ModelSpec, metrics=None):
@@ -411,21 +402,27 @@ def model_backward(
 # ---------------------------------------------------------------------------
 # Token models: a function of token ids, built from the keys of a published
 # ``config.json`` (not from a tuple of ``sizes``), each family from ITS keys.
-# Two families (``model_type``):
+# A layer is a residual form around a mixer and a feed-forward, each a
+# ``Part``; a family (``model_type``) is a ``Family``: the keys it reads and
+# the parts it uses. ``MIXERS`` holds a part a layer kind (the names of
+# ``layer_types``), ``FAMILIES`` a family; nothing outside this module names
+# either. A third family is a ``FAMILIES`` entry, a part for each mechanism
+# it adds and a ``TOKEN_MODELS`` line (ARCHITECTURE.md).
 #
 # ``olmo_hybrid``: layers of Gated DeltaNet (``linear_attention``) and of full
-# attention in the pattern ``layer_types`` gives, norms on the branches
-# (``h = x + norm(mix(x))``), a dense SwiGLU. Written out in
-# benchmarks/references/olmo_hybrid.py.
+# attention in the pattern ``layer_types`` gives, the norms on the branches
+# (``BRANCH_NORM``: ``h = x + norm(mix(x))``), a dense SwiGLU (``SWIGLU``).
+# Written out in benchmarks/references/olmo_hybrid.py.
 #
 # ``solar_open2``: layers of Kimi-style delta attention with a decay per key
 # channel (``kda``) and of gated grouped-query attention without a positional
 # term (``gqa``, at the indices ``gqa_layers`` gives), pre-norm residuals
-# (``h = x + mix(norm(x))``), and in every layer a routed mixture of SwiGLU
-# experts with a shared expert. The router scores all ``n_routed_experts``;
-# this chip HOLDS the range ``routed_experts_held = [lo, hi)`` of them and
-# computes their part of the result (the others are other chips'; nothing
-# stands in for them). Written out in benchmarks/references/solar_open2.py.
+# (``PRE_NORM``: ``h = x + mix(norm(x))``), and in every layer a routed
+# mixture of SwiGLU experts with a shared expert (``ROUTED``). The router
+# scores all ``n_routed_experts``; this chip HOLDS the range
+# ``routed_experts_held = [lo, hi)`` of them and computes their part of the
+# result (the others are other chips'; nothing stands in for them). Written
+# out in benchmarks/references/solar_open2.py.
 #
 # Both: an embedding, a final RMSNorm, an untied head, mean cross-entropy
 # over the (sliced) vocabulary. The sequential path only (trainer.py); the
@@ -433,9 +430,9 @@ def model_backward(
 # R0a, D2).
 #
 # Parameters: ONE stage whose layers are dictionaries of arrays: the
-# embedding ``{"E"}``, one dictionary per layer, the head ``{"norm", "W"}``.
-# Weights are (out, in), as every Linear's; the held experts' are stacked,
-# (held, out, in).
+# embedding ``{"E"}``, one dictionary per layer (its parts' leaves), the head
+# ``{"norm", "W"}``. Weights are (out, in), as every Linear's; the held
+# experts' are stacked, (held, out, in).
 # ---------------------------------------------------------------------------
 
 # name -> the configuration file, relative to the checkout: the published
@@ -445,34 +442,6 @@ TOKEN_MODELS = {
     "solar-open2-250b": "benchmarks/configs/solar-open2-250b.json",
 }
 
-# per family: the keys it reads, and what a config.json may say beside them
-# with the one value this code covers
-_TOKEN_KEYS = {
-    "olmo_hybrid": (
-        "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
-        "num_attention_heads", "num_key_value_heads", "rms_norm_eps", "layer_types",
-        "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
-        "linear_value_head_dim", "linear_conv_kernel_dim", "linear_allow_neg_eigval",
-    ),
-    "solar_open2": (
-        "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
-        "head_dim", "num_key_value_heads", "rms_norm_eps", "gqa_layers",
-        "linear_attn_config", "kda_allow_neg_eigval", "n_routed_experts",
-        "routed_experts_held", "n_shared_experts", "num_experts_per_tok",
-        "moe_intermediate_size", "norm_topk_prob", "routed_scaling_factor",
-    ),
-}
-_TOKEN_FIXED = {
-    "olmo_hybrid": {
-        "hidden_act": "silu", "attention_bias": False, "tie_word_embeddings": False,
-    },
-    "solar_open2": {
-        "tie_word_embeddings": False, "use_rope": False, "use_gqa_gate": True,
-        "kda_use_full_proj": False, "first_k_dense_replace": 0,
-    },
-}
-# which scan a layer kind runs, where it runs one
-_SCAN_KINDS = ("linear_attention", "kda")
 # residual bytes of one microbatch above which a layer's forward is run
 # again in the backward instead of kept
 _RECOMPUTE_ABOVE_BYTES = 1 << 30
@@ -494,30 +463,34 @@ def token_model_config(model):
 
 @dataclasses.dataclass(frozen=True)
 class TokenModelSpec:
-    """Static description of a token model and the job's shape. The fields
-    after ``attn_block`` are the second family's; a family without experts
-    holds none (``experts_held == (0, 0)``)."""
+    """Static description of a token model and the job's shape, each group of fields
+    under what reads it; a family without experts holds none (``experts_held == (0, 0)``)."""
 
+    # every family
     vocab_size: int
     hidden_size: int
-    intermediate_size: int  # the dense SwiGLU's width; a routed expert's
-    layer_types: tuple
+    intermediate_size: int  # the feed-forward's width: SWIGLU's, a routed expert's
+    layer_types: tuple  # per layer, its kind: a key of ``MIXERS``
     num_attention_heads: int
     rms_norm_eps: float
+    # the mixers that scan (``linear_attention``, ``kda``)
     linear_num_heads: int
     linear_key_head_dim: int
     linear_value_head_dim: int
     linear_conv_kernel_dim: int
     linear_allow_neg_eigval: bool
+    # the job
     seq_len: int
     global_batch_size: int
     recompute: bool = True  # run forwards again in the backward (``recomputed``)
     scan_chunk: int = ops.SCAN_CHUNK
     attn_block: int = ops.ATTN_BLOCK
-    family: str = "olmo_hybrid"
+    family: str = "olmo_hybrid"  # a key of ``FAMILIES``
+    # the ``gqa`` and ``kda`` mixers
     num_key_value_heads: int = 0  # 0: as many as query heads
     head_dim: int = 0  # 0: hidden_size // num_attention_heads
     gate_rank: int = 0  # the low-rank decay and output-gate projections' rank
+    # ROUTED
     n_routed_experts: int = 0  # the router's width, as published
     experts_held: tuple = (0, 0)  # [lo, hi) of them are this chip's
     num_experts_per_tok: int = 0
@@ -552,6 +525,12 @@ class TokenModelSpec:
         last = len(self.layer_types) - 1
         return tuple(self.recompute and i < last for i in range(last + 1))
 
+    @property
+    def layer_records(self):
+        """Per layer, its mixer's record and its feed-forward's."""
+        ffn = FAMILIES[self.family].ffn
+        return tuple((MIXERS[kind], ffn) for kind in self.layer_types)
+
 
 def token_scan_plan(spec: "TokenModelSpec", mubatches):
     """Which form the recurrent layers' scan runs at this spec's shapes and
@@ -559,43 +538,24 @@ def token_scan_plan(spec: "TokenModelSpec", mubatches):
     layers' passes (a forward, the forward again where the layer is
     recomputed, a backward; none where the XLA form runs), and how many
     layers of any kind are recomputed (``spec.recomputed``), and the
-    backward launches that read the pair matrices their forward kept (the
-    per-channel rule's kernels: one a scan layer and microbatch). Each rule
-    has a kernel form where its shapes tile, and the op's own rule says
-    where: the Gated DeltaNet's ``ops.scan_path``, the per-channel rule's
-    ``ops.kda_scan_path`` (whose kernels choose their chunk). -> the
-    ``scan_path`` event's fields."""
-    chunk = ops._block_len(spec.seq_len, spec.scan_chunk)
-    shapes = (spec.linear_key_head_dim, spec.linear_value_head_dim, jnp.float32)
-    if "kda" in spec.layer_types:
-        path = ops.kda_scan_path(spec.seq_len, spec.linear_num_heads, *shapes)
-        if path == "pallas":
-            chunk = ops.KDA_KERNEL_CHUNK
-    else:
-        path = ops.scan_path(spec.seq_len, spec.scan_chunk, *shapes)
-    passes = sum(
-        3 if again else 2
-        for kind, again in zip(spec.layer_types, spec.recomputed)
-        if kind in _SCAN_KINDS
-    )
+    backward launches that read the pair matrices their forward kept (one a
+    ``reads_pairs`` layer and microbatch). Each rule has a kernel form where
+    its shapes tile, and the first scan layer's rule (``Part.scan``) says
+    where and which chunk. -> the ``scan_path`` event's fields."""
+    mixers = [MIXERS[kind] for kind in spec.layer_types]
+    rules = [mixer.scan for mixer in mixers if mixer.scan]
+    path, chunk = (rules[0] if rules else _delta_scan)(spec)
+    passes = sum(3 if again else 2 for m, again in zip(mixers, spec.recomputed) if m.scan)
+    pallas = path == "pallas"
     return {
         "path": path,
         "chunk": chunk,
         "d_k": spec.linear_key_head_dim,
         "d_v": spec.linear_value_head_dim,
-        "kernel_calls_per_step": mubatches * passes if path == "pallas" else 0,
+        "kernel_calls_per_step": mubatches * passes if pallas else 0,
         "recomputed_layers": sum(spec.recomputed),
-        "pairs_read_per_step": (
-            mubatches * spec.layer_types.count("kda") if path == "pallas" else 0
-        ),
+        "pairs_read_per_step": mubatches * sum(m.reads_pairs for m in mixers) if pallas else 0,
     }
-
-
-def _layer_residual_bytes(widest, tokens):
-    """Float32 bytes one layer keeps between its forward and its backward
-    where nothing is recomputed, roughly: the widest of its intermediates,
-    a handful of times."""
-    return 4 * 8 * widest * tokens
 
 
 def _olmo_spec_fields(config):
@@ -646,7 +606,6 @@ def _solar_spec_fields(config):
         raise ValueError("num_experts_per_tok exceeds n_routed_experts")
     head_dim = linear["head_dim"]
     return dict(
-        family="solar_open2",
         intermediate_size=config["moe_intermediate_size"],
         layer_types=tuple(
             "gqa" if i in gqa else "kda" for i in range(config["num_hidden_layers"])
@@ -670,9 +629,6 @@ def _solar_spec_fields(config):
     ), max(2 * config["hidden_size"], linear["num_heads"] * head_dim)
 
 
-_SPEC_FIELDS = {"olmo_hybrid": _olmo_spec_fields, "solar_open2": _solar_spec_fields}
-
-
 def make_token_spec(
     config, seq_len, global_batch_size, mubatch_rows=None, recompute=None
 ) -> TokenModelSpec:
@@ -682,27 +638,27 @@ def make_token_spec(
     microbatch's residuals of all layers stay under
     ``_RECOMPUTE_ABOVE_BYTES``."""
     family = config.get("model_type", "olmo_hybrid")
-    if family not in _TOKEN_KEYS:
+    if family not in FAMILIES:
         raise ValueError(
-            f"token models cover model_type {sorted(_TOKEN_KEYS)}, got {family!r}"
+            f"token models cover model_type {sorted(FAMILIES)}, got {family!r}"
         )
-    missing = [k for k in _TOKEN_KEYS[family] if k not in config]
+    missing = [k for k in FAMILIES[family].keys if k not in config]
     if missing:
         raise ValueError(f"token model configuration lacks {missing}")
-    for key, value in _TOKEN_FIXED[family].items():
+    for key, value in FAMILIES[family].fixed.items():
         if config.get(key, value) != value:
             raise ValueError(f"{family} is covered with {key}={value!r} only, got {config[key]!r}")
     if seq_len is None or seq_len < 1:
         raise ValueError("a token model needs seq_len (train.py --seq-len)")
-    fields, widest = _SPEC_FIELDS[family](config)
+    fields, widest = FAMILIES[family].spec_fields(config)
     if len(fields["layer_types"]) != config["num_hidden_layers"]:
         raise ValueError("layer_types does not list num_hidden_layers layers")
     if recompute is None:
-        rows = mubatch_rows or global_batch_size
-        recompute = (
-            len(fields["layer_types"]) * _layer_residual_bytes(widest, rows * seq_len)
-            > _RECOMPUTE_ABOVE_BYTES
-        )
+        # float32 bytes the layers keep between forward and backward where
+        # nothing is recomputed, roughly: a layer's widest intermediate a
+        # handful of times for each token of a microbatch
+        kept = 4 * 8 * widest * (mubatch_rows or global_batch_size) * seq_len
+        recompute = len(fields["layer_types"]) * kept > _RECOMPUTE_ABOVE_BYTES
     return TokenModelSpec(
         vocab_size=config["vocab_size"],
         hidden_size=config["hidden_size"],
@@ -711,74 +667,19 @@ def make_token_spec(
         seq_len=int(seq_len),
         global_batch_size=int(global_batch_size),
         recompute=bool(recompute),
+        family=family,
         **fields,
     )
 
 
 def token_layer_shapes(spec: TokenModelSpec):
-    """The model's layers in order, each ``{leaf name: (shape, kind)}``;
-    ``kind`` picks the leaf's initial values (``init.token_leaf_init``)."""
-    d, ff, v = spec.hidden_size, spec.intermediate_size, spec.vocab_size
-    h, dk, dv = spec.linear_num_heads, spec.linear_key_head_dim, spec.linear_value_head_dim
-    taps = spec.linear_conv_kernel_dim
-    norms = {"attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones")}
-    if spec.family == "solar_open2":
-        q_width, kv_width = (
-            spec.num_attention_heads * spec.attn_head_dim, spec.kv_heads * spec.attn_head_dim
-        )
-        held, rank, shared = spec.experts_held[1] - spec.experts_held[0], spec.gate_rank, spec.shared_size
-        moe = {
-            **norms,
-            "W_r": ((spec.n_routed_experts, d), "weight"),
-            "W1": ((held, ff, d), "weight"), "W3": ((held, ff, d), "weight"),
-            "W2": ((held, d, ff), "weight"),
-            "Ws1": ((shared, d), "weight"), "Ws3": ((shared, d), "weight"),
-            "Ws2": ((d, shared), "weight"),
-        }
-        kinds = {
-            "kda": {
-                "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
-                "Wv": ((h * dv, d), "weight"), "Wo": ((d, h * dv), "weight"),
-                "Wb": ((h, d), "weight"),
-                "W_fa": ((rank, d), "weight"), "W_fb": ((h * dk, rank), "weight"),
-                "W_ga": ((rank, d), "weight"), "W_gb": ((h * dv, rank), "weight"),
-                "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
-                "conv_v": ((h * dv, taps), "taps"),
-                "A_log": ((h,), "a_log"), "dt_bias": ((h * dk,), "dt_bias"),
-                "o_norm": ((dv,), "ones"), **moe,
-            },
-            "gqa": {
-                "Wq": ((q_width, d), "weight"), "Wk": ((kv_width, d), "weight"),
-                "Wv": ((kv_width, d), "weight"), "Wz": ((q_width, d), "weight"),
-                "Wo": ((d, q_width), "weight"), **moe,
-            },
-        }
-    else:
-        mlp = {
-            **norms,
-            "W_gate": ((ff, d), "weight"), "W_up": ((ff, d), "weight"),
-            "W_down": ((d, ff), "weight"),
-        }
-        kinds = {
-            "linear_attention": {
-                "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
-                "Wv": ((h * dv, d), "weight"), "Wg": ((h * dv, d), "weight"),
-                "Wo": ((d, h * dv), "weight"),
-                "Wb": ((h, d), "weight"), "Wa": ((h, d), "weight"),
-                "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
-                "conv_v": ((h * dv, taps), "taps"),
-                "A_log": ((h,), "a_log"), "dt_bias": ((h,), "dt_bias"),
-                "o_norm": ((dv,), "ones"), **mlp,
-            },
-            "full_attention": {
-                "Wq": ((d, d), "weight"), "Wk": ((d, d), "weight"),
-                "Wv": ((d, d), "weight"), "Wo": ((d, d), "weight"),
-                "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"), **mlp,
-            },
-        }
+    """The model's layers in order, each ``{leaf name: (shape, kind)}`` (a layer's:
+    its parts'); ``kind`` picks the leaf's initial values (``init.token_leaf_init``)."""
+    d, v, family = spec.hidden_size, spec.vocab_size, FAMILIES[spec.family]
+    form, ffn = family.form.leaves(spec), family.ffn.leaves(spec)
     return (
         [{"E": ((v, d), "weight")}]
-        + [kinds[kind] for kind in spec.layer_types]
+        + [{**MIXERS[kind].leaves(spec), **form, **ffn} for kind in spec.layer_types]
         + [{"norm": ((d,), "ones"), "W": ((v, d), "weight")}]
     )
 
@@ -804,6 +705,36 @@ def init_token_model(spec: TokenModelSpec, metrics=None):
 def _heads(x, heads):
     """(rows, seq, heads * d) -> (rows, seq, heads, d)."""
     return x.reshape(*x.shape[:2], heads, -1)
+
+
+def _delta_scan(spec):
+    """The Gated DeltaNet's rule, ``ops.scan_path`` -> ``(path, chunk)``."""
+    shapes = (spec.linear_key_head_dim, spec.linear_value_head_dim, jnp.float32)
+    path = ops.scan_path(spec.seq_len, spec.scan_chunk, *shapes)
+    return path, ops._block_len(spec.seq_len, spec.scan_chunk)
+
+
+def _channel_scan(spec):
+    """The per-channel rule's, ``ops.kda_scan_path``, whose kernels take
+    chunks of ``ops.KDA_KERNEL_CHUNK`` tokens."""
+    shapes = (spec.linear_key_head_dim, spec.linear_value_head_dim, jnp.float32)
+    path = ops.kda_scan_path(spec.seq_len, spec.linear_num_heads, *shapes)
+    if path == "pallas":
+        return path, ops.KDA_KERNEL_CHUNK
+    return path, ops._block_len(spec.seq_len, spec.scan_chunk)
+
+
+def _linear_attention_leaves(spec):
+    d, h, taps = spec.hidden_size, spec.linear_num_heads, spec.linear_conv_kernel_dim
+    dk, dv = spec.linear_key_head_dim, spec.linear_value_head_dim
+    return {
+        "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
+        "Wv": ((h * dv, d), "weight"), "Wg": ((h * dv, d), "weight"),
+        "Wo": ((d, h * dv), "weight"), "Wb": ((h, d), "weight"), "Wa": ((h, d), "weight"),
+        "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
+        "conv_v": ((h * dv, taps), "taps"), "A_log": ((h,), "a_log"),
+        "dt_bias": ((h,), "dt_bias"), "o_norm": ((dv,), "ones"),
+    }
 
 
 def _linear_attention_mix(p, x, seg, spec, precision):
@@ -852,6 +783,15 @@ def _linear_attention_mix(p, x, seg, spec, precision):
     return out, back
 
 
+def _full_attention_leaves(spec):
+    d = spec.hidden_size
+    return {
+        "Wq": ((d, d), "weight"), "Wk": ((d, d), "weight"),
+        "Wv": ((d, d), "weight"), "Wo": ((d, d), "weight"),
+        "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"),
+    }
+
+
 def _full_attention_mix(p, x, seg, spec, precision):
     """Full attention with QK-norm over the whole projection, no rotary
     embedding, masked by document. -> ``(out, back)``."""
@@ -887,6 +827,21 @@ def _full_attention_mix(p, x, seg, spec, precision):
         return ops.fan_in(dx_q, dx_k, dx_v), grads
 
     return out, back
+
+
+def _kda_leaves(spec):
+    d, h, taps = spec.hidden_size, spec.linear_num_heads, spec.linear_conv_kernel_dim
+    dk, dv, rank = spec.linear_key_head_dim, spec.linear_value_head_dim, spec.gate_rank
+    return {
+        "Wq": ((h * dk, d), "weight"), "Wk": ((h * dk, d), "weight"),
+        "Wv": ((h * dv, d), "weight"), "Wo": ((d, h * dv), "weight"),
+        "Wb": ((h, d), "weight"),
+        "W_fa": ((rank, d), "weight"), "W_fb": ((h * dk, rank), "weight"),
+        "W_ga": ((rank, d), "weight"), "W_gb": ((h * dv, rank), "weight"),
+        "conv_q": ((h * dk, taps), "taps"), "conv_k": ((h * dk, taps), "taps"),
+        "conv_v": ((h * dv, taps), "taps"), "A_log": ((h,), "a_log"),
+        "dt_bias": ((h * dk,), "dt_bias"), "o_norm": ((dv,), "ones"),
+    }
 
 
 def _kda_mix(p, x, seg, spec, precision):
@@ -936,6 +891,15 @@ def _kda_mix(p, x, seg, spec, precision):
         return ops.fan_in(*parts), grads
 
     return out, back
+
+
+def _gqa_leaves(spec):
+    d, hd = spec.hidden_size, spec.attn_head_dim
+    q, kv = spec.num_attention_heads * hd, spec.kv_heads * hd
+    return {
+        "Wq": ((q, d), "weight"), "Wk": ((kv, d), "weight"), "Wv": ((kv, d), "weight"),
+        "Wz": ((q, d), "weight"), "Wo": ((d, q), "weight"),
+    }
 
 
 def _gqa_mix(p, x, seg, spec, precision):
@@ -989,17 +953,54 @@ def _summed(acc, grads, fresh=False):
         }
 
 
+def _swiglu_leaves(spec):
+    d, ff = spec.hidden_size, spec.intermediate_size
+    gate, down = ((ff, d), "weight"), ((d, ff), "weight")
+    return {"W_gate": gate, "W_up": gate, "W_down": down}
+
+
+def _swiglu_ffn(p, x, spec, precision, census=None, names=("W_gate", "W_up", "W_down")):
+    """The dense SwiGLU, ``W_down(silu(W_gate x) * W_up x)``, of the leaves
+    ``names``."""
+    gate_w, up_w, down_w = names
+    gate, gate_back = ops.dense(x, p[gate_w], precision)
+    up, up_back = ops.dense(x, p[up_w], precision)
+    act, act_back = ops.swiglu(gate, up)
+    down, down_back = ops.dense(act, p[down_w], precision)
+
+    def back(dout, acc=None, fresh=False):
+        grads = {}
+        dact, grads[down_w] = down_back(dout)
+        dgate, dup = act_back(dact)
+        dx_g, grads[gate_w] = gate_back(dgate)
+        dx_u, grads[up_w] = up_back(dup)
+        return (dx_g, dx_u), grads, {}
+
+    return down, back
+
+
+def _routed_leaves(spec):
+    d, ff, shared = spec.hidden_size, spec.intermediate_size, spec.shared_size
+    held = spec.experts_held[1] - spec.experts_held[0]
+    return {
+        "W_r": ((spec.n_routed_experts, d), "weight"),
+        "W1": ((held, ff, d), "weight"), "W3": ((held, ff, d), "weight"),
+        "W2": ((held, d, ff), "weight"), "Ws1": ((shared, d), "weight"),
+        "Ws3": ((shared, d), "weight"), "Ws2": ((d, shared), "weight"),
+    }
+
+
 def _routed_ffn(p, x, spec, precision, census=None):
     """The routed mixture: the shared expert (a SwiGLU every token takes)
     plus, of the ``num_experts_per_tok`` experts the router picks among all
     ``n_routed_experts``, those this chip holds (``ops.route``,
     ``ops.experts``). ``census`` (a list) gains the (held,) int32 count of
     (token, slot) pairs routed to each held expert. -> ``(out, back)``;
-    ``back(dout, acc=None, fresh=False) -> (dx, grads, made)``: ``made``
-    holds the held experts' leaves ``W1``, ``W3``, ``W2``, made in ``acc``
-    (the layer's accumulator, read as zero where ``fresh``) by
-    ``ops.experts`` and so holding ``acc + gradient``; ``grads`` holds every
-    other leaf's gradient alone."""
+    ``back(dout, acc=None, fresh=False) -> (dx parts, grads, made)``: the
+    parts of ``x``'s cotangent, which the form sums; ``made`` holds the held
+    experts' leaves ``W1``, ``W3``, ``W2``, made in ``acc`` (the layer's
+    accumulator, read as zero where ``fresh``) by ``ops.experts`` and so
+    holding ``acc + gradient``; ``grads`` every other leaf's gradient alone."""
     x2 = x.reshape(-1, x.shape[-1])
     (weights, sel), route_back = ops.route(
         x2, p["W_r"], spec.num_experts_per_tok, spec.norm_topk_prob,
@@ -1011,47 +1012,58 @@ def _routed_ffn(p, x, spec, precision, census=None):
     )
     if census is not None:
         census.append(rows)
-    gate, gate_back = ops.dense(x, p["Ws1"], precision)
-    up, up_back = ops.dense(x, p["Ws3"], precision)
-    act, act_back = ops.swiglu(gate, up)
-    shared, down_back = ops.dense(act, p["Ws2"], precision)
+    shared, shared_back = _swiglu_ffn(p, x, spec, precision, names=("Ws1", "Ws3", "Ws2"))
     out = ops.fan_in(shared, routed.reshape(x.shape))
 
     def back(dout, acc=None, fresh=False):
-        grads = {}
-        dact, grads["Ws2"] = down_back(dout)
-        dgate, dup = act_back(dact)
-        dx_g, grads["Ws1"] = gate_back(dgate)
-        dx_u, grads["Ws3"] = up_back(dup)
-        made = {}
+        (dx_g, dx_u), grads, made = shared_back(dout)
         held_acc = None if acc is None else (acc["W1"], acc["W3"], acc["W2"])
         dx_e, dweights, made["W1"], made["W3"], made["W2"] = experts_back(
             dout.reshape(x2.shape), held_acc, fresh
         )
         dx_r, grads["W_r"] = route_back(dweights)
-        return ops.fan_in(dx_g, dx_u, (dx_e + dx_r).reshape(x.shape)), grads, made
+        return (dx_g, dx_u, (dx_e + dx_r).reshape(x.shape)), grads, made
 
     return out, back
 
 
-_MIXERS = {
-    "linear_attention": _linear_attention_mix, "full_attention": _full_attention_mix,
-    "kda": _kda_mix, "gqa": _gqa_mix,
-}
+def _norm_leaves(spec):
+    d = spec.hidden_size
+    return {"attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones")}
 
 
-def _pre_norm_layer(p, x, seg, kind, spec, precision, census):
-    """``h = x + mix(norm(x))``, ``y = h + moe(norm(h))``."""
-    normed, norm1 = ops.rms_norm(x, p["attn_norm"], spec.rms_norm_eps)
-    mixed, mix_back = _MIXERS[kind](p, normed, seg, spec, precision)
-    hid = ops.fan_in(x, mixed)
-    normed2, norm2 = ops.rms_norm(hid, p["mlp_norm"], spec.rms_norm_eps)
-    routed, ffn_back = _routed_ffn(p, normed2, spec, precision, census)
-    y = ops.fan_in(hid, routed)
+def _branch_norm_layer(p, x, seg, mix, ffn, spec, precision, census):
+    """``h = x + norm(mix(x))``, ``y = h + norm(ffn(h))``."""
+    mixed, mix_back = mix(p, x, seg, spec, precision)
+    hid, add1 = ops.residual_norm(x, mixed, p["attn_norm"], spec.rms_norm_eps)
+    out, ffn_back = ffn(p, hid, spec, precision, census)
+    y, add2 = ops.residual_norm(hid, out, p["mlp_norm"], spec.rms_norm_eps)
 
     def back(dy, acc=None, fresh=False):
-        dnormed2, grads, made = ffn_back(dy, acc, fresh)
-        dhid, grads["mlp_norm"] = norm2(dnormed2)
+        grads = {}
+        dhid, dout, grads["mlp_norm"] = add2(dy)
+        parts, ffn_grads, made = ffn_back(dout, acc, fresh)
+        grads.update(ffn_grads)
+        dx, dmixed, grads["attn_norm"] = add1(ops.fan_in(dhid, *parts))
+        dx_mix, mix_grads = mix_back(dmixed)
+        dx = ops.fan_in(dx, dx_mix)
+        return dx, {**_summed(acc, {**grads, **mix_grads}, fresh), **made}
+
+    return y, back
+
+
+def _pre_norm_layer(p, x, seg, mix, ffn, spec, precision, census):
+    """``h = x + mix(norm(x))``, ``y = h + ffn(norm(h))``."""
+    normed, norm1 = ops.rms_norm(x, p["attn_norm"], spec.rms_norm_eps)
+    mixed, mix_back = mix(p, normed, seg, spec, precision)
+    hid = ops.fan_in(x, mixed)
+    normed2, norm2 = ops.rms_norm(hid, p["mlp_norm"], spec.rms_norm_eps)
+    out, ffn_back = ffn(p, normed2, spec, precision, census)
+    y = ops.fan_in(hid, out)
+
+    def back(dy, acc=None, fresh=False):
+        parts, grads, made = ffn_back(dy, acc, fresh)
+        dhid, grads["mlp_norm"] = norm2(ops.fan_in(*parts))
         dhid = ops.fan_in(dy, dhid)
         dnormed, mix_grads = mix_back(dhid)
         dx, grads["attn_norm"] = norm1(dnormed)
@@ -1061,38 +1073,104 @@ def _pre_norm_layer(p, x, seg, kind, spec, precision, census):
     return y, back
 
 
+class Part(NamedTuple):
+    """A mixer, feed-forward or residual form: ``leaves(spec) -> {name:
+    (shape, init kind)}`` and ``forward`` (``_linear_attention_mix``,
+    ``_routed_ffn``, ``_pre_norm_layer`` show the three signatures); a
+    mixer's FLOPs a token beside its products (``mix_flops(spec,
+    pairs_per_token)``) and the rule that picks its scan's form
+    (``scan(spec) -> (path, chunk)``), whose kernels' backward may read the
+    pair matrices their forward kept (``reads_pairs``)."""
+
+    leaves: Callable
+    forward: Callable
+    mix_flops: Callable = None
+    scan: Callable = None
+    reads_pairs: bool = False
+
+    def flops(self, spec, pairs_per_token):
+        """Training FLOPs a token: 6 a weight of its products (a held expert's times
+        the share of experts a token takes) and ``mix_flops``; no norm or gate."""
+        share = spec.num_experts_per_tok / (spec.n_routed_experts or 1)
+        products = sum(
+            math.prod(shape) * (share if len(shape) == 3 else 1)
+            for shape, kind in self.leaves(spec).values() if kind == "weight"
+        )
+        return 6 * products + (self.mix_flops(spec, pairs_per_token) if self.mix_flops else 0)
+
+
+class Family(NamedTuple):
+    """A ``model_type``: the ``keys`` it reads, those it covers at one value
+    only, ``spec_fields(config) -> (fields, widest)`` refusing the rest."""
+
+    keys: tuple
+    fixed: dict
+    spec_fields: Callable
+    form: Part
+    ffn: Part
+
+
+def _scan_flops(per_head):  # per_head d_k d_v a head forward, twice that backward
+    return lambda s, _: 3 * per_head * s.linear_num_heads * (
+        s.linear_key_head_dim * s.linear_value_head_dim
+    )
+
+
+MIXERS = {
+    "linear_attention": Part(
+        _linear_attention_leaves, _linear_attention_mix, _scan_flops(9), scan=_delta_scan
+    ),
+    "full_attention": Part(
+        _full_attention_leaves, _full_attention_mix,
+        lambda s, pairs: 3 * 4 * s.hidden_size * pairs,
+    ),
+    "kda": Part(_kda_leaves, _kda_mix, _scan_flops(7), scan=_channel_scan, reads_pairs=True),
+    "gqa": Part(
+        _gqa_leaves, _gqa_mix,
+        lambda s, pairs: 3 * 4 * s.num_attention_heads * s.attn_head_dim * pairs,
+    ),
+}
+SWIGLU, ROUTED = Part(_swiglu_leaves, _swiglu_ffn), Part(_routed_leaves, _routed_ffn)
+BRANCH_NORM = Part(_norm_leaves, _branch_norm_layer)
+PRE_NORM = Part(_norm_leaves, _pre_norm_layer)
+FAMILIES = {
+    "olmo_hybrid": Family(
+        keys=(
+            "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
+            "num_attention_heads", "num_key_value_heads", "rms_norm_eps", "layer_types",
+            "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+            "linear_value_head_dim", "linear_conv_kernel_dim", "linear_allow_neg_eigval",
+        ),
+        fixed={"hidden_act": "silu", "attention_bias": False, "tie_word_embeddings": False},
+        spec_fields=_olmo_spec_fields, form=BRANCH_NORM, ffn=SWIGLU,
+    ),
+    "solar_open2": Family(
+        keys=(
+            "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
+            "head_dim", "num_key_value_heads", "rms_norm_eps", "gqa_layers",
+            "linear_attn_config", "kda_allow_neg_eigval", "n_routed_experts",
+            "routed_experts_held", "n_shared_experts", "num_experts_per_tok",
+            "moe_intermediate_size", "norm_topk_prob", "routed_scaling_factor",
+        ),
+        fixed={
+            "tie_word_embeddings": False, "use_rope": False, "use_gqa_gate": True,
+            "kda_use_full_proj": False, "first_k_dense_replace": 0,
+        },
+        spec_fields=_solar_spec_fields, form=PRE_NORM, ffn=ROUTED,
+    ),
+}
+
+
 def token_layer(p, x, seg, kind, spec, precision, census=None):
-    """One layer, by the family's residual form: ``olmo_hybrid``'s ``h = x +
-    norm(mix(x))``, ``y = h + norm(mlp(h))`` (the norms sit on the branches),
-    ``solar_open2``'s pre-norm form with a routed feed-forward
-    (``_pre_norm_layer``; ``census``: see ``_routed_ffn``). -> ``(y,
-    back)``; ``back(dy, acc=None, fresh=False) -> (dx, grads)`` with
-    ``grads`` shaped like ``p``: with ``acc``, the layer's accumulator
-    (shaped like ``p``; read as zero where ``fresh``), every leaf holds
-    ``acc + gradient``, the held experts' made in it in place
-    (``_routed_ffn``)."""
-    if spec.family == "solar_open2":
-        return _pre_norm_layer(p, x, seg, kind, spec, precision, census)
-    mixed, mix_back = _MIXERS[kind](p, x, seg, spec, precision)
-    hid, add1 = ops.residual_norm(x, mixed, p["attn_norm"], spec.rms_norm_eps)
-    gate, gate_back = ops.dense(hid, p["W_gate"], precision)
-    up, up_back = ops.dense(hid, p["W_up"], precision)
-    act, act_back = ops.swiglu(gate, up)
-    down, down_back = ops.dense(act, p["W_down"], precision)
-    y, add2 = ops.residual_norm(hid, down, p["mlp_norm"], spec.rms_norm_eps)
-
-    def back(dy, acc=None, fresh=False):
-        grads = {}
-        dhid, ddown, grads["mlp_norm"] = add2(dy)
-        dact, grads["W_down"] = down_back(ddown)
-        dgate, dup = act_back(dact)
-        dhid_g, grads["W_gate"] = gate_back(dgate)
-        dhid_u, grads["W_up"] = up_back(dup)
-        dx, dmixed, grads["attn_norm"] = add1(ops.fan_in(dhid, dhid_g, dhid_u))
-        dx_mix, mix_grads = mix_back(dmixed)
-        return ops.fan_in(dx, dx_mix), _summed(acc, {**grads, **mix_grads}, fresh)
-
-    return y, back
+    """One layer: the family's form around the kind's mixer and the
+    family's feed-forward (``census``: see ``_routed_ffn``). -> ``(y,
+    back)``; ``back(dy, acc=None, fresh=False) -> (dx, grads)``, ``grads``
+    shaped like ``p``: with ``acc``, the layer's accumulator (read as zero
+    where ``fresh``), every leaf holds ``acc + gradient``."""
+    family = FAMILIES[spec.family]
+    return family.form.forward(
+        p, x, seg, MIXERS[kind].forward, family.ffn.forward, spec, precision, census
+    )
 
 
 def token_loss_and_grads(

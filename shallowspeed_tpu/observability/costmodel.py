@@ -79,47 +79,19 @@ def mlp_train_flops_per_sample(sizes):
 def token_train_flops_per_token(spec, pairs_per_token=None):
     """Analytical training FLOPs per TOKEN of a token model
     (``model.TokenModelSpec``), forward and backward, recomputation not
-    counted: 6 per weight of every matrix product (projections, SwiGLU, the
-    head over the held vocabulary slice), the gated delta rule in its
-    recurrence form (9 d_k d_v per head forward, twice that backward), and
-    attention's two products forward and four backward over the (query, key)
-    pairs the mask admits: ``pairs_per_token``, which depends on how the
-    documents were packed (``data.packed_counts``); the causal mask's
-    ``(seq_len + 1) / 2`` where it is not given. The embedding is a lookup;
-    norms, gates and the convolution are O(width) noise and not counted."""
-    d, ff = spec.hidden_size, spec.intermediate_size
-    h, dk, dv = spec.linear_num_heads, spec.linear_key_head_dim, spec.linear_value_head_dim
+    counted: 6 per weight of the head over the held vocabulary slice (the
+    embedding is a lookup), and each layer's mixer's FLOPs and its
+    feed-forward's, as their records in ``model`` count them
+    (``spec.layer_records``). Attention's depend on the (query, key) pairs
+    the mask admits, ``pairs_per_token``, and so on how the documents were
+    packed (``data.packed_counts``); the causal mask's ``(seq_len + 1) / 2``
+    where it is not given."""
     if pairs_per_token is None:
         pairs_per_token = (spec.seq_len + 1) / 2
-    if spec.family == "solar_open2":
-        # the second family: grouped-query attention with an output gate, the
-        # per-channel rule's low-rank gates, and in every layer the router
-        # (all published experts), the shared expert, and of the
-        # ``num_experts_per_tok`` routed products a token takes the share this
-        # chip holds under even routing (held / published)
-        heads, hd, rank = spec.num_attention_heads, spec.attn_head_dim, spec.gate_rank
-        held = spec.experts_held[1] - spec.experts_held[0]
-        ffn = (
-            d * spec.n_routed_experts + 3 * d * spec.shared_size
-            + 3 * d * ff * spec.num_experts_per_tok * held / spec.n_routed_experts
-        )
-        per_layer = {
-            "gqa": 6 * (d * hd * (3 * heads + 2 * spec.kv_heads) + ffn)
-            + 3 * 4 * heads * hd * pairs_per_token,
-            "kda": 6 * (
-                d * (2 * h * dk + h * dv) + h * dv * d + d * h
-                + rank * (2 * d + h * dk + h * dv) + ffn
-            ) + 3 * 7 * h * dk * dv,  # the per-channel rule: 7 d_k d_v forward
-        }
-    else:
-        mlp = 3 * d * ff
-        per_layer = {
-            "full_attention": 6 * (4 * d * d + mlp) + 3 * 4 * d * pairs_per_token,
-            "linear_attention": 6 * (
-                d * (2 * h * dk + 2 * h * dv) + h * dv * d + 2 * d * h + mlp
-            ) + 3 * 9 * h * dk * dv,
-        }
-    return 6 * d * spec.vocab_size + sum(per_layer[kind] for kind in spec.layer_types)
+    return 6 * spec.hidden_size * spec.vocab_size + sum(
+        mixer.flops(spec, pairs_per_token) + ffn.flops(spec, pairs_per_token)
+        for mixer, ffn in spec.layer_records
+    )
 
 
 def token_train_flops_per_sample(spec, pairs_per_token=None):

@@ -100,12 +100,6 @@ def gelu_grad_mult(z):
     return 0.5 * (1.0 + lax.erf(z * _INV_SQRT2)) + z * phi
 
 
-@scoped("act")
-def gelu_grad(g, z):
-    """VJP of gelu given the cached pre-activation z."""
-    return g * gelu_grad_mult(z)
-
-
 @scoped("linear/fwd")
 def linear(x, w, b, precision=DEFAULT_PRECISION):
     """y = x @ w.T + b with w: (out, in), b: (1, out) or (out,), or ``None``

@@ -174,9 +174,3 @@ def assert_dp_replicas_in_sync_global(arr) -> None:
         raise ValueError(
             f"cross-process replica desync at (leaf, shard-index): {mismatches}"
         )
-
-
-def p0print(*args, **kwargs):
-    """Print from process 0 only (reference rprint, utils.py:8-10)."""
-    if jax.process_index() == 0:
-        print(*args, **kwargs)

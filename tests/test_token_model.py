@@ -36,6 +36,7 @@ def _load(path, name):
 
 ref = _load(ROOT / "benchmarks" / "references" / "olmo_hybrid.py", "ref_olmo_hybrid")
 check = _load(ROOT / "benchmarks" / "check.py", "bench_check")
+solar_ref = _load(ROOT / "benchmarks" / "references" / "solar_open2.py", "ref_solar_open2_cost")
 
 TINY = dict(
     model_type="olmo_hybrid", vocab_size=96, hidden_size=32, intermediate_size=48,
@@ -497,12 +498,23 @@ def test_init_is_seeded_by_layer_and_leaf():
     assert np.all((decay > 0.1) & (decay < 1.0))
 
 
-def test_cost_model_counts_what_the_reference_counts():
-    config = {**TINY, "session": {"model": TINY, "seq_len": SEQ}}
-    spec = Mo.make_token_spec(TINY, SEQ, 2)
-    for pairs in (3.0, 20.5):
-        assert costmodel.token_train_flops_per_sample(spec, pairs) == pytest.approx(
-            ref.train_flops_per_sample(config, pairs)
+@pytest.mark.parametrize(
+    "reference,model,seq,pairs,rel",
+    [
+        (ref, TINY, SEQ, (3.0, 20.5), None),
+        # the second family's named configuration, as tests/test_solar_model.py
+        # checks it: the session's mfu and the benchmark's divide the same count
+        (solar_ref, "solar-open2-250b", 2048, (500.0, 600.0), 1e-12),
+    ],
+    ids=["olmo-tiny", "solar-open2-250b"],
+)
+def test_cost_model_counts_what_the_reference_counts(reference, model, seq, pairs, rel):
+    config = Mo.token_model_config(model)
+    spec = Mo.make_token_spec(config, seq, 2, mubatch_rows=1)
+    session = {**config, "session": {"model": config, "seq_len": seq}}
+    for p in pairs:
+        assert costmodel.token_train_flops_per_sample(spec, p) == pytest.approx(
+            reference.train_flops_per_sample(session, p), rel=rel
         )
     assert costmodel.train_flops_per_sample(spec) == costmodel.token_train_flops_per_sample(spec)
 
